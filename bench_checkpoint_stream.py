@@ -218,6 +218,9 @@ def _pop_opt(argv, name):
 
 
 def main():
+    from paddle_tpu.serving.compile_cache import enable_compile_cache
+
+    enable_compile_cache()   # the one decision where the cache lives
     argv = sys.argv[1:]
     argv, gang = _pop_opt(argv, "--gang")
     argv, steps = _pop_opt(argv, "--steps")

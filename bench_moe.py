@@ -133,6 +133,9 @@ def run_dispatch():
 
 
 if __name__ == "__main__":
+    from paddle_tpu.serving.compile_cache import enable_compile_cache
+
+    enable_compile_cache()   # the one decision where the cache lives
     which = sys.argv[1:] or ["moe", "dense", "dispatch"]
     for w in which:
         if w == "dispatch":

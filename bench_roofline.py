@@ -1,6 +1,6 @@
 """Decompose the 7B int8 decode step against its weight-read roofline.
 
-Round-5 VERDICT #5: BASELINE.md quotes 10.09 ms/step vs an 8.39 ms
+An older review (removed in PR 22) quoted 10.09 ms/step vs an 8.39 ms
 weight-read bound (83%) and never explains the ~1.7 ms residual. This
 bench isolates the non-weight terms by ablation on a DECODE-ONLY
 program (a fori_loop of _make_decode_step with a traced trip count —
@@ -184,6 +184,9 @@ def measure_paged(name, block_size: int = 64):
 
 
 if __name__ == "__main__":
+    from paddle_tpu.serving.compile_cache import enable_compile_cache
+
+    enable_compile_cache()   # the one decision where the cache lives
     for nm in (sys.argv[1:] or ["7b_int8"]):
         if nm.endswith("_paged"):
             measure_paged(nm[:-len("_paged")])

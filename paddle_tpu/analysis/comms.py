@@ -356,13 +356,10 @@ def _norm_named_sharding(s, ndim: int):
     return _trim(tuple(entries[:max(ndim, len(entries))])), sizes
 
 
-def _norm_names_dict(d, ndim: int, sizes: Dict[str, int]):
-    """(spec, axis_sizes) from a shard_map in_names/out_names entry
-    ({dim: (axes, ...)})."""
-    entries = [()] * ndim
-    for dim, names in dict(d).items():
-        if 0 <= int(dim) < ndim:
-            entries[int(dim)] = _norm_entry(names)
+def _norm_partition_spec(spec, ndim: int, sizes: Dict[str, int]):
+    """(spec, axis_sizes) from a shard_map in_specs/out_specs entry (a
+    PartitionSpec)."""
+    entries = [_norm_entry(e) for e in tuple(spec)][:ndim]
     return _trim(tuple(entries)), sizes
 
 
@@ -401,7 +398,7 @@ class _CommsAuditor:
     """One walk over a closed jaxpr: collect communication events with
     axis-size resolution (shard_map meshes), loop amplification (scan
     lengths), and boundary-sharding tracking (pjit in/out_shardings,
-    shard_map in/out_names) for implicit-reshard detection."""
+    shard_map in/out_specs) for implicit-reshard detection."""
 
     def __init__(self, closed_jaxpr, name: str):
         self.closed = closed_jaxpr
@@ -410,7 +407,7 @@ class _CommsAuditor:
         self.mp = 1
         # id(var) -> (normalized spec, axis sizes) where a producer
         # declared the sharding (pjit out_shardings / shard_map
-        # out_names); program inputs are unknown, so the engine's
+        # out_specs); program inputs are unknown, so the engine's
         # jit(shard_map(...)) top level never false-positives
         self._specs: Dict[int, tuple] = {}
 
@@ -427,7 +424,7 @@ class _CommsAuditor:
             where = f"{path}/eqn[{i}]:{prim}"
             if prim in COLLECTIVE_PRIMS:
                 self._collective(eqn, where, axes, trip, in_loop)
-            elif prim == "pjit":
+            elif prim == "jit":
                 self._pjit(eqn, path, where, axes, trip, in_loop)
             elif prim == "scan":
                 sub = eqn.params["jaxpr"]
@@ -532,19 +529,17 @@ class _CommsAuditor:
             self.mp = max(self.mp, int(mesh.size))
         except Exception:
             sizes = {}
-        for v, names in zip(eqn.invars,
-                            eqn.params.get("in_names") or ()):
+        for v, spec in zip(eqn.invars, eqn.params["in_specs"]):
             nd = len(getattr(getattr(v, "aval", None), "shape", ()))
-            self._boundary(v, _norm_names_dict(names, nd, sizes), where,
-                           trip, in_loop)
+            self._boundary(v, _norm_partition_spec(spec, nd, sizes),
+                           where, trip, in_loop)
         sub = eqn.params["jaxpr"]
         self._walk(getattr(sub, "jaxpr", sub),
                    f"{path}/shard_map[jaxpr]", {**axes, **sizes}, trip,
                    in_loop)
-        for v, names in zip(eqn.outvars,
-                            eqn.params.get("out_names") or ()):
+        for v, spec in zip(eqn.outvars, eqn.params["out_specs"]):
             nd = len(getattr(getattr(v, "aval", None), "shape", ()))
-            self._specs[id(v)] = _norm_names_dict(names, nd, sizes)
+            self._specs[id(v)] = _norm_partition_spec(spec, nd, sizes)
 
     def _track_mesh(self, sharding):
         mesh = getattr(sharding, "mesh", None)

@@ -33,8 +33,8 @@ __all__ = [
     "spec_for_device_kind",
 ]
 
-# the repo's baseline serving/training chip — every BASELINE.md and
-# bench bound was derived against it
+# the repo's baseline serving/training chip — every bench bound was
+# derived against it
 DEFAULT_DEVICE = "tpu-v5e"
 
 
@@ -123,17 +123,24 @@ DEVICE_SPECS: Dict[str, DeviceSpec] = {
 
 
 def spec_for_device_kind(kind: str) -> DeviceSpec:
-    """Row for a jax ``device_kind`` string ("TPU v5 lite", "TPU v4",
-    ...). Exactly the switch `bench.py` hardcoded (v6 -> 918e12, else
-    197e12), with the v4/v5p rows it could not express."""
+    """Row for the ``device_kind`` string of an attached device ("TPU
+    v5 lite", "TPU v4", ...). A kind the table does not know raises: a
+    peak taken from the wrong row skews every utilization computed from
+    it, so a device that is not in the table is an error, not a
+    default."""
     k = (kind or "").lower()
     if "v6" in k:
         return DEVICE_SPECS["tpu-v6e"]
     if "v5p" in k:
         return DEVICE_SPECS["tpu-v5p"]
+    if "v5 lite" in k or "v5e" in k or "v5lite" in k:
+        return DEVICE_SPECS["tpu-v5e"]
     if "v4" in k:
         return DEVICE_SPECS["tpu-v4"]
-    return DEVICE_SPECS[DEFAULT_DEVICE]
+    raise KeyError(
+        f"no device spec row for device_kind {kind!r}; rows: "
+        f"{sorted(DEVICE_SPECS)} — add the chip to "
+        f"analysis/device_specs.py with its published peaks")
 
 
 # fraction of a device row's HBM held back from the auto-derived
@@ -161,10 +168,12 @@ def auto_hbm_budget(device: Optional[object] = None, *,
 
 def get_spec(device: Optional[object] = None) -> DeviceSpec:
     """Resolve a `DeviceSpec`: a `DeviceSpec` passes through, a string
-    looks up the table (KeyError lists the rows), and None detects —
-    the live TPU's device_kind when one is attached, else the
-    `DEFAULT_DEVICE` row (prediction targets the serving chip, not the
-    tracing host)."""
+    looks up the table (KeyError lists the rows), and None detects:
+    with a TPU attached, the row of its device_kind (an unknown kind
+    raises, see `spec_for_device_kind`). Only with NO accelerator
+    attached — the static-audit case, tracing on a CPU host to predict
+    for the serving chip — does None mean the documented
+    `DEFAULT_DEVICE` row."""
     if isinstance(device, DeviceSpec):
         return device
     if device is not None:
@@ -174,11 +183,8 @@ def get_spec(device: Optional[object] = None) -> DeviceSpec:
             raise KeyError(
                 f"unknown device spec {device!r}; rows: "
                 f"{sorted(DEVICE_SPECS)}") from None
-    try:
-        import jax
+    import jax
 
-        if jax.default_backend() == "tpu":
-            return spec_for_device_kind(jax.devices()[0].device_kind)
-    except Exception:
-        pass
+    if jax.default_backend() == "tpu":
+        return spec_for_device_kind(jax.devices()[0].device_kind)
     return DEVICE_SPECS[DEFAULT_DEVICE]

@@ -106,7 +106,7 @@ class Graph:
                 continue
             child_in_loop = in_loop or prim in LOOP_PRIMITIVES
             for label, sub in _sub_jaxprs(eqn):
-                tag = prim if prim != "pjit" else _pjit_name(eqn)
+                tag = prim if prim != "jit" else _pjit_name(eqn)
                 self._walk(sub, f"{path}/{tag}[{label}]", depth + 1,
                            child_in_loop, acc)
 

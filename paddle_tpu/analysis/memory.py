@@ -392,7 +392,7 @@ class _Auditor:
             handler = getattr(self, f"_h_{prim.replace('-', '_')}", None)
             if handler is not None:
                 handler(eqn, env, where)
-            elif prim == "pjit":
+            elif prim == "jit":
                 self._h_pjit(eqn, env, where)
             else:
                 subs = _eqn_sub_jaxprs(eqn)
@@ -477,7 +477,7 @@ class _Auditor:
                 env[ov] = bid
 
     def _h_remat2(self, eqn, env, where):
-        # jax 0.4.x names the checkpoint primitive "remat2"; its
+        # jax names the checkpoint primitive "remat2"; its
         # params["jaxpr"] is an OPEN jaxpr, which _h_pjit's
         # getattr(sub, "jaxpr", sub) normalisation already handles
         self._h_pjit(eqn, env, where)
@@ -883,7 +883,7 @@ def _unwrap_trivial_pjit(closed, donated):
     the executable will actually perform."""
     while True:
         jaxpr = closed.jaxpr
-        if len(jaxpr.eqns) != 1 or jaxpr.eqns[0].primitive.name != "pjit":
+        if len(jaxpr.eqns) != 1 or jaxpr.eqns[0].primitive.name != "jit":
             return closed, donated
         eqn = jaxpr.eqns[0]
         if len(eqn.invars) != len(jaxpr.invars) or any(

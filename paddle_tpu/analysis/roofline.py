@@ -538,7 +538,7 @@ class _RooflineAuditor:
         for i, eqn in enumerate(jaxpr.eqns):
             prim = eqn.primitive.name
             where = f"{path}/eqn[{i}]:{prim}"
-            if prim == "pjit":
+            if prim == "jit":
                 self._inline(eqn, eqn.params["jaxpr"], env, where, trip,
                              in_loop)
             elif prim in ("remat", "remat2", "checkpoint"):

@@ -48,6 +48,7 @@ import numpy as np
 
 from ..kernels.constraints import (
     LANE, constraint_for_kernel_fn, min_tile, missing_scale_finding,
+    tensor_operands,
 )
 from .diagnostics import Diagnostic, Severity
 from .graph import EqnCtx, Graph
@@ -197,12 +198,11 @@ class KernelConstraintRule(Rule):
 
 
 def _pallas_kernel_name(eqn):
-    """(fn_name, full_src_string) of a pallas_call's kernel."""
-    info = eqn.params.get("name_and_src_info")
-    name = getattr(info, "name", None)
-    if name:
-        return str(name), str(info)
-    return str(eqn.params.get("name", "")), ""
+    """(fn_name, "fn_name at file:line") of a pallas_call's kernel — the
+    kernel jaxpr's debug info (functools.partial wrappers are already
+    unwrapped there)."""
+    info = eqn.params["jaxpr"].debug_info
+    return str(info.func_name), str(info.func_src_info)
 
 
 # ---------------------------------------------------------------------------
@@ -217,7 +217,7 @@ def _kv_pool_findings(shapes, dtypes):
     the scale-presence check is the SAME
     `kernels.constraints.missing_scale_finding` the q8 kernel checkers
     run, so lint and kernels can never disagree about the layout."""
-    arrs = [(s, d) for s, d in zip(shapes, dtypes) if len(s) >= 3]
+    arrs = tensor_operands(shapes, dtypes)
     if len(arrs) < 3:
         return []
     out = []
@@ -722,7 +722,7 @@ class HostSyncRule(Rule):
     default_severity = Severity.WARNING
 
     CALLBACKS = frozenset({
-        "io_callback", "pure_callback", "debug_callback",
+        "io_callback", "pure_callback", "debug_callback", "debug_print",
         "python_callback", "outside_call",
     })
 

@@ -7,6 +7,8 @@ JAX's bfloat16 extension type, and keep paddle's public names.
 """
 from __future__ import annotations
 
+import contextlib
+
 import numpy as np
 import jax.numpy as jnp
 import ml_dtypes
@@ -94,6 +96,18 @@ def set_default_dtype(dtype):
 
 def get_default_dtype() -> np.dtype:
     return _DEFAULT_DTYPE[0]
+
+
+@contextlib.contextmanager
+def default_dtype(dtype):
+    """The default floating dtype for the duration of the block — layers
+    built inside create their parameters in it directly."""
+    prev = get_default_dtype()
+    set_default_dtype(dtype)
+    try:
+        yield
+    finally:
+        _DEFAULT_DTYPE[0] = prev
 
 
 def is_floating_point(dtype) -> bool:

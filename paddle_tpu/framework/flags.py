@@ -163,10 +163,11 @@ define_flag("unified_step", "auto",
             "program over the ragged_paged_attention kernel, admission "
             "becomes token-budget packing, and long prompts prefill in "
             "chunks so decode latency is immune to prefill bursts. "
-            "'auto' (default) = on off-TPU (interpret-mode parity is "
-            "cheap; silicon default flips with the gated ragged_step "
-            "OPBENCH row), '1'/'0' force. The split-program path stays "
-            "the oracle. Read when the engine is BUILT "
+            "'auto' (default) = on, on every backend: the chip serves "
+            "the step tier-1 exercises (PR 22; before it 'auto' meant "
+            "off on a TPU, so the chip's default was the path the "
+            "tests ran least). '1'/'0' force. The split-program path "
+            "stays the oracle. Read when the engine is BUILT "
             "(also: PADDLE_TPU_UNIFIED_STEP)",
             env_aliases=("PADDLE_TPU_UNIFIED_STEP",))
 
@@ -256,12 +257,13 @@ define_flag("spec_adaptive", False,
 
 define_flag("compile_cache", "",
             "persistent XLA compile-cache directory for the serving "
-            "engine (serving/compile_cache.py): non-empty enables "
-            "jax's compilation cache there at engine build, so a "
-            "fleet restart / elastic scale-out serves warm()'s "
-            "program zoo from disk instead of recompiling "
-            "(warm_compile_stats in engine.metrics() reports cold vs "
-            "warm counts). Empty (default) = off "
+            "engine (serving/compile_cache.py): jax's compilation "
+            "cache lives there from engine build on, so a fleet "
+            "restart / elastic scale-out serves warm()'s program zoo "
+            "from disk instead of recompiling (warm_compile_stats in "
+            "engine.metrics() reports cold vs warm counts). Empty "
+            "(default) = the fixed <checkout>/.jax_cache. Never "
+            "overrides JAX_COMPILATION_CACHE_DIR "
             "(also: PADDLE_TPU_COMPILE_CACHE)",
             env_aliases=("PADDLE_TPU_COMPILE_CACHE",))
 define_flag("tuned_config", "",

@@ -547,7 +547,7 @@ class Model:
         import jax
         from jax.sharding import Mesh, PartitionSpec as P
 
-        from ..parallel.shard_map_compat import shard_map
+        from jax import shard_map
 
         dp_mesh = Mesh(np.asarray(jax.devices()[:dp]), ("dp",))
         p_specs = jax.tree.map(lambda _: P(), params)

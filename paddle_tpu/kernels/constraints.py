@@ -138,18 +138,34 @@ def min_tile(dtype) -> Tuple[int, int]:
     return SUBLANE.get(str(np.dtype(dtype)), 8), LANE
 
 
+def is_scale_operand(shape, dtype) -> bool:
+    """Whether a pallas_call operand is an int8 pool's absmax scale
+    sidecar: a small f32 array — rank <= 2, or the [pages*nkv, 1, 1]
+    layout the q8 attention kernels stream (PR 22: the trailing (1, 1)
+    is what lets a one-row block through the Mosaic lowering)."""
+    return dtype == "float32" and (
+        1 <= len(shape) <= 2 or tuple(shape[1:]) == (1, 1))
+
+
+def tensor_operands(shapes, dtypes):
+    """The (shape, dtype) pairs of a kernel's rank>=3 TENSOR operands —
+    q and the streamed caches — scale sidecars excluded."""
+    return [(s, d) for s, d in zip(shapes, dtypes)
+            if len(s) >= 3 and not is_scale_operand(s, d)]
+
+
 def missing_scale_finding(shapes, dtypes):
     """The ONE int8-pool-without-scales check (shared by the q8 kernel
     checkers in decode_attention/prefix_prefill and the TPU103 lint
     rule — a scale-layout change edits exactly here): quantized pools
-    are the rank>=3 int8 operands, their absmax scales the small
-    rank<=2 f32 operands; an int8 pool travelling with fewer than two
+    are the rank>=3 int8 operands, their absmax scales the
+    `is_scale_operand` f32 ones; an int8 pool travelling with fewer than two
     scale operands (one each for K and V) is consumed scale-less.
     Returns a ("warning", message) finding or None."""
     n_pools = sum(1 for s, dt in zip(shapes, dtypes)
                   if len(s) >= 3 and dt == "int8")
     n_scales = sum(1 for s, dt in zip(shapes, dtypes)
-                   if 1 <= len(s) <= 2 and dt == "float32")
+                   if is_scale_operand(s, dt))
     if n_pools and n_scales < 2:
         return ("warning",
                 f"{n_pools} int8 KV pool operand(s) but only "

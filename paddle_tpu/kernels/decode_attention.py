@@ -21,10 +21,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_compat import CompilerParams as _CompilerParams
-
 from .constraints import (KernelConstraint, LANE, VMEM_BUDGET_BYTES,
-                          fit_vmem_block, missing_scale_finding,
+                          fit_vmem_block, is_scale_operand,
+                          missing_scale_finding, tensor_operands,
                           register_constraint)
 
 _NEG_INF = -1e30
@@ -76,7 +75,7 @@ def _decode_attention_roofline(shapes, dtypes):
     operand layout doesn't resolve."""
     from .constraints import dtype_itemsize
 
-    arrs = [(s, d) for s, d in zip(shapes, dtypes) if len(s) >= 3]
+    arrs = tensor_operands(shapes, dtypes)
     if len(arrs) < 3 or not arrs[0][0][0]:
         return None
     (q_s, q_d), (pool_s, pool_d) = arrs[0], arrs[1]
@@ -95,7 +94,7 @@ def _decode_attention_roofline(shapes, dtypes):
         kv_bytes = 2 * b * ctx * hkv * d_head * dtype_itemsize(pool_d)
         # int8 pools travel with per-(page, kv head) f32 scale rows
         n_scales = sum(1 for s, dt in zip(shapes, dtypes)
-                       if len(s) == 2 and dt == "float32")
+                       if is_scale_operand(s, dt))
         if n_scales:
             kv_bytes += n_scales * b * n_blocks * hkv * 4
     else:                                  # contiguous: whole cache
@@ -147,10 +146,7 @@ CONSTRAINT_Q8 = register_constraint(KernelConstraint(
 
 
 def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    return jax.default_backend() == "tpu"
 
 
 def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
@@ -254,7 +250,7 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=not _on_tpu(),
     )(lens.astype(jnp.int32), q, k_cache, v_cache)
@@ -502,7 +498,7 @@ def gqa_decode_attention(q: jax.Array, k_cache: jax.Array,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b * hkv, group, d), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=not _on_tpu(),
     )(lens.astype(jnp.int32), qg, kc, vc)
@@ -522,8 +518,10 @@ def _paged_decode_gqa(q, key_cache, value_cache, block_tables, lens, scale,
     collapse (page, hkv) so page selection becomes tbl[b, j]*hkv + h —
     both are metadata-only row-major collapses, no data movement. With
     `k_scale`/`v_scale` [max_pages, hkv] (int8 pools) the collapse also
-    flattens the scales to [max_pages*hkv, 1] so each grid step's (1, 1)
-    scale tile rides the same tbl[b, j]*hkv + h row as its page."""
+    flattens the scales to [max_pages*hkv, 1, 1] so each grid step's
+    (1, 1, 1) scale tile rides the same tbl[b, j]*hkv + h row (and the
+    same index map) as its page — the trailing (1, 1) equals the array's
+    own trailing dims, which is what the Mosaic lowering accepts."""
     b, hq, d = q.shape
     hkv = key_cache.shape[1]
     group = hq // hkv
@@ -542,9 +540,6 @@ def _paged_decode_gqa(q, key_cache, value_cache, block_tables, lens, scale,
     def pool_map(b_, h, j, tbl, lens_, hkv=hkv):
         return (tbl[b_, j] * hkv + h, 0, 0)
 
-    def scale_map(b_, h, j, tbl, lens_, hkv=hkv):
-        return (tbl[b_, j] * hkv + h, 0)
-
     def q_map(b_, h, j, tbl, lens_, hkv=hkv):
         return (b_ * hkv + h, 0, 0)
 
@@ -555,10 +550,10 @@ def _paged_decode_gqa(q, key_cache, value_cache, block_tables, lens, scale,
     ]
     operands = [qg, kc, vc]
     if quant:
-        in_specs += [pl.BlockSpec((1, 1), scale_map),
-                     pl.BlockSpec((1, 1), scale_map)]
-        operands += [k_scale.astype(jnp.float32).reshape(-1, 1),
-                     v_scale.astype(jnp.float32).reshape(-1, 1)]
+        in_specs += [pl.BlockSpec((1, 1, 1), pool_map),
+                     pl.BlockSpec((1, 1, 1), pool_map)]
+        operands += [k_scale.astype(jnp.float32).reshape(-1, 1, 1),
+                     v_scale.astype(jnp.float32).reshape(-1, 1, 1)]
         kernel = functools.partial(_paged_gqa_q8_kernel,
                                    block_size=block_size, scale=scale)
     else:
@@ -578,7 +573,7 @@ def _paged_decode_gqa(q, key_cache, value_cache, block_tables, lens, scale,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b * hkv, group, d), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=not _on_tpu(),
     )(block_tables.astype(jnp.int32), lens.astype(jnp.int32), *operands)
@@ -672,7 +667,7 @@ def paged_decode_attention(q: jax.Array, key_cache: jax.Array,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=not _on_tpu(),
     )(block_tables.astype(jnp.int32), lens.astype(jnp.int32), *operands)

@@ -37,7 +37,7 @@ Fusion boundary (one kernel per decoder layer — the attention block):
 
 On the ATTN rung the MLP half of the layer stays with XLA: its three
 [1, H] x [H, F] matmuls are weight-read-bound and XLA schedules them
-well (measured for swiglu in BASELINE.md); the dispatch overhead that
+well (an older record, removed in PR 22, had this for swiglu); the dispatch overhead that
 rung recovers lives in the many tiny attention-block ops. The FULL and
 SCAN rungs pull the MLP in too (the `_swiglu` math at M=1, weights
 streamed per block), and SCAN then removes the per-layer launch
@@ -95,8 +95,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from .pallas_compat import CompilerParams as _CompilerParams
 
 from .constraints import (KernelConstraint, LANE, VMEM_BUDGET_BYTES,
                           dtype_itemsize, fit_vmem_block,
@@ -680,7 +678,7 @@ def decode_layer_megakernel(h, lens, tables, w_in, wq, wk, wv, wo,
         ),
         out_shape=out_shape,
         input_output_aliases=aliases,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=not _on_tpu(),
     )(tables.astype(jnp.int32), lens.astype(jnp.int32), *operands)
@@ -1370,7 +1368,7 @@ def decode_layers_megakernel(h, lens, tables, w_in, w_post, wq, wk, wv,
         ),
         out_shape=out_shape,
         input_output_aliases=aliases,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary", "arbitrary",
                                  "arbitrary")),
         interpret=not _on_tpu(),

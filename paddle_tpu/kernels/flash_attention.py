@@ -22,12 +22,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_compat import CompilerParams as _CompilerParams
-
+from ._vma import like_primal, operand_vma
 from .constraints import KernelConstraint, LANE, register_constraint
 
 _NEG_INF = -1e30
-_splash_warned = False
 
 # default seq tiling of the in-repo kernels: both grids walk the kv axis
 # in BLOCK_K steps with BLOCK_Q query rows resident in VMEM (clamped to
@@ -110,10 +108,7 @@ CONSTRAINT = register_constraint(KernelConstraint(
 
 
 def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    return jax.default_backend() == "tpu"
 
 
 # ---------------------------------------------------------------------------
@@ -187,6 +182,7 @@ def _fwd_pallas(q, k, v, causal: bool, scale: float,
         raise ValueError(f"seq lens ({sq},{sk}) not divisible by blocks "
                          f"({block_q},{block_k})")
     grid = (bh, sq // block_q, sk // block_k)
+    vma = operand_vma(q, k, v)
     kernel = functools.partial(
         _fwd_kernel, causal=causal, scale=scale,
         block_q=block_q, block_k=block_k, q_offset=sk - sq)
@@ -203,15 +199,15 @@ def _fwd_pallas(q, k, v, causal: bool, scale: float,
             pl.BlockSpec((1, block_q, 128), lambda b, i, j: (b, i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
-            jax.ShapeDtypeStruct((bh, sq, 128), jnp.float32),
+            jax.ShapeDtypeStruct((bh, sq, d), q.dtype, vma=vma),
+            jax.ShapeDtypeStruct((bh, sq, 128), jnp.float32, vma=vma),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, 128), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=not _on_tpu(),
     )(q, k, v)
@@ -240,21 +236,23 @@ def _fwd_ref(q, k, v, causal: bool, scale: float):
     return out
 
 
-def _pallas_ok(q, k):
+def _pallas_ok(q, k, v):
+    """Whether the in-repo kernels take these operands — decided here,
+    before the call, so whatever the kernel then raises propagates."""
     # must match the kernels' default block choice (min(BLOCK, seq))
-    return (q.shape[1] % min(BLOCK_Q, q.shape[1]) == 0
-            and k.shape[1] % min(BLOCK_K, k.shape[1]) == 0
-            and q.shape[0] % k.shape[0] == 0)
+    if (q.shape[1] % min(BLOCK_Q, q.shape[1])
+            or k.shape[1] % min(BLOCK_K, k.shape[1])
+            or q.shape[0] % k.shape[0]):
+        return False
+    # jax's Pallas interpreter cannot run under a vma-checked shard_map
+    return _on_tpu() or not operand_vma(q, k, v)
 
 
 def _fwd_core(q, k, v, causal, scale):
     """Returns (out, lse) — lse is [BH,Sq,128] from the pallas path or None
-    (jnp fallback recomputes stats in the backward)."""
-    if _pallas_ok(q, k):
-        try:
-            return _fwd_pallas(q, k, v, causal, scale)
-        except Exception:
-            pass
+    (the jnp form recomputes stats in the backward)."""
+    if _pallas_ok(q, k, v):
+        return _fwd_pallas(q, k, v, causal, scale)
     return _fwd_ref(q, k, v, causal, scale), None
 
 
@@ -372,6 +370,7 @@ def _bwd_pallas(q, k, v, out, lse, do, causal: bool, scale: float,
     block_k = min(block_k, sk)
     kern_kw = dict(causal=causal, scale=scale, block_q=block_q,
                    block_k=block_k, q_offset=sk - sq)
+    vma = operand_vma(q, k, v, do)
     q_spec = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0))
     kv_spec = pl.BlockSpec((1, block_k, d),
                            lambda b, i, j, rep=rep: (b // rep, j, 0))
@@ -381,9 +380,9 @@ def _bwd_pallas(q, k, v, out, lse, do, causal: bool, scale: float,
         grid=(bh, sq // block_q, sk // block_k),
         in_specs=[q_spec, kv_spec, kv_spec, q_spec, q_spec, lse_spec],
         out_specs=pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype, vma=vma),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=not _on_tpu(),
     )(q, k, v, out, do, lse)
@@ -398,11 +397,11 @@ def _bwd_pallas(q, k, v, out, lse, do, causal: bool, scale: float,
         grid=(bh, sk // block_k, sq // block_q),
         in_specs=[q_spec2, kv_spec2, kv_spec2, q_spec2, q_spec2, lse_spec2],
         out_specs=[dkv_out, dkv_out],
-        out_shape=[jax.ShapeDtypeStruct((bh, sk, d), k.dtype),
-                   jax.ShapeDtypeStruct((bh, sk, d), v.dtype)],
+        out_shape=[jax.ShapeDtypeStruct((bh, sk, d), k.dtype, vma=vma),
+                   jax.ShapeDtypeStruct((bh, sk, d), v.dtype, vma=vma)],
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=not _on_tpu(),
     )(q, k, v, out, do, lse)
@@ -437,7 +436,7 @@ def _flash_core_bwd(causal, scale, res, do):
         if rep > 1:
             dk = dk.reshape(k.shape[0], rep, *dk.shape[1:]).sum(1)
             dv = dv.reshape(v.shape[0], rep, *dv.shape[1:]).sum(1)
-        return dq, dk, dv
+        return like_primal(dq, q), like_primal(dk, k), like_primal(dv, v)
     bkv, sk, _ = k.shape
     rep = bh // bkv
     kr = jnp.repeat(k, rep, axis=0) if rep > 1 else k
@@ -458,7 +457,9 @@ def _flash_core_bwd(causal, scale, res, do):
     if rep > 1:
         dk = dk.reshape(bkv, rep, sk, d).sum(1)
         dv = dv.reshape(bkv, rep, sk, d).sum(1)
-    return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
+    return (like_primal(dq.astype(q.dtype), q),
+            like_primal(dk.astype(k.dtype), k),
+            like_primal(dv.astype(v.dtype), v))
 
 
 _flash_core.defvjp(_flash_core_fwd, _flash_core_bwd)
@@ -518,8 +519,11 @@ def flash_attention(q, k, v, causal: bool = False,
     Fast path: the pallas flash kernel bundled with the installed jax
     (jax.experimental.pallas.ops.tpu.flash_attention) — the TPU analog of
     the reference vendoring Dao's flash-attn library
-    (third_party/flashattn). GQA/odd shapes take the in-repo kernel pack;
-    CPU takes the jnp reference.
+    (third_party/flashattn) — and splash for GQA, each behind its shape
+    predicate (`_bundled_ok`, `_splash_ok`). Every other shape takes the
+    in-repo kernel pack where `_pallas_ok` holds and the jnp form
+    otherwise. The choice is made from shapes before the call: nothing a
+    kernel raises is caught.
     """
     b, sq, hq, dh = q.shape
     hk = k.shape[2]
@@ -527,43 +531,27 @@ def flash_attention(q, k, v, causal: bool = False,
     if scale is None:
         scale = 1.0 / math.sqrt(dh)
     if _splash_ok(sq, sk, hq, hk, dh):
-        try:
-            with jax.ensure_compile_time_eval():
-                kernel = _splash_kernel(sq, sk, hq, bool(causal))
-            # splash takes pre-scaled q, per-example [h, s, d] layout
-            qs = jnp.swapaxes(q, 1, 2) * jnp.asarray(scale, q.dtype)
-            out = jax.vmap(kernel)(qs, jnp.swapaxes(k, 1, 2),
-                                   jnp.swapaxes(v, 1, 2))
-            return jnp.swapaxes(out, 1, 2)
-        except (ImportError, TypeError, ValueError, NotImplementedError) as e:
-            # trace-time API/shape failures only; Mosaic compile errors
-            # surface after tracing and abort anyway. Warn once so a silent
-            # downgrade of the GQA fast path is visible in perf triage.
-            global _splash_warned
-            if not _splash_warned:
-                _splash_warned = True
-                import warnings
-
-                warnings.warn(
-                    f"splash GQA fast path unavailable ({type(e).__name__}: "
-                    f"{e}); falling back to the in-repo kernel pack")
+        with jax.ensure_compile_time_eval():
+            kernel = _splash_kernel(sq, sk, hq, bool(causal))
+        # splash takes pre-scaled q, per-example [h, s, d] layout
+        qs = jnp.swapaxes(q, 1, 2) * jnp.asarray(scale, q.dtype)
+        out = jax.vmap(kernel)(qs, jnp.swapaxes(k, 1, 2),
+                               jnp.swapaxes(v, 1, 2))
+        return jnp.swapaxes(out, 1, 2)
     if _bundled_ok(sq, sk, hq, hk, dh):
-        try:
-            from jax.experimental.pallas.ops.tpu.flash_attention import (
-                BlockSizes, flash_attention as _jax_fa)
+        from jax.experimental.pallas.ops.tpu.flash_attention import (
+            BlockSizes, flash_attention as _jax_fa)
 
-            bs = min(FAST_PATH_BLOCK, sq)
-            blocks = BlockSizes(
-                block_q=bs, block_k_major=bs, block_k=bs, block_b=1,
-                block_q_major_dkv=bs, block_k_major_dkv=bs,
-                block_k_dkv=bs, block_q_dkv=bs,
-                block_k_major_dq=bs, block_k_dq=bs, block_q_dq=bs)
-            out = _jax_fa(jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2),
-                          jnp.swapaxes(v, 1, 2), causal=causal,
-                          sm_scale=scale, block_sizes=blocks)
-            return jnp.swapaxes(out, 1, 2)
-        except Exception:
-            pass
+        bs = min(FAST_PATH_BLOCK, sq)
+        blocks = BlockSizes(
+            block_q=bs, block_k_major=bs, block_k=bs, block_b=1,
+            block_q_major_dkv=bs, block_k_major_dkv=bs,
+            block_k_dkv=bs, block_q_dkv=bs,
+            block_k_major_dq=bs, block_k_dq=bs, block_q_dq=bs)
+        out = _jax_fa(jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2),
+                      jnp.swapaxes(v, 1, 2), causal=causal,
+                      sm_scale=scale, block_sizes=blocks)
+        return jnp.swapaxes(out, 1, 2)
     qc = jnp.swapaxes(q, 1, 2).reshape(b * hq, sq, dh)
     kc = jnp.swapaxes(k, 1, 2).reshape(b * hk, sk, dh)
     vc = jnp.swapaxes(v, 1, 2).reshape(b * hk, sk, dh)

@@ -6,7 +6,7 @@ mixed-dtype path behind nn.quant.weight_only_linear(weight_dtype='int4')).
 
 XLA materializes the sign-extended nibble halves of a packed int4 weight
 before the dot, so the HBM read stays int8-sized and int4 decode measured
-SLOWER than int8 (BASELINE.md). This kernel keeps the packed bytes all the
+SLOWER than int8 (older record, removed in PR 22). This kernel keeps the packed bytes all the
 way into VMEM and unpacks in-register per tile: HBM traffic is the true
 0.5 byte/weight, which is the whole point of int4 on a weight-bound
 decode. Per-channel scales applied on the output tile.

@@ -35,10 +35,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_compat import CompilerParams as _CompilerParams
-
 from .constraints import (KernelConstraint, LANE, fit_vmem_block,
-                          missing_scale_finding, register_constraint,
+                          is_scale_operand, missing_scale_finding,
+                          register_constraint, tensor_operands,
                           vmem_row_cap)
 from .decode_attention import _on_tpu
 
@@ -114,7 +113,7 @@ def _prefix_prefill_roofline(shapes, dtypes):
     shape math; None when the layout doesn't resolve."""
     from .constraints import dtype_itemsize
 
-    arrs = [(s, d) for s, d in zip(shapes, dtypes) if len(s) >= 3]
+    arrs = tensor_operands(shapes, dtypes)
     tables = next((s for s, dt in zip(shapes, dtypes)
                    if len(s) == 2 and dt.startswith("int")), None)
     if len(arrs) < 5 or tables is None:
@@ -130,7 +129,7 @@ def _prefix_prefill_roofline(shapes, dtypes):
     kv_item = dtype_itemsize(pool_d)
     prefix_bytes = 2 * q_rows * w * page * dh * kv_item
     n_scales = sum(1 for s, dt in zip(shapes, dtypes)
-                   if len(s) == 2 and dt == "float32")
+                   if is_scale_operand(s, dt))
     if n_scales:
         prefix_bytes += n_scales * q_rows * w * 4
     suffix_bytes = 2 * math.prod(ks_s) * dtype_itemsize(ks_d)
@@ -189,7 +188,7 @@ def prefix_prefill_reference(q: jax.Array, k_suf: jax.Array,
     """The exact masked-softmax math the Pallas kernel replaces — and
     the SINGLE source of it: models.llama._make_prefill_with_prefix
     calls this per layer on its fallback path, and the kernel parity
-    tests, OPBENCH's `prefix_prefill_ref` row and tpu_smoke all oracle
+    tests, OPBENCH's `prefix_prefill_ref` row and chip_smoke all oracle
     against it. Gathers the whole padded prefix to query width
     ([b, w_pre, nkv, page, dh]) — exact, gather-bound. Same operand
     layout as `prefix_prefill_attention` (minus suffix_lens: every
@@ -466,20 +465,16 @@ def prefix_prefill_attention(q: jax.Array, k_suf: jax.Array,
         js = jnp.minimum(js, jnp.maximum((slens[b_] - 1) // block_s, 0))
         return ((b_ * nkv + h) * n_suf + js, 0, 0)
 
-    def scale_map(b_, h, qi, j, tbl, plens, slens):
-        # the (1, 1) scale tile rides the same pinned page row as the
-        # int8 pool tile it dequantizes
-        jp = jnp.minimum(j, jnp.maximum(plens[b_] // page - 1, 0))
-        return (tbl[b_, jp] * nkv + h, 0)
-
     pool_specs = [pl.BlockSpec((1, page, dh), pool_map),
                   pl.BlockSpec((1, page, dh), pool_map)]
     pool_operands = [kp, vp]
     if quant:
-        pool_specs += [pl.BlockSpec((1, 1), scale_map),
-                       pl.BlockSpec((1, 1), scale_map)]
-        pool_operands += [k_scale.astype(jnp.float32).reshape(-1, 1),
-                          v_scale.astype(jnp.float32).reshape(-1, 1)]
+        # the [pages*nkv, 1, 1] scale tile rides the same pinned page
+        # row (same index map) as the int8 pool tile it dequantizes
+        pool_specs += [pl.BlockSpec((1, 1, 1), pool_map),
+                       pl.BlockSpec((1, 1, 1), pool_map)]
+        pool_operands += [k_scale.astype(jnp.float32).reshape(-1, 1, 1),
+                          v_scale.astype(jnp.float32).reshape(-1, 1, 1)]
         kernel = functools.partial(
             _prefix_prefill_q8_kernel, page=page, block_q=block_q,
             block_s=block_s, group=group, w_pre=w_pre, scale=scale)
@@ -504,7 +499,7 @@ def prefix_prefill_attention(q: jax.Array, k_suf: jax.Array,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b * nkv * nq, bqg, dh), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=not _on_tpu(),

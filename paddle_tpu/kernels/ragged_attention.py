@@ -39,8 +39,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_compat import CompilerParams as _CompilerParams
-
 from .constraints import (KernelConstraint, LANE, fit_vmem_block,
                           missing_scale_finding, register_constraint,
                           vmem_row_cap)
@@ -179,7 +177,7 @@ def ragged_paged_attention_reference(q: jax.Array, k_new: jax.Array,
     """The exact masked-softmax math the ragged kernel replaces — and
     the SINGLE source of it: the unified-step fallback path
     (FLAGS_prefix_prefill_kernel=0) calls this per layer, and the
-    kernel parity tests / OPBENCH / tpu_smoke oracle against it.
+    kernel parity tests / OPBENCH / chip_smoke oracle against it.
 
     q/k_new/v_new: [b, tn, nh/nkv, dh] rotary-applied new-token window;
     key_cache/value_cache: [max_pages, nkv, page, dh] pools (int8 with
@@ -339,18 +337,14 @@ def ragged_paged_attention(q: jax.Array, k_new: jax.Array,
         js = jnp.minimum(js, jnp.maximum((nlens[b_] - 1) // block_n, 0))
         return ((b_ * nkv + h) * n_new + js, 0, 0)
 
-    def scale_map(b_, h, qi, j, tbl, clens, nlens):
-        jp = jnp.minimum(j, _last_page(clens, b_))
-        return (tbl[b_, jp] * nkv + h, 0)
-
     pool_specs = [pl.BlockSpec((1, page, dh), pool_map),
                   pl.BlockSpec((1, page, dh), pool_map)]
     pool_operands = [kp, vp]
     if quant:
-        pool_specs += [pl.BlockSpec((1, 1), scale_map),
-                       pl.BlockSpec((1, 1), scale_map)]
-        pool_operands += [k_scale.astype(jnp.float32).reshape(-1, 1),
-                          v_scale.astype(jnp.float32).reshape(-1, 1)]
+        pool_specs += [pl.BlockSpec((1, 1, 1), pool_map),
+                       pl.BlockSpec((1, 1, 1), pool_map)]
+        pool_operands += [k_scale.astype(jnp.float32).reshape(-1, 1, 1),
+                          v_scale.astype(jnp.float32).reshape(-1, 1, 1)]
         kernel = functools.partial(
             _ragged_attention_q8_kernel, page=page, block_q=block_q,
             block_s=block_n, group=group, w_pre=w, scale=scale)
@@ -375,7 +369,7 @@ def ragged_paged_attention(q: jax.Array, k_new: jax.Array,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b * nkv * nq, bqg, dh), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=not _on_tpu(),

@@ -16,7 +16,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_compat import CompilerParams as _CompilerParams
+from ._vma import like_primal, operand_vma
 
 
 def _rms_kernel(x_ref, w_ref, o_ref, *, eps: float):
@@ -26,11 +26,23 @@ def _rms_kernel(x_ref, w_ref, o_ref, *, eps: float):
     o_ref[...] = (x * inv * w_ref[...].astype(jnp.float32)).astype(o_ref.dtype)
 
 
-def _rms_pallas(x2d, w, eps: float, block_rows: int = 256):
+_BLOCK_ROWS = 256
+
+
+def _row_block(n: int):
+    """Rows per grid step, or None when the kernel does not take this
+    row count: the Mosaic lowering wants the block's row dim to equal
+    the array's or be a multiple of 8 that divides it."""
+    if n <= _BLOCK_ROWS:
+        return n
+    for b in range(_BLOCK_ROWS, 7, -8):
+        if n % b == 0:
+            return b
+    return None
+
+
+def _rms_pallas(x2d, w, eps: float, block_rows: int, vma, interpret):
     n, d = x2d.shape
-    block_rows = min(block_rows, n)
-    if n % block_rows:
-        block_rows = 1
     return pl.pallas_call(
         functools.partial(_rms_kernel, eps=eps),
         grid=(n // block_rows,),
@@ -39,10 +51,10 @@ def _rms_pallas(x2d, w, eps: float, block_rows: int = 256):
             pl.BlockSpec((d,), lambda i: (0,)),
         ],
         out_specs=pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n, d), x2d.dtype),
-        compiler_params=_CompilerParams(
+        out_shape=jax.ShapeDtypeStruct((n, d), x2d.dtype, vma=vma),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
-        interpret=jax.default_backend() != "tpu",
+        interpret=interpret,
     )(x2d, w)
 
 
@@ -54,13 +66,22 @@ def _rms_ref(x, w, eps: float):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
 def rms_norm(x, w, eps: float = 1e-6):
-    """y = x / rms(x) * w over the last axis."""
+    """y = x / rms(x) * w over the last axis. Two cases take the jnp
+    form (XLA fuses it into one loop), both decided before the call: a
+    row count `_row_block` rejects, and interpret mode inside a
+    vma-checked shard_map, which jax's Pallas interpreter cannot
+    evaluate. Whatever the kernel raises otherwise propagates."""
     shape = x.shape
-    try:
-        y = _rms_pallas(x.reshape(-1, shape[-1]), w, eps).reshape(shape)
-    except Exception:
-        y = _rms_ref(x, w, eps)
-    return y
+    x2d = x.reshape(-1, shape[-1])
+    block_rows = _row_block(x2d.shape[0])
+    interpret = jax.default_backend() != "tpu"
+    # under shard_map the output varies over every manual axis an
+    # operand varies over
+    vma = operand_vma(x, w)
+    if block_rows is None or (interpret and vma):
+        return _rms_ref(x, w, eps)
+    return _rms_pallas(x2d, w, eps, block_rows, vma,
+                       interpret).reshape(shape)
 
 
 def _rms_fwd(x, w, eps):
@@ -77,7 +98,8 @@ def _rms_bwd(eps, res, dy):
     dw = jnp.sum(dy32 * xhat, axis=tuple(range(x.ndim - 1)))
     g = dy32 * w32
     dx = inv * (g - xhat * jnp.mean(g * xhat, axis=-1, keepdims=True))
-    return dx.astype(x.dtype), dw.astype(w.dtype)
+    return (like_primal(dx.astype(x.dtype), x),
+            like_primal(dw.astype(w.dtype), w))
 
 
 rms_norm.defvjp(_rms_fwd, _rms_bwd)

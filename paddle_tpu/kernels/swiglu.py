@@ -12,8 +12,9 @@ pass — 2/3 of the intermediate HBM writes for the MLP's first stage.
 Backward is a custom vjp: recompute gate/up per tile (the remat the bench
 runs anyway), then three XLA matmuls for dx/dWg/dWu.
 
-A jnp path covers CPU and is the numerics oracle. Measured (BASELINE.md):
-XLA's own dual-matmul schedule beats this kernel on the bench MLP shape,
+A jnp path covers CPU and is the numerics oracle. An older record (removed
+in PR 22, predates PRs 1-20; not measured on this machine) had XLA's own
+dual-matmul schedule beating this kernel at an MLP shape it does tile,
 so the fused path is opt-in (`fused=True`) per the let-XLA-fuse rule.
 """
 from __future__ import annotations
@@ -24,8 +25,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from .pallas_compat import CompilerParams as _CompilerParams
 
 from .constraints import (KernelConstraint, LANE, SUBLANE,
                           register_constraint)
@@ -130,7 +129,7 @@ def _fwd_pallas(x2d, wg, wu, *, bm: int = _BLOCK, bf: int = _BLOCK,
         out_shape=jax.ShapeDtypeStruct((m, f), x2d.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bf), jnp.float32),
                         pltpu.VMEM((bm, bf), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=_interpret(),
     )(x2d, wg, wu)
@@ -193,7 +192,7 @@ def _bwd_pallas(x2d, wg, wu, dout, *, bm: int = _BLOCK, bf: int = _BLOCK,
                    jax.ShapeDtypeStruct((m, f), x2d.dtype)],
         scratch_shapes=[pltpu.VMEM((bm, bf), jnp.float32),
                         pltpu.VMEM((bm, bf), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=_interpret(),
     )(x2d, wg, wu, dout)
@@ -213,12 +212,6 @@ def _swiglu_fused_fwd(x2d, wg, wu):
 
 def _swiglu_fused_bwd(res, dout):
     x2d, wg, wu = res
-    m, k = x2d.shape
-    f = wg.shape[1]
-    if not _aligned(m, f, k):
-        # these shapes went through the ref path in fwd; mirror it
-        _, vjp = jax.vjp(_swiglu_ref, x2d, wg, wu)
-        return vjp(dout)
     dh_g, dh_u = _bwd_pallas(x2d, wg, wu, dout)
     dx = dh_g @ wg.T + dh_u @ wu.T
     dwg = x2d.T @ dh_g
@@ -237,13 +230,19 @@ def swiglu_matmul(x, wg, wu, fused=None):
     5.88 ms vs 6.97-7.8 ms for this kernel across block configs — XLA's
     own dual-matmul schedule wins, so the Pallas path is opt-in
     (fused=True), kept as the §7.1 inventory item and for shapes/hardware
-    where it may win."""
+    where it may win. fused=True on a shape the kernel does not tile
+    (M, K, F not all multiples of 512) raises: a caller who asked for
+    the kernel is never handed the XLA form in its place."""
     lead = x.shape[:-1]
     k = x.shape[-1]
     x2d = x.reshape(-1, k)
-    use_fused = False if fused is None else fused
     m, f = x2d.shape[0], wg.shape[1]
-    if use_fused and _aligned(m, f, k):
+    if fused:
+        if not _aligned(m, f, k):
+            raise ValueError(
+                f"swiglu_matmul(fused=True): M={m}, K={k}, F={f} must all "
+                f"be multiples of {_BLOCK}; pass fused=None for the XLA "
+                f"form")
         out = _swiglu_fused(x2d, wg, wu)
     else:
         out = _swiglu_ref(x2d, wg, wu)

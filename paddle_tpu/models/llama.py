@@ -157,6 +157,22 @@ def _act_spec(mesh: Optional[Mesh], shape, *dims) -> Optional[NamedSharding]:
     return NamedSharding(mesh, P(*out))
 
 
+def _per_shard(fn, mesh, in_specs, out_specs):
+    """`fn` run once per shard of `mesh` (identity without a multi-device
+    mesh). The Pallas-backed ops of the training path need it: GSPMD
+    cannot partition a Mosaic kernel ("Mosaic kernels cannot be
+    automatically partitioned. Please wrap the call in a shard_map" — what
+    the sharded train step died of when it was first compiled for four
+    chips, PR 22), so each kernel call states its own row/head layout and
+    runs on the local block. check_vma=False: jax's Pallas interpreter —
+    the CPU tests' form of the same kernels — cannot run under the
+    check."""
+    if mesh is None or mesh.size == 1:
+        return fn
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
+
+
 def _constrain(x, mesh, *dims):
     sh = _act_spec(mesh, list(x.shape), *dims)
     if sh is None:
@@ -189,10 +205,13 @@ class LlamaRMSNorm(Layer):
             [config.hidden_size], default_initializer=Constant(1.0),
             dtype=config.dtype)
 
-    def forward(self, x):
-        return dispatch(
-            "rms_norm",
-            lambda a, w: _k_rms(a, w, self.variance_epsilon), (x, self.weight))
+    def forward(self, x, mesh=None):
+        norm = lambda a, w: _k_rms(a, w, self.variance_epsilon)
+        if mesh is not None and len(x.shape) == 3:
+            # row-wise: any batch/seq split of the rows is exact
+            rows = _act_spec(mesh, x.shape, BATCH_AXES, SEQ_AXIS, None).spec
+            norm = _per_shard(norm, mesh, (rows, P()), rows)
+        return dispatch("rms_norm", norm, (x, self.weight))
 
 
 class LlamaAttention(Layer):
@@ -278,7 +297,27 @@ class LlamaAttention(Layer):
                                (MP_AXIS, SEQ_AXIS), None)
                 v = _constrain(v, mesh, BATCH_AXES, None,
                                (MP_AXIS, SEQ_AXIS), None)
-            out, _ = F.flash_attention(q, k, v, causal=causal)
+            if mesh is None or mesh.size == 1:
+                out, _ = F.flash_attention(q, k, v, causal=causal)
+            else:
+                # batch over (dp, sharding), heads over (mp, sep) — the
+                # layout both branches above leave q/k/v in. kv heads
+                # that cannot split the way q heads do keep every head
+                # whole on each shard (the GQA group map is by position)
+                lay = [BATCH_AXES, None, (MP_AXIS, SEQ_AXIS), None]
+                qs = _act_spec(mesh, q.shape, *lay).spec
+                if _act_spec(mesh, k.shape, *lay).spec != qs:
+                    lay[2] = None
+                    qs = _act_spec(mesh, q.shape, *lay).spec
+
+                def attend(qa, ka, va):
+                    return unwrap(F.flash_attention(
+                        Tensor(qa), Tensor(ka), Tensor(va),
+                        causal=causal)[0])
+
+                out = dispatch("flash_attn_per_shard",
+                               _per_shard(attend, mesh, (qs, qs, qs), qs),
+                               (q, k, v))
             if ulysses:
                 from ..parallel.ulysses import head_to_seq
 
@@ -331,7 +370,7 @@ class LlamaDecoderLayer(Layer):
         (sub-layer recompute granularity; the reference's recompute is
         op-level too, fleet/recompute/recompute.py:109)."""
         residual = hidden
-        h = self.input_layernorm(hidden)
+        h = self.input_layernorm(hidden, mesh=mesh)
         if cache is not None:
             attn, new_cache = self.self_attn(h, cos, sin, cache=cache, mesh=mesh)
         else:
@@ -346,7 +385,7 @@ class LlamaDecoderLayer(Layer):
                 attn = self.self_attn(h, cos, sin, mesh=mesh)
         hidden = residual + attn
         residual = hidden
-        h = self.post_attention_layernorm(hidden)
+        h = self.post_attention_layernorm(hidden, mesh=mesh)
         if remat == "mlp" and cache is None:
             def mlp_fn(h_):
                 return unwrap(self.mlp(Tensor(h_)))
@@ -364,14 +403,19 @@ class LlamaModel(Layer):
     def __init__(self, config: LlamaConfig):
         super().__init__()
         self.config = config
-        self.embed_tokens = Embedding(config.vocab_size, config.hidden_size)
+        from ..framework import dtype as dtypes
         from ..nn.layer.container import LayerList
 
-        self.layers = LayerList(
-            [LlamaDecoderLayer(config) for _ in range(config.num_hidden_layers)])
-        self.norm = LlamaRMSNorm(config)
-        if config.dtype != "float32":
-            self.to(dtype=config.dtype)
+        # every parameter is BORN in config.dtype: building in f32 and
+        # casting at the end holds the whole model twice over — at
+        # llama3-8B widths that alone overran one 16 GB chip (PR 22)
+        with dtypes.default_dtype(config.dtype):
+            self.embed_tokens = Embedding(config.vocab_size,
+                                          config.hidden_size)
+            self.layers = LayerList(
+                [LlamaDecoderLayer(config)
+                 for _ in range(config.num_hidden_layers)])
+            self.norm = LlamaRMSNorm(config)
 
     def forward(self, input_ids, caches=None, position_offset: int = 0):
         mesh = mesh_mod.get_global_mesh()
@@ -412,7 +456,7 @@ class LlamaModel(Layer):
                     unwrap(hidden)))
             else:
                 hidden = layer(hidden, cos, sin, mesh=mesh)
-        hidden = self.norm(hidden)
+        hidden = self.norm(hidden, mesh=mesh)
         if caches is not None:
             return hidden, new_caches
         return hidden
@@ -424,10 +468,11 @@ class LlamaForCausalLM(Layer):
         self.config = config
         self.llama = LlamaModel(config)
         if not config.tie_word_embeddings:
-            self.lm_head = Linear(config.hidden_size, config.vocab_size,
-                                  bias_attr=False)
-            if config.dtype != "float32":
-                self.lm_head.to(dtype=config.dtype)
+            from ..framework import dtype as dtypes
+
+            with dtypes.default_dtype(config.dtype):
+                self.lm_head = Linear(config.hidden_size,
+                                      config.vocab_size, bias_attr=False)
 
     def forward(self, input_ids, caches=None, position_offset: int = 0):
         out = self.llama(input_ids, caches=caches,
@@ -676,7 +721,8 @@ def _mm(x, w):
             # in-register Pallas dequant-matmul: the packed bytes stay
             # packed all the way into VMEM (kernels/int4_matmul.py) —
             # end-to-end decode 1.68 ms/step vs 2.79 for the XLA shift
-            # form (int8 remains fastest at ~1.1-1.3; BASELINE.md)
+            # form (int8 remains fastest at ~1.1-1.3) — older record,
+            # removed in PR 22; not measured on this machine
             from ..kernels.int4_matmul import int4_matmul
 
             lead = x.shape[:-1]
@@ -1333,22 +1379,19 @@ def resolve_unified_step(unified_step=None) -> bool:
     14) — one chunked-prefill+decode program over
     `ragged_paged_attention` instead of the split cold/prefix-prefill
     program zoo — from the argument or FLAGS_unified_step /
-    PADDLE_TPU_UNIFIED_STEP. 'auto' (the default) resolves ON off-TPU,
-    where interpret-mode parity is cheap; on silicon the default stays
-    the split oracle until the gated `ragged_step` OPBENCH row
-    confirms. Read at engine-BUILD time like every other serving
-    flag."""
+    PADDLE_TPU_UNIFIED_STEP. 'auto' (the default) resolves ON, on the
+    chip as off it: a default that depended on the backend meant the
+    chip served the step the CPU tests exercised least. Which step is
+    FASTER on the chip is not measured (benchmark PR); the split oracle
+    stays one flag away. Read at engine-BUILD time like every other
+    serving flag."""
     if unified_step is None:
         from ..framework.flags import flag as _flag
 
         unified_step = _flag("unified_step")
     if isinstance(unified_step, str):
         s = unified_step.strip().lower()
-        if s in ("auto", ""):
-            from ..kernels.decode_attention import _on_tpu
-
-            return not _on_tpu()
-        if s in ("1", "true", "on", "yes"):
+        if s in ("auto", "", "1", "true", "on", "yes"):
             return True
         if s in ("0", "false", "off", "no"):
             return False
@@ -2519,7 +2562,7 @@ def build_paged_generate(cfg, b, sb, max_new, block_size: int = 64,
         return run
 
     from ..parallel.mesh import serving_mesh
-    from ..parallel.shard_map_compat import shard_map
+    from jax import shard_map
 
     mesh = serving_mesh(tp.mp)
 

@@ -34,14 +34,6 @@ from .llama import LlamaConfig, LlamaForCausalLM, LlamaPretrainingCriterion
 __all__ = ["make_llama_pp_train_step", "split_llama_state",
            "chunk_llama_state", "merge_llama_chunked_state"]
 
-def _flatten_with_path(tree):
-    """jax.tree.flatten_with_path newer-API spelling, with the
-    jax.tree_util fallback for 0.4.x."""
-    if hasattr(jax.tree, "flatten_with_path"):
-        return jax.tree.flatten_with_path(tree)[0]
-    return jax.tree_util.tree_flatten_with_path(tree)[0]
-
-
 _LAYER_PREFIX = "llama.layers."
 
 
@@ -113,7 +105,7 @@ def merge_llama_chunked_state(outer: Dict, chunked, n_layers: int) -> Dict:
     leaves = jax.tree.leaves(chunked)
     n_stages, vpp = leaves[0].shape[0], leaves[0].shape[1]
     lpc = n_layers // (n_stages * vpp)
-    flat = _flatten_with_path(chunked)
+    flat = jax.tree.flatten_with_path(chunked)[0]
     for path, arr in flat:
         sub = ".".join(str(getattr(p, "key", getattr(p, "idx", p)))
                        for p in path)
@@ -130,7 +122,7 @@ def merge_llama_state(outer: Dict, stacked, n_layers: int) -> Dict:
     state = dict(outer)
     n_stages = jax.tree.leaves(stacked)[0].shape[0]
     lps = n_layers // n_stages
-    flat = _flatten_with_path(stacked)
+    flat = jax.tree.flatten_with_path(stacked)[0]
     for path, arr in flat:
         sub = ".".join(str(getattr(p, "key", getattr(p, "idx", p)))
                        for p in path)

@@ -50,21 +50,19 @@ def flash_attention(query, key, value, dropout=0.0, causal=False, return_softmax
     """paddle.nn.functional.flash_attention.flash_attention.
 
     Layout [batch, seqlen, num_heads, head_dim] (ref ops.yaml:1765 flash_attn).
-    Uses the Pallas kernel on TPU for the causal/no-mask path.
+    Uses the Pallas kernel pack for the no-dropout path (the flag and the
+    dropout rate decide, before the call: nothing the kernel raises is
+    caught).
     """
-    use_pallas = flag("FLAGS_enable_pallas_kernels")
-    if use_pallas and dropout == 0.0:
-        try:
-            from ...kernels.flash_attention import flash_attention_fwd
+    if flag("FLAGS_enable_pallas_kernels") and dropout == 0.0:
+        from ...kernels.flash_attention import flash_attention_fwd
 
-            out = dispatch(
-                "flash_attn",
-                lambda q, k, v: flash_attention_fwd(q, k, v, causal=causal),
-                (query, key, value),
-            )
-            return (out, None) if return_softmax else (out, None)
-        except Exception:
-            pass
+        out = dispatch(
+            "flash_attn",
+            lambda q, k, v: flash_attention_fwd(q, k, v, causal=causal),
+            (query, key, value),
+        )
+        return out, None
     out = dispatch(
         "flash_attn_ref",
         lambda q, k, v: _sdpa_ref(q, k, v, None, dropout, causal),

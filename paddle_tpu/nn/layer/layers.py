@@ -10,6 +10,8 @@ uses.
 from __future__ import annotations
 
 import collections
+import functools
+import math
 from typing import Any, Callable, Dict, Iterator, List, Optional, Tuple
 
 import jax
@@ -18,6 +20,7 @@ import numpy as np
 
 from ...core.tensor import Parameter, Tensor, unwrap
 from ...framework import dtype as dtypes
+from ...framework import random as _random
 
 
 _param_auto_counter = 0
@@ -32,16 +35,53 @@ class HookRemoveHelper:
         self._hooks.pop(self._hook_id, None)
 
 
+# parameters at or above this many elements are initialised by ONE jitted
+# program (see _initialize)
+_JIT_INIT_ELEMS = 1 << 24
+
+
+@functools.lru_cache(maxsize=64)
+def _jitted_init(init_type, init_attrs, shape, dtype):
+    init = init_type.__new__(init_type)
+    init.__dict__.update(init_attrs)
+
+    def run(key):
+        with _random.rng_scope(key):
+            return init(shape, dtype)
+
+    return jax.jit(run)
+
+
+def _initialize(init, shape, dtype):
+    """Run an initializer. Small parameters run it eagerly, as ever. A
+    LARGE one runs it as one jitted program fed a fresh key: eagerly,
+    `std * normal(f32)` then `.astype(bf16)` holds two f32 copies of the
+    weight next to the result — 5 GB for a 128k-vocab head, on top of
+    whatever is already resident (PR 22: llama3-8B widths on one 16 GB
+    v5e). Fused, only the result is ever materialised."""
+    if math.prod(shape) < _JIT_INIT_ELEMS:
+        return init(shape, dtype)
+    try:
+        fn = _jitted_init(type(init), tuple(sorted(vars(init).items())),
+                          shape, dtype)
+    except TypeError:       # an attribute that does not hash (an array)
+        return init(shape, dtype)
+    return fn(_random.next_key())
+
+
 class Layer:
     """Base class for all neural network layers (paddle.nn.Layer)."""
 
-    def __init__(self, name_scope=None, dtype="float32"):
+    def __init__(self, name_scope=None, dtype=None):
         object.__setattr__(self, "_parameters", collections.OrderedDict())
         object.__setattr__(self, "_sub_layers", collections.OrderedDict())
         object.__setattr__(self, "_buffers", collections.OrderedDict())
         object.__setattr__(self, "_non_persistable_buffer_names_set", set())
         self.training = True
-        self._dtype = dtypes.convert_dtype(dtype)
+        # None = the default dtype in force (float32 unless
+        # set_default_dtype / dtypes.default_dtype says otherwise)
+        self._dtype = dtypes.convert_dtype(dtype) \
+            or dtypes.get_default_dtype()
         self._name_scope = name_scope or type(self).__name__.lower()
         self._forward_pre_hooks = collections.OrderedDict()
         self._forward_post_hooks = collections.OrderedDict()
@@ -154,7 +194,7 @@ class Layer:
             lr = attr.learning_rate
         if init is None:
             init = default_initializer or (Constant(0.0) if is_bias else XavierNormal())
-        arr = init(tuple(int(s) for s in shape), dtype)
+        arr = _initialize(init, tuple(int(s) for s in shape), dtype)
         if name is None:
             # reference Parameters always carry an auto-generated unique
             # name ("linear_0.w_0", LayerHelper naming) assigned at

@@ -29,7 +29,7 @@ lifecycle (enqueue → admit → prefill dispatch/commit → handoff →
 per-chunk decode → retire, eviction + watchdog retirement + stall
 spans), `hapi.Model.fit` step phases (data fetch, step dispatch,
 checkpoint save), and the resilience seams. See README.md here for
-the span taxonomy and the Perfetto workflow.
+the span catalogue and the Perfetto workflow.
 """
 from __future__ import annotations
 

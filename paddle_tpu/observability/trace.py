@@ -61,13 +61,11 @@ def _under_jit() -> bool:
     a pure-host process that never imports jax never pays for it."""
     import sys
 
-    jax = sys.modules.get("jax")
-    if jax is None:
+    if "jax" not in sys.modules:
         return False
-    try:
-        return not jax.core.trace_state_clean()
-    except Exception:  # pragma: no cover - very old/new jax
-        return False
+    from jax._src import core
+
+    return not core.trace_state_clean()
 
 
 def write_chrome_trace(events, path: str, *, metadata: Optional[dict] = None,

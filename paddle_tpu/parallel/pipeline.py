@@ -27,7 +27,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
-from .shard_map_compat import shard_map
+from jax import shard_map
 
 from ..core.tensor import Tensor
 from ..nn.layer.layers import Layer

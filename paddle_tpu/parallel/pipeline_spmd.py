@@ -26,9 +26,8 @@ from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-from .shard_map_compat import shard_map
 
 from . import mesh as mesh_mod
 
@@ -39,13 +38,7 @@ __all__ = ["pipeline_forward", "pipeline_1f1b", "pipeline_eager_1f1b",
 
 def _to_varying(x, axis):
     """Mark x as varying over the manual axis (scan-carry requirement)."""
-    if hasattr(jax.lax, "pcast"):
-        return jax.lax.pcast(x, axis, to="varying")
-    if hasattr(jax.lax, "pvary"):
-        return jax.lax.pvary(x, axis)
-    # jax 0.4.x: the compat shim runs partial-auto shard_map with the
-    # replication check off, so there is no varying-ness to mark
-    return x
+    return jax.lax.pcast(x, axis, to="varying")
 
 
 def stack_stage_params(per_stage_params: list, mesh: Optional[Mesh] = None,

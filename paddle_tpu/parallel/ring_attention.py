@@ -25,7 +25,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from . import mesh as mesh_mod
 
-from .shard_map_compat import shard_map
+from jax import shard_map
 from .pipeline_spmd import _to_varying
 
 __all__ = ["ring_attention"]

@@ -21,7 +21,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from .mesh import BATCH_AXES, divisible_prefix as _divisible_prefix
 
-from .shard_map_compat import shard_map
+from jax import shard_map
 
 
 def _axes_size(mesh: Mesh, names) -> int:

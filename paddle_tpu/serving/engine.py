@@ -280,7 +280,7 @@ class ContinuousBatchingEngine:
         identical to a build without the flag.
 
         `unified_step` (ISSUE 14; default from FLAGS_unified_step /
-        PADDLE_TPU_UNIFIED_STEP, 'auto' = ON off-TPU, resolved HERE at
+        PADDLE_TPU_UNIFIED_STEP, 'auto' = ON, resolved HERE at
         build time like every other serving flag) serves prefill
         through the UNIFIED ragged step: ONE
         chunked-prefill+decode-chunk program over
@@ -339,10 +339,11 @@ class ContinuousBatchingEngine:
         flag-loaded artifact warns and is ignored; a stale EXPLICIT
         one raises — the operator named it, so silence would serve
         the wrong config); False forces OFF even when the flag is
-        set. With FLAGS_compile_cache / PADDLE_TPU_COMPILE_CACHE the
-        persistent compile cache (serving/compile_cache.py) is
-        enabled at build time, and `warm()` reports cold-vs-warm
-        compile counts on `metrics()['warm_compile_stats']`."""
+        set. The persistent compile cache (serving/compile_cache.py:
+        JAX_COMPILATION_CACHE_DIR, else FLAGS_compile_cache, else a
+        fixed path in the checkout) is enabled at build time, and
+        `warm()` reports cold-vs-warm compile counts on
+        `metrics()['warm_compile_stats']`."""
         # tuned-config artifact (analysis/tuner.py): fill unset
         # build-time knobs from the autotuner's winner BEFORE any flag
         # resolution below — the resolve_* helpers only see a value
@@ -371,13 +372,12 @@ class ContinuousBatchingEngine:
         if block_size is None:
             block_size = 64
         block_size = int(block_size)
-        # persistent compile cache (FLAGS_compile_cache): enabled at
-        # build time so this engine's warm() compiles persist to (and
-        # load from) disk; a no-op when the flag is empty and the
-        # cache was not enabled explicitly
+        # persistent compile cache: on at build time so this engine's
+        # warm() compiles persist to (and load from) disk; WHERE it
+        # lives is compile_cache.enable_compile_cache's one decision
         from . import compile_cache as _compile_cache
 
-        _compile_cache.enable_compile_cache(None)
+        _compile_cache.enable_compile_cache()
         self.warm_compile_stats = None  # set by warm()
         if prompt_bucket % block_size:
             raise ValueError(
@@ -811,7 +811,7 @@ class ContinuousBatchingEngine:
             return fn
         from jax.sharding import PartitionSpec as P
 
-        from ..parallel.shard_map_compat import shard_map
+        from jax import shard_map
 
         pools = [self._pool_entry_spec()] * len(self.kcs)
         in_specs = (self._param_specs, pools, pools) + (P(),) * n_repl

@@ -494,7 +494,7 @@ class TestCollectives:
     def _smap(self, fn, mesh):
         from jax.sharding import PartitionSpec as P
 
-        from paddle_tpu.parallel.shard_map_compat import shard_map
+        from jax import shard_map
 
         return shard_map(fn, mesh=mesh, in_specs=P("dp"),
                          out_specs=P("dp"), check_vma=False)
@@ -672,7 +672,7 @@ class TestCollectives:
     def _smap2(self, fn, mesh):
         from jax.sharding import PartitionSpec as P
 
-        from paddle_tpu.parallel.shard_map_compat import shard_map
+        from jax import shard_map
 
         return shard_map(fn, mesh=mesh, in_specs=(P("dp"), P("dp")),
                          out_specs=P("dp"), check_vma=False)

@@ -1,0 +1,170 @@
+"""The main path's Pallas kernels compiled by the TPU compiler, no chip.
+
+Rehearsal 3 of the on-chip-measurement guide kept as tests: each kernel is
+lowered and compiled for a DESCRIBED v5e at the widths chip_smoke.py serves
+and trains (llama3-8B heads: 32 q / 8 kv, dh 128; the 1B trainer: 16 q / 4 kv,
+seq 2048), and must come out as a Mosaic custom call — interpret mode hid
+three kernels the lowering refused for fifteen PRs.
+
+This is the ONE file that touches the TPU library, and only from inside the
+`topo` fixture: the driver's xdist workers each import every test file, only
+one process may load libtpu, and a module that loads it while imported gives
+the workers different collections (the whole suite then counts 0). Nothing
+here runs at import time; shardings and shapes are built in fixtures/tests.
+"""
+import importlib
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def for_chip(monkeypatch):
+    """Steer the kernels' am-I-on-a-TPU question to yes (the process itself
+    still sees the CPU, and would lower the interpreter), and keep the
+    persistent cache out of the way: an entry compiled for a described chip
+    cannot be read back without one."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _compile(fn, one_chip, *shapes):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert text.count("tpu_custom_call") >= 1, "no Mosaic kernel in program"
+    return text
+
+
+BF, I32, I8, F32 = jnp.bfloat16, jnp.int32, jnp.int8, jnp.float32
+# serving geometry: 8 slots, 32 q / 8 kv heads, dh 128, 64-token pages
+B, HQ, HK, D, PAGE, W, TN = 8, 32, 8, 128, 64, 17, 64
+MAX_PAGES = B * W + 1
+POOL = ((MAX_PAGES, HK, PAGE, D), BF)
+POOL8 = ((MAX_PAGES, HK, PAGE, D), I8)
+SCALE = ((MAX_PAGES, HK), F32)
+TABLES, LENS = ((B, W), I32), ((B,), I32)
+QWIN, KWIN = ((B, TN, HQ, D), BF), ((B, TN, HK, D), BF)
+# trainer geometry: batch 4, seq 2048, 16 q / 4 kv heads
+TQ, TKV = ((4, 2048, 16, 128), BF), ((4, 2048, 4, 128), BF)
+
+
+def _mod(name):
+    # `import paddle_tpu.kernels.flash_attention` resolves to the re-exported
+    # function of the same name; the module itself comes from importlib
+    return importlib.import_module(f"paddle_tpu.kernels.{name}")
+
+
+def test_flash_fwd(for_chip, one_chip):
+    fa = _mod("flash_attention")
+    _compile(lambda q, k, v: fa.flash_attention(q, k, v, causal=True),
+             one_chip, TQ, TKV, TKV)
+
+
+def test_flash_bwd(for_chip, one_chip):
+    fa = _mod("flash_attention")
+
+    def loss(q, k, v):
+        return fa.flash_attention(q, k, v, causal=True).astype(F32).sum()
+
+    _compile(jax.grad(loss, argnums=(0, 1, 2)), one_chip, TQ, TKV, TKV)
+
+
+def test_flash_in_repo_kernels(for_chip, one_chip):
+    """The in-repo fwd/bwd kernels behind `_flash_core` (the shapes the
+    vendored fast paths' predicates turn away)."""
+    fa = _mod("flash_attention")
+    q, kv = ((64, 2048, 128), BF), ((16, 2048, 128), BF)
+
+    def loss(q, k, v):
+        return fa._flash_core(q, k, v, True, 0.088).astype(F32).sum()
+
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)), one_chip, q, kv, kv)
+    assert text.count("tpu_custom_call") >= 3      # fwd, dq, dkv
+
+
+@pytest.mark.parametrize("rows,hidden", [(4 * 2048, 2048), (8, 4096),
+                                         (8 * 64, 4096)])
+def test_rms_norm(for_chip, one_chip, rows, hidden):
+    rn = _mod("rms_norm")
+    _compile(lambda x, w: rn.rms_norm(x, w, 1e-6), one_chip,
+             ((rows, hidden), BF), ((hidden,), BF))
+
+
+def test_paged_gqa_decode(for_chip, one_chip):
+    da = _mod("decode_attention")
+    _compile(da.paged_decode_attention, one_chip,
+             ((B, HQ, D), BF), POOL, POOL, TABLES, LENS)
+
+
+def test_ragged_step(for_chip, one_chip):
+    ra = _mod("ragged_attention")
+    _compile(ra.ragged_paged_attention, one_chip,
+             QWIN, KWIN, KWIN, POOL, POOL, TABLES, LENS, LENS)
+
+
+def test_prefix_prefill(for_chip, one_chip):
+    pp = _mod("prefix_prefill")
+    _compile(pp.prefix_prefill_attention, one_chip,
+             QWIN, KWIN, KWIN, POOL, POOL, TABLES, LENS, LENS)
+
+
+# int8 KV: the f32 scale sidecar rides as a (1, 1, 1) block of a
+# [pages*nkv, 1, 1] array — the layout the Mosaic lowering accepts (PR 22;
+# the (1, 1) block of [pages*nkv, 1] it replaced was refused)
+
+def test_int8_paged_gqa_decode(for_chip, one_chip):
+    da = _mod("decode_attention")
+    _compile(lambda q, k, v, t, n, ks, vs: da.paged_decode_attention(
+        q, k, v, t, n, k_scale=ks, v_scale=vs), one_chip,
+        ((B, HQ, D), BF), POOL8, POOL8, TABLES, LENS, SCALE, SCALE)
+
+
+def test_int8_ragged_step(for_chip, one_chip):
+    ra = _mod("ragged_attention")
+    _compile(lambda q, kn, vn, k, v, t, c, n, ks, vs:
+             ra.ragged_paged_attention(q, kn, vn, k, v, t, c, n,
+                                       k_scale=ks, v_scale=vs), one_chip,
+             QWIN, KWIN, KWIN, POOL8, POOL8, TABLES, LENS, LENS, SCALE, SCALE)
+
+
+def test_int8_prefix_prefill(for_chip, one_chip):
+    pp = _mod("prefix_prefill")
+    _compile(lambda q, kn, vn, k, v, t, c, n, ks, vs:
+             pp.prefix_prefill_attention(q, kn, vn, k, v, t, c, n,
+                                         k_scale=ks, v_scale=vs), one_chip,
+             QWIN, KWIN, KWIN, POOL8, POOL8, TABLES, LENS, LENS, SCALE, SCALE)
+
+
+def test_swiglu_fused_refuses_the_1b_mlp_shape():
+    """K 2048, F 5504 is not 512-tileable: a caller who asked for the kernel
+    gets an error, never the XLA form under the kernel's name."""
+    sw = _mod("swiglu")
+    x, w = jnp.zeros((512, 2048), BF), jnp.zeros((2048, 5504), BF)
+    with pytest.raises(ValueError, match="fused=True"):
+        sw.swiglu_matmul(x, w, w, fused=True)
+    assert sw.swiglu_matmul(x[:8], w, w).shape == (8, 5504)

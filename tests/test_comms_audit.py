@@ -24,7 +24,7 @@ from paddle_tpu.serving import ContinuousBatchingEngine
 def _smap(fn, n, in_specs=None, out_specs=None):
     from jax.sharding import Mesh, PartitionSpec as P
 
-    from paddle_tpu.parallel.shard_map_compat import shard_map
+    from jax import shard_map
 
     mesh = Mesh(np.array(jax.devices()[:n]), ("mp",))
     return shard_map(fn, mesh=mesh,
@@ -173,7 +173,7 @@ class TestShardMapAttribution(unittest.TestCase):
         per-chip math by construction — and totals split per axis."""
         from jax.sharding import Mesh, PartitionSpec as P
 
-        from paddle_tpu.parallel.shard_map_compat import shard_map
+        from jax import shard_map
 
         mesh = Mesh(np.array(jax.devices()[:4]).reshape(2, 2),
                     ("dp", "mp"))

@@ -679,7 +679,7 @@ class TestQuantizeOutEpilogue(unittest.TestCase):
         from jax.sharding import Mesh, PartitionSpec as P
 
         from paddle_tpu.parallel import collectives as qc
-        from paddle_tpu.parallel.shard_map_compat import shard_map
+        from jax import shard_map
 
         rng = np.random.default_rng(11)
         for n in (2, 4):
@@ -703,7 +703,7 @@ class TestQuantizeOutEpilogue(unittest.TestCase):
         from jax.sharding import Mesh, PartitionSpec as P
 
         from paddle_tpu.parallel import collectives as qc
-        from paddle_tpu.parallel.shard_map_compat import shard_map
+        from jax import shard_map
 
         # 3 * 128 = 384 flat elements do not split into 2 * 128 blocks
         x = jnp.ones((2, 3, 128), jnp.float32)

@@ -91,7 +91,7 @@ class TestCollectivesInShardMap:
     """Collectives lower to lax ops inside shard_map over the mesh axis."""
 
     def test_all_reduce(self, _mesh):
-        from paddle_tpu.parallel.shard_map_compat import shard_map
+        from jax import shard_map
 
         def f(x):
             t = paddle.Tensor(x)
@@ -106,7 +106,7 @@ class TestCollectivesInShardMap:
         np.testing.assert_allclose(np.asarray(out), ref)
 
     def test_all_gather(self, _mesh):
-        from paddle_tpu.parallel.shard_map_compat import shard_map
+        from jax import shard_map
 
         def f(x):
             out = dist.all_gather(paddle.Tensor(x), group="mp")
@@ -121,7 +121,7 @@ class TestCollectivesInShardMap:
         np.testing.assert_allclose(np.sort(out.ravel()), [0, 0, 1, 1, 2, 2, 3, 3])
 
     def test_reduce_scatter(self, _mesh):
-        from paddle_tpu.parallel.shard_map_compat import shard_map
+        from jax import shard_map
 
         def f(x):
             out = dist.reduce_scatter(paddle.Tensor(x), group="dp")

@@ -348,10 +348,10 @@ class TestLlama:
 
     @pytest.mark.slow  # over tier-1 budget; run explicitly with -m slow
     def test_remat_scope_and_fused_swiglu_match_baseline(self):
-        """Sub-layer remat granularity (remat_scope='attn'/'mlp') and the
-        fused-swiglu MLP are numerics-preserving: same loss trajectory as
-        the plain config (round-4 VERDICT item 4 levers; reference:
-        fleet/recompute/recompute.py:109 — op-level recompute)."""
+        """Sub-layer remat granularity (remat_scope='attn'/'mlp') is
+        numerics-preserving: same loss trajectory as the plain config
+        (reference: fleet/recompute/recompute.py:109 — op-level
+        recompute)."""
         from paddle_tpu.models import LlamaPretrainingCriterion
         from paddle_tpu.parallel import make_train_step
 
@@ -374,10 +374,13 @@ class TestLlama:
 
         base = losses(recompute=True)
         for over in ({"recompute": True, "remat_scope": "attn"},
-                     {"recompute": True, "remat_scope": "mlp"},
-                     {"recompute": True, "fused_swiglu": True}):
+                     {"recompute": True, "remat_scope": "mlp"}):
             np.testing.assert_allclose(losses(**over), base, atol=2e-5,
                                        err_msg=str(over))
+        # tiny()'s MLP is not 512-tileable: asking for the fused kernel
+        # there is an error, not a silent XLA answer
+        with pytest.raises(ValueError, match="fused=True"):
+            losses(recompute=True, fused_swiglu=True)
 
     def test_paged_generation_matches_contiguous(self):
         """cache_layout='paged' (block tables + paged pools) must produce

@@ -102,7 +102,7 @@ class TestLivenessPass(unittest.TestCase):
         size."""
         from jax.sharding import Mesh, PartitionSpec as P
 
-        from paddle_tpu.parallel.shard_map_compat import shard_map
+        from jax import shard_map
 
         mesh = Mesh(np.array(jax.devices()[:2]), ("mp",))
 

@@ -51,7 +51,7 @@ def test_acknowledged_regression_is_silenced(tmp_path, monkeypatch):
     bench._op_regressions({"matmul": 10.0}, path=path)
     monkeypatch.setattr(
         bench, "ACKNOWLEDGED_REGRESSIONS",
-        {"matmul": "2026-07-31: known, documented in BASELINE.md"})
+        {"matmul": "2026-07-31: known, documented in PERF.md"})
     warned = bench._op_regressions({"matmul": 20.0}, path=path)
     assert warned == []
     with open(path) as f:

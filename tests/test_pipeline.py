@@ -7,7 +7,6 @@ import pytest
 
 import paddle_tpu as paddle
 
-from conftest import requires_partial_auto
 
 from paddle_tpu.parallel.mesh import build_mesh, set_global_mesh
 from paddle_tpu.parallel.pipeline_spmd import (pipeline_forward,
@@ -34,7 +33,6 @@ def _stage_fn(p, h):
 
 
 class TestPipelineSpmd:
-    @requires_partial_auto
     def test_forward_matches_sequential(self):
         mesh = build_mesh({"dp": 1, "pp": 4, "mp": 2})
         set_global_mesh(mesh)
@@ -48,7 +46,6 @@ class TestPipelineSpmd:
             h = _stage_fn(p, h)
         np.testing.assert_allclose(np.asarray(out), np.asarray(h), atol=1e-6)
 
-    @requires_partial_auto
     def test_gradients_match_sequential(self):
         mesh = build_mesh({"dp": 1, "pp": 4, "mp": 2})
         set_global_mesh(mesh)
@@ -94,7 +91,6 @@ class TestPipelineSpmd:
 
 
 class TestLlamaPipeline:
-    @requires_partial_auto
     def test_pp_first_loss_matches_serial_and_trains(self):
         from paddle_tpu.models import (LlamaConfig, LlamaForCausalLM,
                                        LlamaPretrainingCriterion)
@@ -127,7 +123,6 @@ class TestLlamaPipeline:
         l2, p2, o2 = s2(p2, o2, x, y)
         np.testing.assert_allclose(losses[0], float(l2), atol=2e-3)
 
-    @requires_partial_auto
     def test_1f1b_grads_match_serial(self):
         """pipeline_1f1b's manual schedule must reproduce plain autodiff
         gradients exactly (reference bar:
@@ -172,7 +167,6 @@ class TestLlamaPipeline:
         np.testing.assert_allclose(np.asarray(d_x), np.asarray(d_x_s),
                                    atol=1e-6)
 
-    @requires_partial_auto
     def test_1f1b_matches_fthenb_and_reduces_memory(self):
         """The 1F1B schedule must match FThenB numerics while compiling to
         a lower peak temp memory at n_micro=8 (the point of 1F1B:
@@ -206,7 +200,6 @@ class TestLlamaPipeline:
             f"1F1B did not reduce peak temp memory: "
             f"{results['1F1B'][1]} vs {results['FThenB'][1]}")
 
-    @requires_partial_auto
     def test_scheduler_pass_drives_pp_step(self):
         """A pipeline-scheduler pass output must select the schedule and
         microbatching of the pp train step (reference:
@@ -271,7 +264,6 @@ class TestSchedulesRound3:
             h = stage_fn(jax.tree.map(lambda t, s=s: t[s], stacked), h)
         return head_fn(head, h, lb)
 
-    @requires_partial_auto
     def test_zb1f1b_grads_match_serial(self):
         from paddle_tpu.parallel.pipeline_spmd import pipeline_zb1f1b
 
@@ -307,7 +299,6 @@ class TestSchedulesRound3:
         np.testing.assert_allclose(np.asarray(d_x), np.asarray(d_x_s),
                                    atol=1e-5)
 
-    @requires_partial_auto
     def test_vpp_forward_and_grads_match_serial(self):
         from paddle_tpu.parallel.pipeline_spmd import pipeline_vpp_forward
 
@@ -359,7 +350,6 @@ class TestSchedulesRound3:
             pipeline_vpp_forward(lambda W, h: h, chunked,
                                  jnp.zeros((6, 8)), mesh=mesh, n_micro=6)
 
-    @requires_partial_auto
     def test_llama_all_schedules_match_serial(self):
         """schedule='VPP'/'ZBH1' accepted and loss-matching serial over 3
         steps (round-2 VERDICT item 1 'Done' bar)."""
@@ -394,7 +384,6 @@ class TestSchedulesRound3:
             np.testing.assert_allclose(losses, serial, atol=3e-3,
                                        err_msg=sched)
 
-    @requires_partial_auto
     def test_eager_1f1b_grads_match_serial(self):
         """pipeline_eager_1f1b's slack schedule must reproduce plain
         autodiff gradients exactly (reference bar: the eager-1F1B pass,
@@ -440,7 +429,6 @@ class TestSchedulesRound3:
         np.testing.assert_allclose(np.asarray(d_x), np.asarray(d_x_s),
                                    atol=1e-6)
 
-    @requires_partial_auto
     def test_eager_1f1b_memory_relation_and_pass(self):
         """Eager1F1B buys comm slack with activation memory: its input
         buffer is strictly larger than 1F1B's (min(n_micro, 4S-3) vs 2S
@@ -479,7 +467,6 @@ class TestSchedulesRound3:
                               {"accumulate_steps": 4})]).apply(config)
         assert config["pipeline"]["schedule_mode"] == "Eager1F1B"
 
-    @requires_partial_auto
     def test_coop_head_matches_and_shrinks_head_cost(self):
         """The cooperative vocab-parallel head (VERDICT item 2): numerics
         match the replicated head, and the per-rank head matmul is

@@ -18,7 +18,7 @@ from paddle_tpu.kernels import prefix_prefill as pp
 
 # the oracle IS the exported fallback math: the serving fallback
 # (models.llama), this parity suite, bench.py's prefix_prefill_ref row
-# and tpu_smoke all share the one prefix_prefill_reference
+# and chip_smoke all share the one prefix_prefill_reference
 def _reference(q, k_suf, v_suf, kc, vc, tables, plens, scale):
     return pp.prefix_prefill_reference(q, k_suf, v_suf, kc, vc, tables,
                                        plens, scale=scale)

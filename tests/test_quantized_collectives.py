@@ -47,7 +47,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 import paddle_tpu as paddle
 from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
 from paddle_tpu.parallel import collectives as qc
-from paddle_tpu.parallel.shard_map_compat import shard_map
+from jax import shard_map
 from paddle_tpu.serving import ContinuousBatchingEngine
 
 

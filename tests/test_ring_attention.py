@@ -8,7 +8,6 @@ import pytest
 
 import paddle_tpu as paddle
 
-from conftest import requires_partial_auto
 
 from paddle_tpu.parallel.mesh import build_mesh, set_global_mesh
 from paddle_tpu.parallel.ring_attention import _block_attn, ring_attention
@@ -25,7 +24,6 @@ def _full(q, k, v, causal, d):
     return (num / l).astype(q.dtype)
 
 
-@requires_partial_auto
 class TestRingAttention:
     @pytest.mark.parametrize("causal", [True, False])
     def test_matches_full_attention(self, causal):
@@ -92,7 +90,6 @@ class TestRingAttention:
                                    atol=1e-6)
 
 
-@requires_partial_auto
 class TestLlamaRing:
     def test_ring_matches_ulysses_losses(self):
         from paddle_tpu.models import (LlamaConfig, LlamaForCausalLM,

@@ -29,7 +29,7 @@ V5E = DEVICE_SPECS["tpu-v5e"]
 def _smap(fn, n, in_specs=None, out_specs=None):
     from jax.sharding import Mesh, PartitionSpec as P
 
-    from paddle_tpu.parallel.shard_map_compat import shard_map
+    from jax import shard_map
 
     mesh = Mesh(np.array(jax.devices()[:n]), ("mp",))
     return shard_map(fn, mesh=mesh,
@@ -69,6 +69,26 @@ class TestDeviceSpecs(unittest.TestCase):
         self.assertEqual(spec_for_device_kind("TPU v5 lite").name,
                          "tpu-v5e")
         self.assertEqual(spec_for_device_kind("TPU v4").name, "tpu-v4")
+
+    def test_unknown_device_kind_raises(self):
+        """An attached device the table does not know is an error, not
+        the v5e row (PR 22)."""
+        from unittest import mock
+
+        import jax
+
+        from paddle_tpu.analysis.device_specs import spec_for_device_kind
+
+        with self.assertRaisesRegex(KeyError, "TPU v9"):
+            spec_for_device_kind("TPU v9 mega")
+
+        class _Dev:
+            device_kind = "TPU v9 mega"
+
+        with mock.patch.object(jax, "default_backend", lambda: "tpu"), \
+                mock.patch.object(jax, "devices", lambda *a: [_Dev()]):
+            with self.assertRaisesRegex(KeyError, "TPU v9"):
+                get_spec(None)
 
 
 class TestFlopsBytesReferences(unittest.TestCase):

@@ -12,6 +12,7 @@ import sys
 import tempfile
 import unittest
 import warnings
+from unittest import mock
 
 import pytest
 
@@ -440,6 +441,12 @@ class TestPersistentCompileCache(unittest.TestCase):
         self.addCleanup(
             lambda: jax.config.update("jax_compilation_cache_dir",
                                       None))
+        # a cache placed from outside wins over any directory named in
+        # code; this test names its own, so it runs without one
+        env = mock.patch.dict(os.environ)
+        env.start()
+        self.addCleanup(env.stop)
+        os.environ.pop("JAX_COMPILATION_CACHE_DIR", None)
         self.assertEqual(cc.enable_compile_cache(tmp), tmp)
         self.assertEqual(cc.cache_dir(), tmp)
         kw = dict(_KW, kv_cache_dtype="int8")
@@ -458,6 +465,63 @@ class TestPersistentCompileCache(unittest.TestCase):
         self.assertGreater(hot["compile_requests"], 0)
         self.assertEqual(hot["cache_misses"], 0, hot)
         self.assertEqual(hot["cache_hits"], hot["compile_requests"])
+
+
+class TestCompileCachePlacement(unittest.TestCase):
+    """Where the persistent cache lives is ONE decision
+    (compile_cache.enable_compile_cache, PR 22): placed from outside by
+    JAX_COMPILATION_CACHE_DIR, else a fixed path in the checkout."""
+
+    def _decide(self, env_dir, *args):
+        """Run the decision with the module's memory cleared and every
+        jax-side effect recorded instead of applied (the suite's own
+        cache must stay where conftest put it)."""
+        import jax
+
+        from paddle_tpu.serving import compile_cache as cc
+
+        updates = {}
+        env = dict(os.environ)
+        env.pop("JAX_COMPILATION_CACHE_DIR", None)
+        if env_dir:
+            env["JAX_COMPILATION_CACHE_DIR"] = env_dir
+        with mock.patch.dict(os.environ, env, clear=True), \
+                mock.patch.object(cc, "_CACHE_DIR", None), \
+                mock.patch.object(jax.config, "update",
+                                  lambda k, v: updates.__setitem__(k, v)), \
+                mock.patch("jax._src.compilation_cache.reset_cache",
+                           lambda: None), \
+                mock.patch("os.makedirs", lambda *a, **k: None):
+            return cc.enable_compile_cache(*args), updates
+
+    def test_env_placement_wins_and_no_code_sets_a_directory(self):
+        for args in ((), ("/somewhere/else",)):
+            got, updates = self._decide("/placed/from/outside", *args)
+            self.assertEqual(got, "/placed/from/outside")
+            self.assertNotIn("jax_compilation_cache_dir", updates)
+            # the floors are still zeroed: every engine program persists
+            self.assertEqual(
+                updates["jax_persistent_cache_min_compile_time_secs"], 0.0)
+
+    def test_unset_means_the_fixed_in_checkout_path(self):
+        from paddle_tpu.serving import compile_cache as cc
+
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        self.assertEqual(cc.DEFAULT_CACHE_DIR,
+                         os.path.join(repo, ".jax_cache"))
+        for _ in range(2):      # the same on every run
+            got, updates = self._decide(None)
+            self.assertEqual(got, cc.DEFAULT_CACHE_DIR)
+            self.assertEqual(updates["jax_compilation_cache_dir"],
+                             cc.DEFAULT_CACHE_DIR)
+
+    def test_repo_flag_places_it_only_when_env_is_unset(self):
+        paddle.set_flags({"compile_cache": "/from/the/flag"})
+        try:
+            self.assertEqual(self._decide(None)[0], "/from/the/flag")
+            self.assertEqual(self._decide("/placed")[0], "/placed")
+        finally:
+            paddle.set_flags({"compile_cache": ""})
 
 
 class TestCLITune(unittest.TestCase):
