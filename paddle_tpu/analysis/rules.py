@@ -198,9 +198,11 @@ class KernelConstraintRule(Rule):
 
 
 def _pallas_kernel_name(eqn):
-    """(fn_name, "fn_name at file:line") of a pallas_call's kernel — the
-    kernel jaxpr's debug info (functools.partial wrappers are already
-    unwrapped there)."""
+    """(name, "name at file:line") of a pallas_call's kernel — the
+    kernel jaxpr's debug info: the call's `name=` where it passed one
+    (every kernel in `kernels/` passes its registry name), else the
+    kernel function's name (functools.partial wrappers are already
+    unwrapped there). The file is the kernel function's either way."""
     info = eqn.params["jaxpr"].debug_info
     return str(info.func_name), str(info.func_src_info)
 

@@ -179,10 +179,15 @@ def missing_scale_finding(shapes, dtypes):
 class KernelConstraint:
     """One kernel's declared TPU layout contract.
 
-    `kernel_fns` are the Pallas kernel *function* names (what shows up in
-    a traced `pallas_call` equation's name_and_src_info) this constraint
-    covers. `blocks` are the named block-size constants the kernel tiles
-    with. `checker(shapes, dtypes)` receives the pallas_call operand aval
+    `name` is the kernel's ONE name: every `pl.pallas_call` of the
+    kernel passes it as `name=` (with a role suffix where one entry
+    covers several calls: `flash_attention_bwd_dq`), so it is the name
+    of the operation in the compiled program and in a profiler's trace,
+    and the `func_name` of the traced equation's kernel jaxpr.
+    `kernel_fns` are the Pallas kernel *function* names this constraint
+    covers, which is what a call without `name=` shows there. `blocks`
+    are the named block-size constants the kernel tiles with.
+    `checker(shapes, dtypes)` receives the pallas_call operand aval
     shapes/dtype-names and returns violations: plain strings (severity
     decided by the lint rule) or ("error"|"warning", message) pairs —
     "error" for shapes the kernel rejects outright, "warning" for silent
@@ -225,19 +230,21 @@ class KernelConstraint:
 
 
 KERNEL_CONSTRAINTS: Dict[str, KernelConstraint] = {}
-_BY_KERNEL_FN: Dict[str, KernelConstraint] = {}
+_BY_TRACED_NAME: Dict[str, KernelConstraint] = {}
 
 
 def register_constraint(c: KernelConstraint) -> KernelConstraint:
     KERNEL_CONSTRAINTS[c.name] = c
-    for fn in c.kernel_fns:
-        _BY_KERNEL_FN[fn] = c
+    for fn in (c.name,) + tuple(c.kernel_fns):
+        _BY_TRACED_NAME[fn] = c
     return c
 
 
 def constraint_for_kernel_fn(fn_name: str,
                              src: str = "") -> Optional[KernelConstraint]:
-    """Look up the constraint covering a Pallas kernel function name.
+    """Look up the constraint covering a traced `pallas_call`'s kernel
+    name: the `name=` the call passed (the registry's own, or that
+    plus a role suffix) or, without one, its kernel function's name.
     `src` is the full traced name-and-source string (when available) —
     constraints with a `source` hint only match when it appears there,
     so generic names like `_fwd_kernel` cannot cross-match kernels."""
@@ -245,11 +252,11 @@ def constraint_for_kernel_fn(fn_name: str,
     def source_ok(c: KernelConstraint) -> bool:
         return not c.source or not src or c.source in src
 
-    c = _BY_KERNEL_FN.get(fn_name)
+    c = _BY_TRACED_NAME.get(fn_name)
     if c is not None and source_ok(c):
         return c
     # prefix match: name_and_src_info may append wrapper suffixes
-    for k, cand in _BY_KERNEL_FN.items():
+    for k, cand in _BY_TRACED_NAME.items():
         if fn_name.startswith(k) and source_ok(cand):
             return cand
     return None

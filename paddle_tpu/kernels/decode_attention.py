@@ -232,6 +232,7 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     kernel = functools.partial(_decode_kernel, block_s=block_s, scale=scale)
     return pl.pallas_call(
         kernel,
+        name=CONSTRAINT.name,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=grid,
@@ -474,6 +475,7 @@ def gqa_decode_attention(q: jax.Array, k_cache: jax.Array,
                                scale=scale)
     out = pl.pallas_call(
         kernel,
+        name=CONSTRAINT.name,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(b, hkv, nb),
@@ -561,6 +563,7 @@ def _paged_decode_gqa(q, key_cache, value_cache, block_tables, lens, scale,
                                    block_size=block_size, scale=scale)
     out = pl.pallas_call(
         kernel,
+        name=(CONSTRAINT_Q8 if quant else CONSTRAINT).name,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(b, hkv, n_blocks),
@@ -654,6 +657,7 @@ def paged_decode_attention(q: jax.Array, key_cache: jax.Array,
     # block table — each grid step streams exactly one page of one sequence
     return pl.pallas_call(
         kernel,
+        name=(CONSTRAINT_Q8 if quant else CONSTRAINT).name,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(b, n_blocks),

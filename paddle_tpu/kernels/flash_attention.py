@@ -188,6 +188,7 @@ def _fwd_pallas(q, k, v, causal: bool, scale: float,
         block_q=block_q, block_k=block_k, q_offset=sk - sq)
     out, lse = pl.pallas_call(
         kernel,
+        name=CONSTRAINT.name + "_fwd",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
@@ -377,6 +378,7 @@ def _bwd_pallas(q, k, v, out, lse, do, causal: bool, scale: float,
     lse_spec = pl.BlockSpec((1, block_q, 128), lambda b, i, j: (b, i, 0))
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, **kern_kw),
+        name=CONSTRAINT.name + "_bwd_dq",
         grid=(bh, sq // block_q, sk // block_k),
         in_specs=[q_spec, kv_spec, kv_spec, q_spec, q_spec, lse_spec],
         out_specs=pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
@@ -394,6 +396,7 @@ def _bwd_pallas(q, k, v, out, lse, do, causal: bool, scale: float,
     dkv_out = pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0))
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, **kern_kw),
+        name=CONSTRAINT.name + "_bwd_dkv",
         grid=(bh, sk // block_k, sq // block_q),
         in_specs=[q_spec2, kv_spec2, kv_spec2, q_spec2, q_spec2, lse_spec2],
         out_specs=[dkv_out, dkv_out],

@@ -129,6 +129,7 @@ def int4_matmul(x, w_packed, scale, *, block_n: int = BLOCK_N,
     # tiling mismatches
     out = pl.pallas_call(
         functools.partial(_kernel, dot_dtype=dot_dtype),
+        name=CONSTRAINT.name,
         grid=(n // bn,),
         in_specs=[
             pl.BlockSpec((xp.shape[0], k // 2), lambda j: (0, 0)),

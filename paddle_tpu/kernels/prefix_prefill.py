@@ -484,6 +484,7 @@ def prefix_prefill_attention(q: jax.Array, k_suf: jax.Array,
             block_s=block_s, group=group, w_pre=w_pre, scale=scale)
     out = pl.pallas_call(
         kernel,
+        name=(CONSTRAINT_Q8 if quant else CONSTRAINT).name,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(b, nkv, nq, w_pre + n_suf),

@@ -354,6 +354,7 @@ def ragged_paged_attention(q: jax.Array, k_new: jax.Array,
             block_s=block_n, group=group, w_pre=w, scale=scale)
     out = pl.pallas_call(
         kernel,
+        name=(CONSTRAINT_Q8 if quant else CONSTRAINT).name,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(b, nkv, nq, w + n_new),

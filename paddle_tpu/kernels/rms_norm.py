@@ -45,6 +45,7 @@ def _rms_pallas(x2d, w, eps: float, block_rows: int, vma, interpret):
     n, d = x2d.shape
     return pl.pallas_call(
         functools.partial(_rms_kernel, eps=eps),
+        name="rms_norm",
         grid=(n // block_rows,),
         in_specs=[
             pl.BlockSpec((block_rows, d), lambda i: (i, 0)),

@@ -119,6 +119,7 @@ def _fwd_pallas(x2d, wg, wu, *, bm: int = _BLOCK, bf: int = _BLOCK,
     grid = (m // bm, f // bf, n_k)
     return pl.pallas_call(
         functools.partial(_swiglu_fwd_kernel, n_k=n_k),
+        name=CONSTRAINT.name + "_fwd",
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
@@ -179,6 +180,7 @@ def _bwd_pallas(x2d, wg, wu, dout, *, bm: int = _BLOCK, bf: int = _BLOCK,
     grid = (m // bm, f // bf, n_k)
     return pl.pallas_call(
         functools.partial(_swiglu_bwd_kernel, n_k=n_k),
+        name=CONSTRAINT.name + "_bwd",
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),

@@ -3,8 +3,10 @@
 Two halves, one activation story:
 
 - `trace` — thread-safe monotonic-clock span recorder (bounded ring
-  buffer, nested spans on per-thread tracks, instant/counter events)
-  with Perfetto/chrome://tracing export and `jax.profiler` bridging.
+  buffer, nested spans on per-thread tracks, instant events) with
+  Perfetto/chrome://tracing export; every span is also a
+  `jax.profiler.TraceAnnotation`, so a live profiler session holds it
+  on the same clock as the device's operations.
   Armed by `FLAGS_trace` / ``PADDLE_TPU_TRACE=<path>`` (the export
   path); `trace.enable(path)` programmatically.
 - `metrics` — registry of counters / gauges / bucketed histograms

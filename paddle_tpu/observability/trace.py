@@ -12,14 +12,19 @@ host spans are recorded by ONE `Tracer`:
 - **nested spans, per-thread tracks**: spans are chrome "X" complete
   events keyed by thread id, so Perfetto renders nesting per track from
   timestamp containment; `set_thread_name` labels the track;
-- **structured instant events** (`instant`) and counter series
-  (`counter`) for point-in-time facts (retire, eviction, chaos fault,
-  watchdog retirement);
-- **device bridging**: `span(..., device=True)` also enters
-  `jax.profiler.TraceAnnotation` and `step_span` wraps
-  `jax.profiler.StepTraceAnnotation`, so host spans align with the
-  XPlane device trace when `jax.profiler.start_trace` is live (view
-  both in Perfetto/TensorBoard on one timeline);
+- **structured instant events** (`instant`) for point-in-time facts
+  (retire, eviction, chaos fault, watchdog retirement);
+- **one clock with the device trace**: every `span()` also enters a
+  `jax.profiler.TraceAnnotation` of the same name with the span's
+  arguments (`step_span` a `StepTraceAnnotation`), so whenever a
+  profiler session is live the span is an event on the host plane of
+  the same `.xplane.pb` as the device's operations, its arguments the
+  event's stats. The two records keep their own clocks: this JSON
+  counts `time.perf_counter_ns`, the profiler counts from the start
+  of its session — lay host spans over device operations in the
+  profiler's trace, never by matching the JSON's timestamps. Outside
+  a session an annotation is one check of an atomic. `complete()`
+  records after the fact and so cannot enter one;
 - **trace-safety guard** (lint rule TPU602): a span/instant emitted
   while jax is TRACING a program would bake a host callback — and a
   per-execution host round-trip — into the compiled artifact. Like
@@ -43,6 +48,8 @@ import threading
 import time
 from collections import deque
 from typing import Optional
+
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 __all__ = ["Tracer", "TraceUnderJitError", "write_chrome_trace",
            "merge_chrome_traces", "get_tracer", "enable", "disable",
@@ -131,24 +138,24 @@ def merge_chrome_traces(paths, out: Optional[str] = None, *,
 
 class _SpanHandle:
     """Context manager for one live span (created only when tracing is
-    ON — the disabled path never reaches here)."""
+    ON — the disabled path never reaches here). `ann` is the profiler
+    annotation entered and left with the span."""
 
     __slots__ = ("tracer", "name", "args", "t0", "_ann")
 
-    def __init__(self, tracer: "Tracer", name: str, args: dict,
-                 device: bool):
+    def __init__(self, tracer: "Tracer", name: str, args: dict, ann):
         self.tracer = tracer
         self.name = name
         self.args = args
         self.t0 = 0
-        self._ann = None
-        if device:
-            try:
-                import jax
+        self._ann = ann
 
-                self._ann = jax.profiler.TraceAnnotation(name)
-            except Exception:  # pragma: no cover - no jax / no profiler
-                self._ann = None
+    def set(self, **args) -> None:
+        """Arguments known only inside the span (a count, what was
+        retired): added to the recorded event and, in a live profiler
+        session, to the annotation's stats."""
+        self.args.update(args)
+        self._ann.set_metadata(**args)
 
     def __enter__(self):
         if _under_jit():
@@ -157,15 +164,13 @@ class _SpanHandle:
                 "program: the emitter would compile into the jitted "
                 "artifact (lint rule TPU602); trace on the host "
                 "between dispatches instead")
-        if self._ann is not None:
-            self._ann.__enter__()
+        self._ann.__enter__()
         self.t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc):
         t1 = time.perf_counter_ns()
-        if self._ann is not None:
-            self._ann.__exit__(*exc)
+        self._ann.__exit__(*exc)
         self.tracer._record_complete(self.name, self.t0, t1, self.args)
         return False
 
@@ -194,25 +199,19 @@ class Tracer:
         self.n_recorded = 0
 
     # -- recording -----------------------------------------------------
-    def span(self, name: str, device: bool = False, **args) -> _SpanHandle:
+    def span(self, name: str, **args) -> _SpanHandle:
         """Context manager recording a complete ("X") span on this
-        thread's track. `device=True` additionally enters
-        `jax.profiler.TraceAnnotation(name)` so the span shows up in a
-        live XPlane device trace."""
-        return _SpanHandle(self, name, args, device)
+        thread's track, and a `jax.profiler.TraceAnnotation` of the
+        same name and arguments in the profiler's trace when a session
+        is live. `handle.set(**args)` adds arguments from inside."""
+        return _SpanHandle(self, name, args, TraceAnnotation(name, **args))
 
     def step_span(self, name: str, step: int) -> _SpanHandle:
-        """Span for one training/serving step, bridged to
+        """Span for one training/serving step, entered as a
         `jax.profiler.StepTraceAnnotation` (the annotation XProf's step
-        views key on) when a device trace is live."""
-        h = _SpanHandle(self, name, {"step": int(step)}, device=False)
-        try:
-            import jax
-
-            h._ann = jax.profiler.StepTraceAnnotation(name, step_num=step)
-        except Exception:  # pragma: no cover
-            h._ann = None
-        return h
+        views key on)."""
+        return _SpanHandle(self, name, {"step": int(step)},
+                           StepTraceAnnotation(name, step_num=step))
 
     def instant(self, name: str, **args) -> None:
         """Structured point-in-time event ("i" phase, thread scope)."""
@@ -227,23 +226,11 @@ class Tracer:
             ev["args"] = args
         self._push(ev)
 
-    def counter(self, name: str, value) -> None:
-        """Counter-series sample ("C" phase) — Perfetto renders these as
-        a stacked value track."""
-        if _under_jit():
-            raise TraceUnderJitError(
-                f"counter {name!r} sampled while jax is tracing a "
-                "program (lint rule TPU602): it would record ONE "
-                "trace-time point, never a per-execution series")
-        self._push({"name": name, "ph": "C",
-                    "ts": time.perf_counter_ns() / 1e3, "pid": self.pid,
-                    "tid": threading.get_ident(),
-                    "args": {"value": float(value)}})
-
     def complete(self, name: str, t0_ns: int, t1_ns: int, **args) -> None:
-        """Record an already-measured interval retroactively (the
-        engine's sync-wait is timed anyway; this avoids a second pair
-        of clock reads)."""
+        """Record an interval whose ends were stamped elsewhere
+        (`hapi.Model.fit` times its data fetch and checkpoint save
+        anyway). Not in the profiler's trace: an annotation cannot be
+        entered after the fact."""
         if _under_jit():
             raise TraceUnderJitError(
                 f"complete {name!r} recorded while jax is tracing a "

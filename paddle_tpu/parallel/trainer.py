@@ -317,7 +317,7 @@ def make_train_step(model: Layer, loss_fn: Callable, mesh: Optional[Mesh] = None
                 else jnp.float32), g_sum, p)
         return loss_sum / k_steps, grads
 
-    def step(p, s, lr_, *batch):
+    def train_step(p, s, lr_, *batch):
         loss, grads = loss_and_grads(p, *batch)
         if fused is not None:
             new_p, new_s = fused.update(p, grads, s, lr_)
@@ -327,7 +327,8 @@ def make_train_step(model: Layer, loss_fn: Callable, mesh: Optional[Mesh] = None
                 grad_clip_norm=grad_clip_norm)
         return loss, new_p, new_s
 
-    jitted = jax.jit(step, donate_argnums=(0, 1) if donate else ())
+    # named by role: the profiler's module row reads `jit_train_step`
+    jitted = jax.jit(train_step, donate_argnums=(0, 1) if donate else ())
 
     def step_fn(p, s, *batch):
         cur_lr = fused.host_lr() if fused is not None else lr
@@ -351,11 +352,11 @@ def make_eval_step(model: Layer, mesh: Optional[Mesh] = None,
                    batch_spec: Optional[Tuple] = None):
     mesh = mesh or mesh_mod.get_global_mesh()
 
-    def fwd(p, inputs):
+    def eval_step(p, inputs):
         if mesh is not None:
             inputs = jax.lax.with_sharding_constraint(
                 inputs, batch_sharding(mesh, inputs.shape, batch_spec))
         with _tape.no_grad():
             return unwrap(model.func_call(p, Tensor(inputs), training=False))
 
-    return jax.jit(fwd)
+    return jax.jit(eval_step)

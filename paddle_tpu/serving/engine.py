@@ -115,6 +115,7 @@ from ..models.llama import (STACKED_LAYER_NAMES, STACKED_PREFIX,
                             stack_decode_layer_params)
 from ..observability import metrics as obs_metrics
 from ..observability import trace as obs_trace
+from ..observability.trace import _NULL_SPAN
 from ..resilience import chaos
 
 
@@ -597,6 +598,7 @@ class ContinuousBatchingEngine:
             out = tuple(NamedSharding(self.mp_mesh, s) for s in sp) \
                 if self.kv_dtype == "int8" \
                 else NamedSharding(self.mp_mesh, sp)
+            _pool.__name__ = "serve_kv_pool_init"
             _pool = jax.jit(_pool, out_shardings=out)
         self.kcs = [_pool() for _ in range(n_pools)]
         self.vcs = [_pool() for _ in range(n_pools)]
@@ -622,28 +624,29 @@ class ContinuousBatchingEngine:
         self.finished: list[ServeRequest] = []
         self._next_id = 0
         self._prefill_cache = {}
-        self._decode = jax.jit(
-            self._shard_program(self._build_decode_chunk(), 8, 3),
-            donate_argnums=(1, 2))
+        self._decode = self._program(
+            self._build_decode_chunk(), "serve_decode_chunk", 8, 3)
         # the ONE mixed prefill+decode program (ISSUE 14) — built only
         # on the unified path; its shape key is (token_budget, slots,
         # steps, kv-dtype, mp) and warm() compiles it once
-        self._unified = jax.jit(
-            self._shard_program(self._build_unified_step(), 13, 4),
-            donate_argnums=(1, 2)) if self.unified else None
+        self._unified = self._program(
+            self._build_unified_step(), "serve_unified_step", 13, 4) \
+            if self.unified else None
         # speculative verify: one ragged window of spec_k+1 rows per
         # slot scores every draft + the pending token in a single pass
         # (models/llama._make_verify_window); built only when the
         # policy is on, so "off" stays byte-identical
-        self._verify = jax.jit(
-            self._shard_program(self._build_verify_chunk(), 4, 1),
-            donate_argnums=(1, 2)) if self.spec_k else None
+        self._verify = self._program(
+            self._build_verify_chunk(), "serve_verify_chunk", 4, 1) \
+            if self.spec_k else None
         # the request currently streaming prefill windows through the
         # unified step: {"req": ServeRequest, "done": tokens committed}
         self._prefilling = None
         self.prefill_chunks = 0  # unified prefill windows dispatched
         self.chunk_tokens = 0    # prompt tokens prefilled via windows
         self.device_steps = 0    # decode-chunk dispatches (for metrics)
+        self.sched_iters = 0     # scheduling iterations (spans' `iter`)
+        self._step_kind = "decode"  # this iteration's `sched.step` kind
         self.prefill_calls = 0   # batched-admission device calls
         self.spec_steps = 0      # speculative verify dispatches
         self.spec_drafted = 0    # draft tokens offered for verification
@@ -818,6 +821,17 @@ class ContinuousBatchingEngine:
         out_specs = (P(),) * n_out_repl + (pools, pools)
         return shard_map(fn, mesh=self.mp_mesh, in_specs=in_specs,
                          out_specs=out_specs, check_vma=False)
+
+    def _program(self, fn, name: str, n_repl: int, n_out_repl: int):
+        """An engine device program, sharded over the serving mesh and
+        jitted under the name of its ROLE: the compiler's module and
+        the profiler's "XLA Modules" row read `jit_<name>`, so the pure
+        decode chunk and the mixed step are two programs in a trace
+        (every builder's closure is called `run`). The pools (arguments
+        1 and 2) are donated."""
+        fn = self._shard_program(fn, n_repl, n_out_repl)
+        fn.__name__ = name
+        return jax.jit(fn, donate_argnums=(1, 2))
 
     @property
     def n_active(self) -> int:
@@ -1565,9 +1579,9 @@ class ContinuousBatchingEngine:
                self.spec_k, self.cp, int(self.quantized_collectives),
                self.mp)
         if key not in self._prefill_cache:
-            self._prefill_cache[key] = jax.jit(
-                self._shard_program(self._build_prefill(sb, bsz), 6, 1),
-                donate_argnums=(1, 2))
+            self._prefill_cache[key] = self._program(
+                self._build_prefill(sb, bsz),
+                f"serve_prefill_s{sb}_b{bsz}", 6, 1)
         return self._prefill_cache[key]
 
     def _get_prefix_prefill(self, sb: int, bsz: int, w_pre: int):
@@ -1575,10 +1589,9 @@ class ContinuousBatchingEngine:
                self.use_megakernel, self.spec_k, self.cp,
                int(self.quantized_collectives), self.mp)
         if key not in self._prefill_cache:
-            self._prefill_cache[key] = jax.jit(
-                self._shard_program(
-                    self._build_prefix_prefill(sb, bsz, w_pre), 8, 1),
-                donate_argnums=(1, 2))
+            self._prefill_cache[key] = self._program(
+                self._build_prefix_prefill(sb, bsz, w_pre),
+                f"serve_prefix_prefill_s{sb}_b{bsz}_w{w_pre}", 8, 1)
         return self._prefill_cache[key]
 
     def _prefix_width_ladder(self) -> list:
@@ -2243,9 +2256,11 @@ class ContinuousBatchingEngine:
         block tables and prefill only the suffix; cold rows take the
         flash-attention prefill path unchanged. After commit, every
         freshly computed full prompt block is inserted into the prefix
-        cache for future requests."""
+        cache for future requests. Returns the ids of the requests
+        admitted (the caller's `sched.admit` span names them)."""
+        admitted = []
         if self._admission_paused:
-            return
+            return admitted
         bs = self.block_size
         while self.waiting:
             self._check_owner(token)
@@ -2256,13 +2271,13 @@ class ContinuousBatchingEngine:
                 # decoupling is the disaggregation
                 room = self.slots - len(self._handoff)
                 if room <= 0:
-                    return
+                    return admitted
                 limit = min(room, self.prefill_batch)
             else:
                 free_slots = [i for i, s in enumerate(self._slots)
                               if s.req is None]
                 if not free_slots:
-                    return
+                    return admitted
                 limit = min(len(free_slots), self.prefill_batch)
             head = self._plan(self.waiting[0])
             key = (head.sb_suf, head.n_cached > 0)
@@ -2282,7 +2297,7 @@ class ContinuousBatchingEngine:
                 batch.append(req)
                 plans.append(plan)
             if not batch:
-                return  # head is blocked on pages
+                return admitted  # head is blocked on pages
             sb_suf, has_prefix = key
             n_pre = sb_suf // bs
             bsz = 1
@@ -2300,61 +2315,59 @@ class ContinuousBatchingEngine:
             ptbl = np.full((bsz, w_call), self.scratch_page, np.int32)
             plens = np.zeros((bsz,), np.int32)
             tr, mt = self._tracer, self._metrics
-            t_disp0 = time.perf_counter()
-            with self._commit_lock:
-                self._check_owner(token)
-                # pin every row's cached prefix BEFORE any alloc —
-                # alloc_pages evicts refcount-0 cached pages, and a
-                # pinned page can never be the victim
-                acquired = [self.mgr.acquire_prefix(
-                                req.prompt, plan.n_cached,
-                                hashes=req.block_hashes)
-                            if plan.n_cached else []
-                            for req, plan in zip(batch, plans)]
-                for row, (req, plan) in enumerate(zip(batch, plans)):
-                    cached = acquired[row]
-                    priv = self.mgr.alloc_pages(plan.need)
-                    req.bucket = sb_suf
-                    if not self.disaggregated:
-                        req.slot = free_slots[row]
-                    req.pages = cached + priv
-                    req.n_prefix = len(cached)
-                    req.cached_tokens = len(cached) * bs
-                    suffix = req.prompt[req.cached_tokens:]
-                    ids[row, :len(suffix)] = suffix
-                    s0s[row] = len(suffix)
-                    pages[row] = priv[:n_pre]
-                    if cached:
-                        ptbl[row, :len(cached)] = cached
-                        plens[row] = req.cached_tokens
-                self._key, k = jax.random.split(self._key)
-                self.prefill_calls += 1
-                if has_prefix:
-                    fn = self._get_prefix_prefill(sb_suf, bsz, w_call)
-                    out = fn(self.p, self.kcs, self.vcs, jnp.asarray(ids),
-                             jnp.asarray(s0s), jnp.asarray(pages),
-                             jnp.asarray(ptbl), jnp.asarray(plens), k,
-                             jnp.asarray(self.temperature, jnp.float32),
-                             jnp.asarray(self.top_p, jnp.float32))
-                else:
-                    fn = self._get_prefill(sb_suf, bsz)
-                    out = fn(self.p, self.kcs, self.vcs, jnp.asarray(ids),
-                             jnp.asarray(s0s), jnp.asarray(pages), k,
-                             jnp.asarray(self.temperature, jnp.float32),
-                             jnp.asarray(self.top_p, jnp.float32))
-                firsts_dev, self.kcs, self.vcs = out
-            # blocking readback OUTSIDE the lock: a hung device wait
-            # must never hold the lock the timeout path needs
-            firsts = np.asarray(firsts_dev)
-            if tr is not None:
-                # dispatch + readback as ONE span: the prefill program's
-                # host-visible cost for this admission batch
-                tr.complete("prefill.dispatch",
-                            int(t_disp0 * 1e9),
-                            time.perf_counter_ns(),
-                            bucket=sb_suf, batch=len(batch),
-                            cached_prefix=has_prefix,
-                            req_ids=[r.req_id for r in batch])
+            # dispatch + readback as ONE span: the prefill program's
+            # host-visible cost for this admission batch
+            with (_NULL_SPAN if tr is None else tr.span(
+                    "prefill.dispatch", bucket=sb_suf, batch=len(batch),
+                    cached_prefix=has_prefix,
+                    req_ids=[r.req_id for r in batch])):
+                t_disp0 = time.perf_counter()
+                with self._commit_lock:
+                    self._check_owner(token)
+                    # pin every row's cached prefix BEFORE any alloc —
+                    # alloc_pages evicts refcount-0 cached pages, and a
+                    # pinned page can never be the victim
+                    acquired = [self.mgr.acquire_prefix(
+                                    req.prompt, plan.n_cached,
+                                    hashes=req.block_hashes)
+                                if plan.n_cached else []
+                                for req, plan in zip(batch, plans)]
+                    for row, (req, plan) in enumerate(zip(batch, plans)):
+                        cached = acquired[row]
+                        priv = self.mgr.alloc_pages(plan.need)
+                        req.bucket = sb_suf
+                        if not self.disaggregated:
+                            req.slot = free_slots[row]
+                        req.pages = cached + priv
+                        req.n_prefix = len(cached)
+                        req.cached_tokens = len(cached) * bs
+                        suffix = req.prompt[req.cached_tokens:]
+                        ids[row, :len(suffix)] = suffix
+                        s0s[row] = len(suffix)
+                        pages[row] = priv[:n_pre]
+                        if cached:
+                            ptbl[row, :len(cached)] = cached
+                            plens[row] = req.cached_tokens
+                    self._key, k = jax.random.split(self._key)
+                    self.prefill_calls += 1
+                    self._step_kind = "mixed"
+                    if has_prefix:
+                        fn = self._get_prefix_prefill(sb_suf, bsz, w_call)
+                        out = fn(self.p, self.kcs, self.vcs, jnp.asarray(ids),
+                                 jnp.asarray(s0s), jnp.asarray(pages),
+                                 jnp.asarray(ptbl), jnp.asarray(plens), k,
+                                 jnp.asarray(self.temperature, jnp.float32),
+                                 jnp.asarray(self.top_p, jnp.float32))
+                    else:
+                        fn = self._get_prefill(sb_suf, bsz)
+                        out = fn(self.p, self.kcs, self.vcs, jnp.asarray(ids),
+                                 jnp.asarray(s0s), jnp.asarray(pages), k,
+                                 jnp.asarray(self.temperature, jnp.float32),
+                                 jnp.asarray(self.top_p, jnp.float32))
+                    firsts_dev, self.kcs, self.vcs = out
+                # blocking readback OUTSIDE the lock: a hung device wait
+                # must never hold the lock the timeout path needs
+                firsts = np.asarray(firsts_dev)
             if mt is not None:
                 mt.histogram(
                     "prefill_chunk_s",
@@ -2367,63 +2380,70 @@ class ContinuousBatchingEngine:
             # leaking beats racing the live thread for the free list.
             # The lock makes check+commit atomic against the timeout
             # path's epoch-bump+retire.
-            with self._commit_lock:
-                self._check_owner(token)
-                del self.waiting[:len(batch)]
-                now = time.perf_counter()
-                for row, (req, plan) in enumerate(zip(batch, plans)):
-                    first = int(firsts[row])
-                    req.tokens.append(first)
-                    req.prefill_time = now
-                    self.prompt_tokens += len(req.prompt)
-                    self.prefix_hit_tokens += req.cached_tokens
-                    if tr is not None:
-                        tr.instant("req.admit", req_id=req.req_id,
-                                   cached_tokens=req.cached_tokens,
-                                   suffix_bucket=sb_suf)
-                    if mt is not None:
-                        # TTFT = arrival -> first token committed;
-                        # queue wait = arrival -> prefill dispatch
-                        mt.histogram(
-                            "ttft_s", "arrival to first token").observe(
-                                now - req.arrival_time)
-                        mt.histogram(
-                            "queue_wait_s",
-                            "arrival to prefill dispatch").observe(
-                                max(t_disp0 - req.arrival_time, 0.0))
-                        mt.counter("requests_admitted").inc()
-                        mt.counter("prompt_tokens").inc(len(req.prompt))
-                        mt.counter("prefix_hit_tokens").inc(
-                            req.cached_tokens)
-                    if self.prefix_cache:
-                        # register every freshly computed FULL prompt
-                        # block (its K/V is prefix-deterministic; decode
-                        # writes start at position len(prompt), never
-                        # inside it) — first writer wins on hash races
-                        full = len(req.prompt) // bs
-                        if full > req.n_prefix:
-                            self.prefix_inserts += self.mgr.insert_prefix(
-                                req.prompt,
-                                req.pages[req.n_prefix:full],
-                                start_block=req.n_prefix,
-                                hashes=req.block_hashes)
-                    if self.disaggregated:
-                        # prefill -> decode HANDOFF: the "KV transfer"
-                        # is nothing — the pages (sharded under mp) are
-                        # already resident; the decode worker maps them
-                        # through the replicated block table at install
-                        self.prefill_handoffs += 1
+            with (_NULL_SPAN if tr is None else tr.span(
+                    "sched.commit", iter=self.sched_iters,
+                    produced=len(batch))) as sp:
+                with self._commit_lock:
+                    self._check_owner(token)
+                    del self.waiting[:len(batch)]
+                    now = time.perf_counter()
+                    for row, (req, plan) in enumerate(zip(batch, plans)):
+                        first = int(firsts[row])
+                        req.tokens.append(first)
+                        req.prefill_time = now
+                        self.prompt_tokens += len(req.prompt)
+                        self.prefix_hit_tokens += req.cached_tokens
                         if tr is not None:
-                            tr.instant("req.handoff", req_id=req.req_id)
+                            tr.instant("req.admit", req_id=req.req_id,
+                                       cached_tokens=req.cached_tokens,
+                                       suffix_bucket=sb_suf)
                         if mt is not None:
-                            mt.counter("prefill_handoffs").inc()
-                        if (self.eos is not None and first == self.eos) \
-                                or req.max_new == 1:
-                            self._finish_prefilled(req)
+                            # TTFT = arrival -> first token committed;
+                            # queue wait = arrival -> prefill dispatch
+                            mt.histogram(
+                                "ttft_s", "arrival to first token").observe(
+                                    now - req.arrival_time)
+                            mt.histogram(
+                                "queue_wait_s",
+                                "arrival to prefill dispatch").observe(
+                                    max(t_disp0 - req.arrival_time, 0.0))
+                            mt.counter("requests_admitted").inc()
+                            mt.counter("prompt_tokens").inc(len(req.prompt))
+                            mt.counter("prefix_hit_tokens").inc(
+                                req.cached_tokens)
+                        if self.prefix_cache:
+                            # register every freshly computed FULL prompt
+                            # block (its K/V is prefix-deterministic; decode
+                            # writes start at position len(prompt), never
+                            # inside it) — first writer wins on hash races
+                            full = len(req.prompt) // bs
+                            if full > req.n_prefix:
+                                self.prefix_inserts += self.mgr.insert_prefix(
+                                    req.prompt,
+                                    req.pages[req.n_prefix:full],
+                                    start_block=req.n_prefix,
+                                    hashes=req.block_hashes)
+                        if self.disaggregated:
+                            # prefill -> decode HANDOFF: the "KV transfer"
+                            # is nothing — the pages (sharded under mp) are
+                            # already resident; the decode worker maps them
+                            # through the replicated block table at install
+                            self.prefill_handoffs += 1
+                            if tr is not None:
+                                tr.instant("req.handoff", req_id=req.req_id)
+                            if mt is not None:
+                                mt.counter("prefill_handoffs").inc()
+                            if (self.eos is not None and first == self.eos) \
+                                    or req.max_new == 1:
+                                self._finish_prefilled(req)
+                            else:
+                                self._handoff.append(req)
                         else:
-                            self._handoff.append(req)
-                    else:
-                        self._bind_slot(req.slot, req)
+                            self._bind_slot(req.slot, req)
+                if tr is not None:
+                    sp.set(retired=[r.req_id for r in batch if r.done],
+                           emitted={r.req_id: 1 for r in batch})
+            admitted += [r.req_id for r in batch]
             # LRU prefix evictions since last report (alloc_pages evicts
             # under pool pressure; surfacing the delta here keeps the
             # manager observability-free)
@@ -2434,6 +2454,7 @@ class ContinuousBatchingEngine:
                     tr.instant("prefix.evict", n=ev_delta)
                 if mt is not None:
                     mt.counter("prefix_evictions").inc(ev_delta)
+        return admitted
 
     def _bind_slot(self, slot_id: int, req: ServeRequest):
         """Install a prefilled request into a decode slot: map its
@@ -2482,13 +2503,22 @@ class ContinuousBatchingEngine:
         replicated block table row and chunk seeds."""
         if not self._handoff:
             return
-        with self._commit_lock:
-            self._check_owner(token)
-            for slot_id, slot in enumerate(self._slots):
-                if not self._handoff:
-                    break
-                if slot.req is None:
-                    self._bind_slot(slot_id, self._handoff.pop(0))
+        tr = self._tracer
+        with (_NULL_SPAN if tr is None else tr.span(
+                "sched.admit", iter=self.sched_iters,
+                waiting=len(self._handoff))) as sp:
+            installed = []
+            with self._commit_lock:
+                self._check_owner(token)
+                for slot_id, slot in enumerate(self._slots):
+                    if not self._handoff:
+                        break
+                    if slot.req is None:
+                        req = self._handoff.pop(0)
+                        self._bind_slot(slot_id, req)
+                        installed.append(req.req_id)
+            if tr is not None:
+                sp.set(req_ids=installed)
 
     # ---- unified ragged step scheduling (ISSUE 14) ----------------------
 
@@ -2523,23 +2553,24 @@ class ContinuousBatchingEngine:
         prefill admission never queues behind decode occupancy). The
         request's WHOLE reservation (cached prefix pinned + private
         pages) commits here; its prompt then streams through
-        `token_budget` windows across steps."""
+        `token_budget` windows across steps. Returns the ids of the
+        requests admitted (one or none)."""
         if self._admission_paused:
-            return
+            return []
         if self._prefilling is not None or not self.waiting:
-            return
+            return []
         req = self.waiting[0]
         plan = self._plan_unified(req)
         if self.disaggregated:
             if len(self._handoff) >= self.slots:
-                return
+                return []
         else:
             # one free slot now guarantees one at completion: only
             # completion binds slots in unified mode, retires only add
             if not any(s.req is None for s in self._slots):
-                return
+                return []
         if plan.need + plan.n_lru > self.mgr.n_available:
-            return
+            return []
         tr, mt = self._tracer, self._metrics
         with self._commit_lock:
             self._check_owner(token)
@@ -2567,6 +2598,7 @@ class ContinuousBatchingEngine:
                 tr.instant("prefix.evict", n=ev_delta)
             if mt is not None:
                 mt.counter("prefix_evictions").inc(ev_delta)
+        return [req.req_id]
 
     def _dispatch_commit_unified(self, token: Optional[int] = None) -> int:
         """One MIXED step: dispatch the unified program — every live
@@ -2577,86 +2609,98 @@ class ContinuousBatchingEngine:
         first token into a slot bind (or disaggregated handoff)."""
         st = self._prefilling
         req = st["req"]
-        L = len(req.prompt)
-        done = st["done"]
-        tn, bs = self.token_budget, self.block_size
-        n_win = tn // bs
-        this_chunk = min(L - done, tn)
-        wp0 = done // bs
-        win_pages = req.pages[wp0:wp0 + n_win]
-        win_pages += [self.scratch_page] * (n_win - len(win_pages))
-        ids = np.zeros((1, tn), np.int32)
-        ids[0, :this_chunk] = req.prompt[done:done + this_chunk]
-        tbl = np.full((1, self.table_width), self.scratch_page, np.int32)
-        tbl[0, :len(req.pages)] = req.pages
+        tr, mt = self._tracer, self._metrics
+        with (_NULL_SPAN if tr is None else tr.span(
+                "sched.build", iter=self.sched_iters, req_id=req.req_id)):
+            L = len(req.prompt)
+            done = st["done"]
+            tn, bs = self.token_budget, self.block_size
+            n_win = tn // bs
+            this_chunk = min(L - done, tn)
+            wp0 = done // bs
+            win_pages = req.pages[wp0:wp0 + n_win]
+            win_pages += [self.scratch_page] * (n_win - len(win_pages))
+            ids = np.zeros((1, tn), np.int32)
+            ids[0, :this_chunk] = req.prompt[done:done + this_chunk]
+            tbl = np.full((1, self.table_width), self.scratch_page,
+                          np.int32)
+            tbl[0, :len(req.pages)] = req.pages
         if self._watchdog is not None:
             self._watchdog.phase = "decode"
         chaos.maybe_hang("decode")
-        tr, mt = self._tracer, self._metrics
-        t_disp0 = time.perf_counter()
-        with self._commit_lock:
-            self._check_owner(token)
-            st["dispatched"] = True
-            self._key, k = jax.random.split(self._key)
-            live = np.asarray([s.req is not None for s in self._slots])
-            res = self._unified(
-                self.p, self.kcs, self.vcs, jnp.asarray(self._tokens),
-                jnp.asarray(np.asarray([s.length for s in self._slots],
-                                       np.int32)),
-                jnp.asarray(self._budgets), jnp.asarray(self._tables),
-                jnp.asarray(live), jnp.asarray(ids), jnp.asarray(tbl),
-                jnp.asarray([done], np.int32),
-                jnp.asarray([this_chunk], np.int32),
-                jnp.asarray([win_pages], np.int32), k,
-                jnp.asarray(self.temperature, jnp.float32),
-                jnp.asarray(self.top_p, jnp.float32))
-            out, new_lens, dn, first_dev, self.kcs, self.vcs = res
-            self.device_steps += 1
-            self.prefill_chunks += 1
-            # a mixed step is authoritative host state — never chain a
-            # pipelined decode chunk across it
-            self._chain_tok = None
-            self._chain_lens = None
-            self._override[:] = True
-            if tr is not None:
-                tr.complete("decode.dispatch", int(t_disp0 * 1e9),
-                            time.perf_counter_ns(),
-                            chunk=self.device_steps,
-                            live=int(live.sum()), prefill_window=True,
-                            req_id=req.req_id)
-            if mt is not None:
-                mt.gauge("live_slots", "slots decoding").set(
-                    int(live.sum()))
-                mt.gauge("kv_pages_available",
-                         "free + evictable pool pages").set(
-                             self.mgr.n_available)
-            rec = {"out": out, "lens": new_lens, "done": dn,
-                   "reqs": [s.req for s in self._slots],
-                   "t_disp0": t_disp0}
-        produced = self._commit_chunk(rec, token)
-        first = int(np.asarray(first_dev)[0])
-        if tr is not None:
-            # the window is this request's prefill work for the step —
-            # span-coverage checks see the same prefill.dispatch
-            # lifecycle event the split engine's batched admit emits
-            tr.complete("prefill.dispatch", int(t_disp0 * 1e9),
-                        time.perf_counter_ns(),
-                        bucket=self.token_budget, batch=1,
-                        cached_prefix=req.n_prefix > 0,
-                        chunk_tokens=this_chunk,
-                        req_ids=[req.req_id])
+        self._step_kind = "mixed"
+        # the window is this request's prefill work for the step —
+        # span-coverage checks see the same prefill.dispatch lifecycle
+        # event the split engine's batched admit emits
+        with (_NULL_SPAN if tr is None else tr.span(
+                "prefill.dispatch", bucket=self.token_budget, batch=1,
+                cached_prefix=req.n_prefix > 0, chunk_tokens=this_chunk,
+                req_ids=[req.req_id])):
+            with (_NULL_SPAN if tr is None
+                  else tr.span("decode.dispatch")) as sp:
+                t_disp0 = time.perf_counter()
+                with self._commit_lock:
+                    self._check_owner(token)
+                    st["dispatched"] = True
+                    self._key, k = jax.random.split(self._key)
+                    live = np.asarray(
+                        [s.req is not None for s in self._slots])
+                    res = self._unified(
+                        self.p, self.kcs, self.vcs,
+                        jnp.asarray(self._tokens),
+                        jnp.asarray(np.asarray(
+                            [s.length for s in self._slots], np.int32)),
+                        jnp.asarray(self._budgets),
+                        jnp.asarray(self._tables),
+                        jnp.asarray(live), jnp.asarray(ids),
+                        jnp.asarray(tbl),
+                        jnp.asarray([done], np.int32),
+                        jnp.asarray([this_chunk], np.int32),
+                        jnp.asarray([win_pages], np.int32), k,
+                        jnp.asarray(self.temperature, jnp.float32),
+                        jnp.asarray(self.top_p, jnp.float32))
+                    out, new_lens, dn, first_dev, self.kcs, self.vcs = res
+                    self.device_steps += 1
+                    self.prefill_chunks += 1
+                    # a mixed step is authoritative host state — never
+                    # chain a pipelined decode chunk across it
+                    self._chain_tok = None
+                    self._chain_lens = None
+                    self._override[:] = True
+                    if tr is not None:
+                        sp.set(chunk=self.device_steps,
+                               live=int(live.sum()), prefill_window=True,
+                               req_id=req.req_id)
+                    if mt is not None:
+                        mt.gauge("live_slots", "slots decoding").set(
+                            int(live.sum()))
+                        mt.gauge("kv_pages_available",
+                                 "free + evictable pool pages").set(
+                                     self.mgr.n_available)
+                    rec = {"out": out, "lens": new_lens, "done": dn,
+                           "reqs": [s.req for s in self._slots],
+                           "t_disp0": t_disp0}
+            produced = self._commit_chunk(rec, token)
+            first = int(np.asarray(first_dev)[0])
         if mt is not None:
             mt.histogram(
                 "prefill_chunk_s",
                 "prefill dispatch + first-token readback").observe(
                     time.perf_counter() - t_disp0)
-        with self._commit_lock:
-            self._check_owner(token)
-            st["done"] = done + this_chunk
-            self.chunk_tokens += this_chunk
-            if st["done"] >= L:
-                self._prefilling = None
-                self._finish_unified_prefill(req, first, st["t0"])
+        with (_NULL_SPAN if tr is None else tr.span(
+                "sched.commit", iter=self.sched_iters)) as sp:
+            with self._commit_lock:
+                self._check_owner(token)
+                st["done"] = done + this_chunk
+                self.chunk_tokens += this_chunk
+                final = st["done"] >= L
+                if final:
+                    self._prefilling = None
+                    self._finish_unified_prefill(req, first, st["t0"])
+            if tr is not None:
+                sp.set(produced=int(final),
+                       retired=[req.req_id] if req.done else [],
+                       emitted={req.req_id: 1} if final else {})
         return produced
 
     def _finish_unified_prefill(self, req: ServeRequest, first: int,
@@ -2721,10 +2765,15 @@ class ContinuousBatchingEngine:
         MIXED dispatch of the unified program; any pipelined chunk in
         flight commits first (its device-side chain cannot span a
         program that rewrites host state)."""
-        wd = self._watchdog
+        wd, tr = self._watchdog, self._tracer
         if wd is not None:
             wd.phase = "admit"
-        self._admit_unified(token)
+        with (_NULL_SPAN if tr is None else tr.span(
+                "sched.admit", iter=self.sched_iters,
+                waiting=len(self.waiting))) as sp:
+            admitted = self._admit_unified(token)
+            if tr is not None:
+                sp.set(req_ids=admitted)
         if self.disaggregated:
             self._install_handoffs(token)
         if self._prefilling is not None:
@@ -2818,54 +2867,56 @@ class ContinuousBatchingEngine:
         # donated KV pools from a dead thread
         chaos.maybe_hang("decode")
         tr, mt = self._tracer, self._metrics
-        t_disp0 = time.perf_counter()
-        with self._commit_lock:
-            self._check_owner(token)
-            self._key, k = jax.random.split(self._key)
-            host_toks = jnp.asarray(self._tokens)
-            host_lens = jnp.asarray(np.asarray(
-                [s.length for s in self._slots], np.int32))
-            if chain and self._chain_tok is not None \
-                    and not self._override.all():
-                ov = jnp.asarray(self._override)
-                toks_in = jnp.where(ov, host_toks, self._chain_tok)
-                lens_in = jnp.where(ov, host_lens, self._chain_lens)
-            else:
-                toks_in, lens_in = host_toks, host_lens
-            res = self._decode(
-                self.p, self.kcs, self.vcs, toks_in, lens_in,
-                jnp.asarray(self._budgets), jnp.asarray(self._tables),
-                jnp.asarray(live), k,
-                jnp.asarray(self.temperature, jnp.float32),
-                jnp.asarray(self.top_p, jnp.float32))
-            out, new_lens, done, self.kcs, self.vcs = res
-            self.device_steps += 1
-            if chain:
-                self._chain_tok = out[:, -1]
-                self._chain_lens = new_lens
-                self._override[:] = False
-            else:
-                # host state is authoritative after a synchronous step;
-                # a later pipelined dispatch must not chain a stale chunk
-                self._chain_tok = None
-                self._chain_lens = None
-                self._override[:] = True
-            if tr is not None:
-                tr.complete("decode.dispatch", int(t_disp0 * 1e9),
-                            time.perf_counter_ns(),
-                            chunk=self.device_steps,
-                            live=int(live.sum()))
-            if mt is not None:
-                mt.gauge("live_slots", "slots decoding").set(
-                    int(live.sum()))
-                mt.gauge("kv_pages_available",
-                         "free + evictable pool pages").set(
-                             self.mgr.n_available)
-            # dispatch wall time rides the record: _commit_chunk turns
-            # (dispatch start -> readback done) into decode_chunk_s
-            return {"out": out, "lens": new_lens, "done": done,
-                    "reqs": [s.req for s in self._slots],
-                    "t_disp0": t_disp0}
+        with (_NULL_SPAN if tr is None
+              else tr.span("decode.dispatch")) as sp:
+            t_disp0 = time.perf_counter()
+            with self._commit_lock:
+                self._check_owner(token)
+                self._key, k = jax.random.split(self._key)
+                host_toks = jnp.asarray(self._tokens)
+                host_lens = jnp.asarray(np.asarray(
+                    [s.length for s in self._slots], np.int32))
+                if chain and self._chain_tok is not None \
+                        and not self._override.all():
+                    ov = jnp.asarray(self._override)
+                    toks_in = jnp.where(ov, host_toks, self._chain_tok)
+                    lens_in = jnp.where(ov, host_lens, self._chain_lens)
+                else:
+                    toks_in, lens_in = host_toks, host_lens
+                res = self._decode(
+                    self.p, self.kcs, self.vcs, toks_in, lens_in,
+                    jnp.asarray(self._budgets), jnp.asarray(self._tables),
+                    jnp.asarray(live), k,
+                    jnp.asarray(self.temperature, jnp.float32),
+                    jnp.asarray(self.top_p, jnp.float32))
+                out, new_lens, done, self.kcs, self.vcs = res
+                self.device_steps += 1
+                if chain:
+                    self._chain_tok = out[:, -1]
+                    self._chain_lens = new_lens
+                    self._override[:] = False
+                else:
+                    # host state is authoritative after a synchronous
+                    # step; a later pipelined dispatch must not chain a
+                    # stale chunk
+                    self._chain_tok = None
+                    self._chain_lens = None
+                    self._override[:] = True
+                if tr is not None:
+                    sp.set(chunk=self.device_steps, live=int(live.sum()))
+                if mt is not None:
+                    mt.gauge("live_slots", "slots decoding").set(
+                        int(live.sum()))
+                    mt.gauge("kv_pages_available",
+                             "free + evictable pool pages").set(
+                                 self.mgr.n_available)
+                # dispatch wall time rides the record: _commit_chunk
+                # turns (dispatch start -> readback done) into
+                # decode_chunk_s
+                rec = {"out": out, "lens": new_lens, "done": done,
+                       "reqs": [s.req for s in self._slots],
+                       "t_disp0": t_disp0}
+        return rec
 
     def _commit_chunk(self, rec, token: Optional[int] = None) -> int:
         """Block on a dispatched chunk's host-visible outputs and commit
@@ -2876,53 +2927,65 @@ class ContinuousBatchingEngine:
         are confined to pages that are overwritten before any new owner
         reads them. Returns live tokens produced."""
         tr, mt = self._tracer, self._metrics
-        t0 = time.perf_counter()
-        out = np.asarray(rec["out"])          # the blocking host sync
-        new_lens = np.asarray(rec["lens"])
-        done = np.asarray(rec["done"])
-        t1 = time.perf_counter()
-        wait = t1 - t0
-        stalled = wait > self.stall_threshold_s
-        if tr is not None:
-            # the sync wait was timed anyway — record it retroactively
-            # (a `stalled` span is the double-buffer stall the pipeline
-            # exists to hide; Perfetto query: name='decode.sync_wait'
-            # AND args.stalled)
-            tr.complete("decode.sync_wait", int(t0 * 1e9), int(t1 * 1e9),
-                        stalled=stalled)
-        if mt is not None:
-            mt.histogram("sync_wait_s",
-                         "host blocked on decode readback").observe(wait)
-            mt.histogram("decode_chunk_s",
-                         "decode-chunk dispatch to readback").observe(
-                             t1 - rec.get("t_disp0", t0))
-            if stalled:
-                mt.counter("blocked_syncs").inc()
-        with self._commit_lock:
-            self._check_owner(token)  # abandoned mid-wait: discard
-            self.sync_wait_s += wait
-            if wait > self.stall_threshold_s:
-                self.blocked_syncs += 1
-            produced = 0
-            for slot_id, slot in enumerate(self._slots):
-                req = rec["reqs"][slot_id]
-                if req is None or slot.req is not req or req.done:
-                    continue
-                take = min(self.steps, req.max_new - slot.emitted)
-                toks = out[slot_id, :take].tolist()
-                if self.eos is not None and self.eos in toks:
-                    toks = toks[:toks.index(self.eos) + 1]
-                req.tokens.extend(toks)
-                produced += len(toks)
-                slot.emitted += len(toks)
-                slot.length = int(new_lens[slot_id])
-                slot.done = bool(done[slot_id])
-                self._tokens[slot_id] = toks[-1] if toks else 0
-                if slot.done or slot.emitted >= req.max_new:
-                    self._retire(slot_id)
+        # a `stalled` span is the double-buffer stall the pipeline
+        # exists to hide (Perfetto query: name='decode.sync_wait' AND
+        # args.stalled)
+        with (_NULL_SPAN if tr is None
+              else tr.span("decode.sync_wait")) as sp:
+            t0 = time.perf_counter()
+            out = np.asarray(rec["out"])      # the blocking host sync
+            new_lens = np.asarray(rec["lens"])
+            done = np.asarray(rec["done"])
+            t1 = time.perf_counter()
+            wait = t1 - t0
+            stalled = wait > self.stall_threshold_s
+            if tr is not None:
+                sp.set(stalled=stalled)
+        with (_NULL_SPAN if tr is None else tr.span(
+                "sched.commit", iter=self.sched_iters)) as sp:
             if mt is not None:
-                mt.counter("output_tokens").inc(produced)
-            return produced
+                mt.histogram(
+                    "sync_wait_s",
+                    "host blocked on decode readback").observe(wait)
+                mt.histogram("decode_chunk_s",
+                             "decode-chunk dispatch to readback").observe(
+                                 t1 - rec.get("t_disp0", t0))
+                if stalled:
+                    mt.counter("blocked_syncs").inc()
+            if tr is not None:
+                retired, emitted = [], {}
+            with self._commit_lock:
+                self._check_owner(token)  # abandoned mid-wait: discard
+                self.sync_wait_s += wait
+                if stalled:
+                    self.blocked_syncs += 1
+                produced = 0
+                for slot_id, slot in enumerate(self._slots):
+                    req = rec["reqs"][slot_id]
+                    if req is None or slot.req is not req or req.done:
+                        continue
+                    take = min(self.steps, req.max_new - slot.emitted)
+                    toks = out[slot_id, :take].tolist()
+                    if self.eos is not None and self.eos in toks:
+                        toks = toks[:toks.index(self.eos) + 1]
+                    req.tokens.extend(toks)
+                    produced += len(toks)
+                    slot.emitted += len(toks)
+                    slot.length = int(new_lens[slot_id])
+                    slot.done = bool(done[slot_id])
+                    self._tokens[slot_id] = toks[-1] if toks else 0
+                    if slot.done or slot.emitted >= req.max_new:
+                        self._retire(slot_id)
+                    if tr is not None:
+                        emitted[req.req_id] = len(toks)
+                        if req.done:
+                            retired.append(req.req_id)
+                if mt is not None:
+                    mt.counter("output_tokens").inc(produced)
+            if tr is not None:
+                sp.set(produced=produced, retired=retired,
+                       emitted=emitted)
+        return produced
 
     def _step_spec(self, token: Optional[int] = None) -> int:
         """One speculative iteration (ISSUE 19): draft up to spec_k
@@ -2955,123 +3018,142 @@ class ContinuousBatchingEngine:
         # as a narrower ragged window (same program, no recompile)
         k_eff = self._spec_policy.spec_k_effective \
             if self._spec_policy is not None else k
-        t_disp0 = time.perf_counter()
-        with self._commit_lock:
-            self._check_owner(token)
-            ids = np.zeros((b, k + 1), np.int32)
-            new_lens = np.ones((b,), np.int32)
-            lens = np.asarray([s.length for s in self._slots], np.int32)
-            drafts = [None] * b
-            reqs = [s.req for s in self._slots]
-            for slot_id, slot in enumerate(self._slots):
-                req = slot.req
-                if req is None:
-                    continue  # dead rows ride along on the scratch page
-                ids[slot_id, 0] = self._tokens[slot_id]
-                # never draft past the row budget: window position L+j
-                # writes K/V there, and the corrected token needs its
-                # own headroom too
-                want = min(k_eff,
-                           int(self._budgets[slot_id]) - slot.length - 1,
-                           req.max_new - slot.emitted - 1)
-                d = []
-                if want > 0 and self._drafter is not None:
-                    d = list(self._drafter.draft(
-                        slot_id, req.req_id, req.prompt + req.tokens,
-                        want, table_row=self._tables[slot_id],
-                        budget=int(self._budgets[slot_id])))[:want]
-                drafts[slot_id] = d
-                ids[slot_id, 1:1 + len(d)] = d
-                new_lens[slot_id] = 1 + len(d)
-            res = self._verify(
-                self.p, self.kcs, self.vcs, jnp.asarray(ids),
-                jnp.asarray(self._tables), jnp.asarray(lens),
-                jnp.asarray(new_lens))
-            preds_dev, self.kcs, self.vcs = res
-            self.device_steps += 1
-            self.spec_steps += 1
-            # acceptance rewrites host tokens/lengths per slot — a
-            # later pipelined dispatch must not chain stale device state
-            self._chain_tok = None
-            self._chain_lens = None
-            self._override[:] = True
-            if tr is not None:
-                tr.complete("spec.verify", int(t_disp0 * 1e9),
-                            time.perf_counter_ns(),
-                            chunk=self.device_steps,
-                            live=int(live.sum()),
-                            drafted=int(sum(len(d) for d in drafts
-                                            if d)))
-            if mt is not None:
-                mt.gauge("live_slots", "slots decoding").set(
-                    int(live.sum()))
+        self._step_kind = "spec"
+        with (_NULL_SPAN if tr is None else tr.span("spec.verify")) as sp:
+            t_disp0 = time.perf_counter()
+            with self._commit_lock:
+                self._check_owner(token)
+                with (_NULL_SPAN if tr is None else tr.span(
+                        "sched.build", iter=self.sched_iters)):
+                    ids = np.zeros((b, k + 1), np.int32)
+                    new_lens = np.ones((b,), np.int32)
+                    lens = np.asarray([s.length for s in self._slots],
+                                      np.int32)
+                    drafts = [None] * b
+                    reqs = [s.req for s in self._slots]
+                    for slot_id, slot in enumerate(self._slots):
+                        req = slot.req
+                        if req is None:
+                            continue  # dead rows ride the scratch page
+                        ids[slot_id, 0] = self._tokens[slot_id]
+                        # never draft past the row budget: window
+                        # position L+j writes K/V there, and the
+                        # corrected token needs its own headroom too
+                        want = min(
+                            k_eff,
+                            int(self._budgets[slot_id]) - slot.length - 1,
+                            req.max_new - slot.emitted - 1)
+                        d = []
+                        if want > 0 and self._drafter is not None:
+                            d = list(self._drafter.draft(
+                                slot_id, req.req_id,
+                                req.prompt + req.tokens, want,
+                                table_row=self._tables[slot_id],
+                                budget=int(self._budgets[slot_id])))[:want]
+                        drafts[slot_id] = d
+                        ids[slot_id, 1:1 + len(d)] = d
+                        new_lens[slot_id] = 1 + len(d)
+                res = self._verify(
+                    self.p, self.kcs, self.vcs, jnp.asarray(ids),
+                    jnp.asarray(self._tables), jnp.asarray(lens),
+                    jnp.asarray(new_lens))
+                preds_dev, self.kcs, self.vcs = res
+                self.device_steps += 1
+                self.spec_steps += 1
+                # acceptance rewrites host tokens/lengths per slot — a
+                # later pipelined dispatch must not chain stale device
+                # state
+                self._chain_tok = None
+                self._chain_lens = None
+                self._override[:] = True
+                if tr is not None:
+                    sp.set(chunk=self.device_steps, live=int(live.sum()),
+                           drafted=int(sum(len(d) for d in drafts if d)))
+                if mt is not None:
+                    mt.gauge("live_slots", "slots decoding").set(
+                        int(live.sum()))
         # the blocking readback stays OUTSIDE the lock — sync-wait
         # telemetry identical to _commit_chunk's
-        t0 = time.perf_counter()
-        preds = np.asarray(preds_dev)
-        t1 = time.perf_counter()
-        wait = t1 - t0
-        stalled = wait > self.stall_threshold_s
-        if tr is not None:
-            tr.complete("decode.sync_wait", int(t0 * 1e9), int(t1 * 1e9),
-                        stalled=stalled)
-        if mt is not None:
-            mt.histogram("sync_wait_s",
-                         "host blocked on decode readback").observe(wait)
-            mt.histogram("decode_chunk_s",
-                         "decode-chunk dispatch to readback").observe(
-                             t1 - t_disp0)
-            if stalled:
-                mt.counter("blocked_syncs").inc()
+        with (_NULL_SPAN if tr is None
+              else tr.span("decode.sync_wait")) as sp:
+            t0 = time.perf_counter()
+            preds = np.asarray(preds_dev)
+            t1 = time.perf_counter()
+            wait = t1 - t0
+            stalled = wait > self.stall_threshold_s
+            if tr is not None:
+                sp.set(stalled=stalled)
         if wd is not None:
             wd.phase = "commit"
-        with self._commit_lock:
-            self._check_owner(token)  # abandoned mid-wait: discard
-            self.sync_wait_s += wait
-            if stalled:
-                self.blocked_syncs += 1
-            produced = 0
-            for slot_id, slot in enumerate(self._slots):
-                req = reqs[slot_id]
-                if req is None or slot.req is not req or req.done:
-                    continue
-                d = drafts[slot_id]
-                row = preds[slot_id]
-                n_acc = 0
-                while n_acc < len(d) and d[n_acc] == int(row[n_acc]):
-                    n_acc += 1
-                # accepted drafts + the target's corrected token; clip
-                # to the request's remaining output budget, then to EOS
-                toks = d[:n_acc] + [int(row[n_acc])]
-                toks = toks[:max(req.max_new - slot.emitted, 0)]
-                if self.eos is not None and self.eos in toks:
-                    toks = toks[:toks.index(self.eos) + 1]
-                self.spec_drafted += len(d)
-                self.spec_accepted += min(n_acc, len(toks))
-                if d and self._spec_policy is not None:
-                    self._spec_policy.observe(len(d), n_acc)
-                if d and mt is not None:
-                    mt.histogram(
-                        "spec_acceptance",
-                        "accepted draft fraction per window").observe(
-                            n_acc / len(d))
-                req.tokens.extend(toks)
-                produced += len(toks)
-                slot.emitted += len(toks)
-                # the window WROTE positions L..L+new_len-1; everything
-                # before the new pending token (toks[-1]) is committed
-                # cache, the rest is garbage a later commit overwrites
-                slot.length += len(toks)
-                self._tokens[slot_id] = toks[-1] if toks else 0
-                if self._drafter is not None:
-                    self._drafter.note_commit(slot_id, slot.length)
-                if (self.eos is not None and toks
-                        and toks[-1] == self.eos) \
-                        or slot.emitted >= req.max_new:
-                    self._retire(slot_id)
+        with (_NULL_SPAN if tr is None else tr.span(
+                "sched.commit", iter=self.sched_iters)) as sp:
             if mt is not None:
-                mt.counter("output_tokens").inc(produced)
-            return produced
+                mt.histogram(
+                    "sync_wait_s",
+                    "host blocked on decode readback").observe(wait)
+                mt.histogram("decode_chunk_s",
+                             "decode-chunk dispatch to readback").observe(
+                                 t1 - t_disp0)
+                if stalled:
+                    mt.counter("blocked_syncs").inc()
+            if tr is not None:
+                retired, emitted = [], {}
+            with self._commit_lock:
+                self._check_owner(token)  # abandoned mid-wait: discard
+                self.sync_wait_s += wait
+                if stalled:
+                    self.blocked_syncs += 1
+                produced = 0
+                for slot_id, slot in enumerate(self._slots):
+                    req = reqs[slot_id]
+                    if req is None or slot.req is not req or req.done:
+                        continue
+                    d = drafts[slot_id]
+                    row = preds[slot_id]
+                    n_acc = 0
+                    while n_acc < len(d) and d[n_acc] == int(row[n_acc]):
+                        n_acc += 1
+                    # accepted drafts + the target's corrected token;
+                    # clip to the request's remaining output budget,
+                    # then to EOS
+                    toks = d[:n_acc] + [int(row[n_acc])]
+                    toks = toks[:max(req.max_new - slot.emitted, 0)]
+                    if self.eos is not None and self.eos in toks:
+                        toks = toks[:toks.index(self.eos) + 1]
+                    self.spec_drafted += len(d)
+                    self.spec_accepted += min(n_acc, len(toks))
+                    if d and self._spec_policy is not None:
+                        self._spec_policy.observe(len(d), n_acc)
+                    if d and mt is not None:
+                        mt.histogram(
+                            "spec_acceptance",
+                            "accepted draft fraction per window").observe(
+                                n_acc / len(d))
+                    req.tokens.extend(toks)
+                    produced += len(toks)
+                    slot.emitted += len(toks)
+                    # the window WROTE positions L..L+new_len-1;
+                    # everything before the new pending token
+                    # (toks[-1]) is committed cache, the rest is
+                    # garbage a later commit overwrites
+                    slot.length += len(toks)
+                    self._tokens[slot_id] = toks[-1] if toks else 0
+                    if self._drafter is not None:
+                        self._drafter.note_commit(slot_id, slot.length)
+                    if (self.eos is not None and toks
+                            and toks[-1] == self.eos) \
+                            or slot.emitted >= req.max_new:
+                        self._retire(slot_id)
+                    if tr is not None:
+                        emitted[req.req_id] = len(toks)
+                        if req.done:
+                            retired.append(req.req_id)
+                if mt is not None:
+                    mt.counter("output_tokens").inc(produced)
+            if tr is not None:
+                sp.set(produced=produced, retired=retired,
+                       emitted=emitted)
+        return produced
 
     def _drain_inflight(self, token: Optional[int] = None) -> int:
         """Commit (and clear) any pipelined chunk in flight — the
@@ -3089,24 +3171,37 @@ class ContinuousBatchingEngine:
     def step(self) -> int:
         """One synchronous scheduling iteration: admit -> decode chunk
         -> wait -> retire. Returns the number of live tokens produced."""
-        wd = self._watchdog
+        wd, tr = self._watchdog, self._tracer
         # ownership token: if the watchdog abandons this step, run()
         # bumps _step_epoch and every later commit point in THIS thread
         # raises _AbandonedStep instead of racing the live loop
         token = self._step_epoch if wd is not None else None
-        if self.unified:
-            return self._step_unified(token, pipeline=False)
-        if wd is not None:
-            wd.phase = "admit"
-        self._admit(token)
-        if self.disaggregated:
-            self._install_handoffs(token)
-        if self.spec_k:
-            return self._step_spec(token)
-        rec = self._dispatch_chunk(token, chain=False)
-        if rec is None:
-            return 0
-        return self._commit_chunk(rec, token)
+        self.sched_iters += 1
+        self._step_kind = "decode"  # a prefill or a verify renames it
+        with (_NULL_SPAN if tr is None else tr.span(
+                "sched.step", iter=self.sched_iters)) as step_sp:
+            if self.unified:
+                n = self._step_unified(token, pipeline=False)
+            else:
+                if wd is not None:
+                    wd.phase = "admit"
+                with (_NULL_SPAN if tr is None else tr.span(
+                        "sched.admit", iter=self.sched_iters,
+                        waiting=len(self.waiting))) as sp:
+                    admitted = self._admit(token)
+                    if tr is not None:
+                        sp.set(req_ids=admitted)
+                if self.disaggregated:
+                    self._install_handoffs(token)
+                if self.spec_k:
+                    n = self._step_spec(token)
+                else:
+                    rec = self._dispatch_chunk(token, chain=False)
+                    n = 0 if rec is None \
+                        else self._commit_chunk(rec, token)
+            if tr is not None:
+                step_sp.set(kind=self._step_kind)
+        return n
 
     def _pipeline_step(self) -> int:
         """One double-buffered iteration: admit, dispatch chunk N+1,
@@ -3116,28 +3211,44 @@ class ContinuousBatchingEngine:
         retirements take effect one chunk later than in synchronous
         mode; budgets and the slot-ownership snapshot keep the
         speculative chunk harmless (see module docstring)."""
-        wd = self._watchdog
+        wd, tr = self._watchdog, self._tracer
         token = self._step_epoch if wd is not None else None
-        if self.unified:
-            return self._step_unified(token, pipeline=True)
-        if wd is not None:
-            wd.phase = "admit"
-        self._admit(token)
-        if self.disaggregated:
-            self._install_handoffs(token)
-        if self.spec_k:
-            # speculative steps are synchronous (acceptance is a host
-            # decision) — drain any chained chunk, then verify
-            return self._drain_inflight(token) + self._step_spec(token)
-        rec = self._dispatch_chunk(token, chain=True)
-        with self._commit_lock:
-            self._check_owner(token)
-            prev, self._inflight = self._inflight, rec
-        if prev is not None:
-            if wd is not None:
-                wd.phase = "commit"
-            return self._commit_chunk(prev, token)
-        return 0
+        self.sched_iters += 1
+        self._step_kind = "decode"
+        with (_NULL_SPAN if tr is None else tr.span(
+                "sched.step", iter=self.sched_iters)) as step_sp:
+            if self.unified:
+                n = self._step_unified(token, pipeline=True)
+            else:
+                if wd is not None:
+                    wd.phase = "admit"
+                with (_NULL_SPAN if tr is None else tr.span(
+                        "sched.admit", iter=self.sched_iters,
+                        waiting=len(self.waiting))) as sp:
+                    admitted = self._admit(token)
+                    if tr is not None:
+                        sp.set(req_ids=admitted)
+                if self.disaggregated:
+                    self._install_handoffs(token)
+                if self.spec_k:
+                    # speculative steps are synchronous (acceptance is a
+                    # host decision) — drain any chained chunk, then
+                    # verify
+                    n = self._drain_inflight(token) \
+                        + self._step_spec(token)
+                else:
+                    rec = self._dispatch_chunk(token, chain=True)
+                    with self._commit_lock:
+                        self._check_owner(token)
+                        prev, self._inflight = self._inflight, rec
+                    n = 0
+                    if prev is not None:
+                        if wd is not None:
+                            wd.phase = "commit"
+                        n = self._commit_chunk(prev, token)
+            if tr is not None:
+                step_sp.set(kind=self._step_kind)
+        return n
 
     def run(self, max_iters: int = 100000,
             watchdog_timeout: Optional[float] = None,

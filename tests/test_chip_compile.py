@@ -121,6 +121,23 @@ def test_paged_gqa_decode(for_chip, one_chip):
              ((B, HQ, D), BF), POOL, POOL, TABLES, LENS)
 
 
+def test_role_names_reach_the_compiled_program(for_chip, one_chip):
+    """What a profiler's trace will list (ISSUE 25): the Mosaic call is the
+    instruction `%decode_attention...` — the kernel's registry name, whatever
+    its shapes — of the module `jit_<the jitted function's role>`."""
+    import re
+
+    da = _mod("decode_attention")
+
+    def serve_decode_chunk(*a):
+        return da.paged_decode_attention(*a)
+
+    text = _compile(serve_decode_chunk, one_chip,
+                    ((B, HQ, D), BF), POOL, POOL, TABLES, LENS)
+    assert text.startswith("HloModule jit_serve_decode_chunk")
+    assert re.search(r"%decode_attention(\.\d+)? = [^\n]*custom-call", text)
+
+
 def test_ragged_step(for_chip, one_chip):
     ra = _mod("ragged_attention")
     _compile(ra.ragged_paged_attention, one_chip,

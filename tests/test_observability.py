@@ -11,6 +11,7 @@ structured events, spans covering every request's lifecycle).
 import dataclasses
 import json
 import threading
+import time
 import unittest
 
 import numpy as np
@@ -98,7 +99,6 @@ class TestTracer(unittest.TestCase):
         tr.set_thread_name("main")
         with tr.span("a", x=1):
             tr.instant("i")
-        tr.counter("q", 3)
         with tempfile.TemporaryDirectory() as d:
             path = tr.export(d + "/t.json", metadata={"run": "test"})
             with open(path) as f:
@@ -117,9 +117,7 @@ class TestTracer(unittest.TestCase):
                 self.assertIn("tid", e)
             if e["ph"] == "X":
                 self.assertGreaterEqual(e["dur"], 0)
-            if e["ph"] == "C":
-                self.assertIn("value", e["args"])
-        self.assertEqual(phases, {"M", "X", "i", "C"})
+        self.assertEqual(phases, {"M", "X", "i"})
 
     def test_shared_writer_serves_pipeline_viz_and_profiler(self):
         """The satellite dedup: both legacy writers emit through
@@ -172,13 +170,6 @@ class TestTracer(unittest.TestCase):
 
         with pytest.raises(TraceUnderJitError):
             jax.jit(g)(jnp.ones((2,)))
-
-        def h(x):
-            tr.counter("bad", 1)  # would record ONE trace-time point
-            return x
-
-        with pytest.raises(TraceUnderJitError):
-            jax.jit(h)(jnp.ones((2,)))
 
         def k(x):
             tr.complete("bad", 0, 1)
@@ -362,11 +353,27 @@ class TestDisabledFastPath(unittest.TestCase):
         import gc
         import sys
 
+        tr = obs_trace.get_tracer()     # None: what a site holds when off
+        null = obs_trace._NULL_SPAN
+
         def loop(n):
-            for _ in range(n):
+            for i in range(n):
                 with obs_trace.span("hot"):
                     pass
                 obs_trace.instant("hot")
+                # a module-level site with arguments (hapi's `fit.step`)
+                with obs_trace.span("hot.args", step=i):
+                    pass
+                # the engine's sites: one `is None` test picks the shared
+                # no-op, arguments are never evaluated, `set` never called
+                with (null if tr is None else tr.span(
+                        "sched.step", iter=i)) as sp:
+                    with (null if tr is None else tr.span(
+                            "sched.commit", iter=i)) as inner:
+                        if tr is not None:
+                            inner.set(produced=i, emitted={i: 1})
+                    if tr is not None:
+                        sp.set(kind="decode")
 
         loop(100)  # warm any lazy caches
         gc.collect()
@@ -613,6 +620,210 @@ class TestEngineObservabilityOverhead(unittest.TestCase):
         eng.add_request(rng.integers(1, cfg.vocab_size, (5,)).tolist())
         eng.run(max_iters=50)
         self.assertEqual(len(eng.finished), 1)
+
+
+# ---- ISSUE 25: the engine and the trainer trace themselves ---------------
+
+STEP_CHILDREN = {"sched.admit", "sched.build", "sched.commit",
+                 "decode.dispatch", "decode.sync_wait", "prefill.dispatch",
+                 "spec.verify"}
+ENGINE_MODES = {
+    "unified": dict(),
+    "split": dict(unified_step=False),
+    "pipelined": dict(double_buffer=True),
+    "speculative": dict(speculative="ngram", spec_k=2),
+    "disaggregated": dict(disaggregated=True),
+    "disaggregated_split": dict(disaggregated=True, unified_step=False),
+}
+
+
+def _serve_some(eng, cfg, lengths=(5, 7, 3, 12), seed=3):
+    rng = np.random.default_rng(seed)
+    reqs = [eng.add_request(rng.integers(1, cfg.vocab_size, (n,)).tolist())
+            for n in lengths]
+    eng.run(max_iters=200)
+    assert all(r.done and not r.failed for r in reqs)
+    return reqs
+
+
+def _inside(e, st) -> bool:
+    return st["ts"] <= e["ts"] \
+        and e["ts"] + e["dur"] <= st["ts"] + st["dur"]
+
+
+def _coverage(st, spans) -> float:
+    """Share of `st` its children cover (union of their intervals)."""
+    covered, cur = 0.0, st["ts"]
+    for e in sorted((e for e in spans if e is not st and _inside(e, st)
+                     and e["name"] in STEP_CHILDREN),
+                    key=lambda e: e["ts"]):
+        end = e["ts"] + e["dur"]
+        if end > cur:
+            covered += end - max(e["ts"], cur)
+            cur = end
+    return covered / st["dur"]
+
+
+@pytest.mark.parametrize("mode", sorted(ENGINE_MODES))
+def test_step_phase_spans_account_for_every_iteration(mode):
+    """Every scheduling iteration is one `sched.step`; its host work lies
+    in its children, which cover it; every token a request holds was
+    stamped by one `sched.commit`; every request was admitted once."""
+    tr = Tracer()
+    cfg, eng = _tiny_engine(tracer=tr, steps_per_sync=8, slots=4,
+                            max_new_tokens=16, **ENGINE_MODES[mode])
+    _serve_some(eng, cfg, seed=5)         # compiles; spans not counted
+    tr.clear()
+    reqs = _serve_some(eng, cfg)
+    spans = [e for e in tr.events() if e["ph"] == "X"]
+    steps = [e for e in spans if e["name"] == "sched.step"]
+    assert [e["args"]["iter"] for e in steps] == list(range(
+        steps[0]["args"]["iter"], eng.sched_iters + 1))
+    assert {e["args"]["kind"] for e in steps} <= {"decode", "mixed", "spec"}
+    assert ("spec" in {e["args"]["kind"] for e in steps}) \
+        == (mode == "speculative")
+    assert "mixed" in {e["args"]["kind"] for e in steps}
+    # children: inside one step, carrying its `iter`; covering it
+    for e in spans:
+        if e["name"] in STEP_CHILDREN:
+            (st,) = [st for st in steps if _inside(e, st)]
+            if e["name"].startswith("sched."):
+                assert e["args"]["iter"] == st["args"]["iter"], e
+    cover = sorted(_coverage(st, spans) for st in steps)
+    assert cover[len(cover) // 2] >= 0.95 and cover[0] >= 0.8, cover
+    # the per-chunk emission stamp: every token surfaced is in one commit
+    commits = [e["args"] for e in spans if e["name"] == "sched.commit"]
+    emitted = {}
+    for c in commits:
+        assert c["produced"] == sum(c["emitted"].values()), c
+        for rid, n in c["emitted"].items():
+            emitted[rid] = emitted.get(rid, 0) + n
+    assert emitted == {r.req_id: len(r.tokens) for r in reqs}
+    assert sorted(rid for c in commits for rid in c["retired"]) \
+        == sorted(r.req_id for r in reqs)
+    # admissions name the requests they touched, once each
+    admitted = [rid for e in spans if e["name"] == "sched.admit"
+                for rid in e["args"]["req_ids"]]
+    if "disaggregated" not in mode:     # there install names them again
+        assert sorted(admitted) == sorted(r.req_id for r in reqs)
+
+
+def _profiler_host_events(trace_dir):
+    """{name: [(line, start_ns, end_ns, stats)]} of the host planes."""
+    import glob
+    import os
+
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(os.path.join(trace_dir, "plugins", "profile", "*",
+                                     "*.xplane.pb"))
+    out = {}
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                out.setdefault(e.name, []).append(
+                    (line.name, e.start_ns, e.start_ns + e.duration_ns,
+                     dict(e.stats)))
+    return out
+
+
+@pytest.mark.parametrize("mode", ["unified", "split"])
+def test_spans_are_events_of_a_live_profiler_session(mode, tmp_path):
+    """One clock: with a profiler session live, every span is an event on
+    the host plane of the same trace as the device's operations, its
+    arguments the event's stats, nested as the Tracer nests them."""
+    import jax
+
+    tr = Tracer()
+    cfg, eng = _tiny_engine(tracer=tr, **ENGINE_MODES[mode])
+    _serve_some(eng, cfg, seed=5)
+    tr.clear()
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        _serve_some(eng, cfg)
+        t = time.perf_counter_ns()
+        tr.complete("after.the.fact", t - 1000, t)
+    finally:
+        jax.profiler.stop_trace()
+    got = _profiler_host_events(str(tmp_path))
+    spans = [e for e in tr.events() if e["ph"] == "X"]
+    want_stats = {"sched.step": {"iter", "kind"},
+                  "sched.admit": {"iter", "waiting", "req_ids"},
+                  "sched.commit": {"iter", "produced", "retired",
+                                   "emitted"},
+                  "decode.dispatch": {"chunk", "live"},
+                  "decode.sync_wait": {"stalled"},
+                  "prefill.dispatch": {"bucket", "batch", "cached_prefix",
+                                       "req_ids"}}
+    for name, keys in want_stats.items():
+        mine = [e for e in spans if e["name"] == name]
+        assert mine and len(got.get(name, [])) == len(mine), name
+        for (_, _, _, stats), e in zip(
+                sorted(got[name], key=lambda x: x[1]),
+                sorted(mine, key=lambda e: e["ts"])):
+            assert keys <= set(stats), (name, stats)
+            for k in keys:      # stats come back as numbers or strings
+                v = e["args"][k]
+                assert str(stats[k]) in (str(v), str(int(v)) if isinstance(
+                    v, bool) else ""), (name, k, stats[k], v)
+    # `complete()` is after the fact: in the JSON, not in the profiler
+    assert any(e["name"] == "after.the.fact" for e in spans)
+    assert "after.the.fact" not in got
+    steps = got["sched.step"]
+    for name in ("sched.admit", "sched.commit", "decode.dispatch",
+                 "decode.sync_wait"):
+        for line, a, b, _ in got[name]:
+            assert any(ln == line and s <= a and b <= t
+                       for ln, s, t, _ in steps), name
+    # the profiler counts from its session's start, the Tracer from the
+    # process's monotonic clock: the same span, two time bases
+    first = min(spans, key=lambda e: e["ts"])
+    assert min(a for v in got.values() for _, a, _, _ in v) \
+        < first["ts"] * 1e3
+
+
+def test_disabled_engine_enters_no_span(monkeypatch):
+    """With no tracer every site takes the shared no-op: no span handle
+    and no profiler annotation is ever made."""
+    def boom(*a, **k):
+        raise AssertionError("a span was made with tracing off")
+
+    monkeypatch.setattr(obs_trace._SpanHandle, "__init__", boom)
+    monkeypatch.setattr(obs_trace, "TraceAnnotation", boom)
+    for kw in (dict(), dict(unified_step=False)):
+        cfg, eng = _tiny_engine(**kw)
+        assert eng._tracer is None
+        _serve_some(eng, cfg)
+
+
+@pytest.mark.parametrize("mode", ["unified", "split"])
+def test_dispatch_span_starts_at_the_dispatch_stamp(mode):
+    """`decode.dispatch` starts where its stamp was taken before it was
+    a context manager: `t_disp0`, which `decode_chunk_s` and
+    `step.chunk_ms.sat` count from, is the first thing inside it."""
+    tr = Tracer()
+    cfg, eng = _tiny_engine(tracer=tr, **ENGINE_MODES[mode])
+    stamps = []
+    commit = eng._commit_chunk
+
+    def spy(rec, token=None):
+        stamps.append(rec["t_disp0"] * 1e6)
+        return commit(rec, token)
+
+    eng._commit_chunk = spy
+    _serve_some(eng, cfg)
+    disp = sorted((e for e in tr.events() if e["name"] == "decode.dispatch"),
+                  key=lambda e: e["ts"])
+    assert stamps and len(stamps) == len(disp)
+    for t, e in zip(sorted(stamps), disp):
+        assert e["ts"] <= t <= e["ts"] + e["dur"], (t, e)
+        # the stamp is at the start, before the lock and the enqueue
+        assert t - e["ts"] <= 0.5 * e["dur"], (t, e)
 
 
 if __name__ == "__main__":
