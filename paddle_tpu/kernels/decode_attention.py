@@ -10,6 +10,23 @@ XLA's materialized [B, H, S] logits round-trip.
 Layouts match the incubate serving API:
   contiguous: cache [B, H, max_seq, D], q [B, H, D], lens [B]
   paged:      cache [max_pages, H, block_size, D], block_tables [B, n_blk]
+
+Work schedules:
+  contiguous, equal heads   grid (B, S // block): all heads of one block
+  contiguous, grouped       grid (B, Hkv, S // block): one kv head's block
+  paged, equal heads        grid (B, n_blk): all heads of one table column,
+                            dead columns skipped by `pl.when` (a grid step
+                            each all the same)
+  paged, grouped (serving)  work follows `lens`, not the table's width; bf16
+                            and int8 pools alike. Head dims of whole lane
+                            tiles: grid (B,), a loop over the slot's LIVE
+                            pages, `_pages_per_step` pages of every kv head
+                            a step, copied from the pools in HBM into two
+                            VMEM buffers (`_paged_gqa_body`). Narrower heads
+                            (and equal heads narrower than a lane tile): grid
+                            over the flattened list of live (slot, column)
+                            pairs, a page of every kv head a step through
+                            BlockSpecs (`_paged_gqa_list_kernel`)
 """
 from __future__ import annotations
 
@@ -24,7 +41,7 @@ from jax.experimental.pallas import tpu as pltpu
 from .constraints import (KernelConstraint, LANE, VMEM_BUDGET_BYTES,
                           fit_vmem_block, is_scale_operand,
                           missing_scale_finding, tensor_operands,
-                          register_constraint)
+                          register_constraint, vmem_row_cap)
 
 _NEG_INF = -1e30
 
@@ -33,6 +50,13 @@ BLOCK_S = 512
 # below this block length the grid degenerates (near-prime max_seq) and
 # the kernel warns to pad the cache
 MIN_BLOCK_S = 32
+# (kv head, token) rows one step of the paged GQA kernel fetches and
+# scores at once. On one v5e at 32 slots x 8 kv heads x 64-token pages
+# (PR 26's chip runs): 512 rows (a page a step) left the loop's fixed
+# cost showing (0.129 ms a call at ~6 live pages a slot, 0.52 ms at 28),
+# 2048 reads 0.094 / 0.33 ms, 4096 the same but 0.058 ms against 0.038
+# where every slot holds one page
+STEP_ROWS = 2048
 
 
 def _fitted_block(block_s: int, max_seq: int, h: int, d: int,
@@ -68,11 +92,15 @@ def _check_decode_shapes(shapes, dtypes):
 def _decode_attention_roofline(shapes, dtypes):
     """Roofline model for one decode-attention launch (contiguous and
     paged, bf16 and int8 pools): FLOPs = qk^T + p·v = 4·B·Hq·D·ctx;
-    HBM bytes = q in + out + the K/V actually STREAMED — for the paged
-    grids that is the `B x n_blocks` POOL PAGES the block table names
-    (plus their f32 scale rows when quantized), never the whole pool.
-    Pure shape math (the KernelConstraint contract); None when the
-    operand layout doesn't resolve."""
+    HBM bytes = q in + out + K/V — for the paged kernels the
+    `B x n_blocks` POOL PAGES the block table names (plus their f32
+    scale rows when quantized), never the whole pool. That is an UPPER
+    BOUND for the paged kernels: shapes cannot see `lens`, and the
+    grouped kernel copies a slot's live pages only (the equal-heads
+    grid skips the dead ones' compute), so a launch over short slots
+    moves fewer bytes than this says. Pure shape math (the
+    KernelConstraint contract); None when the operand layout doesn't
+    resolve."""
     from .constraints import dtype_itemsize
 
     arrs = tensor_operands(shapes, dtypes)
@@ -85,11 +113,7 @@ def _decode_attention_roofline(shapes, dtypes):
                    if len(s) == 2 and dt.startswith("int")), None)
     if tables is not None:                 # paged: stream table pages
         b, n_blocks = tables
-        # rank-4 pool [P, Hkv, page, D]; rank-3 (GQA grid) collapses
-        # (page, kv head) -> [P*Hkv, page, D]
-        page = pool_s[2] if len(pool_s) >= 4 else pool_s[1]
-        hkv = pool_s[1] if len(pool_s) >= 4 \
-            else max(q_s[0] // max(b, 1), 1)
+        _, hkv, page, _ = pool_s           # both paged kernels: rank 4
         ctx = n_blocks * page
         kv_bytes = 2 * b * ctx * hkv * d_head * dtype_itemsize(pool_d)
         # int8 pools travel with per-(page, kv head) f32 scale rows
@@ -111,8 +135,10 @@ def _decode_attention_roofline(shapes, dtypes):
 CONSTRAINT = register_constraint(KernelConstraint(
     name="decode_attention",
     kernel_fns=("_decode_kernel", "_paged_decode_kernel",
-                "_gqa_contig_kernel", "_paged_gqa_kernel"),
-    blocks={"block_s": BLOCK_S, "min_block_s": MIN_BLOCK_S},
+                "_gqa_contig_kernel", "_paged_gqa_kernel",
+                "_paged_gqa_body", "_paged_gqa_list_kernel"),
+    blocks={"block_s": BLOCK_S, "min_block_s": MIN_BLOCK_S,
+            "step_rows": STEP_ROWS},
     note="bandwidth-bound single-token decode; cache length should admit "
          f"a divisor >= {MIN_BLOCK_S} under the VMEM double-buffer cap",
     checker=_check_decode_shapes,
@@ -134,9 +160,11 @@ def _check_q8_decode_shapes(shapes, dtypes):
 
 CONSTRAINT_Q8 = register_constraint(KernelConstraint(
     name="decode_attention_q8",
-    kernel_fns=("_paged_decode_q8_kernel", "_paged_gqa_q8_kernel"),
-    blocks={"block_s": BLOCK_S, "min_block_s": MIN_BLOCK_S},
-    note="int8 paged decode streams quantized page tiles + their "
+    kernel_fns=("_paged_decode_q8_kernel", "_paged_gqa_q8_kernel",
+                "_paged_gqa_body", "_paged_gqa_list_kernel"),
+    blocks={"block_s": BLOCK_S, "min_block_s": MIN_BLOCK_S,
+            "step_rows": STEP_ROWS},
+    note="int8 paged decode streams quantized pages + their "
          "per-(page, kv head) f32 absmax scale rows; the dequantized "
          "bf16 pool never materializes",
     checker=_check_q8_decode_shapes,
@@ -348,25 +376,15 @@ def _paged_decode_q8_kernel(tables_ref, len_ref, q_ref, k_ref, v_ref,
 
 
 def _gqa_grid_body(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
-                   acc_scr, *, block_size: int, scale: float,
-                   ksc_ref=None, vsc_ref=None):
-    """Shared grouped-query decode body for grid (B, Hkv, n_blocks):
-    each step streams ONE kv block of ONE kv head and scores the whole
-    query group against it — the block never leaves VMEM at query-head
-    width (reference GQA decode: block_attn.h with gqa_group_size). The
-    paged and contiguous kernels differ only in how their k/v index maps
-    pick the block.
-
-    With `ksc_ref`/`vsc_ref` (the int8 paged path) the k/v blocks are
-    symmetric-absmax int8 and each step also carries that (page, kv
-    head)'s f32 scale as a (1, 1) tile: scores rescale by the k scale
-    AFTER the dot (the scale is uniform over the tile, so the dequant
-    never materializes a widened block) and the weighted sum rescales by
-    the v scale — the f32 accumulation the bf16 path already does."""
+                   acc_scr, *, block_size: int, scale: float):
+    """Grouped-query decode body of the CONTIGUOUS grid (B, Hkv,
+    n_blocks): each step streams ONE kv block of ONE kv head and scores
+    the whole query group against it — the block never leaves VMEM at
+    query-head width (reference GQA decode: block_attn.h with
+    gqa_group_size)."""
     b = pl.program_id(0)
     j = pl.program_id(2)
     nb = pl.num_programs(2)
-    quant = ksc_ref is not None
 
     @pl.when(j == 0)
     def _init():
@@ -378,21 +396,12 @@ def _gqa_grid_body(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
 
     @pl.when(j * block_size <= valid_until)
     def _compute():
-        q = q_ref[0]                                   # [group, D]
-        k = k_ref[0]                                   # [block_size, D]
-        if quant:
-            # int8 tiles score through the f32 path; one scalar multiply
-            # folds the absmax scale into the softmax scale
-            q = q.astype(jnp.float32)
-            k = k.astype(jnp.float32)
         # grouped decode has real matmuls (group >= 2 rows), so the MXU
         # does the scoring — unlike the equal-heads kernels' batched
         # matvec, these 2-D dots lower cleanly at any D
         s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
+            q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale  # [group, bs]
-        if quant:
-            s = s * ksc_ref[0, 0]
         pos = j * block_size + jax.lax.broadcasted_iota(
             jnp.int32, s.shape, 1)
         s = jnp.where(pos <= valid_until, s, _NEG_INF)
@@ -405,8 +414,6 @@ def _gqa_grid_body(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
         pv = jax.lax.dot_general(
             p, v_ref[0].astype(jnp.float32), (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)        # [group, D]
-        if quant:
-            pv = pv * vsc_ref[0, 0]
         acc_scr[...] = acc_scr[...] * corr + pv
         m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
         l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
@@ -414,26 +421,6 @@ def _gqa_grid_body(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
     @pl.when(j == nb - 1)
     def _final():
         o_ref[0] = (acc_scr[...] / l_scr[:, :1]).astype(o_ref.dtype)
-
-
-def _paged_gqa_kernel(tables_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-                      m_scr, l_scr, acc_scr, *, block_size: int,
-                      scale: float):
-    # tables_ref is consumed by the BlockSpec index maps, not the body
-    _gqa_grid_body(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
-                   acc_scr, block_size=block_size, scale=scale)
-
-
-def _paged_gqa_q8_kernel(tables_ref, len_ref, q_ref, k_ref, v_ref,
-                         ksc_ref, vsc_ref, o_ref, m_scr, l_scr, acc_scr,
-                         *, block_size: int, scale: float):
-    """int8 paged GQA decode: the `_gqa_grid_body` grid streaming int8
-    (kv head, page) tiles plus their (1, 1) f32 absmax scales — the
-    dequantized bf16 pool never materializes, HBM reads stay at int8
-    width."""
-    _gqa_grid_body(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
-                   acc_scr, block_size=block_size, scale=scale,
-                   ksc_ref=ksc_ref, vsc_ref=vsc_ref)
 
 
 def gqa_decode_attention(q: jax.Array, k_cache: jax.Array,
@@ -513,74 +500,347 @@ def _gqa_contig_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
                    acc_scr, block_size=block_size, scale=scale)
 
 
-def _paged_decode_gqa(q, key_cache, value_cache, block_tables, lens, scale,
-                      k_scale=None, v_scale=None):
-    """Refs stay rank-3 (Mosaic cannot shape-cast 4-D blocks): q/out
-    collapse (hkv, group) into one axis indexed at h*group; the pools
-    collapse (page, hkv) so page selection becomes tbl[b, j]*hkv + h —
-    both are metadata-only row-major collapses, no data movement. With
-    `k_scale`/`v_scale` [max_pages, hkv] (int8 pools) the collapse also
-    flattens the scales to [max_pages*hkv, 1, 1] so each grid step's
-    (1, 1, 1) scale tile rides the same tbl[b, j]*hkv + h row (and the
-    same index map) as its page — the trailing (1, 1) equals the array's
-    own trailing dims, which is what the Mosaic lowering accepts."""
+def _pages_per_step(n_blocks: int, hkv: int, page: int, d: int,
+                    itemsize: int) -> int:
+    """Pages one loop step of the paged GQA kernel fetches and scores:
+    enough for `STEP_ROWS` (kv head, token) rows, no more than the table
+    is wide, and K and V double-buffered under the VMEM budget."""
+    want = max(1, STEP_ROWS // (hkv * page))
+    return min(want, n_blocks,
+               vmem_row_cap(hkv * page * d * itemsize, n_buffers=4))
+
+
+def _step_columns(hq: int, hkv: int, page: int, pps: int):
+    """A paged GQA step scores `pps` pages of every kv head as one
+    [Hq, pps*Hkv*page] product; column c is (page i, kv head h, token t)
+    of the step. Returns each column's offset from the step's first
+    position — past every length where the column is another kv head's
+    than the query head's (row's) own — and the page i it lies in."""
+    shape = (hq, pps * hkv * page)
+    col = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    row = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    col_page = col // (hkv * page)
+    offset = jnp.where((col // page) % hkv == row // (hq // hkv),
+                       col_page * page + col % page, 2 ** 30)
+    return offset, col_page
+
+
+def _head_scales(page_rows, hq: int, hkv: int, col_page):
+    """int8 pools: `page_rows` holds, for each page of a step, its
+    [1, lanes] row of per-kv-head f32 scales (lanes >= Hkv, padded).
+    Returns what rescales the step's [Hq, rows] scores: each query
+    head's own kv head's scale of the page a column belongs to ([Hq, 1]
+    at one page a step)."""
+    lanes = page_rows[0].shape[-1]
+    own_head = (jax.lax.broadcasted_iota(jnp.int32, (hq, lanes), 1)
+                == jax.lax.broadcasted_iota(jnp.int32, (hq, lanes), 0)
+                // (hq // hkv))
+    out = None
+    for i, sc in enumerate(page_rows):
+        sc = jnp.sum(jnp.where(own_head, sc, 0.0), axis=1, keepdims=True)
+        out = sc if out is None else jnp.where(col_page >= i, sc, out)
+    return out
+
+
+def _gqa_softmax_step(q, k, v, live, carry, scale, k_sc=None, v_sc=None):
+    """One step of the paged GQA kernels' online softmax: q [Hq, D]
+    against the step's rows k, v [rows, D]; `live` [Hq, rows] marks a
+    query head's own kv head's columns at positions <= lens. bf16
+    products (f32 for int8 pools, whose scales `k_sc`/`v_sc` rescale the
+    scores after the dot and the probabilities before the weighted
+    sum), f32 scores, f32 running max / sum / accumulator."""
+    m_prev, l_prev, acc = carry
+    if k_sc is not None:
+        q, k = q.astype(jnp.float32), k.astype(jnp.float32)
+    s = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32) * scale        # [Hq, rows]
+    if k_sc is not None:
+        s = s * k_sc
+    s = jnp.where(live, s, _NEG_INF)
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    corr = jnp.exp(m_prev - m_new)
+    p = jnp.exp(s - m_new)
+    l_new = l_prev * corr + jnp.sum(p, axis=1, keepdims=True)
+    if v_sc is not None:
+        p = p * v_sc
+    pv = jax.lax.dot_general(
+        p, v.astype(jnp.float32), (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)                # [Hq, D]
+    return m_new, l_new, acc * corr + pv
+
+
+def _paged_gqa_body(tables_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf,
+                    v_buf, sem, cur_ref, *, scale: float, ksc_hbm=None,
+                    vsc_hbm=None, ksc_buf=None, vsc_buf=None):
+    """Paged grouped-query decode, grid (B,): one grid step is one slot,
+    and inside it a loop visits the slot's LIVE pages only —
+    `lens[b] // page + 1` of them, `pps` (= k_buf.shape[1]) a step.
+
+    The pools stay in HBM; a step's pages, every kv head of each (one
+    contiguous [Hkv, page, D] slab), are fetched by async copies into
+    one of two VMEM buffers while the other is scored, and the last
+    step of a slot already fetches the first pages of the next slot, so
+    only the call's very first fetch is exposed. Which buffer is
+    current survives from one grid step to the next in `cur_ref`.
+
+    A step scores all query heads against all its rows at once
+    (`_gqa_softmax_step`) and masks the columns that belong to another
+    kv head or lie past `lens[b]`. Where a slot's last step runs over
+    its last live page, the pages beyond are not fetched (a dead table
+    column costs no copy) and their columns are masked. Online softmax
+    in f32 over the steps, one division at the end.
+
+    int8 pools: `ksc_hbm`/`vsc_hbm` are the per-(page, kv head) f32
+    absmax scales, [P, Hkv] padded to whole lane tiles (a row narrower
+    than a tile cannot be sliced out by a copy); a page's row travels
+    with the page (`ksc_buf`/`vsc_buf` [2, pps, 1, lanes]) — the
+    dequantized pool never materializes."""
+    b = pl.program_id(0)
+    n_slots = pl.num_programs(0)
+    w = tables_ref.shape[1]
+    hq = q_ref.shape[1]
+    _, pps, hkv, page, d = k_buf.shape
+    rows = pps * hkv * page
+    quant = ksc_hbm is not None
+
+    def last_page(slot):                   # the slot's last live column
+        return jnp.minimum(len_ref[slot] // page, w - 1)
+
+    def copies(slot, step, buf, act):
+        """Start or wait for the copies of a step's live pages."""
+        last = last_page(slot)
+        for i in range(pps):
+            col = step * pps + i
+
+            @pl.when(col <= last)
+            def _live(i=i, col=col):
+                pid = tables_ref[slot, col]
+                act(pltpu.make_async_copy(k_hbm.at[pid], k_buf.at[buf, i],
+                                          sem.at[0, buf]))
+                act(pltpu.make_async_copy(v_hbm.at[pid], v_buf.at[buf, i],
+                                          sem.at[1, buf]))
+                if quant:
+                    row = pl.ds(pid, 1)
+                    act(pltpu.make_async_copy(
+                        ksc_hbm.at[row], ksc_buf.at[buf, i], sem.at[0, buf]))
+                    act(pltpu.make_async_copy(
+                        vsc_hbm.at[row], vsc_buf.at[buf, i], sem.at[1, buf]))
+
+    def fetch(slot, step, buf):
+        copies(slot, step, buf, lambda cp: cp.start())
+
+    @pl.when(b == 0)
+    def _first_fetch():
+        cur_ref[0] = 0
+        # a page a step does not fetch keeps what the buffer held: its
+        # columns are masked, but 0 x v must not meet what an unwritten
+        # buffer may hold
+        v_buf[...] = jnp.zeros_like(v_buf)
+        if quant:
+            vsc_buf[...] = jnp.zeros_like(vsc_buf)
+        fetch(0, 0, 0)
+
+    offset, col_page = _step_columns(hq, hkv, page, pps)
+    valid_until = len_ref[b]
+    n_steps = last_page(b) // pps + 1
+
+    def step_fn(step, carry):
+        *state, cur = carry
+        more = step + 1 < n_steps
+        nxt_slot = jnp.where(more, b, b + 1)
+
+        @pl.when(nxt_slot < n_slots)
+        def _fetch_next():
+            fetch(nxt_slot, jnp.where(more, step + 1, 0), 1 - cur)
+
+        copies(b, step, cur, lambda cp: cp.wait())
+        k_sc = v_sc = None
+        if quant:
+            k_sc, v_sc = (
+                _head_scales([buf[cur, i] for i in range(pps)], hq, hkv,
+                             col_page) for buf in (ksc_buf, vsc_buf))
+        state = _gqa_softmax_step(
+            q_ref[0], k_buf[cur].reshape(rows, d),
+            v_buf[cur].reshape(rows, d),
+            offset <= valid_until - step * (pps * page), state, scale,
+            k_sc, v_sc)
+        return *state, 1 - cur
+
+    _, l_fin, acc, cur = jax.lax.fori_loop(
+        0, n_steps, step_fn,
+        (jnp.full((hq, 1), _NEG_INF, jnp.float32),
+         jnp.zeros((hq, 1), jnp.float32),
+         jnp.zeros((hq, d), jnp.float32), cur_ref[0]))
+    cur_ref[0] = cur
+    o_ref[0] = (acc / l_fin).astype(o_ref.dtype)
+
+
+def _paged_gqa_kernel(tables_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
+                      k_buf, v_buf, sem, cur_ref, *, scale: float):
+    _paged_gqa_body(tables_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf,
+                    v_buf, sem, cur_ref, scale=scale)
+
+
+def _paged_gqa_q8_kernel(tables_ref, len_ref, q_ref, k_hbm, v_hbm, ksc_hbm,
+                         vsc_hbm, o_ref, k_buf, v_buf, sem, cur_ref,
+                         ksc_buf, vsc_buf, *, scale: float):
+    _paged_gqa_body(tables_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf,
+                    v_buf, sem, cur_ref, scale=scale, ksc_hbm=ksc_hbm,
+                    vsc_hbm=vsc_hbm, ksc_buf=ksc_buf, vsc_buf=vsc_buf)
+
+
+# rows of an int8 pool's scales one block of the listed kernel holds
+_SCALE_ROWS = 8
+
+
+def _paged_gqa_list_kernel(tables_ref, len_ref, slot_ref, col_ref, n_ref,
+                           q_ref, k_ref, v_ref, *rest, scale: float):
+    """Paged grouped-query decode where the head dim is not a whole
+    number of lane tiles (a copy cannot slice such a page out of the
+    pool, so `_paged_gqa_body` does not lower): grid (N,) over the
+    flattened list of LIVE (slot, table column) pairs that XLA builds
+    from `lens` (`_live_page_list`). BlockSpec index maps read the
+    list — one page of every kv head a step — and a slot's items are
+    consecutive, so its softmax state sits in scratch from its first
+    item to its last. N is the static bound B x W: steps past the
+    list's end name the last item again (no copy) and do nothing.
+
+    int8 pools: `rest` leads with the two scale blocks, `_SCALE_ROWS`
+    rows of the lane-padded [P, Hkv] scales around the page's own."""
+    *scales, o_ref, m_scr, l_scr, acc_scr = rest
+    i = pl.program_id(0)
+    slot, col = slot_ref[i], col_ref[i]
+    listed = i < n_ref[0]
+    hq = q_ref.shape[1]
+    _, hkv, page, d = k_ref.shape
+    valid_until = len_ref[slot]
+
+    @pl.when(listed & (col == 0))
+    def _init():
+        m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    @pl.when(listed)
+    def _step():
+        offset, col_page = _step_columns(hq, hkv, page, 1)
+        m_scr[...], l_scr[...], acc_scr[...] = _gqa_softmax_step(
+            q_ref[0], k_ref[0].reshape(hkv * page, d),
+            v_ref[0].reshape(hkv * page, d),
+            offset <= valid_until - col * page,
+            (m_scr[...], l_scr[...], acc_scr[...]), scale,
+            *(_head_scales(
+                [sc[pl.ds(tables_ref[slot, col] % _SCALE_ROWS, 1), :]],
+                hq, hkv, col_page) for sc in scales))
+
+    @pl.when(listed & (col == jnp.minimum(valid_until // page,
+                                          tables_ref.shape[1] - 1)))
+    def _final():
+        o_ref[0] = (acc_scr[...] / l_scr[...]).astype(o_ref.dtype)
+
+
+def _live_page_list(lens, page: int, w: int, n: int):
+    """The live (slot, table column) pairs in order, padded to `n` with
+    the last pair, and their count: what `_paged_gqa_list_kernel`'s
+    grid walks."""
+    n_live = jnp.minimum(lens // page, w - 1) + 1            # [B]
+    ends = jnp.cumsum(n_live)
+    item = jnp.minimum(jnp.arange(n, dtype=jnp.int32), ends[-1] - 1)
+    slot = jnp.searchsorted(ends, item, side="right").astype(jnp.int32)
+    return slot, item - (ends - n_live)[slot], ends[-1:]
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+def _paged_decode_gqa(q, key_cache, value_cache, block_tables, lens,
+                      k_scale=None, v_scale=None, *, scale: float,
+                      interpret: bool):
+    """Launch the paged GQA decode. Head dims of whole lane tiles take
+    `_paged_gqa_body`: q and the output ride BlockSpecs (one slot's
+    [Hq, D] a grid step), the rank-4 pools — and an int8 pool's scales —
+    are handed over whole in HBM, and the table and the lengths are
+    scalar-prefetched so the kernel can name the pages it copies.
+    Narrower heads take `_paged_gqa_list_kernel`, whose pages ride
+    BlockSpecs too. Both see an int8 pool's [P, Hkv] scales padded to
+    whole lane tiles.
+
+    Jitted so that a program which calls it once a layer traces and
+    lowers the kernel once: 21 separate traces made a serving program's
+    lowering 1.3 s longer, and the benchmark's set-up 10 s (PR 26)."""
     b, hq, d = q.shape
-    hkv = key_cache.shape[1]
-    group = hq // hkv
-    block_size = key_cache.shape[2]
-    n_blocks = block_tables.shape[1]
-    max_pages = key_cache.shape[0]
+    n_pages, hkv, page, _ = key_cache.shape
+    w = block_tables.shape[1]
     quant = k_scale is not None
-    # blocks must exactly span trailing array dims unless 8/128-divisible,
-    # so q/out collapse to [b*hkv, group, d] (block = one full row) and
-    # the pools to [pages*hkv, block_size, d] (block = one page x one kv
-    # head at flat row tbl[b, j]*hkv + h)
-    qg = q.reshape(b * hkv, group, d)
-    kc = key_cache.reshape(max_pages * hkv, block_size, d)
-    vc = value_cache.reshape(max_pages * hkv, block_size, d)
-
-    def pool_map(b_, h, j, tbl, lens_, hkv=hkv):
-        return (tbl[b_, j] * hkv + h, 0, 0)
-
-    def q_map(b_, h, j, tbl, lens_, hkv=hkv):
-        return (b_ * hkv + h, 0, 0)
-
-    in_specs = [
-        pl.BlockSpec((1, group, d), q_map),
-        pl.BlockSpec((1, block_size, d), pool_map),
-        pl.BlockSpec((1, block_size, d), pool_map),
-    ]
-    operands = [qg, kc, vc]
+    tables = block_tables.astype(jnp.int32)
+    lens = lens.astype(jnp.int32)
+    operands = [q, key_cache, value_cache]
+    lanes = hkv + -hkv % LANE
     if quant:
-        in_specs += [pl.BlockSpec((1, 1, 1), pool_map),
-                     pl.BlockSpec((1, 1, 1), pool_map)]
-        operands += [k_scale.astype(jnp.float32).reshape(-1, 1, 1),
-                     v_scale.astype(jnp.float32).reshape(-1, 1, 1)]
-        kernel = functools.partial(_paged_gqa_q8_kernel,
-                                   block_size=block_size, scale=scale)
-    else:
-        kernel = functools.partial(_paged_gqa_kernel,
-                                   block_size=block_size, scale=scale)
-    out = pl.pallas_call(
-        kernel,
-        name=(CONSTRAINT_Q8 if quant else CONSTRAINT).name,
+        pad = (0, -n_pages % _SCALE_ROWS), (0, lanes - hkv)
+        operands += [jnp.pad(k_scale.astype(jnp.float32), pad),
+                     jnp.pad(v_scale.astype(jnp.float32), pad)]
+    name = (CONSTRAINT_Q8 if quant else CONSTRAINT).name
+    out_shape = jax.ShapeDtypeStruct((b, hq, d), q.dtype)
+    # both carry state from one grid step to the next: in order
+    params = pltpu.CompilerParams(dimension_semantics=("arbitrary",))
+
+    if d % LANE:
+        slot, col, n_items = _live_page_list(lens, page, w, b * w)
+
+        def slot_map(i, tbl, lens_, slot_, col_, n_):
+            return (slot_[i], 0, 0)
+
+        def page_map(i, tbl, lens_, slot_, col_, n_):
+            return (tbl[slot_[i], col_[i]], 0, 0, 0)
+
+        def scale_map(i, tbl, lens_, slot_, col_, n_):
+            return (tbl[slot_[i], col_[i]] // _SCALE_ROWS, 0)
+
+        return pl.pallas_call(
+            functools.partial(_paged_gqa_list_kernel, scale=scale),
+            name=name,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=5,
+                grid=(b * w,),
+                in_specs=[pl.BlockSpec((1, hq, d), slot_map)]
+                + [pl.BlockSpec((1, hkv, page, d), page_map)] * 2
+                + [pl.BlockSpec((_SCALE_ROWS, lanes), scale_map)]
+                * (2 * quant),
+                out_specs=pl.BlockSpec((1, hq, d), slot_map),
+                scratch_shapes=[pltpu.VMEM((hq, 1), jnp.float32),
+                                pltpu.VMEM((hq, 1), jnp.float32),
+                                pltpu.VMEM((hq, d), jnp.float32)],
+            ),
+            out_shape=out_shape, compiler_params=params,
+            interpret=interpret,
+        )(tables, lens, slot, col, n_items, *operands)
+
+    pps = _pages_per_step(w, hkv, page, d, key_cache.dtype.itemsize)
+
+    def slot_map(b_, tbl, lens_):
+        return (b_, 0, 0)
+
+    in_hbm = pl.BlockSpec(memory_space=pl.ANY)
+    return pl.pallas_call(
+        functools.partial(
+            _paged_gqa_q8_kernel if quant else _paged_gqa_kernel,
+            scale=scale),
+        name=name,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(b, hkv, n_blocks),
-            in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, group, d), q_map),
+            grid=(b,),
+            in_specs=[pl.BlockSpec((1, hq, d), slot_map)]
+            + [in_hbm] * (len(operands) - 1),
+            out_specs=pl.BlockSpec((1, hq, d), slot_map),
             scratch_shapes=[
-                pltpu.VMEM((group, 128), jnp.float32),
-                pltpu.VMEM((group, 128), jnp.float32),
-                pltpu.VMEM((group, d), jnp.float32),
-            ],
+                pltpu.VMEM((2, pps, hkv, page, d), key_cache.dtype),
+                pltpu.VMEM((2, pps, hkv, page, d), value_cache.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),   # (k | v, buffer)
+                pltpu.SMEM((1,), jnp.int32),
+            ] + [pltpu.VMEM((2, pps, 1, lanes), jnp.float32)] * (2 * quant),
         ),
-        out_shape=jax.ShapeDtypeStruct((b * hkv, group, d), q.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=not _on_tpu(),
-    )(block_tables.astype(jnp.int32), lens.astype(jnp.int32), *operands)
-    return out.reshape(b, hq, d)
+        out_shape=out_shape, compiler_params=params, interpret=interpret,
+    )(tables, lens, *operands)
 
 
 def paged_decode_attention(q: jax.Array, key_cache: jax.Array,
@@ -592,22 +852,23 @@ def paged_decode_attention(q: jax.Array, key_cache: jax.Array,
     """One decode step over a paged cache (reference: block_attn.h).
 
     q: [B, Hq, D]; key_cache/value_cache: [max_pages, Hkv, block_size, D]
-    with Hq a multiple of Hkv (grouped queries take the GQA grid, equal
-    heads the all-heads-per-page grid); block_tables: [B, n_blocks] page
+    with Hq a multiple of Hkv (grouped queries take the live-page loop
+    of `_paged_gqa_body`, whose cost follows `lens`; equal heads the
+    all-heads-per-page grid); block_tables: [B, n_blocks] page
     ids covering positions [0, n_blocks*block_size); lens: [B]
     previous-token counts (current token already written at position
     lens[b]). Returns [B, Hq, D].
 
     int8 pools (``FLAGS_kv_cache_dtype=int8``): pass the per-(page, kv
     head) f32 absmax scale arrays as ``k_scale``/``v_scale``
-    [max_pages, Hkv] — each grid step then streams the int8 tile plus
-    its scale and rescales inside the f32 accumulation; the dequantized
-    bf16 pool never materializes.
+    [max_pages, Hkv] — each step then streams the int8 pages plus
+    their scales and rescales inside the f32 accumulation; the
+    dequantized bf16 pool never materializes.
 
     Head counts (and therefore the GQA group) derive from the OPERAND
     shapes, never a model config: under tensor-parallel serving
     (FLAGS_serving_mp) this call sees the shard-LOCAL q heads and pool
-    kv heads inside shard_map, so both the kv-head-sharded grid and
+    kv heads inside shard_map, so both the kv-head-sharded call and
     the replicated-KV MQA fallback (full Hkv, local Hq) lower to the
     correct group without any head-offset plumbing.
     """
@@ -626,11 +887,13 @@ def paged_decode_attention(q: jax.Array, key_cache: jax.Array,
     if h != hkv or d % LANE:
         # grouped queries — or narrow head dims, where the equal-heads
         # kernel's [H, 1, D] broadcast fails to lower (see
-        # decode_attention); the GQA grid covers group=1 too
+        # decode_attention); the grouped kernel's 2-D dots cover
+        # group=1 too
         if h % hkv:
             raise ValueError(f"Hq {h} not a multiple of Hkv {hkv}")
         return _paged_decode_gqa(q, key_cache, value_cache, block_tables,
-                                 lens, scale, k_scale, v_scale)
+                                 lens, k_scale, v_scale, scale=scale,
+                                 interpret=not _on_tpu())
     block_size = key_cache.shape[2]
     n_blocks = block_tables.shape[1]
     in_specs = [

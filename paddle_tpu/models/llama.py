@@ -2415,9 +2415,10 @@ def build_paged_generate(cfg, b, sb, max_new, block_size: int = 64,
 
     Decode attention: the Pallas paged kernel
     (kernels/decode_attention.paged_decode_attention) for equal heads AND
-    grouped queries — the GQA grid streams one page of one kv head per
-    step and scores the whole query group in VMEM, so no path ever
-    gathers pages at query width (the round-4 jnp fallback is gone).
+    grouped queries — the grouped kernel copies a slot's live pages,
+    every kv head of each, and scores all query heads in VMEM, so no
+    path ever gathers pages at query width (the round-4 jnp fallback is
+    gone).
 
     Weights are read through `_mm`, so the dec_params dict may hold
     dense OR nn.quant-quantized projections (int8/int4 serving composes
@@ -2495,7 +2496,7 @@ def build_paged_generate(cfg, b, sb, max_new, block_size: int = 64,
     def paged_attn(q1, kc, vc, tables, lens):
         """q1 [b, nh, dh]; lens [b] = cached positions (current token
         already written at lens[b]). The Pallas kernel covers both equal
-        and grouped heads (GQA grid: one page x one kv head per step).
+        and grouped heads (a loop over each slot's live pages).
         int8 pools arrive as (pool, scale) tuples."""
         if isinstance(kc, tuple):
             (kcp, ksc), (vcp, vsc) = kc, vc

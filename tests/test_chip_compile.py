@@ -115,10 +115,32 @@ def test_rms_norm(for_chip, one_chip, rows, hidden):
              ((rows, hidden), BF), ((hidden,), BF))
 
 
-def test_paged_gqa_decode(for_chip, one_chip):
+# (slots, q heads, kv heads, head dim, page, table width, pool pages)
+PAGED_GQA = {
+    "smoke-8x17": (B, HQ, HK, D, PAGE, W, MAX_PAGES),
+    # the geometry the ledger measures (`mistral7b-reason-sat`): 32 slots,
+    # a 28-page table, 576 pool pages — four pages of all kv heads a step
+    "cell-32x28": (32, HQ, HK, D, PAGE, 28, 576),
+    # head dims that are not whole lane tiles take the listed kernel: a copy
+    # cannot slice their pages out of the pool (refused on the chip, PR 26)
+    "d64-group1": (8, 4, 4, 64, 32, 9, 74),
+    "d16-tiny-llama": (2, 4, 2, 16, 8, 16, 34),
+}
+
+
+@pytest.mark.parametrize("kv", [BF, I8], ids=["bf16", "int8"])
+@pytest.mark.parametrize("geometry", sorted(PAGED_GQA))
+def test_paged_gqa_decode(for_chip, one_chip, geometry, kv):
     da = _mod("decode_attention")
-    _compile(da.paged_decode_attention, one_chip,
-             ((B, HQ, D), BF), POOL, POOL, TABLES, LENS)
+    slots, hq, hk, d, page, width, pages = PAGED_GQA[geometry]
+    shapes = [((slots, hq, d), BF)] + [((pages, hk, page, d), kv)] * 2 \
+        + [((slots, width), I32), ((slots,), I32)]
+    if kv == I8:
+        shapes += [((pages, hk), F32)] * 2
+        _compile(lambda q, k, v, t, n, ks, vs: da.paged_decode_attention(
+            q, k, v, t, n, k_scale=ks, v_scale=vs), one_chip, *shapes)
+    else:
+        _compile(da.paged_decode_attention, one_chip, *shapes)
 
 
 def test_role_names_reach_the_compiled_program(for_chip, one_chip):
@@ -150,9 +172,10 @@ def test_prefix_prefill(for_chip, one_chip):
              QWIN, KWIN, KWIN, POOL, POOL, TABLES, LENS, LENS)
 
 
-# int8 KV: the f32 scale sidecar rides as a (1, 1, 1) block of a
-# [pages*nkv, 1, 1] array — the layout the Mosaic lowering accepts (PR 22;
-# the (1, 1) block of [pages*nkv, 1] it replaced was refused)
+# int8 KV: the ragged and prefix-prefill grids carry the f32 scale sidecar as
+# a (1, 1, 1) block of a [pages*nkv, 1, 1] array — the layout the Mosaic
+# lowering accepts (PR 22; the (1, 1) block of [pages*nkv, 1] it replaced was
+# refused); the paged GQA decode copies a page's lane-padded scale row
 
 def test_int8_paged_gqa_decode(for_chip, one_chip):
     da = _mod("decode_attention")

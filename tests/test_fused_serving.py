@@ -632,8 +632,9 @@ class TestDecodeKernels(unittest.TestCase):
                                    self._oracle(q, kc, vc, lens), atol=2e-5)
 
     def test_paged_gqa_matches_oracle(self):
-        """Grouped queries (Hq > Hkv) take the GQA grid — one page x one
-        kv head per step; oracle repeats kv to query width."""
+        """Grouped queries (Hq > Hkv) take the live-page loop — every
+        kv head of a slot's pages a step; oracle repeats kv to query
+        width."""
         from paddle_tpu.kernels.decode_attention import \
             paged_decode_attention
         import jax.numpy as jnp

@@ -122,7 +122,7 @@ class TestPagedDecodeInt8Parity(unittest.TestCase):
         self._case(2, 4, 1, 16, seed=2)
 
     def test_equal_heads_group_1(self):
-        # D=16 routes group=1 through the GQA grid
+        # D=16 routes group=1 through the grouped kernel
         self._case(2, 4, 4, 16, seed=3)
 
     def test_equal_heads_lane_aligned_kernel(self):

@@ -105,6 +105,18 @@ CASES = {
         lambda *a: _mod("decode_attention").paged_decode_attention(*a),
         [Q1, POOL, POOL, TABLES, LENS],
         {"decode_attention": "decode_attention"}),
+    # head dims narrower than a lane tile: the listed kernel's own call site
+    "decode_attention.paged_gqa_d64": (
+        lambda *a: _mod("decode_attention").paged_decode_attention(*a),
+        [((B, HQ, 64), BF)] + [((MAX_PAGES, HK, PAGE, 64), BF)] * 2
+        + [TABLES, LENS],
+        {"decode_attention": "decode_attention"}),
+    "decode_attention.paged_gqa_d64_q8": (
+        _with_scales(lambda *a, **kw: _mod(
+            "decode_attention").paged_decode_attention(*a, **kw)),
+        [((B, HQ, 64), BF)] + [((MAX_PAGES, HK, PAGE, 64), I8)] * 2
+        + [TABLES, LENS, SCALE, SCALE],
+        {"decode_attention_q8": "decode_attention_q8"}),
     "decode_attention.paged_mha_q8": (
         _with_scales(lambda *a, **kw: _mod(
             "decode_attention").paged_decode_attention(*a, **kw)),
@@ -202,7 +214,7 @@ def test_no_pallas_call_in_kernels_lacks_a_name():
                       for node in ast.walk(tree)
                       if isinstance(node, ast.Call)
                       and getattr(node.func, "attr", "") == "pallas_call"]
-    assert len(calls) == 15     # the call sites CASES covers
+    assert len(calls) == 16     # the call sites CASES covers
     assert [c[:2] for c in calls if "name" not in c[2]] == []
 
 
