@@ -8,10 +8,9 @@ step in the trace (one `bench.step` host span each) — from shapes and live
 tokens only, so it reads the same work whatever kernel does it. Memory-bound
 is the bound that holds at one query token a slot; it cannot pass 100%.
 
-`device_ops` holds only the trace's ten longest labels
-(`tracing.reduce_events`): once the kernel falls out of them this reads
-nothing, and if it ever ran under several labels, some of them cut, the
-share would read too high (PERF.md 7c).
+Seconds: `trace["device_op_s"]`, the summed device time of every label of the
+trace, uncut (`tracing.reduce_events`), so the kernel is found whatever its
+rank among the operations and under however many labels it ran.
 """
 from benchmark import arith
 
@@ -19,7 +18,7 @@ from benchmark import arith
 def read(ctx):
     tr = ctx["trace"]
     n_steps = tr["host_spans"].get("step", 0)
-    secs = sum(s for label, s in tr.get("device_ops", [])
+    secs = sum(s for label, s in tr.get("device_op_s", {}).items()
                if label.startswith("decode_attention"))
     if not n_steps or not secs or not ctx.get("live_kv_tokens"):
         return None

@@ -133,10 +133,12 @@ def op_events(lines: dict) -> list:
 
 def reduce_events(events: dict) -> dict:
     """Busy and idle time of the traced window, averaged over the device
-    planes; the TOP operations by summed time; the TOP idle gaps by what the
-    host was doing while the device waited; the device durations of every
-    program ("XLA Modules") by name; how many of each host span the window
-    holds.
+    planes; the TOP operations by summed time, and under `device_op_s` the
+    summed time of every label, uncut, for the readers (a kernel's share of
+    its roofline needs its label's whole sum whatever its rank); the TOP idle
+    gaps by what the host was doing while the device waited; the device
+    durations of every program ("XLA Modules") by name; how many of each host
+    span the window holds.
 
     The window is the span of the benchmark's own host spans inside the trace
     (the profiler's start and stop themselves are left out); a gap is named by
@@ -185,6 +187,7 @@ def reduce_events(events: dict) -> dict:
     return {"busy_s": sum(busy) / len(busy) / 1e9,
             "window_s": (w1 - w0) / 1e9, "chips": len(planes),
             "device_ops": top(ops), "idle_gaps": top(gaps),
+            "device_op_s": {k: v / 1e9 for k, v in ops.items()},
             "programs": programs, "host_spans": counts,
             "planes": {k: {ln: len(ev) for ln, ev in v.items()}
                        for k, v in events["device"].items()}}

@@ -1,16 +1,19 @@
 """Every cell rehearsed end to end on the CPU through run.py's own functions,
-at `LlamaConfig.tiny()` sizes: the traffic, the driver, the correctness checks,
-the trace and its readers, and the keys of the last line. Sizes are steered
-here, in the test — run.py has no option for it. No number from these runs
-means anything: the CPU is not the device.
+at tiny sizes: the traffic, the driver, the correctness checks, the trace and
+its readers, and the keys of the last line. Sizes are steered here, in the
+test — run.py has no option for it — from the presets under
+`tests/benchmark/tiny/`, found by the cell's configuration, traffic mix and
+driver kind (`test_benchmark_contract.tiny_overlays`): a new cell brings its
+own. No number from these runs means anything: the CPU is not the device.
 """
 import copy
 import json
 import os
 import sys
 
-import jax.numpy as jnp
 import pytest
+from test_benchmark_contract import (cpu_line_metrics, load_bench, overlay,
+                                     tiny_overlays)
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
@@ -18,12 +21,8 @@ sys.path.insert(0, ROOT)
 
 from benchmark import run, tracing  # noqa: E402
 
-TINY = dict(hidden_size=64, intermediate_size=128, num_hidden_layers=2,
-            num_attention_heads=4, num_key_value_heads=2, head_dim=16,
-            vocab_size=128, max_position_embeddings=128)
-# 8-token pages and 16-token buckets, f32 end to end, so that the sample
-# tokens must equal the reference's argmax exactly
-ENGINE = dict(prompt_bucket=16, block_size=8, dtype=jnp.float32)
+# device-trace metrics that a CPU line holds all the same, kept as pins
+IN_THE_CPU_LINE = {"mistral7b-reason-sat": {"kernel.decode_read_share"}}
 
 
 @pytest.fixture
@@ -43,28 +42,19 @@ def on_cpu(monkeypatch):
 
 
 def _tiny(workload):
+    """The cell as run.py loads it from `run.ROOT`, its tiny presets written
+    over its sizes and its mix, and the keyword arguments its driver's
+    rehearsal takes."""
     cell = copy.deepcopy(run.load_cell(workload))
-    cell["config"].update(TINY)
-    dep, mix = cell["config"]["deployment"], cell["mix"]
-    if mix["driver"] == "train":
-        dep.update(batch=2, seq=32)
-        return cell, dict(dtype="float32")
-    dep.update(slots=4, kv_pool_tokens=640, max_prompt_len=64,
-               max_new_tokens=32)
-    mix["prompt_len"].update(median=12, min=4, max=24)
-    mix["output_len"].update(median=16, min=4, max=32)
-    if mix.get("shared_prefix_tokens"):
-        mix["shared_prefix_tokens"] = 16
-    if mix["arrivals"]["kind"] == "closed":
-        mix["arrivals"]["clients"] = 8
-    else:
-        mix["arrivals"].update(rate_per_s=10.0, lead_in_s=0.5)
-    return cell, dict(engine_kw=ENGINE)
+    config, mix, driver_kw = tiny_overlays(load_bench(run.ROOT), run.ROOT,
+                                           workload)
+    overlay(cell["config"], config, "config")
+    overlay(cell["mix"], mix, "mix")
+    return cell, driver_kw
 
 
 def _cells():
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        return [w["name"] for w in json.load(f)["workloads"]]
+    return [w["name"] for w in load_bench(ROOT)["workloads"]]
 
 
 def _check_line(out, cell, trace):
@@ -103,11 +93,9 @@ def test_cell_traced_run(on_cpu, workload):
     assert 0 < out["device"]["busy_s"] <= out["device"]["window_s"]
     bd = out["breakdown"]
     assert 0 < len(bd["device_ops"]) <= 10 and len(bd["idle_gaps"]) <= 10
-    want = {m["name"] for m in cell["per_layer"]}
-    # the trainer's step time is read off whole programs on the device
-    # plane, which the CPU's trace does not have: those readers return
-    # nothing and are left out of the line
-    assert set(out["metrics"]) == want - {"train.step_ms", "train.mfu"}
+    must, may = cpu_line_metrics(cell["per_layer"])
+    must |= IN_THE_CPU_LINE.get(workload, set())
+    assert must <= set(out["metrics"]) <= may
 
 
 def test_sharded_train_cell_on_four_virtual_devices(on_cpu):
