@@ -36,6 +36,9 @@ BLOCK_K = 512
 # 512-divisible seqs and a 128-lane-aligned head dim
 FAST_PATH_BLOCK = 1024
 FAST_PATH_SEQ_MULTIPLE = 512
+# the widest head the bundled MHA kernel is given: its backward blocks are
+# 1024 * 128 / head size rows, rounded down to a power of two (256 here)
+BUNDLED_MAX_HEAD = 512
 
 
 def _check_attention_shapes(shapes, dtypes):
@@ -476,6 +479,7 @@ def _bundled_ok(sq, sk, hq, hk, dh) -> bool:
     """Shapes the bundled jax pallas MHA kernel handles well (equal heads,
     long block-divisible sequences)."""
     return (_on_tpu() and hq == hk and dh % LANE == 0
+            and dh <= BUNDLED_MAX_HEAD
             and sq % FAST_PATH_SEQ_MULTIPLE == 0
             and sk % FAST_PATH_SEQ_MULTIPLE == 0 and sq == sk)
 
@@ -546,11 +550,18 @@ def flash_attention(q, k, v, causal: bool = False,
             BlockSizes, flash_attention as _jax_fa)
 
         bs = min(FAST_PATH_BLOCK, sq)
+        # the backward kernels hold q, k, v, do and dk, dv blocks at once:
+        # at head size 256 (latent attention) 1024-row blocks overrun the
+        # 16 MiB of scoped VMEM by 0.9 MiB (the chip's compiler, PR 28), so
+        # their rows shrink as the head widens, by powers of two so that a
+        # block stays a lane multiple and a divisor of the sequence (head
+        # size 384 gives 256, not 341); head size 128 keeps 1024
+        bw = min(bs, 1 << (FAST_PATH_BLOCK * LANE // dh).bit_length() - 1)
         blocks = BlockSizes(
             block_q=bs, block_k_major=bs, block_k=bs, block_b=1,
-            block_q_major_dkv=bs, block_k_major_dkv=bs,
-            block_k_dkv=bs, block_q_dkv=bs,
-            block_k_major_dq=bs, block_k_dq=bs, block_q_dq=bs)
+            block_q_major_dkv=bw, block_k_major_dkv=bw,
+            block_k_dkv=bw, block_q_dkv=bw,
+            block_k_major_dq=bw, block_k_dq=bw, block_q_dq=bw)
         out = _jax_fa(jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2),
                       jnp.swapaxes(v, 1, 2), causal=causal,
                       sm_scale=scale, block_sizes=blocks)

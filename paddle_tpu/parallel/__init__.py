@@ -49,6 +49,7 @@ from .sequence_parallel import (  # noqa: F401
     split_seq, ulysses_alltoall,
 )
 from .moe import GShardGate, MoELayer, NaiveGate, SwitchGate, moe_dispatch  # noqa: F401
+from .moe import BiasBalancedSigmoidGate, DroplessMoELayer  # noqa: F401
 from .fleet import DistributedStrategy  # noqa: F401
 from . import fleet  # noqa: F401  (module; its own `fleet` instance plus
 #                      init/distributed_model are module-level, matching the
@@ -71,7 +72,7 @@ from .elastic import ElasticManager  # noqa: F401
 from .checkpoint import load_state_dict, save_state_dict  # noqa: F401
 from .trainer import (  # noqa: F401
     AdamWState, adamw_update, init_adamw_state, make_eval_step,
-    make_train_step,
+    make_train_step, read_report,
 )
 from . import mpu  # noqa: F401
 from . import collective as communication  # noqa: F401

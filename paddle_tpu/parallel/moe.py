@@ -11,6 +11,27 @@ tokens are routed with a capacity-bounded one-hot dispatch einsum
 (GShard-style — compiler-friendly static shapes, no dynamic gather), and
 XLA lowers the dispatch/combine einsums against expert-sharded weights to
 the same all-to-all pattern as global_scatter/global_gather.
+
+Beside that capacity-bounded `MoELayer` (GShard: softmax gates, a capacity
+that drops tokens, every expert present) lives the dropless layer of today's
+expert models, `DroplessMoELayer`: it is told which experts it HOLDS (one
+chip's share of an expert-parallel group; all of them by default), routes
+over all of them with `BiasBalancedSigmoidGate` (sigmoid scores, choice by
+score + a bias that a balancing rule moves after each step and no gradient
+reaches, gates from the scores alone), sorts the (token, expert) assignments
+that fall on held experts by expert and hands them to the grouped matmul
+(kernels/grouped_matmul.py) with the group sizes. No capacity exists and no
+token is dropped: a held expert computes every row routed to it, and what the
+experts held elsewhere would add is left out (on one chip the layer runs
+without its exchange). It returns its result with the step's counters
+(`moe.rows_held`, `moe.rows_routed`, `moe.rows_multiplied`, `moe.load_max`,
+`moe.load_mean`, `moe.rows_dropped`, `moe.load`), computed on the device, and
+the router's choice (`moe.choice`).
+
+A layer that holds a share of the experts does not train its router's weight:
+the gates' gradient needs every chosen expert's output, which the exchange
+brings and one chip alone has not (`dropless_experts`). The bias rule runs
+either way.
 """
 from __future__ import annotations
 
@@ -19,14 +40,16 @@ from typing import Callable, List, Optional
 import jax
 import jax.numpy as jnp
 
-from ..core.tensor import Tensor, dispatch
+from ..core.tensor import Tensor, dispatch, unwrap
 from ..nn.layer.layers import Layer
 from . import mesh as mesh_mod
 from .api import shard_constraint
 from .placement import Replicate, Shard
 
 __all__ = ["NaiveGate", "SwitchGate", "GShardGate", "MoELayer",
-           "moe_dispatch", "moe_dispatch_sorted", "moe_combine_sorted"]
+           "moe_dispatch", "moe_dispatch_sorted", "moe_combine_sorted",
+           "BiasBalancedSigmoidGate", "HeldExperts", "DroplessMoELayer",
+           "dropless_experts", "MOE_COUNTERS"]
 
 
 class NaiveGate(Layer):
@@ -243,3 +266,247 @@ class MoELayer(Layer):
                          lambda c, o: jnp.einsum("tec,ecd->td", c, o),
                          (combine, stacked))
         return y.reshape(orig_shape)
+
+
+# ---------------------------------------------------------------------------
+# dropless layer over the experts held here
+# ---------------------------------------------------------------------------
+
+class BiasBalancedSigmoidGate(Layer):
+    """The auxiliary-loss-free router of DeepSeek-V3 (arXiv:2412.19437
+    section 2.1.2; `topk_method: noaux_tc` with one group): scores are
+    sigmoids in f32, the `topk` experts are the top of score + bias, the
+    gates are the scores alone at those experts, normalised to sum to one
+    (`norm_topk_prob`) and scaled. The bias is a buffer: state, not a
+    parameter — no gradient, no optimizer state; `updated_bias` is its
+    rule."""
+
+    def __init__(self, d_model: int, num_experts: int, topk: int,
+                 norm_topk_prob: bool = True,
+                 routed_scaling_factor: float = 1.0):
+        super().__init__()
+        self.num_experts, self.topk = num_experts, topk
+        self.norm_topk_prob = norm_topk_prob
+        self.routed_scaling_factor = routed_scaling_factor
+        self.weight = self.create_parameter([d_model, num_experts])
+        self.register_buffer("e_score_correction_bias", Tensor(
+            jnp.zeros((num_experts,), jnp.float32)))
+
+    def forward(self, x):
+        """x [T, d] -> (experts [T, topk] int32, gates [T, topk] f32)."""
+
+        def impl(h, w, bias):
+            score = jax.nn.sigmoid(jnp.dot(
+                h.astype(jnp.float32), w.astype(jnp.float32),
+                precision=jax.lax.Precision.HIGHEST))
+            _, idx = jax.lax.top_k(
+                score + jax.lax.stop_gradient(bias), self.topk)
+            gates = jnp.take_along_axis(score, idx, axis=-1)
+            if self.norm_topk_prob:
+                gates = gates / (gates.sum(-1, keepdims=True) + 1e-20)
+            return idx, gates * self.routed_scaling_factor
+
+        return dispatch("moe_route", impl,
+                        (x, self.weight, self.e_score_correction_bias),
+                        n_outs=2)
+
+    @staticmethod
+    def updated_bias(bias, load, rate: float):
+        """bias + rate * sign(mean load - load): an expert that saw fewer
+        tokens than the mean becomes likelier, a busier one less likely."""
+        load = load.astype(jnp.float32)
+        return bias + rate * jnp.sign(jnp.mean(load) - load)
+
+
+@jax.custom_vjp
+def _rows_in(x, row_token, dest):
+    """xs[r] = x[row_token[r]] (a zero row where row_token is out of range).
+    `dest` [T, k] is the inverse map: the buffer row of each assignment, out
+    of range where it has none. Both directions are gathers."""
+    return jnp.take(x, row_token, axis=0, mode="fill", fill_value=0)
+
+
+def _rows_in_fwd(x, row_token, dest):
+    return _rows_in(x, row_token, dest), dest
+
+
+def _rows_in_bwd(dest, dxs):
+    picked = jnp.take(dxs, dest.reshape(-1), axis=0, mode="fill",
+                      fill_value=0)
+    return (picked.reshape(dest.shape + dxs.shape[1:]).sum(1)
+            .astype(dxs.dtype), None, None)
+
+
+_rows_in.defvjp(_rows_in_fwd, _rows_in_bwd)
+
+
+@jax.custom_vjp
+def _rows_out(ys, gates, row_token, dest, row_gate):
+    """y[t] = sum_j gates[t, j] * ys[dest[t, j]], f32 accumulation.
+    `row_gate` [rows] is the gate of the assignment in each buffer row (0 in
+    padding): the backward pass spreads dy over the rows by a gather too."""
+    picked = jnp.take(ys, dest.reshape(-1), axis=0, mode="fill",
+                      fill_value=0).reshape(dest.shape + ys.shape[1:])
+    return jnp.einsum("tk,tkd->td", gates, picked,
+                      preferred_element_type=jnp.float32).astype(ys.dtype)
+
+
+def _rows_out_fwd(ys, gates, row_token, dest, row_gate):
+    return (_rows_out(ys, gates, row_token, dest, row_gate),
+            (ys, row_token, dest, row_gate))
+
+
+def _rows_out_bwd(res, dy):
+    ys, row_token, dest, row_gate = res
+    dys = jnp.take(dy, row_token, axis=0, mode="fill", fill_value=0) \
+        * row_gate[:, None].astype(dy.dtype)
+    picked = jnp.take(ys, dest.reshape(-1), axis=0, mode="fill",
+                      fill_value=0).reshape(dest.shape + ys.shape[1:])
+    dgates = jnp.einsum("td,tkd->tk", dy, picked,
+                        preferred_element_type=jnp.float32)
+    return dys.astype(ys.dtype), dgates, None, None, None
+
+
+_rows_out.defvjp(_rows_out_fwd, _rows_out_bwd)
+
+
+# what a dropless layer counts in a step, on the device (observability/README);
+# the last is no counter but the router's choice itself [T, k], for a check
+# that holds the layer to a reference token by token
+MOE_COUNTERS = ("moe.rows_held", "moe.rows_routed", "moe.rows_multiplied",
+                "moe.rows_dropped", "moe.load_max", "moe.load_mean",
+                "moe.load", "moe.choice")
+
+
+def dropless_experts(x, idx, gates, w_gate, w_up, w_down, held,
+                     num_experts: int):
+    """The held experts' part of the layer: x [T, d], the router's choice
+    idx [T, k] over all `num_experts` and its gates [T, k]; stacked SwiGLU
+    weights [G, d, f], [G, d, f], [G, f, d] of the experts `held` (global
+    indices, G of them). Returns (y [T, d], counters).
+
+    The assignments that fall on held experts are sorted by expert into a
+    buffer in which every expert's rows start on a row tile (sized for the
+    worst case, every token on `min(k, G)` held experts: the grouped matmul
+    walks only the tiles in use); the rest are not computed, here or
+    anywhere on this chip.
+
+    Where `held` is a share of the experts, the gates are constants of the
+    backward pass: a gate's gradient needs the outputs of every expert its
+    token chose, and only the held ones are here. The part this chip could
+    form says "an absent expert adds nothing, a held one adds noise", and a
+    router trained from it moves its tokens off the held experts within a
+    hundred steps (PERF.md section 6, PR 28), which no deployment's router
+    does: there the exchange brings every chosen expert's output back to the
+    token. So the router's weight trains where the layer holds every expert,
+    and waits for the exchange where it holds a share; the bias rule, which
+    needs counts alone, runs in both."""
+    from ..kernels.grouped_matmul import (ROW_TILE, buffer_rows,
+                                          group_layout, grouped_matmul)
+
+    t, k = idx.shape
+    g = len(held)
+    if g < num_experts:
+        gates = jax.lax.stop_gradient(gates)
+    rows = buffer_rows(t * min(k, g), g)
+    local = jnp.full((num_experts,), g, jnp.int32).at[
+        jnp.asarray(held, jnp.int32)].set(jnp.arange(g, dtype=jnp.int32))
+    lid = local[idx.reshape(-1)]                    # [T*k]; g = held elsewhere
+    order = jnp.argsort(lid, stable=True).astype(jnp.int32)
+    lid_sorted = lid[order]
+    sizes = jnp.bincount(lid, length=g + 1)[:g].astype(jnp.int32)
+    layout = group_layout(sizes, rows)
+    # unpadded and padded start of each group; one more entry for "elsewhere"
+    first = jnp.concatenate([jnp.cumsum(sizes) - sizes,
+                             jnp.zeros((1,), jnp.int32)])
+    starts = jnp.concatenate([layout.starts,
+                              jnp.full((1,), rows, jnp.int32)])
+    rank = jnp.arange(t * k, dtype=jnp.int32) - first[lid_sorted]
+    dest_sorted = jnp.where(lid_sorted < g, starts[lid_sorted] + rank, rows)
+    dest = jnp.full((t * k,), rows, jnp.int32).at[order].set(dest_sorted)
+    row_assign = jnp.full((rows,), t * k, jnp.int32).at[dest_sorted].set(
+        order, mode="drop")
+    row_token = jnp.where(row_assign < t * k, row_assign // k, t)
+    row_gate = jnp.take(gates.reshape(-1), row_assign, mode="fill",
+                        fill_value=0)
+    dest = dest.reshape(t, k)
+
+    xs = _rows_in(x, row_token, dest)
+    act = jax.nn.silu(grouped_matmul(xs, w_gate, layout)) \
+        * grouped_matmul(xs, w_up, layout)
+    ys = grouped_matmul(act, w_down, layout)
+    y = _rows_out(ys, gates, row_token, dest, row_gate)
+
+    load = jnp.bincount(idx.reshape(-1), length=num_experts)
+    live = jnp.sum(layout.tile_rows)
+    counters = {
+        "moe.rows_held": live,
+        "moe.rows_routed": jnp.asarray(t * k, jnp.int32),
+        "moe.rows_multiplied": layout.n_tiles * ROW_TILE,
+        "moe.rows_dropped": jnp.sum(lid < g).astype(jnp.int32) - live,
+        "moe.load_max": jnp.max(sizes),
+        "moe.load_mean": jnp.mean(sizes.astype(jnp.float32)),
+        "moe.load": load.astype(jnp.float32),
+        "moe.choice": idx,
+    }
+    return y, counters
+
+
+class HeldExperts(Layer):
+    """Stacked SwiGLU weights of the experts this chip holds."""
+
+    def __init__(self, d_model: int, d_hidden: int, held):
+        super().__init__()
+        from ..nn.initializer import Normal
+
+        self.held = tuple(int(e) for e in held)
+        g = len(self.held)
+        # each expert a Xavier-normal [d, f] matrix (the stacked shape's own
+        # fans would count the expert dim)
+        init = Normal(std=(2.0 / (d_model + d_hidden)) ** 0.5)
+        self.gate_proj = self.create_parameter(
+            [g, d_model, d_hidden], default_initializer=init)
+        self.up_proj = self.create_parameter(
+            [g, d_model, d_hidden], default_initializer=init)
+        self.down_proj = self.create_parameter(
+            [g, d_hidden, d_model], default_initializer=init)
+
+
+class DroplessMoELayer(Layer):
+    """gate -> sort the assignments on held experts -> grouped matmuls ->
+    weighted sum. `held`: the global indices of the experts held here, all
+    `num_experts` by default. forward(x [..., d]) -> (y, counters)."""
+
+    def __init__(self, d_model: int, d_hidden: int, num_experts: int,
+                 topk: int, held=None, norm_topk_prob: bool = True,
+                 routed_scaling_factor: float = 1.0):
+        super().__init__()
+        held = range(num_experts) if held is None else held
+        self.num_experts = num_experts
+        self.gate = BiasBalancedSigmoidGate(
+            d_model, num_experts, topk, norm_topk_prob,
+            routed_scaling_factor)
+        self.experts = HeldExperts(d_model, d_hidden, held)
+        if not all(0 <= e < num_experts for e in self.experts.held) \
+                or len(set(self.experts.held)) != len(self.experts.held):
+            raise ValueError(f"held experts {self.experts.held} are not "
+                             f"distinct indices below {num_experts}")
+
+    def forward(self, x):
+        shape = x.shape
+        h = x.reshape([-1, shape[-1]])
+        with jax.named_scope("moe.route"):
+            idx, gates = self.gate(h)
+        ex = self.experts
+
+        def impl(hh, ii, gg, wg, wu, wd):
+            y, c = dropless_experts(hh, ii, gg, wg, wu, wd, ex.held,
+                                    self.num_experts)
+            return (y,) + tuple(c[k] for k in MOE_COUNTERS)
+
+        with jax.named_scope("moe.experts"):
+            y, *counted = dispatch(
+                "moe_dropless_experts", impl,
+                (h, idx, gates, ex.gate_proj, ex.up_proj, ex.down_proj))
+        return y.reshape(shape), {k: unwrap(v) for k, v in
+                                  zip(MOE_COUNTERS, counted)}

@@ -179,6 +179,27 @@ def _zero_shard_states(opt_state, params, mesh, axis):
     return {"step": opt_state["step"], "acc": acc}
 
 
+def read_report(report: dict, registry=None) -> Dict[str, float]:
+    """The host's reading of what a step reported of itself (the fourth
+    result of a `make_train_step` step whose loss_fn returns a report): every
+    scalar as a float, per-expert arrays left out. With a metrics registry
+    armed (`observability.metrics.get_metrics()`, or the one passed) each is
+    set as a gauge of its own name (`loss.main`, `moe.rows_held`, ...). The
+    values are results of the step's program: reading them waits for that
+    step and for nothing else."""
+    from ..observability import metrics as obs_metrics
+
+    # one transfer for all of them
+    out = {k: float(v) for k, v in jax.device_get(
+        {k: v for k, v in report.items()
+         if getattr(v, "ndim", 0) == 0}).items()}
+    registry = obs_metrics.get_metrics() if registry is None else registry
+    if registry is not None:
+        for k, v in out.items():
+            registry.gauge(k).set(v)
+    return out
+
+
 def make_train_step(model: Layer, loss_fn: Callable, mesh: Optional[Mesh] = None,
                     lr: float = 1e-4, weight_decay: float = 0.01,
                     grad_clip_norm: Optional[float] = 1.0,
@@ -189,6 +210,18 @@ def make_train_step(model: Layer, loss_fn: Callable, mesh: Optional[Mesh] = None
     `loss_fn(logits_or_output, *batch_rest) -> scalar Tensor`; batch is
     (input, *rest). The returned step_fn is jitted with buffer donation;
     call it as `loss, params, opt_state = step_fn(params, opt_state, *batch)`.
+
+    A model may report on itself: where `loss_fn` returns `(loss, report)`,
+    `report` a dict of device values (the parts of the loss, routing
+    counters), the step returns it as a fourth result — `loss, params,
+    opt_state, report = step_fn(...)` — for the host to read with the loss,
+    at no barrier of its own. And a model may keep state that is no
+    parameter (a buffer: no gradient, no optimizer state): where it defines
+    `state_updates(state, report) -> {leaf name: new value}`, that rule runs
+    inside the step, after the optimizer and outside it.
+    `step_fn.loss_and_grads(params, *batch)` is the step's own loss (with the
+    report, if any) and gradients, jitted apart, for checks against a
+    reference.
 
     `optimizer`: any paddle_tpu Optimizer with a pure update rule — its
     update math, per-group weight decay, decay-exclusion fns, grad clip and
@@ -252,7 +285,10 @@ def make_train_step(model: Layer, loss_fn: Callable, mesh: Optional[Mesh] = None
         with _tape.no_grad():
             out = model.func_call(p, Tensor(inputs))
             loss = loss_fn(out, *(Tensor(r) for r in rest))
-        return unwrap(loss).astype(jnp.float32)
+        report = {}
+        if isinstance(loss, tuple):
+            loss, report = loss
+        return unwrap(loss).astype(jnp.float32), jax.tree.map(unwrap, report)
 
     if strat["recompute"].get("enable"):
         model_cfg = getattr(model, "config", None)
@@ -289,8 +325,10 @@ def make_train_step(model: Layer, loss_fn: Callable, mesh: Optional[Mesh] = None
     gm_avg = bool(gm_cfg.get("avg", True))
 
     def loss_and_grads(p, *batch):
+        """((loss, report), grads); the report of a merged step is its last
+        microbatch's."""
         if k_steps <= 1:
-            return jax.value_and_grad(compute_loss)(p, *batch)
+            return jax.value_and_grad(compute_loss, has_aux=True)(p, *batch)
         micro = tuple(
             b.reshape((k_steps, b.shape[0] // k_steps) + b.shape[1:])
             for b in batch)
@@ -303,36 +341,42 @@ def make_train_step(model: Layer, loss_fn: Callable, mesh: Optional[Mesh] = None
 
         def body(carry, mb):
             acc_loss, acc_g = carry
-            loss, grads = jax.value_and_grad(compute_loss)(p, *mb)
-            return (acc_loss + loss, jax.tree.map(acc_add, acc_g, grads)), None
+            (loss, report), grads = jax.value_and_grad(
+                compute_loss, has_aux=True)(p, *mb)
+            return (acc_loss + loss,
+                    jax.tree.map(acc_add, acc_g, grads)), report
 
         zeros = jax.tree.map(
             lambda x: jnp.zeros(x.shape, jnp.float32), p)
-        (loss_sum, g_sum), _ = jax.lax.scan(
+        (loss_sum, g_sum), reports = jax.lax.scan(
             body, (jnp.zeros((), jnp.float32), zeros), micro)
         scale = 1.0 / k_steps if gm_avg else 1.0
         grads = jax.tree.map(
             lambda g, x: (g * scale).astype(
                 x.dtype if jnp.issubdtype(x.dtype, jnp.floating)
                 else jnp.float32), g_sum, p)
-        return loss_sum / k_steps, grads
+        return (loss_sum / k_steps,
+                jax.tree.map(lambda r: r[-1], reports)), grads
 
     def train_step(p, s, lr_, *batch):
-        loss, grads = loss_and_grads(p, *batch)
+        (loss, report), grads = loss_and_grads(p, *batch)
         if fused is not None:
             new_p, new_s = fused.update(p, grads, s, lr_)
         else:
             new_p, new_s = adamw_update(
                 p, grads, s, lr_, weight_decay=weight_decay,
                 grad_clip_norm=grad_clip_norm)
-        return loss, new_p, new_s
+        if hasattr(model, "state_updates"):
+            new_p = {**new_p, **model.state_updates(p, report)}
+        # a model that reports nothing keeps the three results it had
+        return (loss, new_p, new_s) + ((report,) if report else ())
 
     # named by role: the profiler's module row reads `jit_train_step`
     jitted = jax.jit(train_step, donate_argnums=(0, 1) if donate else ())
 
     def step_fn(p, s, *batch):
         cur_lr = fused.host_lr() if fused is not None else lr
-        loss, new_p, new_s = jitted(
+        loss, new_p, new_s, *report = jitted(
             p, s, jnp.asarray(cur_lr, jnp.float32), *batch)
         # keep the Layer view fresh: donation invalidated the old arrays
         # (pointer swap only, no transfer)
@@ -340,9 +384,10 @@ def make_train_step(model: Layer, loss_fn: Callable, mesh: Optional[Mesh] = None
         if fused is not None:
             fused.latest_state = new_s  # lazily exported by state_dict()
             fused.host_tick()
-        return loss, new_p, new_s
+        return (loss, new_p, new_s, *report)
 
     step_fn.jitted = jitted  # for lowering/compile introspection
+    step_fn.loss_and_grads = jax.jit(loss_and_grads)
     if fused is not None:
         step_fn.fused_optimizer = fused
     return step_fn, params, opt_state

@@ -200,6 +200,55 @@ def test_int8_prefix_prefill(for_chip, one_chip):
              QWIN, KWIN, KWIN, POOL8, POOL8, TABLES, LENS, LENS, SCALE, SCALE)
 
 
+# the expert trainer (glm-4.7-flash cell): 2 x 4096 tokens, 20 heads of 256;
+# 8 held experts of width 1536 under hidden 2048
+MLA_QKV = ((2, 4096, 20, 256), BF)
+
+
+@pytest.mark.parametrize("qkv", [
+    MLA_QKV,
+    # no power of two: the backward blocks are 256 rows, not 1024 * 128 //
+    # 384 = 341, which is no lane multiple and divides no sequence
+    ((1, 2048, 4, 384), BF),
+    ((1, 2048, 4, 512), BF)], ids=["20x256", "4x384", "4x512"])
+def test_flash_fwd_bwd_at_wide_heads(for_chip, one_chip, qkv):
+    """Latent attention hands flash attention 20 equal q and kv heads of
+    192 + 64 = 256: the bundled kernel's path, at a head size no other
+    configuration has; and the widest heads `_bundled_ok` admits."""
+    fa = _mod("flash_attention")
+    assert fa._bundled_ok(qkv[0][1], qkv[0][1], qkv[0][2], qkv[0][2],
+                          qkv[0][3])
+
+    def loss(q, k, v):
+        return fa.flash_attention(q, k, v, causal=True).astype(F32).sum()
+
+    _compile(lambda q, k, v: fa.flash_attention(q, k, v, causal=True),
+             one_chip, qkv, qkv, qkv)
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)), one_chip,
+                    qkv, qkv, qkv)
+    assert text.count("tpu_custom_call") >= 3
+
+
+@pytest.mark.parametrize("rows", [4096, 32768])
+@pytest.mark.parametrize("k,n", [(2048, 1536), (1536, 2048)])
+def test_grouped_matmul(for_chip, one_chip, rows, k, n):
+    """Forward and both backward products over 8 groups, from the rows one
+    chip's share sees in a step to the buffer's worst case."""
+    gm = _mod("grouped_matmul")
+    m = gm.buffer_rows(rows, 8)
+
+    def loss(lhs, rhs, sizes):
+        out = gm.grouped_matmul(lhs, rhs, gm.group_layout(sizes, m))
+        return jnp.square(out.astype(F32)).sum()    # keeps the forward
+
+    text = _compile(jax.grad(loss, argnums=(0, 1)), one_chip,
+                    ((m, k), BF), ((8, k, n), BF), ((8,), I32))
+    for name in ("grouped_matmul", "grouped_matmul_dlhs",
+                 "grouped_matmul_drhs"):
+        assert f"{name}" in text, name
+    assert text.count("tpu_custom_call") >= 3
+
+
 def test_swiglu_fused_refuses_the_1b_mlp_shape():
     """K 2048, F 5504 is not 512-tileable: a caller who asked for the kernel
     gets an error, never the XLA form under the kernel's name."""

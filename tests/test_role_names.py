@@ -44,6 +44,13 @@ def _swiglu_grad(x, wg, wu):
             x, wg, wu)
 
 
+def _grouped_grad(lhs, rhs, sizes):
+    gm = _mod("grouped_matmul")
+    return jax.grad(lambda lhs, rhs: gm.grouped_matmul(
+        lhs, rhs, gm.group_layout(sizes, lhs.shape[0])).astype(F32).sum(),
+        argnums=(0, 1))(lhs, rhs)
+
+
 def _with_scales(fn):
     return lambda *a: fn(*a[:-2], k_scale=a[-2], v_scale=a[-1])
 
@@ -86,6 +93,11 @@ CASES = {
         _swiglu_grad, [((512, 512), BF), ((512, 1024), BF),
                        ((512, 1024), BF)],
         {"swiglu_fwd": "swiglu", "swiglu_bwd": "swiglu"}),
+    "grouped_matmul": (
+        _grouped_grad, [((1024, 256), BF), ((4, 256, 128), BF), ((4,), I32)],
+        {"grouped_matmul": "grouped_matmul",
+         "grouped_matmul_dlhs": "grouped_matmul",
+         "grouped_matmul_drhs": "grouped_matmul"}),
     "rms_norm": (
         lambda x, w: _mod("rms_norm").rms_norm(x, w, 1e-6),
         [((512, 4096), BF), ((4096,), BF)], {"rms_norm": None}),
@@ -214,7 +226,7 @@ def test_no_pallas_call_in_kernels_lacks_a_name():
                       for node in ast.walk(tree)
                       if isinstance(node, ast.Call)
                       and getattr(node.func, "attr", "") == "pallas_call"]
-    assert len(calls) == 16     # the call sites CASES covers
+    assert len(calls) == 18     # the call sites CASES covers
     assert [c[:2] for c in calls if "name" not in c[2]] == []
 
 
