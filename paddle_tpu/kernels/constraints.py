@@ -181,7 +181,7 @@ class KernelConstraint:
 
     `name` is the kernel's ONE name: every `pl.pallas_call` of the
     kernel passes it as `name=` (with a role suffix where one entry
-    covers several calls: `flash_attention_bwd_dq`), so it is the name
+    covers several calls: `flash_attention_bwd`), so it is the name
     of the operation in the compiled program and in a profiler's trace,
     and the `func_name` of the traced equation's kernel jaxpr.
     `kernel_fns` are the Pallas kernel *function* names this constraint

@@ -4,8 +4,9 @@ TPU-native counterpart of the reference's flash_attn op family
 (paddle/phi/ops/yaml/ops.yaml:1765-1777, kernel
 paddle/phi/kernels/gpu/flash_attn_kernel.cu): online-softmax tiled attention
 that never materialises the [S, S] score matrix. The forward runs on the MXU
-with fp32 accumulators in VMEM scratch; the backward recomputes scores and
-softmax statistics from q/k/v (flash-attention-2 recompute strategy).
+with fp32 accumulators in VMEM scratch; the backward recomputes each block of
+scores ONCE from q/k and the forward's row statistic and takes dq, dk and dv
+from it in one kernel (flash-attention-2 recompute strategy, one pass).
 
 Public layout matches paddle: [batch, seqlen, num_heads, head_dim]; GQA/MQA
 (fewer kv heads) is supported by routing each query head to its kv head in
@@ -27,23 +28,92 @@ from .constraints import KernelConstraint, LANE, register_constraint
 
 _NEG_INF = -1e30
 
-# default seq tiling of the in-repo kernels: both grids walk the kv axis
-# in BLOCK_K steps with BLOCK_Q query rows resident in VMEM (clamped to
-# the actual seq len; seq lens must then divide the clamped block)
+# default seq tiling of the forward kernel: its grid walks the kv axis in
+# BLOCK_K steps with BLOCK_Q query rows resident in VMEM (clamped to the
+# actual seq len; seq lens must then divide the clamped block)
 BLOCK_Q = 512
 BLOCK_K = 512
-# the bundled jax MHA / splash fast paths tile at 1024 and require
-# 512-divisible seqs and a 128-lane-aligned head dim
+# on a TPU, equal heads on long 512-divisible sequences at a lane-aligned head
+# (`_wide_blocks_ok`: the shapes JAX's bundled MHA kernel ran until PR 30)
+# tile the forward at 1024 rows, and splash tiles grouped heads so. The
+# backward tiles at 1024 rows at a head of one lane tile, fewer at wider heads
+# (`_bwd_block_cap`), whatever the path
 FAST_PATH_BLOCK = 1024
 FAST_PATH_SEQ_MULTIPLE = 512
-# the widest head the bundled MHA kernel is given: its backward blocks are
-# 1024 * 128 / head size rows, rounded down to a power of two (256 here)
-BUNDLED_MAX_HEAD = 512
+# the widest head whose forward takes the 1024-row blocks (compiled for the
+# chip up to here: the backward works in blocks of 256 rows at 384 and 512)
+WIDE_BLOCK_MAX_HEAD = 512
+# scoped VMEM on a v5e: what Mosaic gives a kernel unasked, and the most the
+# backward asks for of a core's 128 MiB. Beside dq's whole-sequence f32
+# accumulator its blocks and intermediates take 8.3 MiB at 1024 rows x 128
+# (the compiler's own count; less at the wider heads' smaller blocks)
+VMEM_DEFAULT_BYTES = 16 << 20
+VMEM_MAX_BYTES = 96 << 20
+BWD_VMEM_BESIDE_DQ_BYTES = 12 << 20
+
+
+def _wide_blocks_ok(sq, sk, hq, hk, dh) -> bool:
+    """Shapes whose forward tiles at FAST_PATH_BLOCK rows on a TPU: equal
+    heads — or, in the core's layout, equal batch * heads — on long
+    block-divisible sequences at a lane-aligned head."""
+    return (hq == hk and dh % LANE == 0 and dh <= WIDE_BLOCK_MAX_HEAD
+            and sq % FAST_PATH_SEQ_MULTIPLE == 0
+            and sk % FAST_PATH_SEQ_MULTIPLE == 0 and sq == sk)
+
+
+def _block_rows(seq: int, cap: int) -> int:
+    """Rows of a block of at most `cap` (a power of two) rows: the largest
+    power of two up to it that divides the sequence, so that a block stays a
+    lane multiple. A sequence no longer than the cap, or one that no lane
+    multiple of such rows divides, is one block."""
+    rows = math.gcd(seq, cap)
+    return rows if seq > cap and rows % LANE == 0 else seq
+
+
+def _bwd_block_cap(d: int) -> int:
+    """The most rows of a backward block: FAST_PATH_BLOCK at a head of one
+    lane tile, fewer as the head widens (the kernel holds q, k, v, do, dk and
+    dv blocks and four `[block_k, block_q]` f32 intermediates at once), by
+    powers of two: 128 -> 1024, 256 -> 512, 384 and 512 -> 256."""
+    return min(FAST_PATH_BLOCK,
+               1 << (FAST_PATH_BLOCK * LANE // d).bit_length() - 1)
+
+
+def _fwd_blocks(q_shape, k_shape, on_tpu: bool):
+    """(block_q, block_k) of the forward kernel on q [BH, Sq, D] and k
+    [BKVH, Sk, D]."""
+    (bh, sq, d), (bkv, sk) = q_shape, k_shape[:2]
+    if on_tpu and _wide_blocks_ok(sq, sk, bh, bkv, d):
+        return (_block_rows(sq, FAST_PATH_BLOCK),
+                _block_rows(sk, FAST_PATH_BLOCK))
+    return min(BLOCK_Q, sq), min(BLOCK_K, sk)
+
+
+def _bwd_vmem_bytes(sq: int, d: int) -> int:
+    """Scoped VMEM the one-pass backward needs: dq's whole-sequence f32
+    accumulator and what its blocks take beside it."""
+    return sq * d * 4 + BWD_VMEM_BESIDE_DQ_BYTES
+
+
+def _bwd_refusal(sq: int, d: int) -> Optional[str]:
+    """Why the one-pass backward does not take `sq` query rows at head size
+    `d`, or None where it does: up to sq * d = 22M, which is 172,032 rows at
+    a head of 128, 86,016 at 256, 43,008 at 512 (compiled for the chip there,
+    `tests/test_chip_compile.py`)."""
+    if _bwd_vmem_bytes(sq, d) <= VMEM_MAX_BYTES:
+        return None
+    return (f"the flash-attention backward keeps dq for all {sq} query rows "
+            f"at head size {d} in VMEM ({sq * d * 4 >> 20} MiB in f32) and "
+            f"takes {VMEM_MAX_BYTES - BWD_VMEM_BESIDE_DQ_BYTES >> 20} MiB; "
+            "split the sequence over chips (ring or Ulysses attention) or "
+            "differentiate it in shorter pieces")
 
 
 def _check_attention_shapes(shapes, dtypes):
     """Checker for the fwd/bwd pallas calls: q [BH, Sq, D], k/v
-    [BKVH, Sk, D] (bwd appends o/do/lse operands — same leading trio)."""
+    [BKVH, Sk, D] (bwd appends do/lse/delta operands — same leading trio).
+    The blocks are the forward's on the chip (`_fwd_blocks`); the backward's
+    (`_block_rows` under `_bwd_block_cap`) divide whatever those divide."""
     out = []
     if len(shapes) < 3:
         return out
@@ -51,26 +121,30 @@ def _check_attention_shapes(shapes, dtypes):
     if len(q) == 3 and len(k) == 3:
         bh, sq, d = q
         bkv, sk = k[0], k[1]
+        block_q, block_k = _fwd_blocks(q, k, True)
         if d % LANE:
             out.append(("warning",
                         f"head_dim {d} is not a multiple of the {LANE}-"
                         "lane tile; VMEM pads every row to "
                         f"{-(-d // LANE) * LANE} lanes"))
-        if sq % min(BLOCK_Q, sq):
+        if sq % block_q:
             out.append(("error",
                         f"q seq len {sq} does not divide the "
-                        f"{min(BLOCK_Q, sq)} query block; the kernel "
+                        f"{block_q} query block; the kernel "
                         "raises at call time"))
-        if sk % min(BLOCK_K, sk):
+        if sk % block_k:
             out.append(("error",
                         f"kv seq len {sk} does not divide the "
-                        f"{min(BLOCK_K, sk)} kv block; the kernel "
+                        f"{block_k} kv block; the kernel "
                         "raises at call time"))
         if bkv and bh % bkv:
             out.append(("error",
                         f"q heads*batch {bh} not a multiple of kv "
                         f"heads*batch {bkv}; GQA grouping requires "
                         "Hq % Hkv == 0"))
+        if _bwd_refusal(sq, d):
+            out.append(("warning", _bwd_refusal(sq, d)
+                        + " (differentiating this call raises)"))
     return out
 
 
@@ -100,10 +174,13 @@ def _flash_attention_roofline(shapes, dtypes):
 
 CONSTRAINT = register_constraint(KernelConstraint(
     name="flash_attention",
-    kernel_fns=("_fwd_kernel", "_bwd_dq_kernel", "_bwd_dkv_kernel"),
-    blocks={"block_q": BLOCK_Q, "block_k": BLOCK_K},
+    kernel_fns=("_fwd_kernel", "_bwd_kernel"),
+    blocks={"block_q": BLOCK_Q, "block_k": BLOCK_K,
+            "wide_block": FAST_PATH_BLOCK},
     note="online-softmax tiled attention; seq lens must divide the "
-         "(clamped) q/kv blocks and head_dim should be 128-lane aligned",
+         "(clamped) q/kv blocks — `wide_block` rows for equal heads on long "
+         "512-divisible sequences on a TPU, and in the backward, there fewer "
+         "as the head widens — and head_dim should be 128-lane aligned",
     checker=_check_attention_shapes,
     source="flash_attention.py",
     roofline=_flash_attention_roofline,
@@ -120,9 +197,12 @@ def _on_tpu() -> bool:
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
                 m_scr, l_scr, acc_scr, *, causal: bool, scale: float,
-                block_q: int, block_k: int, q_offset: int):
+                block_q: int, block_k: int, q_offset: int, lse_rows: bool):
     """q_offset = sk - sq aligns the causal diagonal to the END of the kv
-    sequence (paddle/flash-attn convention: the last q row sees all keys)."""
+    sequence (paddle/flash-attn convention: the last q row sees all keys).
+    `lse_rows`: the row statistic leaves as ONE lane-dense row `[1, block_q]`
+    (a block of whole lane tiles can be transposed) instead of replicated
+    across 128 lanes."""
     qi = pl.program_id(1)
     ki = pl.program_id(2)
     nk = pl.num_programs(2)
@@ -167,15 +247,18 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
     @pl.when(ki == nk - 1)
     def _final():
         o_ref[0] = (acc_scr[...] / l_scr[:, :1]).astype(o_ref.dtype)
-        # row statistic replicated across the 128 lanes (min tile layout)
-        lse_ref[0] = m_scr[...] + jnp.log(l_scr[...])
+        # the scratch holds the row statistic replicated across 128 lanes
+        lse = m_scr[...] + jnp.log(l_scr[...])
+        lse_ref[0] = lse.T[:1] if lse_rows else lse
 
 
 def _fwd_pallas(q, k, v, causal: bool, scale: float,
-                block_q: int = BLOCK_Q, block_k: int = BLOCK_K):
+                block_q: int = BLOCK_Q, block_k: int = BLOCK_K,
+                interpret: Optional[bool] = None):
     """q: [BH, Sq, D]; k/v: [BKVH, Sk, D]. Returns (out [BH, Sq, D],
-    lse [BH, Sq, 128] fp32 — the row statistic replicated across lanes,
-    the TPU-tileable layout the backward kernels consume directly)."""
+    lse [BH, Sq] fp32, the row statistic the backward starts from). The
+    kernel writes it one f32 a row where its q block is whole lane tiles,
+    and replicated across 128 lanes (lane 0 kept here) where it is not."""
     bh, sq, d = q.shape
     bkv, sk, _ = k.shape
     rep = bh // bkv                      # q heads per kv head (GQA)
@@ -186,25 +269,42 @@ def _fwd_pallas(q, k, v, causal: bool, scale: float,
                          f"({block_q},{block_k})")
     grid = (bh, sq // block_q, sk // block_k)
     vma = operand_vma(q, k, v)
+    q_offset = sk - sq
+    lse_rows = block_q % LANE == 0
     kernel = functools.partial(
-        _fwd_kernel, causal=causal, scale=scale,
-        block_q=block_q, block_k=block_k, q_offset=sk - sq)
+        _fwd_kernel, causal=causal, scale=scale, block_q=block_q,
+        block_k=block_k, q_offset=q_offset, lse_rows=lse_rows)
+
+    def kv_map(b, i, j):
+        # a kv block the causal band skips is not fetched: the index stays
+        # on the last one this q block needs
+        if causal:
+            j = jnp.minimum(j, jnp.maximum(
+                (i * block_q + block_q - 1 + q_offset) // block_k, 0))
+        return (b // rep, j, 0)
+
+    if lse_rows:
+        lse_spec = pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i))
+        lse_shape = (bh, 1, sq)
+    else:
+        lse_spec = pl.BlockSpec((1, block_q, LANE), lambda b, i, j: (b, i, 0))
+        lse_shape = (bh, sq, LANE)
     out, lse = pl.pallas_call(
         kernel,
         name=CONSTRAINT.name + "_fwd",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j, rep=rep: (b // rep, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j, rep=rep: (b // rep, j, 0)),
+            pl.BlockSpec((1, block_k, d), kv_map),
+            pl.BlockSpec((1, block_k, d), kv_map),
         ],
         out_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 128), lambda b, i, j: (b, i, 0)),
+            lse_spec,
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bh, sq, d), q.dtype, vma=vma),
-            jax.ShapeDtypeStruct((bh, sq, 128), jnp.float32, vma=vma),
+            jax.ShapeDtypeStruct(lse_shape, jnp.float32, vma=vma),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, 128), jnp.float32),
@@ -213,9 +313,9 @@ def _fwd_pallas(q, k, v, causal: bool, scale: float,
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=not _on_tpu(),
+        interpret=not _on_tpu() if interpret is None else interpret,
     )(q, k, v)
-    return out, lse
+    return out, lse[:, 0, :] if lse_rows else lse[:, :, 0]
 
 
 # ---------------------------------------------------------------------------
@@ -240,204 +340,193 @@ def _fwd_ref(q, k, v, causal: bool, scale: float):
     return out
 
 
-def _pallas_ok(q, k, v):
+def _pallas_ok(q, k, v, on_tpu: bool):
     """Whether the in-repo kernels take these operands — decided here,
     before the call, so whatever the kernel then raises propagates."""
-    # must match the kernels' default block choice (min(BLOCK, seq))
-    if (q.shape[1] % min(BLOCK_Q, q.shape[1])
-            or k.shape[1] % min(BLOCK_K, k.shape[1])
-            or q.shape[0] % k.shape[0]):
+    block_q, block_k = _fwd_blocks(q.shape, k.shape, on_tpu)
+    if q.shape[1] % block_q or k.shape[1] % block_k \
+            or q.shape[0] % k.shape[0]:
         return False
     # jax's Pallas interpreter cannot run under a vma-checked shard_map
-    return _on_tpu() or not operand_vma(q, k, v)
-
-
-def _fwd_core(q, k, v, causal, scale):
-    """Returns (out, lse) — lse is [BH,Sq,128] from the pallas path or None
-    (the jnp form recomputes stats in the backward)."""
-    if _pallas_ok(q, k, v):
-        return _fwd_pallas(q, k, v, causal, scale)
-    return _fwd_ref(q, k, v, causal, scale), None
+    return on_tpu or not operand_vma(q, k, v)
 
 
 # ---------------------------------------------------------------------------
-# backward kernels (FA2): dq over k blocks; dk/dv over q blocks
+# backward kernel: dq, dk and dv from one pass over the score blocks
 # ---------------------------------------------------------------------------
 
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref, dq_ref,
-                   dq_scr, *, causal: bool, scale: float, block_q: int,
-                   block_k: int, q_offset: int):
-    qi = pl.program_id(1)
-    ki = pl.program_id(2)
-    nk = pl.num_programs(2)
-
-    @pl.when(ki == 0)
-    def _init():
-        dq_scr[...] = jnp.zeros_like(dq_scr)
-
-    run = ((qi * block_q + block_q - 1 + q_offset >= ki * block_k)
-           if causal else True)
-
-    @pl.when(run)
-    def _compute():
-        q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
-        do = do_ref[0].astype(jnp.float32)
-        o = o_ref[0].astype(jnp.float32)
-        lse = lse_ref[0][:, :1]                       # [block_q, 1]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        if causal:
-            qpos = qi * block_q + q_offset + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            kpos = ki * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(qpos >= kpos, s, _NEG_INF)
-        p = jnp.exp(s - lse)
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        delta = jnp.sum(do * o, axis=-1, keepdims=True)
-        ds = p * (dp - delta) * scale
-        dq_scr[...] += jax.lax.dot_general(
-            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-
-    @pl.when(ki == nk - 1)
-    def _final():
-        dq_ref[0] = dq_scr[...].astype(dq_ref.dtype)
-
-
-def _bwd_dkv_kernel(q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
-                    dk_ref, dv_ref, dk_scr, dv_scr, *, causal: bool,
-                    scale: float, block_q: int, block_k: int,
-                    q_offset: int):
+def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr, *,
+                causal: bool, scale: float, block_q: int, block_k: int,
+                q_offset: int):
+    """Grid (batch*q_heads, k blocks, q blocks), q innermost. Each block of
+    scores is computed once, TRANSPOSED (`[block_k, block_q]`: k rows, q
+    columns), so that the per-row terms `lse` and `delta` come in as one
+    lane-dense row `[1, block_q]` and broadcast down the sublanes, and dv and
+    dk are plain products. dk and dv accumulate over the inner axis in
+    `[block_k, d]` scratch; dq accumulates over the OUTER axis in a
+    whole-sequence `[sq, d]` f32 scratch and leaves block by block during the
+    last k block's steps (`_bwd_pallas`'s dq index map). That scratch is what
+    bounds the sequence: `_bwd_vmem_bytes` against `VMEM_MAX_BYTES`."""
     ki = pl.program_id(1)
     qi = pl.program_id(2)
+    nk = pl.num_programs(1)
     nq = pl.num_programs(2)
+    rows = pl.ds(pl.multiple_of(qi * block_q, block_q), block_q)
+
+    @pl.when(jnp.logical_and(ki == 0, qi == 0))
+    def _init_dq():
+        dq_scr[...] = jnp.zeros_like(dq_scr)
 
     @pl.when(qi == 0)
-    def _init():
+    def _init_dkv():
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
+    # causal: skip q blocks strictly above the diagonal band
     run = ((qi * block_q + block_q - 1 + q_offset >= ki * block_k)
            if causal else True)
 
     @pl.when(run)
     def _compute():
-        q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
-        do = do_ref[0].astype(jnp.float32)
-        o = o_ref[0].astype(jnp.float32)
-        lse = lse_ref[0][:, :1]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
+        q = q_ref[0]                      # [block_q, d]
+        k = k_ref[0]                      # [block_k, d]
+        do = do_ref[0]
+        st = jax.lax.dot_general(
+            k, q, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
         if causal:
-            qpos = qi * block_q + q_offset + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
             kpos = ki * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(qpos >= kpos, s, _NEG_INF)
-        p = jnp.exp(s - lse)                          # [block_q, block_k]
+                jnp.int32, (block_k, block_q), 0)
+            qpos = qi * block_q + q_offset + jax.lax.broadcasted_iota(
+                jnp.int32, (block_k, block_q), 1)
+            st = jnp.where(qpos >= kpos, st, _NEG_INF)
+        pt = jnp.exp(st - lse_ref[0])                 # [block_k, block_q]
         dv_scr[...] += jax.lax.dot_general(
-            p.astype(do_ref.dtype), do.astype(do_ref.dtype),
-            (((0,), (0,)), ((), ())),
+            pt.astype(do.dtype), do, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(
-            do, v, (((1,), (1,)), ((), ())),
+        dpt = jax.lax.dot_general(
+            v_ref[0], do, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
-        delta = jnp.sum(do * o, axis=-1, keepdims=True)
-        ds = p * (dp - delta) * scale
+        dst = (pt * (dpt - delta_ref[0]) * scale).astype(q.dtype)
         dk_scr[...] += jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+            dst, q, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        dq_scr[rows, :] += jax.lax.dot_general(
+            dst, k, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
     @pl.when(qi == nq - 1)
-    def _final():
+    def _final_dkv():
         dk_ref[0] = dk_scr[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
 
+    @pl.when(ki == nk - 1)
+    def _final_dq():
+        dq_ref[0] = dq_scr[rows, :].astype(dq_ref.dtype)
+
 
 def _bwd_pallas(q, k, v, out, lse, do, causal: bool, scale: float,
-                block_q: int = BLOCK_Q, block_k: int = BLOCK_K):
-    """Flash backward. Returns (dq [BH,Sq,D], dk/dv [BH,Sk,D] per q-head —
-    caller reduces over GQA groups)."""
+                interpret: bool, block_q: Optional[int] = None,
+                block_k: Optional[int] = None):
+    """Flash backward from `lse` [BH, Sq] f32. Returns (dq [BH,Sq,D], dk/dv
+    [BH,Sk,D] per q-head — caller reduces over GQA groups). The scoped VMEM
+    limit follows dq's accumulator by arithmetic (`_bwd_vmem_bytes`): Mosaic's
+    default up to 4 MiB of it (2048 x 128 and 4096 x 256, the trained cells),
+    raised above that, and a sequence past `VMEM_MAX_BYTES` is the caller's
+    to refuse (`_bwd_refusal`)."""
     bh, sq, d = q.shape
     bkv, sk, _ = k.shape
     rep = bh // bkv
-    block_q = min(block_q, sq)
-    block_k = min(block_k, sk)
-    kern_kw = dict(causal=causal, scale=scale, block_q=block_q,
-                   block_k=block_k, q_offset=sk - sq)
+    block_q = block_q or _block_rows(sq, _bwd_block_cap(d))
+    block_k = block_k or _block_rows(sk, _bwd_block_cap(d))
+    nq, nk = sq // block_q, sk // block_k
+    q_offset = sk - sq
     vma = operand_vma(q, k, v, do)
-    q_spec = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0))
+    vmem = _bwd_vmem_bytes(sq, d)
+    # one f32 a row, never broadcast: [BH, 1, Sq] puts a block's rows in lanes
+    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
+                    axis=-1)[:, None, :]
+    lse = lse[:, None, :]
+
+    def q_map(b, j, i):
+        # a q block the causal band skips is not fetched: the index stays on
+        # the first one this k block needs
+        if causal:
+            i = jnp.maximum(i, jnp.clip((j * block_k - q_offset) // block_q,
+                                        0, nq - 1))
+        return (b, i, 0)
+
+    def row_map(b, j, i):
+        return (b, 0, q_map(b, j, i)[1])
+
+    q_spec = pl.BlockSpec((1, block_q, d), q_map)
+    row_spec = pl.BlockSpec((1, 1, block_q), row_map)
     kv_spec = pl.BlockSpec((1, block_k, d),
-                           lambda b, i, j, rep=rep: (b // rep, j, 0))
-    lse_spec = pl.BlockSpec((1, block_q, 128), lambda b, i, j: (b, i, 0))
-    dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, **kern_kw),
-        name=CONSTRAINT.name + "_bwd_dq",
-        grid=(bh, sq // block_q, sk // block_k),
-        in_specs=[q_spec, kv_spec, kv_spec, q_spec, q_spec, lse_spec],
-        out_specs=pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype, vma=vma),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=not _on_tpu(),
-    )(q, k, v, out, do, lse)
-    # dkv grid: (bh, k blocks, q blocks) — q innermost for accumulation
-    q_spec2 = pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, 0))
-    kv_spec2 = pl.BlockSpec((1, block_k, d),
-                            lambda b, j, i, rep=rep: (b // rep, j, 0))
-    lse_spec2 = pl.BlockSpec((1, block_q, 128), lambda b, j, i: (b, i, 0))
-    dkv_out = pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0))
-    dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, **kern_kw),
-        name=CONSTRAINT.name + "_bwd_dkv",
-        grid=(bh, sk // block_k, sq // block_q),
-        in_specs=[q_spec2, kv_spec2, kv_spec2, q_spec2, q_spec2, lse_spec2],
-        out_specs=[dkv_out, dkv_out],
-        out_shape=[jax.ShapeDtypeStruct((bh, sk, d), k.dtype, vma=vma),
+                           lambda b, j, i: (b // rep, j, 0))
+    # dq is whole only after the last k block: until then the output window
+    # rests on block 0, which nothing writes back before the index moves
+    dq_spec = pl.BlockSpec(
+        (1, block_q, d), lambda b, j, i: (b, jnp.where(j == nk - 1, i, 0), 0))
+    dkv_spec = pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0))
+    return pl.pallas_call(
+        functools.partial(_bwd_kernel, causal=causal, scale=scale,
+                          block_q=block_q, block_k=block_k,
+                          q_offset=q_offset),
+        name=CONSTRAINT.name + "_bwd",
+        grid=(bh, nk, nq),
+        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
+        out_specs=[dq_spec, dkv_spec, dkv_spec],
+        out_shape=[jax.ShapeDtypeStruct((bh, sq, d), q.dtype, vma=vma),
+                   jax.ShapeDtypeStruct((bh, sk, d), k.dtype, vma=vma),
                    jax.ShapeDtypeStruct((bh, sk, d), v.dtype, vma=vma)],
-        scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((sq, d), jnp.float32),
+                        pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=not _on_tpu(),
-    )(q, k, v, out, do, lse)
-    return dq, dk, dv
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=vmem if vmem > VMEM_DEFAULT_BYTES else None),
+        interpret=interpret,
+    )(q, k, v, do, lse, delta)
 
 
 # ---------------------------------------------------------------------------
-# custom_vjp over [BH, S, D] core
+# custom_vjp over the [BH, S, D] core, under ONE jit
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _flash_core(q, k, v, causal: bool, scale: float):
-    return _fwd_core(q, k, v, causal, scale)[0]
+def _fwd_core(q, k, v, causal, scale, on_tpu):
+    """Returns (out, lse): lse is [BH, Sq] f32 from the kernel, or None behind
+    the jnp form (whose backward recomputes the statistics)."""
+    if _pallas_ok(q, k, v, on_tpu):
+        return _fwd_pallas(q, k, v, causal, scale,
+                           *_fwd_blocks(q.shape, k.shape, on_tpu),
+                           interpret=not on_tpu)
+    return _fwd_ref(q, k, v, causal, scale), None
 
 
-def _flash_core_fwd(q, k, v, causal, scale):
-    out, lse = _fwd_core(q, k, v, causal, scale)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _flash_vjp(q, k, v, causal: bool, scale: float, on_tpu: bool):
+    return _fwd_core(q, k, v, causal, scale, on_tpu)[0]
+
+
+def _flash_vjp_fwd(q, k, v, causal, scale, on_tpu):
+    out, lse = _fwd_core(q, k, v, causal, scale, on_tpu)
+    if lse is not None and _bwd_refusal(q.shape[1], q.shape[2]):
+        raise ValueError(_bwd_refusal(q.shape[1], q.shape[2]))
     return out, (q, k, v, out, lse)
 
 
-def _flash_core_bwd(causal, scale, res, do):
+def _flash_vjp_bwd(causal, scale, on_tpu, res, do):
     """FA2 backward: dv = P^T dO ; dS = P * (dO V^T - rowsum(dO*O)) * scale;
     dq = dS K ; dk = dS^T Q (reference math:
     paddle/phi/kernels/gpu/flash_attn_grad_kernel.cu via the flashattn
-    library). Pallas kernels when the forward saved LSE; jnp recompute
-    fallback otherwise."""
+    library). The one-pass kernel when a forward kernel saved LSE; jnp
+    recompute otherwise."""
     q, k, v, out, lse = res
     bh, sq, d = q.shape
     if lse is not None:
-        dq, dk, dv = _bwd_pallas(q, k, v, out, lse, do, causal, scale)
+        dq, dk, dv = _bwd_pallas(q, k, v, out, lse, do, causal, scale,
+                                 interpret=not on_tpu)
         rep = bh // k.shape[0]
         if rep > 1:
             dk = dk.reshape(k.shape[0], rep, *dk.shape[1:]).sum(1)
@@ -468,21 +557,23 @@ def _flash_core_bwd(causal, scale, res, do):
             like_primal(dv.astype(v.dtype), v))
 
 
-_flash_core.defvjp(_flash_core_fwd, _flash_core_bwd)
+_flash_vjp.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
+
+# N layers of a model share one trace and one private function of the lowered
+# module, so a step's text holds each kernel's Mosaic payload once; what the
+# trace depends on beside the operands (`jax.default_backend()`) is an
+# argument, so that it is part of the jit's key
+_flash_jit = jax.jit(_flash_vjp, static_argnums=(3, 4, 5))
+
+
+def _flash_core(q, k, v, causal: bool, scale: float):
+    """q: [BH, Sq, D]; k/v: [BKVH, Sk, D]; differentiable."""
+    return _flash_jit(q, k, v, causal, scale, _on_tpu())
 
 
 # ---------------------------------------------------------------------------
 # public API, paddle layout [B, S, H, D]
 # ---------------------------------------------------------------------------
-
-def _bundled_ok(sq, sk, hq, hk, dh) -> bool:
-    """Shapes the bundled jax pallas MHA kernel handles well (equal heads,
-    long block-divisible sequences)."""
-    return (_on_tpu() and hq == hk and dh % LANE == 0
-            and dh <= BUNDLED_MAX_HEAD
-            and sq % FAST_PATH_SEQ_MULTIPLE == 0
-            and sk % FAST_PATH_SEQ_MULTIPLE == 0 and sq == sk)
-
 
 def _splash_ok(sq, sk, hq, hk, dh) -> bool:
     """GQA shapes for the splash kernel (grouped heads natively — the fast
@@ -523,14 +614,21 @@ def flash_attention(q, k, v, causal: bool = False,
     """Differentiable flash attention; layout [B, S, H, D] (paddle
     flash_attn layout, ops.yaml:1765). kv heads may divide q heads (GQA).
 
-    Fast path: the pallas flash kernel bundled with the installed jax
-    (jax.experimental.pallas.ops.tpu.flash_attention) — the TPU analog of
-    the reference vendoring Dao's flash-attn library
-    (third_party/flashattn) — and splash for GQA, each behind its shape
-    predicate (`_bundled_ok`, `_splash_ok`). Every other shape takes the
-    in-repo kernel pack where `_pallas_ok` holds and the jnp form
-    otherwise. The choice is made from shapes before the call: nothing a
-    kernel raises is caught.
+    What runs where, chosen from shapes before the call (nothing a kernel
+    raises is caught): grouped heads on long 512-divisible sequences take
+    splash — the kernel bundled with the installed jax, the TPU analog of the
+    reference vendoring Dao's flash-attn library (third_party/flashattn) —
+    forward and backward (`_splash_ok`). Everything else goes through the
+    jitted `[B*H, S, D]` core: the in-repo `flash_attention_fwd` where
+    `_pallas_ok` holds (on a TPU at 1024-row blocks for equal heads on such
+    sequences, `_wide_blocks_ok`, at 512 otherwise), which hands one f32 a
+    row to the one-pass `flash_attention_bwd`; the jnp form, which
+    differentiates itself, otherwise.
+
+    The backward keeps dq for the whole sequence in VMEM, so differentiating
+    raises a ValueError past `sq * head_dim` = 22M (`_bwd_refusal`: 172,032
+    rows at a head of 128, 86,016 at 256): such a sequence is split over
+    chips.
     """
     b, sq, hq, dh = q.shape
     hk = k.shape[2]
@@ -544,27 +642,6 @@ def flash_attention(q, k, v, causal: bool = False,
         qs = jnp.swapaxes(q, 1, 2) * jnp.asarray(scale, q.dtype)
         out = jax.vmap(kernel)(qs, jnp.swapaxes(k, 1, 2),
                                jnp.swapaxes(v, 1, 2))
-        return jnp.swapaxes(out, 1, 2)
-    if _bundled_ok(sq, sk, hq, hk, dh):
-        from jax.experimental.pallas.ops.tpu.flash_attention import (
-            BlockSizes, flash_attention as _jax_fa)
-
-        bs = min(FAST_PATH_BLOCK, sq)
-        # the backward kernels hold q, k, v, do and dk, dv blocks at once:
-        # at head size 256 (latent attention) 1024-row blocks overrun the
-        # 16 MiB of scoped VMEM by 0.9 MiB (the chip's compiler, PR 28), so
-        # their rows shrink as the head widens, by powers of two so that a
-        # block stays a lane multiple and a divisor of the sequence (head
-        # size 384 gives 256, not 341); head size 128 keeps 1024
-        bw = min(bs, 1 << (FAST_PATH_BLOCK * LANE // dh).bit_length() - 1)
-        blocks = BlockSizes(
-            block_q=bs, block_k_major=bs, block_k=bs, block_b=1,
-            block_q_major_dkv=bw, block_k_major_dkv=bw,
-            block_k_dkv=bw, block_q_dkv=bw,
-            block_k_major_dq=bw, block_k_dq=bw, block_q_dq=bw)
-        out = _jax_fa(jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2),
-                      jnp.swapaxes(v, 1, 2), causal=causal,
-                      sm_scale=scale, block_sizes=blocks)
         return jnp.swapaxes(out, 1, 2)
     qc = jnp.swapaxes(q, 1, 2).reshape(b * hq, sq, dh)
     kc = jnp.swapaxes(k, 1, 2).reshape(b * hk, sk, dh)

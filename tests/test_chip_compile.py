@@ -95,8 +95,8 @@ def test_flash_bwd(for_chip, one_chip):
 
 
 def test_flash_in_repo_kernels(for_chip, one_chip):
-    """The in-repo fwd/bwd kernels behind `_flash_core` (the shapes the
-    vendored fast paths' predicates turn away)."""
+    """The in-repo fwd/bwd kernels behind `_flash_core` at grouped heads
+    (the shapes `_splash_ok` turns away reach it in this layout)."""
     fa = _mod("flash_attention")
     q, kv = ((64, 2048, 128), BF), ((16, 2048, 128), BF)
 
@@ -104,7 +104,8 @@ def test_flash_in_repo_kernels(for_chip, one_chip):
         return fa._flash_core(q, k, v, True, 0.088).astype(F32).sum()
 
     text = _compile(jax.grad(loss, argnums=(0, 1, 2)), one_chip, q, kv, kv)
-    assert text.count("tpu_custom_call") >= 3      # fwd, dq, dkv
+    assert text.count("tpu_custom_call") >= 2      # fwd, one-pass bwd
+    assert "flash_attention_fwd" in text and "flash_attention_bwd" in text
 
 
 @pytest.mark.parametrize("rows,hidden", [(4 * 2048, 2048), (8, 4096),
@@ -206,27 +207,70 @@ MLA_QKV = ((2, 4096, 20, 256), BF)
 
 
 @pytest.mark.parametrize("qkv", [
+    # the dense trainer's cell (`dscoder1p3b-train-2k`): 16 equal heads of
+    # 128, backward blocks of 1024 rows, dq's accumulator 1 MiB
+    ((4, 2048, 16, 128), BF),
+    # the expert trainer's: blocks of 512 rows, the accumulator 4 MiB
     MLA_QKV,
     # no power of two: the backward blocks are 256 rows, not 1024 * 128 //
     # 384 = 341, which is no lane multiple and divides no sequence
     ((1, 2048, 4, 384), BF),
-    ((1, 2048, 4, 512), BF)], ids=["20x256", "4x384", "4x512"])
+    ((1, 2048, 4, 512), BF)], ids=["16x128", "20x256", "4x384", "4x512"])
 def test_flash_fwd_bwd_at_wide_heads(for_chip, one_chip, qkv):
-    """Latent attention hands flash attention 20 equal q and kv heads of
-    192 + 64 = 256: the bundled kernel's path, at a head size no other
-    configuration has; and the widest heads `_bundled_ok` admits."""
+    """Equal heads on long sequences: the in-repo forward at 1024-row blocks
+    (its row statistic transposed into one lane-dense row) and the in-repo
+    one-pass backward (dq, dk, dv from one kernel, with a whole-sequence f32
+    dq in VMEM), both inside the default scoped VMEM limit at both train
+    cells' shapes — latent attention hands over 20 heads of 192 + 64 = 256, a
+    head size no other configuration has — and at the widest heads
+    `_wide_blocks_ok` admits."""
     fa = _mod("flash_attention")
-    assert fa._bundled_ok(qkv[0][1], qkv[0][1], qkv[0][2], qkv[0][2],
-                          qkv[0][3])
+    assert fa._wide_blocks_ok(qkv[0][1], qkv[0][1], qkv[0][2], qkv[0][2],
+                              qkv[0][3])
+    assert fa._bwd_vmem_bytes(qkv[0][1], qkv[0][3]) <= fa.VMEM_DEFAULT_BYTES
 
     def loss(q, k, v):
         return fa.flash_attention(q, k, v, causal=True).astype(F32).sum()
 
-    _compile(lambda q, k, v: fa.flash_attention(q, k, v, causal=True),
-             one_chip, qkv, qkv, qkv)
+    text = _compile(lambda q, k, v: fa.flash_attention(q, k, v, causal=True),
+                    one_chip, qkv, qkv, qkv)
+    assert text.count("tpu_custom_call") == 1
+    assert "flash_attention_fwd" in text
     text = _compile(jax.grad(loss, argnums=(0, 1, 2)), one_chip,
                     qkv, qkv, qkv)
-    assert text.count("tpu_custom_call") >= 3
+    assert text.count("tpu_custom_call") == 2
+    assert "flash_attention_fwd" in text and "flash_attention_bwd" in text
+    assert "flash_mha_bwd" not in text
+
+
+@pytest.mark.parametrize("seq,d", [
+    # dq's accumulator 8 MiB: the first size past Mosaic's default limit
+    # (16.3 MiB asked of 16 at 1024-row blocks), so the limit is raised
+    (16384, 128),
+    # the longest sequences the backward admits: 84 MiB of dq under the
+    # 96 MiB it may ask for
+    (172032, 128), (86016, 256), (43008, 512)])
+def test_flash_bwd_at_the_longest_sequences(for_chip, one_chip, seq, d):
+    """The one-pass backward's VMEM grows with the sequence (dq's
+    whole-sequence accumulator): its limit is raised by arithmetic, up to
+    the bound `_bwd_refusal` holds callers to, and one block of rows past
+    that bound differentiation raises before anything is compiled."""
+    fa = _mod("flash_attention")
+    assert fa._bwd_vmem_bytes(seq, d) > fa.VMEM_DEFAULT_BYTES
+    assert fa._bwd_refusal(seq, d) is None
+
+    def loss(q, k, v):
+        return fa._flash_core(q, k, v, True, 0.088).astype(F32).sum()
+
+    qkv = ((1, seq, d), BF)
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)), one_chip,
+                    qkv, qkv, qkv)
+    assert "flash_attention_bwd" in text
+    if fa._bwd_vmem_bytes(seq, d) == fa.VMEM_MAX_BYTES:
+        past = jax.ShapeDtypeStruct((1, seq + 1024, d), BF)
+        with pytest.raises(ValueError, match="keeps dq for all"):
+            jax.eval_shape(jax.grad(loss, argnums=(0, 1, 2)),
+                           past, past, past)
 
 
 @pytest.mark.parametrize("rows", [4096, 32768])
