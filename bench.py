@@ -29,9 +29,7 @@ OP_INFO = {}
 def _count_step_kernels(step_fn, *args):
     """Kernel-launch count of ONE decode step: pallas_call + dot_general
     equations in its jaxpr, sub-jaxprs included (the number TPU105
-    budgets and the decode megakernel exists to collapse). Recorded in
-    OPBENCH `info` so the megakernel row's win is attributable to fewer
-    launches, not a faster attention kernel. THE walker lives in
+    budgets). Recorded in OPBENCH `info`. THE walker lives in
     `analysis/roofline.py` (ISSUE 13) — one inventory shared by this
     counter, TPU105's fusion budget, and the roofline launch-overhead
     term."""
@@ -300,30 +298,14 @@ def _op_bench(only=None):
             paired_slope_ms(drun, 2, 194, pairs=8), 4)
         del dp, dkcs, dvcs
 
-    if want("decode_step_1b_megakernel", "decode_step_1b_paged_ref",
-            "decode_step_1b_megakernel_full", "decode_step_1b_layerscan"):
-        # the decode megakernel ladder under the gate (ISSUES 6 + 20):
-        # one full 1B int8-weight decode step over PAGED bf16 pools at
-        # each fusion rung — 'attn' (decode_step_1b_megakernel, one
-        # Pallas call per layer's attention block), 'full'
-        # (decode_step_1b_megakernel_full, the MLP half fuses in too),
-        # 'scan' (decode_step_1b_layerscan, ONE call walks every layer
-        # over stacked weights + a layer-major stacked pool) — next to
-        # the informational `decode_step_1b_paged_ref` row, the
-        # IDENTICAL paged program through the multi-kernel path, so the
-        # per-phase split (kernel time vs inter-kernel dispatch + HBM
-        # round-trips) is attributable. Every row records its
-        # kernels_per_step (pallas_call + dot_general launches per
-        # decode step) in OPBENCH's `info`, and the two new rungs land
-        # their static-auditor twins (predicted_step_ms /
-        # predicted_peak_hbm_bytes) so the next TPU run scores the
-        # rooflines that justified the fusion. Target (ROADMAP): the
-        # fused rows at <= 0.5x the decode_step_1b_int8 best.
+    if want("decode_step_1b_paged_ref"):
+        # informational: one full 1B int8-weight decode step over PAGED
+        # bf16 pools, with its kernels_per_step (pallas_call +
+        # dot_general launches per decode step) in OPBENCH's `info`
         from paddle_tpu.models import (LlamaConfig,
                                        init_quant_serving_params)
-        from paddle_tpu.models.llama import (
-            _make_decode_step, _make_decode_step_megakernel,
-            make_paged_kv_helpers, stack_decode_layer_params)
+        from paddle_tpu.models.llama import (_make_decode_step,
+                                             make_paged_kv_helpers)
         from paddle_tpu.kernels.decode_attention import (
             paged_decode_attention)
         from bench_util import paired_slope_ms
@@ -332,96 +314,56 @@ def _op_bench(only=None):
         gp = init_quant_serving_params(gcfg, "weight_only_int8", seed=0)
         np.asarray(jax.tree.leaves(gp)[-1])
         gl = gcfg.num_hidden_layers
-        gp_stacked = stack_decode_layer_params(dict(gp), gl)
         MB, MBS, MW = 4, 64, 8              # 4 rows x 8 pages (512 ctx)
         mnkv, mdh = gcfg.num_key_value_heads, gcfg.head_dim
         m_pages = MB * MW + 1
         mtables = jnp.asarray(
             np.arange(MB * MW).reshape(MB, MW) + 1, jnp.int32)
 
-        def paged_pools(mode=None):
-            if mode == "scan":
-                # layer-major stacked pool: layer i owns page rows
-                # [i*m_pages, (i+1)*m_pages); tables keep per-layer ids
-                return [jnp.zeros((m_pages * gl, mnkv, MBS, mdh),
-                                  jnp.bfloat16)]
+        def paged_pools():
             return [jnp.zeros((m_pages, mnkv, MBS, mdh), jnp.bfloat16)
                     for _ in range(gl)]
 
-        def make_step(mode):
-            if mode is not None:
-                return _make_decode_step_megakernel(gcfg, MB, mtables,
-                                                    mode=mode)
-            _, kv_write = make_paged_kv_helpers(MB, 0, mnkv, mdh, MBS,
-                                                mtables)
+        _, kv_write = make_paged_kv_helpers(MB, 0, mnkv, mdh, MBS,
+                                            mtables)
 
-            def kv_attend(q1, kc, vc, lens):
-                return paged_decode_attention(q1, kc, vc, mtables, lens)
+        def kv_attend(q1, kc, vc, lens):
+            return paged_decode_attention(q1, kc, vc, mtables, lens)
 
-            return _make_decode_step(gcfg, MB, kv_write=kv_write,
-                                     kv_attend=kv_attend)
+        step = _make_decode_step(gcfg, MB, kv_write=kv_write,
+                                 kv_attend=kv_attend)
 
-        def make_loop(step):
-            def run(p, kcs, vcs, tok0, lens0, n):
-                def body(i, carry):
-                    tok, lens, kcs_, vcs_ = carry
-                    logits, kcs_, vcs_ = step(p, kcs_, vcs_,
-                                              tok[:, None], lens)
-                    return (jnp.argmax(logits, -1).astype(tok.dtype),
-                            lens + 1, kcs_, vcs_)
+        def mloop(p, kcs, vcs, tok0, lens0, n):
+            def body(i, carry):
+                tok, lens, kcs_, vcs_ = carry
+                logits, kcs_, vcs_ = step(p, kcs_, vcs_,
+                                          tok[:, None], lens)
+                return (jnp.argmax(logits, -1).astype(tok.dtype),
+                        lens + 1, kcs_, vcs_)
 
-                tok, lens, _, _ = jax.lax.fori_loop(
-                    0, n, body, (tok0, lens0, kcs, vcs))
-                return jnp.sum(tok) + jnp.sum(lens)
+            tok, lens, _, _ = jax.lax.fori_loop(
+                0, n, body, (tok0, lens0, kcs, vcs))
+            return jnp.sum(tok) + jnp.sum(lens)
 
-            return jax.jit(run)
-
+        mloop = jax.jit(mloop)
         mtok = jnp.ones((MB,), jnp.int32)
         mlens = jnp.full((MB,), 128, jnp.int32)
-        for name, mode in (("decode_step_1b_megakernel", "attn"),
-                           ("decode_step_1b_paged_ref", None),
-                           ("decode_step_1b_megakernel_full", "full"),
-                           ("decode_step_1b_layerscan", "scan")):
-            if not want(name):
-                continue
-            params = gp_stacked if mode == "scan" else gp
-            step = make_step(mode)
-            loop = make_loop(step)
-            kcs, vcs = paged_pools(mode), paged_pools(mode)
+        kcs, vcs = paged_pools(), paged_pools()
 
-            def mrun(n, loop=loop, kcs=kcs, vcs=vcs, params=params):
-                return float(loop(params, kcs, vcs, mtok, mlens,
-                                  jnp.asarray(n, jnp.int32)))
+        def mrun(n):
+            return float(mloop(gp, kcs, vcs, mtok, mlens,
+                               jnp.asarray(n, jnp.int32)))
 
-            mrun(2); mrun(194)  # warm (trip count traced: one compile)
-            ops[name] = round(paired_slope_ms(mrun, 2, 194, pairs=8), 4)
-            OP_INFO[name] = {
-                "kernels_per_step": _count_step_kernels(
-                    step, params, paged_pools(mode), paged_pools(mode),
-                    mtok[:, None], mlens),
-                "pages_per_seq": MW,
-            }
-            if mode in ("full", "scan"):
-                # static-auditor twins (ISSUES 10 + 13) for the new
-                # rungs: predicted step roofline + per-chip liveness
-                # peak of the SAME step the slope times
-                from paddle_tpu.analysis.memory import audit_memory
-                from paddle_tpu.analysis.roofline import audit_roofline
-
-                roof = audit_roofline(
-                    step, params, paged_pools(mode), paged_pools(mode),
-                    mtok[:, None], mlens)
-                OP_INFO[name].update({
-                    "predicted_step_ms": round(roof.predicted_step_ms,
-                                               4),
-                    "predicted_mfu": roof.predicted_mfu,
-                    "predicted_bound": roof.bound,
-                    "predicted_peak_hbm_bytes": int(audit_memory(
-                        step, params, paged_pools(mode),
-                        paged_pools(mode), mtok[:, None],
-                        mlens).peak_bytes),
-                })
-        del gp, gp_stacked
+        mrun(2); mrun(194)  # warm (trip count traced: one compile)
+        ops["decode_step_1b_paged_ref"] = round(
+            paired_slope_ms(mrun, 2, 194, pairs=8), 4)
+        OP_INFO["decode_step_1b_paged_ref"] = {
+            "kernels_per_step": _count_step_kernels(
+                step, gp, paged_pools(), paged_pools(), mtok[:, None],
+                mlens),
+            "pages_per_seq": MW,
+        }
+        del gp, kcs, vcs
 
     def _serving_chunk_harness(serving_mp=1, quantized_collectives=False,
                                compile_run=True):
@@ -451,11 +393,6 @@ def _op_bench(only=None):
             scfg, sp, slots=8, prompt_bucket=128, max_prompt_len=128,
             max_new_tokens=64, block_size=64, steps_per_sync=16,
             prefill_batch=1, prefix_cache=False, serving_mp=serving_mp,
-            # pinned: the decode_step_1b_mp gather-bytes formula below
-            # describes the multi-kernel path's bf16 o-proj all-gather;
-            # the megakernel TP path's collective is an f32 psum at
-            # full hidden width (its own row when the default flips)
-            decode_megakernel=False,
             quantized_collectives=quantized_collectives)
         stables = jnp.full((eng.slots, eng.table_width), eng.scratch_page,
                            jnp.int32)

@@ -230,7 +230,7 @@ def run_engine(cfg, p, arrivals, prompts, targets, *, policy="continuous",
                max_prompt_len=PROMPT_BUCKET, warm_buckets=None,
                warm_prefix_widths=None, prefix_kernel=True,
                prefill_batch=4, kv_cache_dtype=None, kv_pool_bytes=None,
-               megakernel=False, serving_mp=1, disaggregated=False,
+               serving_mp=1, disaggregated=False,
                quantized_collectives=None,
                unified=False, token_budget=None,
                speculative=None, spec_k=None,
@@ -258,7 +258,7 @@ def run_engine(cfg, p, arrivals, prompts, targets, *, policy="continuous",
             block_size=BLOCK, steps_per_sync=STEPS_PER_SYNC,
             prefill_batch=prefill_batch, prefix_cache=prefix_cache,
             double_buffer=double_buffer, kv_cache_dtype=kv_cache_dtype,
-            kv_pool_bytes=kv_pool_bytes, decode_megakernel=megakernel,
+            kv_pool_bytes=kv_pool_bytes,
             serving_mp=serving_mp, disaggregated=disaggregated,
             quantized_collectives=quantized_collectives,
             # policies are pinned explicitly: existing rows keep the
@@ -603,37 +603,11 @@ def main():
         warm_prefix_widths=[hit_width], prefill_batch=1,
         kv_cache_dtype="int8",
         kv_pool_bytes=rows[2]["kv_pool_bytes"] // 2))
-    # decode megakernel (ISSUE 6): the same trace with the per-layer
-    # decode step fused into one Pallas call per layer
-    # (FLAGS_decode_megakernel) — decode chunks dominate this trace, so
-    # the summary's tokens/s gain vs the +kernel row is the end-to-end
-    # fusion win, and token_match_rate guards that the fused path serves
-    # the same greedy tokens
-    rows.append(run_engine(
-        cfg, p, arrivals, prompts, targets,
-        policy="continuous+prefix+kernel+megakernel", prefix_cache=True,
-        prefix_kernel=True, max_prompt_len=mpl,
-        warm_buckets=[PROMPT_BUCKET, cold_bucket],
-        warm_prefix_widths=[hit_width], prefill_batch=1,
-        megakernel=True))
-    # layer-scanned megakernel (ISSUE 20): the deepest fusion rung on
-    # the same trace — ONE Pallas call walks every decoder layer over
-    # stacked weights and a layer-major stacked pool, so a decode step
-    # is the scan call + final rms + lm head regardless of depth. The
-    # summary's gain vs the attn-rung row is the inter-layer dispatch
-    # the scan removes; token_match_rate guards numerics end-to-end.
-    rows.append(run_engine(
-        cfg, p, arrivals, prompts, targets,
-        policy="continuous+prefix+kernel+layerscan", prefix_cache=True,
-        prefix_kernel=True, max_prompt_len=mpl,
-        warm_buckets=[PROMPT_BUCKET, cold_bucket],
-        warm_prefix_widths=[hit_width], prefill_batch=1,
-        megakernel="scan"))
     toks = [row.pop("_tokens", None) for row in rows]
     for row in rows:
         row["trace"] = "deep_prefix"
         print(json.dumps(row), flush=True)
-    cold, jnp_row, kern, int8kv, mega, lscan = rows
+    cold, jnp_row, kern, int8kv = rows
     print(json.dumps({
         "trace": "deep_prefix", "summary": True,
         "prefix_hit_rate": kern["prefix_hit_rate"],
@@ -655,18 +629,6 @@ def main():
         "int8kv_n_cacheable_pages": int8kv["n_cacheable_pages"],
         "bf16_n_cacheable_pages": kern["n_cacheable_pages"],
         "int8kv_token_match_rate": _token_match_rate(toks[2], toks[3]),
-        # decode megakernel vs the multi-kernel decode step, same trace:
-        # end-to-end throughput gain + greedy-token agreement
-        "megakernel_useful_tok_s_gain": round(
-            mega["useful_tok_s"] / max(kern["useful_tok_s"], 1e-9), 3),
-        "megakernel_token_match_rate": _token_match_rate(toks[2],
-                                                         toks[4]),
-        # layer-scanned rung vs the attn rung (ISSUE 20): what
-        # collapsing per-layer launches into one scan call buys
-        "layerscan_useful_tok_s_gain_vs_attn": round(
-            lscan["useful_tok_s"] / max(mega["useful_tok_s"], 1e-9), 3),
-        "layerscan_token_match_rate": _token_match_rate(toks[2],
-                                                        toks[5]),
     }), flush=True)
 
     # mixed trace (ISSUE 14): interleaved long prefills + steady

@@ -423,121 +423,6 @@ def check_kv_quant():
             if err > 5e-2 else None)
 
 
-def check_decode_megakernel():
-    """Fused per-layer decode step on silicon (ISSUE 6): the decode
-    megakernel vs the multi-kernel composed oracle at serving dims
-    (dh=128), over bf16 AND int8 pools — layer-output numerics, EXACT
-    bf16 page commits, the int8 monotone-scale commit within one
-    quantization step — plus fused-vs-unfused greedy token identity
-    through a dims-faithful 2-layer paged generate."""
-    import paddle_tpu as paddle
-    from paddle_tpu.kernels.decode_megakernel import (
-        decode_layer_megakernel)
-    from paddle_tpu.kernels.decode_attention import paged_decode_attention
-    from paddle_tpu.kernels.rms_norm import rms_norm
-    from paddle_tpu.kernels.rope import apply_rotary_emb
-    from paddle_tpu.models import quantize_kv_pages
-    from paddle_tpu.models.llama import (make_paged_kv_helpers,
-                                         make_paged_kv_q8_helpers)
-
-    rng = np.random.default_rng(9)
-    B, NH, NKV, DH, H, BS, W = 4, 8, 2, 128, 1024, 64, 4
-    max_pages = B * W + 1
-    dt = jnp.bfloat16
-    h = jnp.asarray(rng.normal(size=(B, 1, H)) * 0.5, dt)
-    w_in = jnp.asarray(rng.normal(size=(H,)) * 0.1 + 1.0, dt)
-    wq = jnp.asarray(rng.normal(size=(H, NH * DH)) * 0.05, dt)
-    wk = jnp.asarray(rng.normal(size=(H, NKV * DH)) * 0.05, dt)
-    wv = jnp.asarray(rng.normal(size=(H, NKV * DH)) * 0.05, dt)
-    wo = jnp.asarray(rng.normal(size=(NH * DH, H)) * 0.05, dt)
-    kc = jnp.asarray(rng.normal(size=(max_pages, NKV, BS, DH)), dt)
-    vc = jnp.asarray(rng.normal(size=(max_pages, NKV, BS, DH)), dt)
-    tables = jnp.asarray(
-        rng.permutation(max_pages - 1)[:B * W].reshape(B, W) + 1,
-        jnp.int32)
-    lens = jnp.asarray([3, BS * W - 1, 0, 100], jnp.int32)
-    base, eps = 10000.0, 1e-6
-
-    def ref_layer(h, kct, vct):
-        quant = isinstance(kct, tuple)
-        x = rms_norm(h, w_in, eps)
-        q = (x @ wq).reshape(B, 1, NH, DH)
-        k = (x @ wk).reshape(B, 1, NKV, DH)
-        v = (x @ wv).reshape(B, 1, NKV, DH)
-        q, k = apply_rotary_emb(q, k, position_ids=lens[:, None],
-                                base=base)
-        if quant:
-            _, kv_write = make_paged_kv_q8_helpers(B, 0, NKV, DH, BS,
-                                                   tables)
-            kct, vct = kv_write(kct, vct, k, v, lens)
-            ctx = paged_decode_attention(
-                q[:, 0], kct[0], vct[0], tables, lens,
-                k_scale=kct[1], v_scale=vct[1])
-        else:
-            _, kv_write = make_paged_kv_helpers(B, 0, NKV, DH, BS,
-                                                tables)
-            kct, vct = kv_write(kct, vct, k, v, lens)
-            ctx = paged_decode_attention(q[:, 0], kct, vct, tables, lens)
-        return h + (ctx.reshape(B, 1, NH * DH) @ wo), kct, vct
-
-    # bf16 pools: layer output to tolerance, page commits EXACT
-    hm, kcm, vcm = jax.jit(lambda a: decode_layer_megakernel(
-        a, lens, tables, w_in, wq, wk, wv, wo, kc, vc,
-        rope_base=base, eps=eps))(h)
-    hr, kcr, vcr = jax.jit(lambda a: ref_layer(a, kc, vc))(h)
-    err = float(jnp.max(jnp.abs(hm.astype(jnp.float32)
-                                - hr.astype(jnp.float32))))
-    if err > 5e-2:
-        return f"megakernel bf16 layer max err {err:.4f} > 5e-2"
-    if not bool((kcm == kcr).all() & (vcm == vcr).all()):
-        return "megakernel bf16 page commit differs from kv_write"
-
-    # int8 pools: the in-kernel monotone-scale commit within one
-    # quantization step of the q8 helpers, scales tight
-    kq, ks = quantize_kv_pages(kc)
-    vq, vs = quantize_kv_pages(vc)
-    hm8, kctm, vctm = jax.jit(lambda a: decode_layer_megakernel(
-        a, lens, tables, w_in, wq, wk, wv, wo, kq, vq,
-        rope_base=base, eps=eps, k_scale=ks, v_scale=vs))(h)
-    hr8, kctr, vctr = jax.jit(lambda a: ref_layer(a, (kq, ks),
-                                                  (vq, vs)))(h)
-    err = float(jnp.max(jnp.abs(hm8.astype(jnp.float32)
-                                - hr8.astype(jnp.float32))))
-    if err > 1e-1:
-        return f"megakernel int8 layer max err {err:.4f} > 1e-1"
-    dint = int(jnp.max(jnp.abs(kctm[0].astype(jnp.int32)
-                               - kctr[0].astype(jnp.int32))))
-    dsc = float(jnp.max(jnp.abs(kctm[1] - kctr[1])))
-    if dint > 1 or dsc > 1e-5:
-        return (f"megakernel int8 commit drift: pool {dint} ints, "
-                f"scale {dsc:.2e}")
-
-    # fused vs unfused greedy token identity, dims-faithful (dh=128)
-    from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
-
-    paddle.seed(11)
-    cfg = LlamaConfig(vocab_size=512, hidden_size=512,
-                      intermediate_size=1024, num_hidden_layers=2,
-                      num_attention_heads=4, num_key_value_heads=2,
-                      max_position_embeddings=128, dtype="bfloat16")
-    model = LlamaForCausalLM(cfg)
-    x = paddle.to_tensor(
-        np.random.default_rng(12).integers(1, cfg.vocab_size, (2, 9)))
-    off = model.jit_generate(x, max_new_tokens=6, cache_layout="paged",
-                             kv_block_size=64).numpy()
-    prev = paddle.get_flags("decode_megakernel")["FLAGS_decode_megakernel"]
-    paddle.set_flags({"decode_megakernel": True})
-    try:
-        on = model.jit_generate(x, max_new_tokens=6,
-                                cache_layout="paged",
-                                kv_block_size=64).numpy()
-    finally:
-        paddle.set_flags({"decode_megakernel": prev})
-    if not (off == on).all():
-        return "fused vs unfused paged generate tokens differ on chip"
-    return None
-
-
 def check_int4_matmul():
     from paddle_tpu.kernels.int4_matmul import _xla_fallback, int4_matmul
 
@@ -607,7 +492,6 @@ DEFAULT_CHECKS = [
 # not by itself fail the run; wrong numbers do
 OPTIONAL_CHECKS = [
     ("kv_quant (int8 KV)", check_kv_quant),
-    ("decode_megakernel", check_decode_megakernel),
     ("int4_matmul", check_int4_matmul),
 ]
 
@@ -808,7 +692,6 @@ def serve_phase(cfg, prompts, shared: int, *, seed: int,
         f"{tm['run_s']:.2f}s; compiles after warm(): 0")
     say(f"serve: step={'unified' if m['unified_step'] else 'split'} "
         f"token_budget={m['token_budget']} "
-        f"megakernel_rung={m['megakernel_rung']} "
         f"kv_dtype={m['kv_cache_dtype']} mp={m['serving_mp']} "
         f"prefix_hit_tokens={m['prefix_hit_tokens']}/{m['prompt_tokens']} "
         f"prefill_chunks={m['prefill_chunks']} "

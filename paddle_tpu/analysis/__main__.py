@@ -46,7 +46,7 @@ the bundled tiny-llama PAGED DECODE program (same demo as ``--memory``
 
 ``--tune`` runs the auditor-driven static autotuner (`analysis/
 tuner.py`) over the bundled tiny-llama serving demo: enumerate the
-engine config space (block size, kv dtype, megakernel, unified step,
+engine config space (block size, kv dtype, unified step,
 quantized collectives, token budget), prune over-HBM candidates
 against a demo budget chosen to exercise BOTH feasibility gates
 (static params+pool bound before tracing, traced liveness peak

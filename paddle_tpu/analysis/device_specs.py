@@ -14,9 +14,9 @@ The numbers are the public per-chip figures (dense matmul peak; HBM
 bytes/s; aggregate ICI bytes/s), deliberately round — the pass that
 consumes them predicts bound CLASSES and ~10-15% step-time envelopes,
 not microseconds. ``launch_overhead_s`` is the fixed per-kernel issue
-cost the MPK megakernel case is built on (sub-microsecond dispatch on
-TPU; the OPBENCH ``kernels_per_step`` counter measures how many a step
-pays). The explicit ``cpu-container`` row exists so audits CAN price
+cost (sub-microsecond dispatch on TPU; the OPBENCH
+``kernels_per_step`` counter measures how many a step pays). The
+explicit ``cpu-container`` row exists so audits CAN price
 the CI container itself; it is never auto-selected — on a non-TPU host
 `get_spec()` defaults to the repo's baseline serving chip (v5e),
 because pre-silicon prediction for the TARGET device is the point of

@@ -2,8 +2,8 @@
 analysis (ISSUE 10).
 
 The serving stack compiles dozens of jitted programs per engine
-(prefill buckets x prefix-width rungs x kv dtype x mp x megakernel
-flag), each threading donated multi-GB paged pools — and the only OOM
+(prefill buckets x prefix-width rungs x kv dtype x mp), each
+threading donated multi-GB paged pools — and the only OOM
 signal at runtime is the device crashing. This pass bounds peak HBM
 *statically*, from the IR, before a program ever touches silicon
 ("Operator Fusion in XLA: Analysis and Evaluation", PAPERS.md: buffer

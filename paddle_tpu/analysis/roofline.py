@@ -7,8 +7,7 @@ prices COMPUTE TIME — every equation gets FLOPs and HBM traffic, and a
 program gets a predicted step time, a bound class, and an MFU, against
 the `analysis/device_specs.py` table ("Operator Fusion in XLA"
 PAPERS.md does exactly this per-op intensity analysis to predict fusion
-wins; MPK's megakernel case rests on the launch-overhead term this pass
-counts statically).
+wins; the launch-overhead term is counted statically too).
 
 - **FLOPs**: `dot_general` / `conv_general_dilated` contraction math
   (2·B·M·N·K), reductions count input elements, elementwise ops count
@@ -45,15 +44,14 @@ Three rules ride the one (memoized per device row) pass:
          in-loop            whose intensity sits below the device's
                             ridge point for >= `min_amplified_ms` of
                             bandwidth time — the fusion-candidate
-                            feeder (megakernel / quantized streams).
+                            feeder (quantized streams).
   TPU902 padding-waste      WARNING: the program spends more than
                             `min_fraction` of its padded MXU FLOPs on
                             (8|16|32)x128 tile padding — quantifying
                             what TPU101 only flags per site.
   TPU903 launch-overhead-   WARNING: predicted launch overhead is
          bound              >= `max_fraction` of the predicted step —
-                            the static twin of TPU105 and the
-                            megakernel's justification.
+                            the static twin of TPU105.
 
 Use it three ways::
 
@@ -91,8 +89,7 @@ KERNEL_LAUNCH_PRIMS = frozenset({"pallas_call", "dot_general"})
 def count_kernel_launches(jaxpr) -> int:
     """Kernel-launch count of ONE execution of a jaxpr: pallas_call +
     dot_general equations, sub-jaxprs included, UN-amplified (a scan
-    body counts once — the per-step number the decode megakernel
-    exists to collapse). Kernel bodies are not separate launches, so
+    body counts once). Kernel bodies are not separate launches, so
     pallas_call params are never descended."""
     n = 0
     for eqn in jaxpr.eqns:
@@ -862,9 +859,8 @@ class BandwidthBoundLoopRule(Rule):
     """TPU901: an equation in a hot loop whose arithmetic intensity
     sits below the device's ridge point while its AMPLIFIED bandwidth
     time exceeds the budget — memory-bound work executed over and over,
-    the direct feeder for fusion (the megakernel collapses exactly
-    these) and for quantized streams (half the bytes, double the
-    intensity).
+    the direct feeder for fusion and for quantized streams (half the
+    bytes, double the intensity).
 
     Config: `min_amplified_ms` (default 0.5 ms of amplified HBM time
     per program execution; 0 disables), `device` (spec row; default
@@ -904,9 +900,9 @@ class BandwidthBoundLoopRule(Rule):
                 f"{e.hbm_bytes} bytes{amp} = {bw_ms:.2f} ms of HBM "
                 "time per execution",
                 where=e.path,
-                hint="fuse it into its neighbours (serving decode: "
-                     "FLAGS_decode_megakernel), quantize the streamed "
-                     "bytes (int8 pools/weights), or batch wider to "
+                hint="fuse it into its neighbours, quantize the "
+                     "streamed bytes (int8 pools/weights), or batch "
+                     "wider to "
                      "raise intensity; raise TPU901.min_amplified_ms "
                      "if this stream is already at the roofline")
         if len(found) > self.MAX_REPORTS:
@@ -967,8 +963,7 @@ class LaunchOverheadBoundRule(Rule):
     """TPU903: predicted kernel-launch overhead is a dominant fraction
     of the predicted step time — the step is dispatch-bound, not
     compute- or bandwidth-bound. The static twin of TPU105 (which
-    counts distinct launches in loop bodies) and the quantitative
-    justification for the megakernel road (ROADMAP): fusing N launches
+    counts distinct launches in loop bodies): fusing N launches
     into one recovers ~(N-1) x launch_overhead per step.
 
     Config: `max_fraction` (default 0.25), `min_overhead_ms` (default
@@ -997,7 +992,6 @@ class LaunchOverheadBoundRule(Rule):
             f"{overhead_ms / step_ms * 100:.0f}% of the "
             f"{step_ms:.2f} ms predicted step on {rep.spec.name}",
             where=graph.name,
-            hint="fuse the step (serving decode: "
-                 "FLAGS_decode_megakernel collapses the per-layer "
-                 "attention block; the ROADMAP layer-scanned megakernel "
-                 "collapses the rest); TPU105 names the loop bodies")
+            hint="fuse neighbouring launches into one kernel, or give "
+                 "each launch more work (serving decode: more slots "
+                 "per engine); TPU105 names the loop bodies")

@@ -12,7 +12,7 @@ Rule catalog (see analysis/README.md for the long-form docs):
   TPU105 fusion-miss          a scan/while body lowering to more
                               distinct small-output Pallas/dot launches
                               than the fusion budget (dispatch-bound
-                              decode steps; FLAGS_decode_megakernel)
+                              decode steps)
   TPU201 recompile-risk       weak-typed python scalars baked into the
                               graph as literals (every new value retraces)
   TPU202 const-bloat          large arrays captured as compile-time
@@ -296,8 +296,7 @@ class FusionMissRule(Rule):
     decode_attention 0.21 ms inside a 1.9 ms decode step). Distinctness
     is by (primitive, operand/result shapes), so a 32-layer stack of
     identical layers counts its per-layer shapes once — the number this
-    rule reports is the per-iteration fusion-boundary count, which the
-    decode megakernel (FLAGS_decode_megakernel) exists to collapse.
+    rule reports is the per-iteration fusion-boundary count.
 
     Config: `max_kernels` (default 6) — the distinct-call budget;
     `small_bytes` (default 1 MiB) — calls whose every result is under
@@ -366,10 +365,9 @@ class FusionMissRule(Rule):
                 f"{max_kernels}-launch fusion budget: per-iteration "
                 "dispatch and HBM round-trips between tiny ops dominate",
                 where=first.path,
-                hint="fuse the step (serving decode: "
-                     "FLAGS_decode_megakernel=attn|full|scan serves the "
-                     "per-layer attention block, the whole layer, or "
-                     "every layer as one Pallas call)")
+                hint="fuse neighbouring small ops into one kernel, or "
+                     "give each launch more work (serving decode: more "
+                     "slots per engine)")
 
 
 # ---------------------------------------------------------------------------

@@ -10,7 +10,7 @@ lets them DECIDE instead of lint:
 - **search space**: the engine's build-time knobs — KV page size
   (`block_size`, candidates from the kernels' own `fit_vmem_block`
   rule via `models.llama.serving_block_size_candidates`),
-  `kv_cache_dtype` (bf16 | int8 pools), `decode_megakernel`,
+  `kv_cache_dtype` (bf16 | int8 pools),
   `unified_step` (split program zoo vs ONE ragged step),
   `token_budget` (unified prefill window), `serving_mp` (kv-head
   sharding degree; only degrees the host's device count and the
@@ -19,11 +19,7 @@ lets them DECIDE instead of lint:
   the default pool rounds itself — with cp*mp meshes the host cannot
   build pruned by name), and `quantized_collectives` (int8 wire;
   collapsed at mp=1 AND cp=1 — the cp merge ships quantized acc
-  partials, so the knob is live whenever either axis is). The
-  megakernel's
-  PAGES_PER_STEP is a kernel constant, not an engine kwarg — it is
-  recorded in the space metadata but not swept until the kernel takes
-  it as a parameter.
+  partials, so the knob is live whenever either axis is).
 - **feasibility gate** (memory.py + `device_specs.auto_hbm_budget`):
   a candidate is pruned BEFORE any trace when its static
   params + pool byte bound already exceeds the device row's budget,
@@ -77,11 +73,11 @@ __all__ = [
 # the engine build-time knobs the tuner sweeps — every one is a
 # ContinuousBatchingEngine kwarg of the same name, which is what makes
 # TunedConfig.apply() a plain dict merge
-KNOBS = ("block_size", "decode_megakernel", "kv_cache_dtype",
-         "quantized_collectives", "serving_cp", "serving_mp",
-         "spec_k", "speculative", "token_budget", "unified_step")
+KNOBS = ("block_size", "kv_cache_dtype", "quantized_collectives",
+         "serving_cp", "serving_mp", "spec_k", "speculative",
+         "token_budget", "unified_step")
 
-SCHEMA_VERSION = 1
+SCHEMA_VERSION = 2
 # the artifact the engine loads; lives next to the persistent compile
 # cache so the tuned knobs and the programs they compiled travel
 # together
@@ -136,8 +132,7 @@ def baseline_config(cfg, engine_kwargs: Optional[dict] = None) -> dict:
     build would resolve it (explicit kwargs win, then the FLAGS_*
     registry). Always enumerated, so `TuningReport.best` can never
     predict worse than what the operator would get by doing nothing."""
-    from ..models.llama import (resolve_decode_megakernel,
-                                resolve_kv_cache_dtype,
+    from ..models.llama import (resolve_kv_cache_dtype,
                                 resolve_serving_cp, resolve_serving_mp,
                                 resolve_unified_step)
     from ..parallel.collectives import resolve_quantized_collectives
@@ -148,8 +143,6 @@ def baseline_config(cfg, engine_kwargs: Optional[dict] = None) -> dict:
     speculative = resolve_speculative(kw.get("speculative"))
     config = {
         "block_size": geo["block_size"],
-        "decode_megakernel": resolve_decode_megakernel(
-            kw.get("decode_megakernel")),
         "kv_cache_dtype": resolve_kv_cache_dtype(
             kw.get("kv_cache_dtype")),
         "quantized_collectives": resolve_quantized_collectives(
@@ -174,28 +167,8 @@ def canonical_config(config: dict, geo: dict) -> dict:
     quantized acc partials even head-unsharded) and `token_budget` is
     meaningless on the split path (no unified window program is
     built), and `spec_k` is meaningless with speculation off (no
-    verify program is built — the window width collapses to 0). The
-    `decode_megakernel` rungs collapse to what the engine would
-    actually SERVE: full/scan fuse the MLP past the per-layer o-proj
-    psum seam, so at cp>1 (and a future int4 pool) they fall back —
-    enumerating them separately would score the same fallen-back
-    program under several names."""
-    from ..models.llama import resolve_decode_megakernel
-
+    verify program is built — the window width collapses to 0)."""
     out = dict(config)
-    out["decode_megakernel"] = resolve_decode_megakernel(
-        out.get("decode_megakernel", "off"))
-    if (out.get("serving_cp", 1) > 1 or out["serving_mp"] > 1) \
-            and out["decode_megakernel"] in ("full", "scan"):
-        # page-sharded attention needs the online-softmax partial
-        # merge, and tensor parallelism the o-proj psum, OUTSIDE any
-        # fused MLP half — the engine serves at most the attn rung on
-        # either axis, so deeper requests collapse to it
-        out["decode_megakernel"] = "attn"
-    if out.get("kv_cache_dtype") == "int4":
-        # no in-kernel nibble unpack yet (ROADMAP rung) — every
-        # megakernel rung refuses int4 pools
-        out["decode_megakernel"] = "off"
     if out["serving_mp"] == 1 and out.get("serving_cp", 1) == 1:
         out["quantized_collectives"] = False
     if not out["unified_step"]:
@@ -247,7 +220,6 @@ def default_space(cfg, engine_kwargs: Optional[dict] = None) -> dict:
     # draft depths; "off" collapses spec_k, so the product stays tight
     return {
         "block_size": blocks,
-        "decode_megakernel": ["off", "attn", "full", "scan"],
         "kv_cache_dtype": ["bf16", "int8"],
         "quantized_collectives": [False, True],
         "serving_cp": cps,
@@ -339,8 +311,7 @@ class CandidateResult:
         """Ascending-is-better, fully deterministic: predicted decode
         step time, then wire bytes per token, traced peak, and the
         canonical config string (ties between byte-identical programs
-        — e.g. an unsupported megakernel that fell back — resolve to
-        the same winner on every run)."""
+        resolve to the same winner on every run)."""
         return (self.predicted_step_ms or 0.0,
                 self.predicted_wire_bytes_per_token or 0.0,
                 self.peak_hbm_bytes or 0,
@@ -427,6 +398,9 @@ class TunedConfig:
         if self.schema_version != SCHEMA_VERSION:
             return (f"schema_version {self.schema_version} != "
                     f"{SCHEMA_VERSION}")
+        unknown = sorted(set(self.knobs) - set(KNOBS))
+        if unknown:
+            return f"knobs {unknown} are not engine knobs {KNOBS}"
         if cfg is not None and model_signature(cfg) != self.model:
             return (f"model signature {model_signature(cfg)!r} != "
                     f"tuned {self.model!r}")
@@ -567,10 +541,10 @@ def _score_candidate(cfg, params, config, engine_kwargs, spec, budget,
     kw = dict(engine_kwargs or {})
     kw.update(config)
     with warnings.catch_warnings():
-        # candidate builds legitimately warn (megakernel fallback on
-        # unsupported shapes, MQA mp fallback) — the tuner scores the
-        # program that would actually run, so the warnings are noise
-        # here; the build the operator ships still warns
+        # candidate builds legitimately warn (MQA mp fallback) — the
+        # tuner scores the program that would actually run, so the
+        # warnings are noise here; the build the operator ships still
+        # warns
         warnings.simplefilter("ignore")
         eng = ContinuousBatchingEngine(cfg, dict(params), **kw)
     progs = ["decode"] + (["unified"] if eng._unified is not None

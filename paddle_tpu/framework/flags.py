@@ -136,25 +136,6 @@ define_flag("kv_cache_dtype", "bf16",
             "(also: PADDLE_TPU_KV_CACHE_DTYPE)",
             env_aliases=("PADDLE_TPU_KV_CACHE_DTYPE",))
 
-define_flag("decode_megakernel", "off",
-            "fusion rung of the paged decode step "
-            "(kernels/decode_megakernel.py), a ladder: 'off' (default) "
-            "= the multi-kernel oracle path; 'attn' = rms + QKV + "
-            "rotary + paged attention + in-kernel KV commit + o-proj "
-            "in ONE Pallas call per layer; 'full' = 'attn' plus the "
-            "MLP half (post-attention rms + gate/up + silu*mul + down "
-            "+ residual) fused into the same per-layer call; 'scan' = "
-            "the whole decode step as ONE Pallas call whose outermost "
-            "grid axis walks every layer over stacked weights and "
-            "stacked K/V pools. Legacy booleans map onto the ladder "
-            "(False/'0' -> off, True/'1' -> attn). Unsupported shapes "
-            "fall back one rung at a time with a build-time warning. "
-            "Read when a paged program / engine is BUILT (the rung "
-            "joins every program key), so flip it before constructing "
-            "(or warming) an engine "
-            "(also: PADDLE_TPU_DECODE_MEGAKERNEL)",
-            env_aliases=("PADDLE_TPU_DECODE_MEGAKERNEL",))
-
 define_flag("unified_step", "auto",
             "serve mixed prefill+decode traffic through the UNIFIED "
             "ragged step (ISSUE 14): the engine's program zoo (cold + "
@@ -206,9 +187,8 @@ define_flag("quantized_collectives", False,
             "ship the hot cross-chip payloads as absmax-scaled int8 "
             "with an f32 scale sidecar (parallel/collectives.py, "
             "EQuARX-style — the int8 KV pools' proven scheme): the "
-            "per-layer o-proj activation all-gather at serving_mp > 1 "
-            "(and the megakernel path's partial-sum psum), and the dp "
-            "gradient psum in Model.fit (reduce-scatter on int8 "
+            "per-layer o-proj activation all-gather at serving_mp > 1, "
+            "and the dp gradient psum in Model.fit (reduce-scatter on int8 "
             "shards + f32 dequant-accumulate + all-gather). ~0.5x the "
             "bf16 wire bytes, ~0.25x f32. Off (default) = every wire "
             "byte-identical to today. Read at program-BUILD time like "
@@ -271,8 +251,8 @@ define_flag("tuned_config", "",
             "(analysis/tuner.py, .paddle_tpu_tune.json; a directory "
             "means <dir>/.paddle_tpu_tune.json): non-empty makes "
             "ContinuousBatchingEngine default its build-time knobs "
-            "(kv_cache_dtype, decode_megakernel, unified_step, "
-            "serving_mp, quantized_collectives, token_budget, "
+            "(kv_cache_dtype, unified_step, serving_mp, "
+            "quantized_collectives, token_budget, "
             "block_size) from the autotuner's winner; explicit "
             "engine kwargs still win per knob. A stale artifact "
             "(schema/model mismatch) is ignored with a warning. "

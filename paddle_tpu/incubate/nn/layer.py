@@ -4,7 +4,7 @@ fused_dropout_add}.py).
 
 TPU-native form: "fused" here means one traced region XLA compiles into
 fused kernels — packed qkv projection, pre/post-norm residual blocks —
-rather than hand-written CUDA megakernels. Parameter layout follows the
+rather than hand-written fused CUDA kernels. Parameter layout follows the
 reference (qkv_weight [3, num_heads, head_dim, embed_dim]) so state_dicts
 line up. Dropout placement follows the reference: attention-probability
 dropout (attn_dropout_rate), branch dropout before the residual add

@@ -21,21 +21,19 @@ from .constraints import KernelConstraint, LANE, register_constraint
 
 # the rotate-half contract every rope consumer shares: head_dim splits
 # into two PAIRED halves of HALF_PAIR * (dh // 2) lanes each — an odd
-# head_dim cannot be rotated (the decode megakernel's fused in-kernel
-# rotary gates on this too, kernels/decode_megakernel.py)
+# head_dim cannot be rotated
 HALF_PAIR = 2
 
 # Registered so the kernels/ TPU102 inventory covers every module: rope
 # itself is pure jnp (XLA fuses the rotate+multiply; no pallas_call
 # exists to lint), so `kernel_fns` is empty and the entry documents the
-# layout contract the fused consumers (decode_megakernel) enforce.
+# layout contract.
 CONSTRAINT = register_constraint(KernelConstraint(
     name="rope",
     kernel_fns=(),
     blocks={"half_pair": HALF_PAIR, "lane": LANE},
     note="rotary tables are [S, head_dim/2] (neox rotate-half pairs); "
-         "head_dim must be even, and lane-aligned head dims keep the "
-         "fused in-kernel application (decode megakernel) unpadded",
+         "head_dim must be even",
     source="rope.py",
 ))
 

@@ -10,7 +10,7 @@ from .llama import (  # noqa: F401
     LlamaConfig, LlamaForCausalLM, LlamaModel, LlamaPretrainingCriterion,
     PagedKVManager, build_paged_generate, build_quant_generate,
     hash_prefix_blocks, init_quant_serving_params, llama_sharding_rules,
-    quantize_kv_pages, resolve_decode_megakernel, resolve_kv_cache_dtype,
+    quantize_kv_pages, resolve_kv_cache_dtype,
     serving_block_size_candidates, shard_llama,
 )
 from .checkpoint import load_quant_serving_params  # noqa: F401

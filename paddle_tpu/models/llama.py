@@ -552,13 +552,11 @@ class LlamaForCausalLM(Layer):
                              "cache_layout='paged'")
         params = dict(self.raw_state())
         dec_params = self._decode_params(params, quant)
-        # the paged program bakes the pool dtype AND the megakernel
-        # choice in at build time, so both flags join the cache key
-        # (flipping either must not serve a stale compiled program)
+        # the paged program bakes the pool dtype in at build time, so
+        # the flag joins the cache key (flipping it must not serve a
+        # stale compiled program)
         kv_dtype = resolve_kv_cache_dtype() if cache_layout == "paged" \
             else None
-        megakernel = resolve_decode_megakernel() \
-            if cache_layout == "paged" else None
         serving_mp = resolve_serving_mp() if cache_layout == "paged" \
             else None
         if cache_layout == "paged":
@@ -570,7 +568,7 @@ class LlamaForCausalLM(Layer):
             qcoll = None
         sig = (b, sb, max_new_tokens, eos_token_id, do_sample, int(top_k),
                quant, prefill_with_quant, cache_layout, kv_block_size,
-               kv_dtype, megakernel, qcoll, serving_mp)
+               kv_dtype, qcoll, serving_mp)
         cache = getattr(self, "_jit_gen_cache", None)
         if cache is None:
             cache = self._jit_gen_cache = {}
@@ -764,80 +762,6 @@ def _make_head_logits(cfg):
     return head_logits
 
 
-# ---------------------------------------------------------------------------
-# stacked decode-layer parameters (FLAGS_decode_megakernel='scan'): a
-# build-time re-layout putting every per-layer weight on a leading layer
-# axis so the layer-scanned megakernel streams them per grid step
-# ---------------------------------------------------------------------------
-
-STACKED_PREFIX = "llama.layers.stacked."
-
-# the per-layer weights the scan megakernel streams — the re-layout
-# stacks exactly these (call order of decode_layers_megakernel)
-STACKED_LAYER_NAMES = (
-    "input_layernorm.weight",
-    "post_attention_layernorm.weight",
-    "self_attn.q_proj.weight",
-    "self_attn.k_proj.weight",
-    "self_attn.v_proj.weight",
-    "self_attn.o_proj.weight",
-    "mlp.gate_proj.weight",
-    "mlp.up_proj.weight",
-    "mlp.down_proj.weight",
-)
-
-
-def stack_decode_layer_params(p: dict, n_layers: int) -> dict:
-    """Re-layout a `_decode_params` dict for the layer-scanned
-    megakernel: every weight in `STACKED_LAYER_NAMES` moves from its
-    `llama.layers.{i}.` entry into ONE ``llama.layers.stacked.<name>``
-    entry stacked along a leading layer axis (quant pairs stack both
-    members), and the per-layer entries are DROPPED — each weight lives
-    in HBM exactly once. Runs once at engine build; every program reads
-    layer slices back through `_lw`, so the multi-kernel oracle, the
-    prefill/verify bodies and the scan kernel all serve the same dict."""
-    out = dict(p)
-    for name in STACKED_LAYER_NAMES:
-        per = [out.pop(f"llama.layers.{i}.{name}")
-               for i in range(n_layers)]
-        if isinstance(per[0], tuple):
-            out[STACKED_PREFIX + name] = (
-                jnp.stack([w[0] for w in per]),
-                jnp.stack([w[1] for w in per]))
-        else:
-            out[STACKED_PREFIX + name] = jnp.stack(per)
-    return out
-
-
-def _lw(p, i, name):
-    """Layer `i`'s weight `name` from a decode-params dict — the flat
-    per-layer entry, or (after `stack_decode_layer_params`) a slice of
-    the stacked entry. The slice is a trace-time gather XLA folds into
-    the consuming matmul; only the scan megakernel streams the stacked
-    array whole."""
-    w = p.get(f"llama.layers.{i}.{name}")
-    if w is not None:
-        return w
-    st = p[STACKED_PREFIX + name]
-    if isinstance(st, tuple):
-        return (st[0][i], st[1][i])
-    return st[i]
-
-
-def _layer_kv(kcs, vcs, i, n_layers):
-    """(kc_i, vc_i, page_off): layer `i`'s K/V pool entries. Per-layer
-    lists return entry i with offset 0; the scan re-layout's length-1
-    lists hold ONE layer-major stacked pool — layer i owns page rows
-    [i*pp, (i+1)*pp), so readers add `page_off` to their block-table
-    ids instead of slicing (a slice would copy the pool; the offset is
-    one broadcast add)."""
-    if len(kcs) == n_layers:
-        return kcs[i], vcs[i], 0
-    kc, vc = kcs[0], vcs[0]
-    pool = kc[0] if isinstance(kc, tuple) else kc
-    return kc, vc, i * (pool.shape[0] // n_layers)
-
-
 def _make_prefill(cfg, b, sb, tp=None):
     """Shared per-layer prefill over the `_decode_params` layout (dense
     OR quantized projections, via _mm): embed -> L x (rms/attn/mlp) ->
@@ -867,12 +791,13 @@ def _make_prefill(cfg, b, sb, tp=None):
         pos_ids = jnp.arange(sb)
         kvs = []
         for i in range(n_layers):
-            x = _k_rms(h, _lw(p, i, "input_layernorm.weight"), eps)
-            q = _mm(x, _lw(p, i, "self_attn.q_proj.weight")).reshape(
+            pre = f"llama.layers.{i}."
+            x = _k_rms(h, p[pre + "input_layernorm.weight"], eps)
+            q = _mm(x, p[pre + "self_attn.q_proj.weight"]).reshape(
                 b, sb, nh_l, dh)
-            k = _mm(x, _lw(p, i, "self_attn.k_proj.weight")).reshape(
+            k = _mm(x, p[pre + "self_attn.k_proj.weight"]).reshape(
                 b, sb, nkv_l, dh)
-            v = _mm(x, _lw(p, i, "self_attn.v_proj.weight")).reshape(
+            v = _mm(x, p[pre + "self_attn.v_proj.weight"]).reshape(
                 b, sb, nkv_l, dh)
             q, k = apply_rotary_emb(q, k, position_ids=pos_ids,
                                     base=cfg.rope_theta)
@@ -881,13 +806,12 @@ def _make_prefill(cfg, b, sb, tp=None):
             if tp is not None:
                 attn = tp.gather_heads(attn)           # [b, sb, nh, dh]
             h = h + _mm(attn.reshape(b, sb, nh * dh),
-                        _lw(p, i, "self_attn.o_proj.weight"))
-            x2 = _k_rms(h, _lw(p, i, "post_attention_layernorm.weight"),
-                        eps)
-            gate = _mm(x2, _lw(p, i, "mlp.gate_proj.weight"))
-            up = _mm(x2, _lw(p, i, "mlp.up_proj.weight"))
+                        p[pre + "self_attn.o_proj.weight"])
+            x2 = _k_rms(h, p[pre + "post_attention_layernorm.weight"], eps)
+            gate = _mm(x2, p[pre + "mlp.gate_proj.weight"])
+            up = _mm(x2, p[pre + "mlp.up_proj.weight"])
             h = h + _mm(jax.nn.silu(gate) * up,
-                        _lw(p, i, "mlp.down_proj.weight"))
+                        p[pre + "mlp.down_proj.weight"])
         h = _k_rms(h, p["llama.norm.weight"], eps)
         return h, kvs
 
@@ -962,22 +886,21 @@ def _make_prefill_with_prefix(cfg, b, sb, w_pre, block_size, tp=None):
         pos_ids = prefix_lens[:, None] + jnp.arange(sb)[None, :]  # [b, sb]
         kvs = []
         for i in range(n_layers):
-            x = _k_rms(h, _lw(p, i, "input_layernorm.weight"), eps)
-            q = _mm(x, _lw(p, i, "self_attn.q_proj.weight")).reshape(
+            pre = f"llama.layers.{i}."
+            x = _k_rms(h, p[pre + "input_layernorm.weight"], eps)
+            q = _mm(x, p[pre + "self_attn.q_proj.weight"]).reshape(
                 b, sb, nh_l, dh)
-            k = _mm(x, _lw(p, i, "self_attn.k_proj.weight")).reshape(
+            k = _mm(x, p[pre + "self_attn.k_proj.weight"]).reshape(
                 b, sb, nkv_l, dh)
-            v = _mm(x, _lw(p, i, "self_attn.v_proj.weight")).reshape(
+            v = _mm(x, p[pre + "self_attn.v_proj.weight"]).reshape(
                 b, sb, nkv_l, dh)
             q, k = apply_rotary_emb(q, k, position_ids=pos_ids,
                                     base=cfg.rope_theta)
             kvs.append((k, v))
-            kc_all, vc_all, poff = _layer_kv(kcs, vcs, i, n_layers)
-            kc_i, ksc_i = kc_all if isinstance(kc_all, tuple) \
-                else (kc_all, None)
-            vc_i, vsc_i = vc_all if isinstance(vc_all, tuple) \
-                else (vc_all, None)
-            ptbl = prefix_tables + poff if poff else prefix_tables
+            kc_i, ksc_i = kcs[i] if isinstance(kcs[i], tuple) \
+                else (kcs[i], None)
+            vc_i, vsc_i = vcs[i] if isinstance(vcs[i], tuple) \
+                else (vcs[i], None)
             if tp is not None and tp.cp > 1:
                 # context parallelism (ISSUE 18): prefix-phase partials
                 # over the LOCAL pool pages, merged cross-chip; the
@@ -987,7 +910,7 @@ def _make_prefill_with_prefix(cfg, b, sb, w_pre, block_size, tp=None):
                     causal_window_partials, combine_partials,
                     cp_local_view, finalize_partials, paged_partials)
 
-                loc, owned = cp_local_view(ptbl,
+                loc, owned = cp_local_view(prefix_tables,
                                            kc_i.shape[0], tp.cp_axis)
                 page = kc_i.shape[2]
                 pos_ok = jnp.arange(loc.shape[1] * page)[None, :] \
@@ -1005,7 +928,7 @@ def _make_prefill_with_prefix(cfg, b, sb, w_pre, block_size, tp=None):
                     prefix_prefill_attention
 
                 attn = prefix_prefill_attention(
-                    q, k, v, kc_i, vc_i, ptbl, prefix_lens,
+                    q, k, v, kc_i, vc_i, prefix_tables, prefix_lens,
                     suffix_lens, scale=scale, k_scale=ksc_i,
                     v_scale=vsc_i).astype(h.dtype)
             else:
@@ -1013,19 +936,18 @@ def _make_prefill_with_prefix(cfg, b, sb, w_pre, block_size, tp=None):
                     prefix_prefill_reference
 
                 attn = prefix_prefill_reference(
-                    q, k, v, kc_i, vc_i, ptbl, prefix_lens,
+                    q, k, v, kc_i, vc_i, prefix_tables, prefix_lens,
                     scale=scale, k_scale=ksc_i,
                     v_scale=vsc_i).astype(h.dtype)
             if tp is not None:
                 attn = tp.gather_heads(attn)
             h = h + _mm(attn.reshape(b, sb, nh * dh),
-                        _lw(p, i, "self_attn.o_proj.weight"))
-            x2 = _k_rms(h, _lw(p, i, "post_attention_layernorm.weight"),
-                        eps)
-            gate = _mm(x2, _lw(p, i, "mlp.gate_proj.weight"))
-            up = _mm(x2, _lw(p, i, "mlp.up_proj.weight"))
+                        p[pre + "self_attn.o_proj.weight"])
+            x2 = _k_rms(h, p[pre + "post_attention_layernorm.weight"], eps)
+            gate = _mm(x2, p[pre + "mlp.gate_proj.weight"])
+            up = _mm(x2, p[pre + "mlp.up_proj.weight"])
             h = h + _mm(jax.nn.silu(gate) * up,
-                        _lw(p, i, "mlp.down_proj.weight"))
+                        p[pre + "mlp.down_proj.weight"])
         h = _k_rms(h, p["llama.norm.weight"], eps)
         return h, kvs
 
@@ -1086,22 +1008,21 @@ def _make_chunk_prefill(cfg, tn, tp=None):
         pos_ids = cached_len[:, None] + jnp.arange(tn)[None, :]
         kvs = []
         for i in range(n_layers):
-            x = _k_rms(h, _lw(p, i, "input_layernorm.weight"), eps)
-            q = _mm(x, _lw(p, i, "self_attn.q_proj.weight")).reshape(
+            pre = f"llama.layers.{i}."
+            x = _k_rms(h, p[pre + "input_layernorm.weight"], eps)
+            q = _mm(x, p[pre + "self_attn.q_proj.weight"]).reshape(
                 1, tn, nh_l, dh)
-            k = _mm(x, _lw(p, i, "self_attn.k_proj.weight")).reshape(
+            k = _mm(x, p[pre + "self_attn.k_proj.weight"]).reshape(
                 1, tn, nkv_l, dh)
-            v = _mm(x, _lw(p, i, "self_attn.v_proj.weight")).reshape(
+            v = _mm(x, p[pre + "self_attn.v_proj.weight"]).reshape(
                 1, tn, nkv_l, dh)
             q, k = apply_rotary_emb(q, k, position_ids=pos_ids,
                                     base=cfg.rope_theta)
             kvs.append((k, v))
-            kc_all, vc_all, poff = _layer_kv(kcs, vcs, i, n_layers)
-            kc_i, ksc_i = kc_all if isinstance(kc_all, tuple) \
-                else (kc_all, None)
-            vc_i, vsc_i = vc_all if isinstance(vc_all, tuple) \
-                else (vc_all, None)
-            ctbl = chunk_table + poff if poff else chunk_table
+            kc_i, ksc_i = kcs[i] if isinstance(kcs[i], tuple) \
+                else (kcs[i], None)
+            vc_i, vsc_i = vcs[i] if isinstance(vcs[i], tuple) \
+                else (vcs[i], None)
             if tp is not None and tp.cp > 1:
                 # context parallelism (ISSUE 18): this shard holds only
                 # 1/cp of the pool pages — stream the LOCAL pages as
@@ -1112,7 +1033,7 @@ def _make_chunk_prefill(cfg, tn, tp=None):
                     causal_window_partials, combine_partials,
                     cp_local_view, finalize_partials, paged_partials)
 
-                loc, owned = cp_local_view(ctbl, kc_i.shape[0],
+                loc, owned = cp_local_view(chunk_table, kc_i.shape[0],
                                            tp.cp_axis)
                 page = kc_i.shape[2]
                 pos_ok = jnp.arange(loc.shape[1] * page)[None, :] \
@@ -1131,20 +1052,19 @@ def _make_chunk_prefill(cfg, tn, tp=None):
             else:
                 attn_fn = ragged_paged_attention if use_kernel \
                     else ragged_paged_attention_reference
-                attn = attn_fn(q, k, v, kc_i, vc_i, ctbl,
+                attn = attn_fn(q, k, v, kc_i, vc_i, chunk_table,
                                cached_len, new_len, scale=scale,
                                k_scale=ksc_i, v_scale=vsc_i
                                ).astype(h.dtype)
             if tp is not None:
                 attn = tp.gather_heads(attn)
             h = h + _mm(attn.reshape(1, tn, nh * dh),
-                        _lw(p, i, "self_attn.o_proj.weight"))
-            x2 = _k_rms(h, _lw(p, i, "post_attention_layernorm.weight"),
-                        eps)
-            gate = _mm(x2, _lw(p, i, "mlp.gate_proj.weight"))
-            up = _mm(x2, _lw(p, i, "mlp.up_proj.weight"))
+                        p[pre + "self_attn.o_proj.weight"])
+            x2 = _k_rms(h, p[pre + "post_attention_layernorm.weight"], eps)
+            gate = _mm(x2, p[pre + "mlp.gate_proj.weight"])
+            up = _mm(x2, p[pre + "mlp.up_proj.weight"])
             h = h + _mm(jax.nn.silu(gate) * up,
-                        _lw(p, i, "mlp.down_proj.weight"))
+                        p[pre + "mlp.down_proj.weight"])
         h = _k_rms(h, p["llama.norm.weight"], eps)
         return h, kvs
 
@@ -1202,37 +1122,35 @@ def _make_verify_window(cfg, b, w, tp=None):
         pos_ids = cached_lens[:, None] + jnp.arange(w)[None, :]
         kvs = []
         for i in range(n_layers):
-            x = _k_rms(h, _lw(p, i, "input_layernorm.weight"), eps)
-            q = _mm(x, _lw(p, i, "self_attn.q_proj.weight")).reshape(
+            pre = f"llama.layers.{i}."
+            x = _k_rms(h, p[pre + "input_layernorm.weight"], eps)
+            q = _mm(x, p[pre + "self_attn.q_proj.weight"]).reshape(
                 b, w, nh_l, dh)
-            k = _mm(x, _lw(p, i, "self_attn.k_proj.weight")).reshape(
+            k = _mm(x, p[pre + "self_attn.k_proj.weight"]).reshape(
                 b, w, nkv_l, dh)
-            v = _mm(x, _lw(p, i, "self_attn.v_proj.weight")).reshape(
+            v = _mm(x, p[pre + "self_attn.v_proj.weight"]).reshape(
                 b, w, nkv_l, dh)
             q, k = apply_rotary_emb(q, k, position_ids=pos_ids,
                                     base=cfg.rope_theta)
             kvs.append((k, v))
-            kc_all, vc_all, poff = _layer_kv(kcs, vcs, i, n_layers)
-            kc_i, ksc_i = kc_all if isinstance(kc_all, tuple) \
-                else (kc_all, None)
-            vc_i, vsc_i = vc_all if isinstance(vc_all, tuple) \
-                else (vc_all, None)
-            tbl = tables + poff if poff else tables
+            kc_i, ksc_i = kcs[i] if isinstance(kcs[i], tuple) \
+                else (kcs[i], None)
+            vc_i, vsc_i = vcs[i] if isinstance(vcs[i], tuple) \
+                else (vcs[i], None)
             attn_fn = ragged_paged_attention if use_kernel \
                 else ragged_paged_attention_reference
-            attn = attn_fn(q, k, v, kc_i, vc_i, tbl,
+            attn = attn_fn(q, k, v, kc_i, vc_i, tables,
                            cached_lens, new_lens, scale=scale,
                            k_scale=ksc_i, v_scale=vsc_i).astype(h.dtype)
             if tp is not None:
                 attn = tp.gather_heads(attn)
             h = h + _mm(attn.reshape(b, w, nh * dh),
-                        _lw(p, i, "self_attn.o_proj.weight"))
-            x2 = _k_rms(h, _lw(p, i, "post_attention_layernorm.weight"),
-                        eps)
-            gate = _mm(x2, _lw(p, i, "mlp.gate_proj.weight"))
-            up = _mm(x2, _lw(p, i, "mlp.up_proj.weight"))
+                        p[pre + "self_attn.o_proj.weight"])
+            x2 = _k_rms(h, p[pre + "post_attention_layernorm.weight"], eps)
+            gate = _mm(x2, p[pre + "mlp.gate_proj.weight"])
+            up = _mm(x2, p[pre + "mlp.up_proj.weight"])
             h = h + _mm(jax.nn.silu(gate) * up,
-                        _lw(p, i, "mlp.down_proj.weight"))
+                        p[pre + "mlp.down_proj.weight"])
         h = _k_rms(h, p["llama.norm.weight"], eps)
         return h, kvs
 
@@ -1335,43 +1253,6 @@ def resolve_kv_cache_dtype(kv_cache_dtype: Optional[str] = None) -> str:
             f"kv_cache_dtype must be one of {KV_CACHE_DTYPES}, got "
             f"{kv_cache_dtype!r}")
     return kv_cache_dtype
-
-
-MEGAKERNEL_MODES = ("off", "attn", "full", "scan")
-
-
-def resolve_decode_megakernel(decode_megakernel=None) -> str:
-    """Fusion rung of the paged decode step — 'off' | 'attn' | 'full' |
-    'scan' — from the argument or FLAGS_decode_megakernel /
-    PADDLE_TPU_DECODE_MEGAKERNEL. The historical boolean maps onto the
-    ladder (False -> 'off', True -> 'attn' — the rung the boolean used
-    to enable), so every pre-tri-state call site keeps its meaning.
-    Read at program-BUILD time (like FLAGS_prefix_prefill_kernel and
-    FLAGS_kv_cache_dtype): flip it before constructing or warming an
-    engine. Default OFF — the multi-kernel path is the oracle."""
-    if decode_megakernel is None:
-        from ..framework.flags import flag as _flag
-
-        decode_megakernel = _flag("decode_megakernel")
-    if isinstance(decode_megakernel, bool):
-        return "attn" if decode_megakernel else "off"
-    s = str(decode_megakernel).strip().lower()
-    if s in ("1", "true", "yes", "on"):
-        return "attn"
-    if s in ("0", "false", "no", ""):
-        return "off"
-    if s not in MEGAKERNEL_MODES:
-        raise ValueError(
-            f"decode_megakernel must be one of {MEGAKERNEL_MODES} (or a "
-            f"legacy boolean), got {decode_megakernel!r}")
-    return s
-
-
-def megakernel_rung_order(mode: str):
-    """The fallback ladder below (and including) `mode`, strongest
-    first: a refused rung steps DOWN one fusion level at a time —
-    scan -> full -> attn -> off — never sideways."""
-    return MEGAKERNEL_MODES[MEGAKERNEL_MODES.index(mode)::-1]
 
 
 def resolve_unified_step(unified_step=None) -> bool:
@@ -1582,24 +1463,6 @@ class ServingTP:
         return jax.lax.all_gather(ctx, self.axis, axis=ctx.ndim - 2,
                                   tiled=True)
 
-    def psum_partial(self, partial):
-        """Sum per-shard PARTIAL results over the mp axis — the
-        megakernel decode path's collective (the fused kernel emits the
-        f32 o-proj partial contraction instead of the pre-o-proj
-        activations; same wire bytes as the all-gather at f32). With
-        FLAGS_quantized_collectives the sum runs as the two-hop
-        quantized exchange (int8 reduce-scatter via all_to_all + f32
-        dequant-accumulate + int8 all-gather,
-        `parallel.collectives.quantized_psum`), composing the
-        megakernel with the quantized wire."""
-        if self.mp <= 1:
-            return partial
-        if self.quantized:
-            from ..parallel.collectives import quantized_psum
-
-            return quantized_psum(partial, self.axis)
-        return jax.lax.psum(partial, self.axis)
-
     def merge_attn_partials(self, m, l, acc):
         """Merge per-cp-shard online-softmax partials into the global
         attention state — the context-parallel seam next to
@@ -1646,12 +1509,12 @@ def make_serving_tp(cfg, serving_mp: Optional[int] = None,
         -> Optional[ServingTP]:
     """ServingTP geometry for the resolved (mp, cp) degrees, or None at
     mp=1 and cp=1 (the single-chip path takes no TP plumbing at all).
-    `quantized_collectives` (default: the flag) arms the int8
-    all-gather / psum wire (ISSUE 15); `serving_cp` (default: the
+    `quantized_collectives` (default: the flag) sends the head seam's
+    all-gather (`gather_heads`) and the cp merge's accumulator psum
+    (`merge_attn_partials`) as int8 (ISSUE 15); `serving_cp` (default: the
     flag) the page-sharded context-parallel geometry (ISSUE 18) —
-    at cp > 1 with mp == 1 the head seams (`gather_heads` /
-    `psum_partial`) are identity and only `merge_attn_partials`
-    crosses chips."""
+    at cp > 1 with mp == 1 the head seam (`gather_heads`) is identity
+    and only `merge_attn_partials` crosses chips."""
     mp = resolve_serving_mp(serving_mp)
     cp = resolve_serving_cp(serving_cp)
     if mp <= 1 and cp <= 1:
@@ -1672,19 +1535,11 @@ def _tp_weight_spec(name: str, w, tp: ServingTP):
     sharded = name.endswith("q_proj.weight") or (
         tp.kv_sharded and (name.endswith("k_proj.weight")
                            or name.endswith("v_proj.weight")))
-    # stacked decode-layer weights (scan rung) carry a leading layer
-    # axis the shard axis shifts past — same suffix naming, same
-    # head-geometry sharding per layer slice
-    stacked = name.startswith(STACKED_PREFIX)
     if isinstance(w, tuple):
         if sharded:
-            if stacked:
-                return (_P(None, tp.axis, None), _P(None, tp.axis))
             return (_P(tp.axis, None), _P(tp.axis))
         return tuple(_P(*([None] * getattr(a, "ndim", 0))) for a in w)
     if sharded:
-        if stacked:
-            return _P(None, None, tp.axis)
         return _P(None, tp.axis)
     return _P(*([None] * getattr(w, "ndim", 0)))
 
@@ -1712,324 +1567,6 @@ def shard_serving_params(params: dict, mesh, tp: ServingTP) -> dict:
         else:
             out[name] = jax.device_put(w, NamedSharding(mesh, sp))
     return out
-
-
-def _tp_slice_o_proj(w, tp: ServingTP, spec_only: bool = False):
-    """The LOCAL contraction slice of a (replicated) o-proj weight for
-    the fused megakernel path: the megakernel computes o-proj in-kernel,
-    so each shard multiplies its local attention heads against its own
-    contraction rows and the partial sums psum outside. Dense weights
-    [nh*dh, H] slice rows; nn.quant pairs (int8 [H, nh*dh], scale [H])
-    slice contraction COLUMNS with the per-output scale replicated.
-    `spec_only` returns ShapeDtypeStructs (megakernel_supported runs
-    shape-only, outside any traced axis context)."""
-    idx = None if spec_only else jax.lax.axis_index(tp.axis)
-    if isinstance(w, tuple):
-        wq, sc = w
-        k_local = wq.shape[1] // tp.mp
-        if spec_only:
-            return (jax.ShapeDtypeStruct((wq.shape[0], k_local),
-                                         wq.dtype), sc)
-        return (jax.lax.dynamic_slice_in_dim(wq, idx * k_local, k_local,
-                                             axis=1), sc)
-    k_local = w.shape[0] // tp.mp
-    if spec_only:
-        return jax.ShapeDtypeStruct((k_local, w.shape[1]), w.dtype)
-    return jax.lax.dynamic_slice_in_dim(w, idx * k_local, k_local, axis=0)
-
-
-def _tp_local_weight_spec(w, tp: ServingTP):
-    """Shard-local ShapeDtypeStruct of a column-sharded projection —
-    what the shard_map body will see of a GLOBAL weight (the engine's
-    build-time rung plan runs before shard_map exists)."""
-    if isinstance(w, tuple):
-        wq, sc = w
-        return (jax.ShapeDtypeStruct((wq.shape[0] // tp.mp,)
-                                     + wq.shape[1:], wq.dtype),
-                jax.ShapeDtypeStruct((sc.shape[0] // tp.mp,), sc.dtype))
-    return jax.ShapeDtypeStruct(w.shape[:-1] + (w.shape[-1] // tp.mp,),
-                                w.dtype)
-
-
-def _megakernel_rung_reason(rung, cfg, b, p, kcs, vcs, tables, tp=None,
-                            localize_tp=False) -> Optional[str]:
-    """None when fusion rung `rung` can serve this decode step's
-    operands (layer-0 weights stand in for every layer —
-    `_decode_params` quantizes them uniformly), else the reason this
-    rung steps DOWN the ladder. Pure shape logic, runnable under trace
-    and on ShapeDtypeStructs. Under ServingTP the check needs the
-    SHARD-LOCAL operands: at trace time (inside shard_map) they arrive
-    local already; the engine's BUILD-time plan passes global weights
-    with `localize_tp=True` and the q/k/v columns are viewed at their
-    local widths here."""
-    from ..kernels.decode_megakernel import (megakernel_full_supported,
-                                             megakernel_scan_supported,
-                                             megakernel_supported)
-
-    if rung == "off":
-        return None
-    if tp is not None and tp.cp > 1:
-        # the fused kernel normalizes in-epilogue — it has no
-        # partial-softmax (m, l, acc) emit for merge_attn_partials to
-        # consume, so context parallelism serves the multi-kernel path
-        return ("serving_cp > 1: the fused layer kernel cannot emit "
-                "online-softmax partials for the cross-chip cp merge")
-    if rung in ("full", "scan") and tp is not None:
-        return (f"serving_mp > 1: the {rung} rung fuses the MLP past "
-                "the per-layer o-proj psum, which must stay a "
-                "cross-chip collective between the fused halves")
-    kc0, vc0 = kcs[0], vcs[0]
-    ksc = vsc = None
-    if isinstance(kc0, tuple):
-        (kc0, ksc), (vc0, vsc) = kc0, vc0
-    H = cfg.hidden_size
-    h_spec = jax.ShapeDtypeStruct(
-        (b, 1, H), p["llama.embed_tokens.weight"].dtype)
-    if rung == "scan":
-        missing = [n for n in STACKED_LAYER_NAMES
-                   if STACKED_PREFIX + n not in p]
-        if missing:
-            return ("scan needs the stacked-parameter re-layout "
-                    "(stack_decode_layer_params — the serving engine "
-                    "builds it at engine build)")
-        return megakernel_scan_supported(
-            h_spec, *(p[STACKED_PREFIX + n] for n in STACKED_LAYER_NAMES),
-            kc0, vc0, tables, n_layers=cfg.num_hidden_layers,
-            k_scale=ksc, v_scale=vsc)
-    wq = _lw(p, 0, "self_attn.q_proj.weight")
-    wk = _lw(p, 0, "self_attn.k_proj.weight")
-    wv = _lw(p, 0, "self_attn.v_proj.weight")
-    if tp is not None and localize_tp:
-        wq = _tp_local_weight_spec(wq, tp)
-        if tp.kv_sharded:
-            wk = _tp_local_weight_spec(wk, tp)
-            wv = _tp_local_weight_spec(wv, tp)
-    wo = _lw(p, 0, "self_attn.o_proj.weight")
-    if tp is not None:
-        wo = _tp_slice_o_proj(wo, tp, spec_only=True)
-    if rung == "full":
-        return megakernel_full_supported(
-            h_spec, _lw(p, 0, "input_layernorm.weight"),
-            _lw(p, 0, "post_attention_layernorm.weight"),
-            wq, wk, wv, wo,
-            _lw(p, 0, "mlp.gate_proj.weight"),
-            _lw(p, 0, "mlp.up_proj.weight"),
-            _lw(p, 0, "mlp.down_proj.weight"),
-            kc0, vc0, tables, k_scale=ksc, v_scale=vsc)
-    return megakernel_supported(
-        h_spec, _lw(p, 0, "input_layernorm.weight"),
-        wq, wk, wv, wo, kc0, vc0, tables, k_scale=ksc, v_scale=vsc)
-
-
-def _megakernel_reason(cfg, b, p, kcs, vcs, tables, tp=None) \
-        -> Optional[str]:
-    """Back-compat shim: the attn rung's support reason
-    (`_megakernel_rung_reason('attn', ...)`)."""
-    return _megakernel_rung_reason("attn", cfg, b, p, kcs, vcs, tables,
-                                   tp=tp)
-
-
-def plan_megakernel_rung(mode, cfg, b, p, kcs, vcs, tables, tp=None,
-                         localize_tp=False):
-    """(served_rung, refusals) for a requested FLAGS_decode_megakernel
-    mode: walk the ladder strongest-first, stepping DOWN one fusion
-    level per refusal. `refusals` is [(rung, reason), ...] for every
-    rung that refused — the engine's once-per-build warning names each
-    (ISSUE 20 satellite). 'off' always serves (the multi-kernel
-    oracle)."""
-    refusals = []
-    for rung in megakernel_rung_order(mode):
-        reason = _megakernel_rung_reason(rung, cfg, b, p, kcs, vcs,
-                                         tables, tp=tp,
-                                         localize_tp=localize_tp)
-        if reason is None:
-            return rung, refusals
-        refusals.append((rung, reason))
-    return "off", refusals
-
-
-def _megakernel_or_fallback_step(cfg, b, tables, p, kcs, vcs, base,
-                                 tp=None, mode="attn", warn=True):
-    """The strongest supported fused decode step at or below `mode`,
-    else `base` (the multi-kernel oracle) — the ONE fallback seam both
-    `build_paged_generate` and the serving engine's decode-chunk
-    builder go through (single-chip AND ServingTP-sharded). Each
-    refused rung warns by NAME with its reason; `warn=False` callers
-    (the engine) already warned once at BUILD time from
-    `plan_megakernel_rung`, so the per-program traces stay silent."""
-    rung, refusals = plan_megakernel_rung(mode, cfg, b, p, kcs, vcs,
-                                          tables, tp=tp)
-    if warn and refusals:
-        import warnings
-
-        down = "the multi-kernel path" if rung == "off" \
-            else f"the '{rung}' rung"
-        for refused, reason in refusals:
-            warnings.warn(
-                f"decode_megakernel rung '{refused}' unsupported here "
-                f"({reason}); serving {down}", stacklevel=3)
-    if rung == "off":
-        return base
-    return _make_decode_step_megakernel(cfg, b, tables, tp=tp, mode=rung)
-
-
-def _make_decode_step_megakernel(cfg, b, tables, tp=None, mode="attn"):
-    """`_make_decode_step`'s paged twin with the decode step fused into
-    Pallas calls (kernels/decode_megakernel.py) at fusion rung `mode`:
-
-    - 'attn': the whole attention block — rms_norm, QKV projection,
-      rotary, paged-KV commit (int8 epilogue included), paged GQA
-      attention, o-proj + residual — ONE call per layer; the MLP half
-      and the lm head keep the shared `_mm`/`_k_rms` path.
-    - 'full': the MLP half (post-attn rms_norm, gate/up, silu·mul,
-      down projection, residual) fuses in too — still one call per
-      layer, but nothing between calls except the residual handoff.
-    - 'scan': ONE call for the whole decoder — the outermost grid axis
-      walks the layers over stacked weights (`stack_decode_layer_params`)
-      and a layer-major stacked pool; `kernels_per_step` collapses to
-      the megakernel + final rms + lm head.
-
-    Under ServingTP (attn rung only — the fused MLP would swallow the
-    psum seam) each shard runs the SAME fused kernel over its local
-    heads/pools with its local o-proj contraction slice and
-    `residual=False` — the kernel emits the o-proj PARTIAL sum, psum'd
-    over the mp axis before the residual add. With quantized
-    collectives at lane-aligned shapes the kernel quantizes the partial
-    IN-EPILOGUE (PR 18 packed-scale layout) and
-    `quantized_psum_prequant` puts it straight on the wire — the
-    partial never round-trips HBM as f32 (ISSUE 20 satellite)."""
-    from ..kernels.decode_megakernel import (decode_layer_megakernel,
-                                             decode_layer_megakernel_full,
-                                             decode_layers_megakernel)
-
-    n_layers = cfg.num_hidden_layers
-    eps = cfg.rms_norm_eps
-    H = cfg.hidden_size
-    head_logits = _make_head_logits(cfg)
-    # in-kernel quantize epilogue gate: bit-identity with
-    # `quantized_psum` on the f32 partial needs the flat [b*H] payload
-    # to split into whole 128-lane blocks per shard (no padding — the
-    # packed-scale layouts then coincide)
-    quantize_wire = (tp is not None and tp.mp > 1
-                     and bool(tp.quantized)
-                     and H % 128 == 0 and (b * H) % (tp.mp * 128) == 0)
-
-    def _embed_lens(p, tok, pos):
-        h = p["llama.embed_tokens.weight"][tok[:, 0]][:, None, :]
-        if getattr(pos, "ndim", 0) == 1:
-            lens = pos.astype(jnp.int32)
-        else:
-            lens = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (b,))
-        return h, lens
-
-    if mode == "scan":
-        def decode_step(p, kcs, vcs, tok, pos):
-            h, lens = _embed_lens(p, tok, pos)
-
-            def st(name):
-                return p[STACKED_PREFIX + name]
-
-            stacked = [st(n) for n in STACKED_LAYER_NAMES]
-            kc, vc = kcs[0], vcs[0]
-            kw = dict(n_layers=n_layers, rope_base=cfg.rope_theta,
-                      eps=eps)
-            if isinstance(kc, tuple):
-                (kcp, ksc), (vcp, vsc) = kc, vc
-                h_out, kc_new, vc_new = decode_layers_megakernel(
-                    h, lens, tables, *stacked, kcp, vcp,
-                    k_scale=ksc, v_scale=vsc, **kw)
-            else:
-                h_out, kc_new, vc_new = decode_layers_megakernel(
-                    h, lens, tables, *stacked, kc, vc, **kw)
-            h = _k_rms(h_out, p["llama.norm.weight"], eps)
-            return head_logits(h, p)[:, -1], [kc_new], [vc_new]
-
-        return decode_step
-
-    if mode == "full":
-        def decode_step(p, kcs, vcs, tok, pos):
-            h, lens = _embed_lens(p, tok, pos)
-            new_kcs, new_vcs = [], []
-            for i in range(n_layers):
-                kc, vc = kcs[i], vcs[i]
-                layer = [
-                    _lw(p, i, n) for n in STACKED_LAYER_NAMES]
-                kw = dict(rope_base=cfg.rope_theta, eps=eps)
-                if isinstance(kc, tuple):
-                    (kcp, ksc), (vcp, vsc) = kc, vc
-                    h, kc_new, vc_new = decode_layer_megakernel_full(
-                        h, lens, tables, *layer, kcp, vcp,
-                        k_scale=ksc, v_scale=vsc, **kw)
-                else:
-                    h, kc_new, vc_new = decode_layer_megakernel_full(
-                        h, lens, tables, *layer, kc, vc, **kw)
-                new_kcs.append(kc_new)
-                new_vcs.append(vc_new)
-            h = _k_rms(h, p["llama.norm.weight"], eps)
-            return head_logits(h, p)[:, -1], new_kcs, new_vcs
-
-        return decode_step
-
-    def decode_step(p, kcs, vcs, tok, pos):
-        h, lens = _embed_lens(p, tok, pos)
-        new_kcs, new_vcs = [], []
-        for i in range(n_layers):
-            kc, vc = kcs[i], vcs[i]
-            wo = _lw(p, i, "self_attn.o_proj.weight")
-            if tp is not None:
-                wo = _tp_slice_o_proj(wo, tp)
-            mk = functools.partial(
-                decode_layer_megakernel, rope_base=cfg.rope_theta,
-                eps=eps, residual=tp is None,
-                quantize_out=quantize_wire)
-            if isinstance(kc, tuple):
-                (kcp, ksc), (vcp, vsc) = kc, vc
-                h_out, kc_new, vc_new = mk(
-                    h, lens, tables, _lw(p, i, "input_layernorm.weight"),
-                    _lw(p, i, "self_attn.q_proj.weight"),
-                    _lw(p, i, "self_attn.k_proj.weight"),
-                    _lw(p, i, "self_attn.v_proj.weight"),
-                    wo, kcp, vcp, k_scale=ksc, v_scale=vsc)
-            else:
-                h_out, kc_new, vc_new = mk(
-                    h, lens, tables, _lw(p, i, "input_layernorm.weight"),
-                    _lw(p, i, "self_attn.q_proj.weight"),
-                    _lw(p, i, "self_attn.k_proj.weight"),
-                    _lw(p, i, "self_attn.v_proj.weight"),
-                    wo, kc, vc)
-            if tp is None:
-                h = h_out
-            else:
-                if quantize_wire:
-                    # the kernel emitted the partial ALREADY int8 in
-                    # the packed-scale layout — straight on the wire,
-                    # no f32 HBM round-trip before the collective
-                    from ..parallel.collectives import \
-                        quantized_psum_prequant
-
-                    q8, q8s = h_out
-                    red = quantized_psum_prequant(
-                        q8, q8s, tp.axis, shape=(b, 1, H),
-                        dtype=jnp.float32)
-                else:
-                    # h_out is the f32 o-proj PARTIAL (no residual):
-                    # psum over the shards' contraction slices
-                    # (quantized when FLAGS_quantized_collectives is
-                    # on), then residual
-                    red = tp.psum_partial(h_out)
-                h = (h.astype(jnp.float32) + red).astype(h.dtype)
-            new_kcs.append(kc_new)
-            new_vcs.append(vc_new)
-            x2 = _k_rms(h, _lw(p, i, "post_attention_layernorm.weight"),
-                        eps)
-            gate = _mm(x2, _lw(p, i, "mlp.gate_proj.weight"))
-            up = _mm(x2, _lw(p, i, "mlp.up_proj.weight"))
-            h = h + _mm(jax.nn.silu(gate) * up,
-                        _lw(p, i, "mlp.down_proj.weight"))
-        h = _k_rms(h, p["llama.norm.weight"], eps)
-        return head_logits(h, p)[:, -1], new_kcs, new_vcs
-
-    return decode_step
 
 
 def quantize_kv_pages(kv):
@@ -2449,16 +1986,6 @@ def build_paged_generate(cfg, b, sb, max_new, block_size: int = 64,
     pages_per_seq = -(-total // block_size)
     n_pre = sb // block_size
     quant_kv = resolve_kv_cache_dtype() == "int8"
-    use_mega = resolve_decode_megakernel()
-    if use_mega == "scan":
-        import warnings
-
-        warnings.warn(
-            "decode_megakernel='scan' requested, but jit_generate keeps "
-            "per-layer params and pools (the stacked re-layout is the "
-            "serving engine's — stack_decode_layer_params at engine "
-            "build); serving the 'full' rung", stacklevel=2)
-        use_mega = "full"
     tp = make_serving_tp(cfg, serving_mp)
     # the kv-head count of the pools the BODY sees (local under tp;
     # full when replicated — including the MQA fallback)
@@ -2507,11 +2034,7 @@ def build_paged_generate(cfg, b, sb, max_new, block_size: int = 64,
     def make_decode_step(tables):
         """The shared per-layer decode body (_make_decode_step) with the
         KV store swapped for page/slot scatter + table-indirect attention;
-        `pos` is the per-sequence [b] length vector (ragged batch). With
-        FLAGS_decode_megakernel (read when this factory ran — program-
-        BUILD time) the whole attention block fuses into one Pallas call
-        per layer; unsupported shapes fall back to this multi-kernel
-        oracle path with a warning."""
+        `pos` is the per-sequence [b] length vector (ragged batch)."""
         _, kv_write = make_paged_kv_helpers(b, n_pre, nkv_eff, dh,
                                             block_size, tables)
         if quant_kv:
@@ -2521,17 +2044,8 @@ def build_paged_generate(cfg, b, sb, max_new, block_size: int = 64,
         def kv_attend(q1, kc, vc, lens):
             return paged_attn(q1, kc, vc, tables, lens)
 
-        base = _make_decode_step(cfg, b, kv_write=kv_write,
+        return _make_decode_step(cfg, b, kv_write=kv_write,
                                  kv_attend=kv_attend, tp=tp)
-        if use_mega == "off":
-            return base
-
-        def step(p, kcs, vcs, tok, pos):
-            return _megakernel_or_fallback_step(
-                cfg, b, tables, p, kcs, vcs, base,
-                tp=tp, mode=use_mega)(p, kcs, vcs, tok, pos)
-
-        return step
 
     def run(p_dec, ids, s0_vec, tables, key, temperature, top_p):
         dtype = p_dec["llama.embed_tokens.weight"].dtype
@@ -2726,12 +2240,13 @@ def _make_decode_step(cfg, b, max_seq=None, kv_write=None, kv_attend=None,
             else jnp.reshape(pos, (1,))
         new_kcs, new_vcs = [], []
         for i in range(n_layers):
-            x = _k_rms(h, _lw(p, i, "input_layernorm.weight"), eps)
-            q = _mm(x, _lw(p, i, "self_attn.q_proj.weight")).reshape(
+            pre = f"llama.layers.{i}."
+            x = _k_rms(h, p[pre + "input_layernorm.weight"], eps)
+            q = _mm(x, p[pre + "self_attn.q_proj.weight"]).reshape(
                 b, 1, nh_l, dh)
-            k = _mm(x, _lw(p, i, "self_attn.k_proj.weight")).reshape(
+            k = _mm(x, p[pre + "self_attn.k_proj.weight"]).reshape(
                 b, 1, nkv_l, dh)
-            v = _mm(x, _lw(p, i, "self_attn.v_proj.weight")).reshape(
+            v = _mm(x, p[pre + "self_attn.v_proj.weight"]).reshape(
                 b, 1, nkv_l, dh)
             q, k = apply_rotary_emb(q, k, position_ids=pos_ids,
                                     base=cfg.rope_theta)
@@ -2742,13 +2257,12 @@ def _make_decode_step(cfg, b, max_seq=None, kv_write=None, kv_attend=None,
             if tp is not None:
                 ctx = tp.gather_heads(ctx)              # [b, nh, dh]
             h = h + _mm(ctx.reshape(b, 1, nh * dh),
-                        _lw(p, i, "self_attn.o_proj.weight"))
-            x2 = _k_rms(h, _lw(p, i, "post_attention_layernorm.weight"),
-                        eps)
-            gate = _mm(x2, _lw(p, i, "mlp.gate_proj.weight"))
-            up = _mm(x2, _lw(p, i, "mlp.up_proj.weight"))
+                        p[pre + "self_attn.o_proj.weight"])
+            x2 = _k_rms(h, p[pre + "post_attention_layernorm.weight"], eps)
+            gate = _mm(x2, p[pre + "mlp.gate_proj.weight"])
+            up = _mm(x2, p[pre + "mlp.up_proj.weight"])
             h = h + _mm(jax.nn.silu(gate) * up,
-                        _lw(p, i, "mlp.down_proj.weight"))
+                        p[pre + "mlp.down_proj.weight"])
         h = _k_rms(h, p["llama.norm.weight"], eps)
         return head_logits(h, p)[:, -1], new_kcs, new_vcs
 

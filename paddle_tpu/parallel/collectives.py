@@ -228,52 +228,6 @@ def quantized_psum(x, axis_name: str, *, block: int = QCOLL_BLOCK):
     return out.reshape(shape).astype(dtype)
 
 
-def quantized_psum_prequant(q, scale, axis_name: str, *, shape, dtype,
-                            block: int = QCOLL_BLOCK):
-    """`quantized_psum` for a payload the PRODUCER already quantized —
-    the decode megakernel's in-kernel o-proj epilogue (ISSUE 20
-    satellite): hop 1's quantization happened inside the kernel, so
-    the f32 partial never round-trips HBM before the wire.
-
-    `q` int8 with `scale` f32 must be the `quantize_blocks` layout of
-    the row-major f32 partial (q.size == prod(shape), one scale per
-    `block` consecutive flat elements — a [b, H] partial with
-    H % block == 0 satisfies this per row). Requires
-    q.size % (n * block) == 0 so the per-destination chunks split on
-    block boundaries with no padding — the caller gates (the serving
-    TP seam checks `(b * H) % (mp * 128) == 0`). Hops 2 and 3 are
-    `quantized_psum`'s verbatim, so the result is BIT-IDENTICAL to
-    `quantized_psum(partial_f32)` of the same partial. At axis size 1
-    there is no wire: the payload just dequantizes (the caller should
-    not pre-quantize in that regime — `quantized_psum` returns the f32
-    partial untouched there)."""
-    n = jax.lax.psum(1, axis_name)  # static: the axis size
-    size = int(q.size)
-    if n == 1:
-        return dequantize_blocks(
-            q.reshape(1, size),
-            scale.astype(jnp.float32).reshape(1, -1)
-        ).reshape(shape).astype(dtype)
-    if size % (n * block):
-        raise ValueError(
-            f"quantized_psum_prequant: payload size {size} does not "
-            f"split into {n} destinations of whole {block}-blocks — "
-            "the caller must gate on (size %% (n * block) == 0)")
-    chunk = size // n
-    qp = q.reshape(n, chunk)
-    sp = scale.astype(jnp.float32).reshape(n, chunk // block)
-    px = jax.lax.all_to_all(_pack_scales(qp, sp), axis_name,
-                            split_axis=0, concat_axis=0)
-    qx, sx = _unpack_scales(px, chunk // block)
-    red = jnp.sum(dequantize_blocks(qx, sx), axis=0)        # f32 [chunk]
-    q2, s2 = quantize_blocks(red, block)
-    pg = jax.lax.all_gather(_pack_scales(q2, s2), axis_name, axis=0,
-                            tiled=False)
-    qg, sg = _unpack_scales(pg, int(s2.shape[-1]))
-    out = dequantize_blocks(qg, sg).reshape(-1)[:size]
-    return out.reshape(shape).astype(dtype)
-
-
 def quantized_reduce_scatter(x, axis_name: str, *,
                              block: int = QCOLL_BLOCK):
     """`lax.psum_scatter(..., scatter_dimension=0, tiled=True)` with an

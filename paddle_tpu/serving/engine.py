@@ -99,20 +99,15 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..models.llama import (STACKED_LAYER_NAMES, STACKED_PREFIX,
-                            PagedKVManager, _make_chunk_prefill,
-                            _make_decode_step, _make_decode_step_megakernel,
-                            _make_head_logits, _make_prefill,
-                            _make_prefill_with_prefix, _make_verify_window,
-                            _megakernel_or_fallback_step, _sample_next,
+from ..models.llama import (PagedKVManager, _make_chunk_prefill,
+                            _make_decode_step, _make_head_logits,
+                            _make_prefill, _make_prefill_with_prefix,
+                            _make_verify_window, _sample_next,
                             hash_prefix_blocks, make_paged_kv_helpers,
                             make_paged_kv_q8_helpers, make_serving_tp,
-                            plan_megakernel_rung,
-                            resolve_decode_megakernel,
                             resolve_kv_cache_dtype, resolve_serving_cp,
                             resolve_serving_mp, resolve_unified_step,
-                            serving_param_specs, shard_serving_params,
-                            stack_decode_layer_params)
+                            serving_param_specs, shard_serving_params)
 from ..observability import metrics as obs_metrics
 from ..observability import trace as obs_trace
 from ..observability.trace import _NULL_SPAN
@@ -219,7 +214,6 @@ class ContinuousBatchingEngine:
                  prefix_cache: bool = True, double_buffer: bool = False,
                  kv_cache_dtype: Optional[str] = None,
                  kv_pool_bytes: Optional[int] = None,
-                 decode_megakernel=None,
                  serving_mp: Optional[int] = None,
                  serving_cp: Optional[int] = None,
                  quantized_collectives: Optional[bool] = None,
@@ -242,8 +236,8 @@ class ContinuousBatchingEngine:
 
         `serving_mp` (default from FLAGS_serving_mp /
         PADDLE_TPU_SERVING_MP, resolved HERE at build time like the
-        kv-dtype and megakernel flags — it joins every program key and
-        `warm()` covers it) shards the engine across an `mp` mesh: the
+        kv-dtype flag — it joins every program key and `warm()`
+        covers it) shards the engine across an `mp` mesh: the
         paged K/V pools and their int8 scale sidecars shard by kv head,
         block tables / budgets / slot state replicate, and every device
         program runs under shard_map with ONE cross-chip collective per
@@ -257,9 +251,9 @@ class ContinuousBatchingEngine:
         PADDLE_TPU_QUANTIZED_COLLECTIVES, resolved HERE at build time
         like every serving flag — it joins every program key and
         `warm()` covers it) ships the per-layer o-proj activation
-        all-gather at mp > 1 (and the megakernel path's partial-sum
-        psum) as absmax-scaled int8 blocks + an f32 scale sidecar
-        (`parallel/collectives.py`, the int8 KV pools' proven scheme):
+        all-gather at mp > 1 as absmax-scaled int8 blocks + an f32
+        scale sidecar (`parallel/collectives.py`, the int8 KV pools'
+        proven scheme):
         ~0.5x the bf16 wire bytes per token at quantization-noise
         accuracy (the token-match gate is the int8-KV bar, not
         identity). OFF (default) keeps every wire byte-identical; at
@@ -296,9 +290,9 @@ class ContinuousBatchingEngine:
         slot's decode chunk — a 100k-token prompt can no longer
         head-of-line-block decode. Pure-decode steps keep dispatching
         the plain decode-chunk program (bitwise the split engine's
-        steady state, multi-step sync amortization and megakernel
-        composition included). The split path stays available as the
-        oracle (`unified_step=False`).
+        steady state, multi-step sync amortization included). The
+        split path stays available as the oracle
+        (`unified_step=False`).
 
         `token_budget` is the prefill window width in tokens (a
         multiple of `block_size`; default = the prompt bucket): each
@@ -332,9 +326,9 @@ class ContinuousBatchingEngine:
         autotuner (`analysis/tuner.py`) — or a dict / a path to a
         persisted `.paddle_tpu_tune.json` — that DEFAULTS every
         build-time knob the tuner swept (kv_cache_dtype,
-        decode_megakernel, unified_step, serving_mp,
-        quantized_collectives, token_budget, block_size). Explicit
-        kwargs win per knob; the flag-registry defaults only apply to
+        unified_step, serving_mp, quantized_collectives,
+        token_budget, block_size). Explicit kwargs win per knob; the
+        flag-registry defaults only apply to
         knobs neither the caller nor the artifact set. None follows
         FLAGS_tuned_config / PADDLE_TPU_TUNED_CONFIG (a stale
         flag-loaded artifact warns and is ignored; a stale EXPLICIT
@@ -353,14 +347,12 @@ class ContinuousBatchingEngine:
         if self.tuned_config is not None:
             merged = self.tuned_config.apply(dict(
                 kv_cache_dtype=kv_cache_dtype,
-                decode_megakernel=decode_megakernel,
                 unified_step=unified_step, serving_mp=serving_mp,
                 serving_cp=serving_cp,
                 quantized_collectives=quantized_collectives,
                 token_budget=token_budget, block_size=block_size,
                 speculative=speculative, spec_k=spec_k))
             kv_cache_dtype = merged["kv_cache_dtype"]
-            decode_megakernel = merged["decode_megakernel"]
             unified_step = merged["unified_step"]
             serving_mp = merged["serving_mp"]
             serving_cp = merged.get("serving_cp", serving_cp)
@@ -406,11 +398,6 @@ class ContinuousBatchingEngine:
         # FLAGS_prefix_prefill_kernel); it also joins the program-cache
         # keys so the compile-point helpers can never mix dtypes
         self.kv_dtype = resolve_kv_cache_dtype(kv_cache_dtype)
-        # fused per-layer decode step (FLAGS_decode_megakernel /
-        # `decode_megakernel=`), likewise read HERE at build time: the
-        # decode-chunk program is compiled once per engine, so the flag
-        # is part of this engine's identity (warm() covers it)
-        self.use_megakernel = resolve_decode_megakernel(decode_megakernel)
         # unified ragged step (FLAGS_unified_step, ISSUE 14), resolved
         # at build time like the flags above: ONE chunked-prefill +
         # decode program instead of the split prefill program zoo
@@ -555,32 +542,17 @@ class ContinuousBatchingEngine:
                                    kv_cache_dtype=self.kv_dtype,
                                    mp=self.kv_shards, cp=self.cp)
         self.scratch_page = self.mgr.alloc_pages(1)[0]  # retired rows' sink
-        # megakernel rung plan (ISSUE 20): walk the requested fusion
-        # ladder ONCE here at build over spec views of this engine's
-        # exact decode operands, warning once per refused rung — every
-        # later program trace serves the planned rung silently. The
-        # scan rung re-lays the engine out: per-layer weights stack
-        # along a leading layer axis and the n_layers pools collapse to
-        # ONE layer-major pool (layer i owns rows [i*max_pages,
-        # (i+1)*max_pages); tables keep per-layer page ids and the
-        # programs add the layer offset).
-        self.megakernel_rung = self._plan_megakernel(max_pages)
-        scan = self.megakernel_rung == "scan"
-        self._page_stride = max_pages if scan else 0
-        pool_rows = max_pages * cfg.num_hidden_layers if scan \
-            else max_pages
-        n_pools = 1 if scan else cfg.num_hidden_layers
         if self.kv_dtype == "int8":
             # (int8 pool, per-(page, kv head) f32 absmax scale) pairs —
             # every program threads the pair, so donation keeps scales
             # in place exactly like the pools
             def _pool():
-                return (jnp.zeros((pool_rows, nkv, block_size, dh),
+                return (jnp.zeros((max_pages, nkv, block_size, dh),
                                   jnp.int8),
-                        jnp.zeros((pool_rows, nkv), jnp.float32))
+                        jnp.zeros((max_pages, nkv), jnp.float32))
         else:
             def _pool():
-                return jnp.zeros((pool_rows, nkv, block_size, dh), dtype)
+                return jnp.zeros((max_pages, nkv, block_size, dh), dtype)
         if self._tp is not None:
             # pools are BORN on the serving mesh (kv-head sharded, or
             # replicated under the MQA fallback): max_pages was sized
@@ -600,15 +572,8 @@ class ContinuousBatchingEngine:
                 else NamedSharding(self.mp_mesh, sp)
             _pool.__name__ = "serve_kv_pool_init"
             _pool = jax.jit(_pool, out_shardings=out)
-        self.kcs = [_pool() for _ in range(n_pools)]
-        self.vcs = [_pool() for _ in range(n_pools)]
-        if scan:
-            # build-time re-layout behind the flag: the scan kernel
-            # streams per-layer weights from ONE stacked tensor per
-            # projection (leading layer axis) — `_lw` serves every
-            # other program the same slices, so tokens stay identical
-            self.p = stack_decode_layer_params(
-                self.p, cfg.num_hidden_layers)
+        self.kcs = [_pool() for _ in range(cfg.num_hidden_layers)]
+        self.vcs = [_pool() for _ in range(cfg.num_hidden_layers)]
         if self._tp is not None:
             # params per `serving_param_specs` (q/k/v columns sharded,
             # the rest — o-proj included — replicated). Logical shapes
@@ -706,66 +671,6 @@ class ContinuousBatchingEngine:
         # same ceil-division as PagedKVManager.pages_needed (which is not
         # constructed yet when __init__ sizes the pool from this)
         return -(-(sb + max_new) // self.block_size)
-
-    def _plan_megakernel(self, max_pages: int) -> str:
-        """Resolve the SERVED megakernel rung once per engine BUILD
-        (ISSUE 20): walk the requested fusion ladder
-        (`plan_megakernel_rung`) over ShapeDtypeStruct views of this
-        engine's exact decode operands — slots-wide hidden batch, the
-        full-width block tables, the would-be stacked weights and
-        layer-major pool — and warn ONCE naming each refused rung and
-        its reason. The per-program traces then serve the plan
-        silently (`warn=False` at the fallback seam), so an engine
-        build emits each downgrade exactly once instead of once per
-        compiled program."""
-        if self.use_megakernel == "off":
-            return "off"
-        import warnings
-
-        cfg = self.cfg
-        L = cfg.num_hidden_layers
-        nkv, dh = self._nkv_eff, cfg.head_dim
-        sds = jax.ShapeDtypeStruct
-
-        def _spec(w):
-            if isinstance(w, tuple):
-                return tuple(sds(a.shape, a.dtype) for a in w)
-            return sds(w.shape, w.dtype)
-
-        pview = {name: _spec(w) for name, w in self.p.items()}
-        for name in STACKED_LAYER_NAMES:
-            # the stacked layout does not exist yet (it is built only
-            # if this plan lands on scan) — synthesize its specs from
-            # layer 0 so the scan rung's check sees what WOULD exist
-            w0 = self.p[f"llama.layers.0.{name}"]
-            if isinstance(w0, tuple):
-                pview[STACKED_PREFIX + name] = tuple(
-                    sds((L,) + a.shape, a.dtype) for a in w0)
-            else:
-                pview[STACKED_PREFIX + name] = sds((L,) + w0.shape,
-                                                   w0.dtype)
-        rows = max_pages * L  # layer-major stacked rows; the attn and
-        # full rung checks never read the row count
-        bs = self.block_size
-        if self.kv_dtype == "int8":
-            pool = (sds((rows, nkv, bs, dh), jnp.int8),
-                    sds((rows, nkv), jnp.float32))
-        else:
-            pool = sds((rows, nkv, bs, dh),
-                       self.p["llama.embed_tokens.weight"].dtype)
-        tables = sds((self.slots, self.table_width), jnp.int32)
-        rung, refusals = plan_megakernel_rung(
-            self.use_megakernel, cfg, self.slots, pview, [pool],
-            [pool], tables, tp=self._tp, localize_tp=True)
-        if refusals:
-            down = "the multi-kernel path" if rung == "off" \
-                else f"the '{rung}' rung"
-            for refused, reason in refusals:
-                warnings.warn(
-                    f"decode_megakernel rung '{refused}' unsupported "
-                    f"on this engine build ({reason}); serving {down}",
-                    stacklevel=3)
-        return rung
 
     # ---- tensor-parallel plumbing (FLAGS_serving_mp) --------------------
 
@@ -923,13 +828,10 @@ class ContinuousBatchingEngine:
             # sync-wait telemetry (what double buffering hides)
             "sync_wait_s": self.sync_wait_s,
             "blocked_syncs": self.blocked_syncs,
-            # decode megakernel (ISSUE 20): the REQUESTED fusion rung
-            # and the rung the build plan actually serves (the plan
-            # steps down one rung per unsupported-shape refusal)
-            "decode_megakernel": self.use_megakernel,
-            "megakernel_rung": self.megakernel_rung,
+            # constant: benchmark/drivers/serve.py prints it
+            "megakernel_rung": "off",
             # quantized collectives (ISSUE 15): int8 wire on the mp
-            # o-proj gather / megakernel psum when True
+            # o-proj gather when True
             "quantized_collectives": self.quantized_collectives,
             # serving parallelism degrees: kv-head (mp) and page-axis
             # context (cp, ISSUE 18) shard counts
@@ -1157,16 +1059,11 @@ class ContinuousBatchingEngine:
         head_logits = _make_head_logits(cfg)
         do_sample, top_k = self.do_sample, self.top_k
         scatter = self._page_scatter(bsz, n_pre)
-        stride = self._page_stride
 
         def run(p, kcs, vcs, ids, s0_vec, pages, key, temperature, top_p):
             h, kvs = base(p, ids)
             for i, (k, v) in enumerate(kvs):
-                # stacked pool (scan rung): layer i's writes land in
-                # pool 0 at its page ids + i*max_pages
-                j = 0 if stride else i
-                pg = pages + i * stride if stride else pages
-                kcs[j], vcs[j] = scatter(kcs[j], vcs[j], k, v, pg)
+                kcs[i], vcs[i] = scatter(kcs[i], vcs[i], k, v, pages)
             h_last = h[jnp.arange(bsz), s0_vec - 1][:, None, :]
             logits = head_logits(h_last, p)[:, -1]
             first = _sample_next(logits.astype(jnp.float32), key,
@@ -1255,17 +1152,12 @@ class ContinuousBatchingEngine:
         head_logits = _make_head_logits(cfg)
         do_sample, top_k = self.do_sample, self.top_k
         scatter = self._page_scatter(bsz, n_pre)
-        stride = self._page_stride
 
         def run(p, kcs, vcs, ids, s0_vec, pages, ptables, plens, key,
                 temperature, top_p):
             h, kvs = base(p, kcs, vcs, ids, ptables, plens, s0_vec)
             for i, (k, v) in enumerate(kvs):
-                # stacked pool (scan rung): layer i's writes land in
-                # pool 0 at its page ids + i*max_pages
-                j = 0 if stride else i
-                pg = pages + i * stride if stride else pages
-                kcs[j], vcs[j] = scatter(kcs[j], vcs[j], k, v, pg)
+                kcs[i], vcs[i] = scatter(kcs[i], vcs[i], k, v, pages)
             h_last = h[jnp.arange(bsz), s0_vec - 1][:, None, :]
             logits = head_logits(h_last, p)[:, -1]
             first = _sample_next(logits.astype(jnp.float32), key,
@@ -1275,29 +1167,23 @@ class ContinuousBatchingEngine:
         return run
 
     def _decode_step_maker(self):
-        """make_step(tables, p, kcs, vcs) -> per-layer decode body,
-        shared by the decode-chunk program AND the unified step's
-        decode lane (megakernel-aware on both)."""
+        """make_step(tables) -> the decode step of `_build_decode_chunk`,
+        its one caller (the unified step runs that chunk as its decode
+        lane)."""
         from ..kernels.decode_attention import paged_decode_attention
 
         cfg, b, bs = self.cfg, self.slots, self.block_size
         quant = self.kv_dtype == "int8"
-        # the rung PLANNED at engine build (_plan_megakernel) — the
-        # build already warned for every refused rung, so the traces
-        # below stay silent (warn=False at the fallback seam)
-        use_mega = self.megakernel_rung
         nkv_eff = self._nkv_eff
         tp = self._tp
         cp_parts = tp is not None and tp.cp > 1
 
-        def make_step(tables, p, kcs, vcs):
-            """Per-layer decode body for one chunk: the megakernel
-            (FLAGS_decode_megakernel) when enabled and supported for
-            these operand shapes, else the multi-kernel oracle path.
-            Under serving_mp this runs inside the shard_map body — the
-            kv helpers and the attention see the LOCAL kv heads. Under
-            serving_cp (ISSUE 18) the pools arrive PAGE-sharded: the
-            kv commit translates global page ids to local rows (non-
+        def make_step(tables):
+            """Per-layer decode body for one chunk. Under serving_mp
+            this runs inside the shard_map body — the kv helpers and
+            the attention see the LOCAL kv heads. Under serving_cp
+            (ISSUE 18) the pools arrive PAGE-sharded: the kv commit
+            translates global page ids to local rows (non-
             owned writes drop out of range), the attend streams only
             the owned pages as online-softmax partials, and
             `merge_attn_partials` folds the per-shard stats — never
@@ -1375,20 +1261,8 @@ class ContinuousBatchingEngine:
                         return paged_decode_attention(q1, kc, vc,
                                                       tables, lens_)
 
-            if use_mega == "scan":
-                # the stacked-pool layout has no multi-kernel twin —
-                # the plan guaranteed support, so build the scanned
-                # step directly (one Pallas call walks every layer)
-                return _make_decode_step_megakernel(cfg, b, tables,
-                                                    tp=tp, mode="scan")
-            base = _make_decode_step(cfg, b, kv_write=kv_write,
+            return _make_decode_step(cfg, b, kv_write=kv_write,
                                      kv_attend=kv_attend, tp=tp)
-            if use_mega == "off":
-                return base
-            return _megakernel_or_fallback_step(cfg, b, tables, p, kcs,
-                                                vcs, base, tp=tp,
-                                                mode=use_mega,
-                                                warn=False)
 
         return make_step
 
@@ -1405,7 +1279,7 @@ class ContinuousBatchingEngine:
 
         def run(p, kcs, vcs, toks, lens, budgets, tables, live, key,
                 temperature, top_p):
-            decode_step = make_step(tables, p, kcs, vcs)
+            decode_step = make_step(tables)
 
             def step(carry, _):
                 tok, lens_, kcs_, vcs_, done, key_ = carry
@@ -1459,7 +1333,6 @@ class ContinuousBatchingEngine:
         chunk_body = _make_chunk_prefill(cfg, tn, tp=self._tp)
         head_logits = _make_head_logits(cfg)
         scatter = self._page_scatter(1, n_win)
-        stride = self._page_stride
 
         def run(p, kcs, vcs, toks, lens, budgets, tables, live,
                 chunk_ids, chunk_table, chunk_cached, chunk_len,
@@ -1473,9 +1346,8 @@ class ContinuousBatchingEngine:
             h, kvs = chunk_body(p, kcs, vcs, chunk_ids, chunk_table,
                                 chunk_cached, chunk_len)
             for i, (k, v) in enumerate(kvs):
-                j = 0 if stride else i
-                pg = chunk_pages + i * stride if stride else chunk_pages
-                kcs[j], vcs[j] = scatter(kcs[j], vcs[j], k, v, pg)
+                kcs[i], vcs[i] = scatter(kcs[i], vcs[i], k, v,
+                                         chunk_pages)
             # first-token logits at the chunk's true last position —
             # meaningful only when this window completes the prompt
             # (the host ignores it otherwise)
@@ -1546,19 +1418,12 @@ class ContinuousBatchingEngine:
         body = _make_verify_window(self.cfg, b, w, tp=self._tp)
         head_logits = _make_head_logits(self.cfg)
         scatter = self._verify_scatter(w)
-        stride = self._page_stride
 
         def run(p, kcs, vcs, ids, tables, cached_lens, new_lens):
             h, kvs = body(p, kcs, vcs, ids, tables, cached_lens,
                           new_lens)
             for i, (k, v) in enumerate(kvs):
-                # stacked pool (scan rung): offset the TABLE per layer
-                # — the scatter's scratch redirect stays at the layer-0
-                # scratch row, a shared don't-care sink no program
-                # attends to
-                j = 0 if stride else i
-                tbl = tables + i * stride if stride else tables
-                kcs[j], vcs[j] = scatter(kcs[j], vcs[j], k, v, tbl,
+                kcs[i], vcs[i] = scatter(kcs[i], vcs[i], k, v, tables,
                                          cached_lens, new_lens)
             logits = head_logits(h, p)  # [b, w, vocab]
             preds = jnp.argmax(logits.astype(jnp.float32),
@@ -1575,9 +1440,8 @@ class ContinuousBatchingEngine:
         dtype rides every key: an engine only ever builds programs at
         its own kv_cache_dtype, and the key makes that self-evident in
         compile_stats()."""
-        key = ("cold", sb, bsz, self.kv_dtype, self.use_megakernel,
-               self.spec_k, self.cp, int(self.quantized_collectives),
-               self.mp)
+        key = ("cold", sb, bsz, self.kv_dtype, self.spec_k, self.cp,
+               int(self.quantized_collectives), self.mp)
         if key not in self._prefill_cache:
             self._prefill_cache[key] = self._program(
                 self._build_prefill(sb, bsz),
@@ -1585,9 +1449,8 @@ class ContinuousBatchingEngine:
         return self._prefill_cache[key]
 
     def _get_prefix_prefill(self, sb: int, bsz: int, w_pre: int):
-        key = ("prefix", sb, bsz, w_pre, self.kv_dtype,
-               self.use_megakernel, self.spec_k, self.cp,
-               int(self.quantized_collectives), self.mp)
+        key = ("prefix", sb, bsz, w_pre, self.kv_dtype, self.spec_k,
+               self.cp, int(self.quantized_collectives), self.mp)
         if key not in self._prefill_cache:
             self._prefill_cache[key] = self._program(
                 self._build_prefix_prefill(sb, bsz, w_pre),

@@ -156,11 +156,11 @@ def test_launcher_elastic_rescale(tmp_path):
         "n = os.environ['PADDLE_TRAINERS_NUM']\n"
         "open(os.path.join(out, f'mark.{n}.{uuid.uuid4().hex}'), 'w')"
         ".write('x')\n"
-        "for _ in range(600):\n"
+        "end = time.time() + 180\n"
+        "while time.time() < end:\n"
         "    if os.path.exists(os.path.join(out, 'stop')):\n"
-        "        sys.exit(0)\n"
-        "    time.sleep(0.05)\n"
-        "sys.exit(0)\n")
+        "        break\n"
+        "    time.sleep(0.05)\n")
 
     rc_box = {}
 
@@ -173,8 +173,12 @@ def test_launcher_elastic_rescale(tmp_path):
     t = threading.Thread(target=run, daemon=True)
     t.start()
 
-    def wait_marks(world, count, timeout=20):
-        deadline = time.time() + timeout
+    # ONE deadline for the whole exchange, generous enough for a machine
+    # that five other test workers keep busy: a spawn, a kill and the
+    # supervisor's settle window each stretch there
+    deadline = time.time() + 120
+
+    def wait_marks(world, count):
         while time.time() < deadline:
             n = len([f for f in out.iterdir()
                      if f.name.startswith(f"mark.{world}.")])
@@ -184,12 +188,14 @@ def test_launcher_elastic_rescale(tmp_path):
         return False
 
     assert wait_marks(1, 1), "initial world-1 worker never started"
+    # default heartbeat TTL: node-b's heartbeat thread lives in this
+    # (busy) process, and it leaves by exit(), not by expiry
     b = ElasticManager(FileStore(str(store)), host="node-b",
-                       np_range=(1, 2), heartbeat_ttl=2.0).register()
+                       np_range=(1, 2)).register()
     assert wait_marks(2, 1), "scale-up respawn (world 2) not observed"
     b.exit()
     assert wait_marks(1, 2), "scale-down respawn (world 1) not observed"
     (out / "stop").touch()
-    t.join(timeout=20)
+    t.join(timeout=max(20.0, deadline - time.time()))
     assert not t.is_alive(), "launcher did not exit after workers stopped"
     assert rc_box.get("rc") == 0

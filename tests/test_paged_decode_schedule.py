@@ -19,13 +19,19 @@ from paddle_tpu.models import quantize_kv_pages
 
 # (Hq, Hkv, D, page, table width): the serving cell's heads; the same with
 # pages so small that a step spans the whole table; a `serving_mp` shard's
-# local heads; the replicated-KV MQA fallback; narrow heads with group 1
+# local heads; the replicated-KV MQA fallback; narrow heads with group 1;
+# and the head groupings the decode step's own test runs (group 2, equal
+# heads, one kv head) at the serving head size and at a narrow one
 GEOMETRIES = {
     "gqa4": (32, 8, 128, 64, 10),
     "gqa4-small-pages": (32, 8, 128, 16, 12),
     "mp-local-8q-2kv": (8, 2, 128, 64, 20),
     "mqa-fallback": (8, 1, 128, 64, 6),
     "group1-d64": (4, 4, 64, 32, 9),
+    "gqa2": (8, 4, 128, 32, 8),
+    "gqa2-d64-small-pages": (4, 2, 64, 8, 14),
+    "group1-d128": (4, 4, 128, 64, 6),
+    "mqa-d64": (4, 1, 64, 16, 10),
 }
 
 

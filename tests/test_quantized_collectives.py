@@ -340,30 +340,60 @@ class TestServingQuantizedGather(unittest.TestCase):
             _engine(cfg, params)
             .metrics()["quantized_collectives"])
 
-    def test_psum_partial_quantized_parity(self):
-        """The megakernel composition seam: ServingTP.psum_partial
-        routes the f32 partial-sum psum through the quantized exchange
-        when the flag is on — parity with the exact psum at
+    def test_cp_merge_quantized_parity(self):
+        """The serving psum seam: under cp > 1 ServingTP.
+        merge_attn_partials ships the weighted accumulator through the
+        int8 two-hop psum when the flag is on and merges the m / l
+        statistics exactly — parity with the exact merge at
         quantization tolerance."""
         from paddle_tpu.models.llama import ServingTP
 
         cfg, _ = _tiny_setup()
-        tp_q = ServingTP(cfg, 2, quantized=True)
-        tp_x = ServingTP(cfg, 2, quantized=False)
+        tp_q = ServingTP(cfg, 1, quantized=True, cp=2)
+        tp_x = ServingTP(cfg, 1, quantized=False, cp=2)
         rng = np.random.default_rng(23)
-        x = rng.normal(size=(2, 4, 64)).astype(np.float32)
-        mesh = Mesh(np.asarray(jax.devices()[:2]), ("mp",))
+        # per-shard partials [cp, rows, heads(, dh)]: shard 1 holds the
+        # larger running max in some rows, so both rescales are live
+        m = rng.normal(size=(2, 4, 4)).astype(np.float32)
+        l = rng.uniform(0.5, 4.0, size=(2, 4, 4)).astype(np.float32)
+        acc = rng.normal(size=(2, 4, 4, 64)).astype(np.float32)
+        mesh = Mesh(np.asarray(jax.devices()[:2]), (tp_x.cp_axis,))
 
         def smap(tp):
+            spec = P(tp.cp_axis)
             return jax.jit(shard_map(
-                lambda v: tp.psum_partial(v[0]), mesh=mesh,
-                in_specs=P("mp"), out_specs=P(None), check_vma=False))
+                lambda a, b, c: tp.merge_attn_partials(a[0], b[0], c[0]),
+                mesh=mesh, in_specs=(spec, spec, spec),
+                out_specs=P(None), check_vma=False))
 
-        exact = np.asarray(smap(tp_x)(jnp.asarray(x)[:, None]))
-        got = np.asarray(smap(tp_q)(jnp.asarray(x)[:, None]))
-        rel = np.max(np.abs(got - exact)
-                     / np.maximum(np.abs(exact), 1.0))
+        exact = [np.asarray(t) for t in smap(tp_x)(m, l, acc)]
+        got = [np.asarray(t) for t in smap(tp_q)(m, l, acc)]
+        np.testing.assert_array_equal(got[0], exact[0])
+        np.testing.assert_array_equal(got[1], exact[1])
+        self.assertFalse(np.array_equal(got[2], exact[2]))
+        rel = np.max(np.abs(got[2] - exact[2])
+                     / np.maximum(np.abs(exact[2]), 1.0))
         self.assertLess(rel, 0.05)
+        # and against the merge written out: softmax over both shards
+        w = np.exp(m - m.max(0))
+        np.testing.assert_allclose(exact[2], (acc * w[..., None]).sum(0),
+                                   rtol=1e-5, atol=1e-5)
+
+    def test_cp2_token_match_vs_exact_merge_through_churn(self):
+        """cp=2 with the int8 merge serves the churn trace at the
+        int8-KV token-match bar against the exact-merge cp=2 engine —
+        the flag's one effect on a head-unsharded mesh."""
+        cfg, params = _tiny_setup()
+        prompts = _churn_prompts(cfg, np.random.default_rng(7))
+        t_base = _serve(_engine(cfg, params, serving_cp=2), prompts)
+        eng = _engine(cfg, params, serving_cp=2,
+                      quantized_collectives=True)
+        t_q = _serve(eng, prompts)
+        self.assertTrue(eng.quantized_collectives)
+        self.assertGreaterEqual(_match_rate(t_base, t_q), 0.8)
+        # the merge's int8 psum is in the program that was served
+        dec = eng.audit_comms(programs=("decode",))["programs"]["decode"]
+        self.assertGreaterEqual(dec["n_quantized_sites"], 1)
 
 
 class TestCommsAuditQuantized(unittest.TestCase):

@@ -55,33 +55,6 @@ def _with_scales(fn):
     return lambda *a: fn(*a[:-2], k_scale=a[-2], v_scale=a[-1])
 
 
-# megakernel geometry: 4 slots, 4 q / 2 kv heads, dh 16, hidden 32, ffn 64
-MB, MNH, MNKV, MDH, MH, MF, MBS, MW = 4, 4, 2, 16, 32, 64, 8, 4
-MPAGES = MB * MW + 1
-_ATTN_W = [((MH,), F32), ((MH, MNH * MDH), F32), ((MH, MNKV * MDH), F32),
-           ((MH, MNKV * MDH), F32), ((MNH * MDH, MH), F32)]
-_MLP_W = [((MH, MF), F32), ((MH, MF), F32), ((MF, MH), F32)]
-_MEGA_HEAD = [((MB, 1, MH), F32), ((MB,), I32), ((MB, MW), I32)]
-_MPOOL = ((MPAGES, MNKV, MBS, MDH), F32)
-
-
-def _stacked(shapes, n):
-    return [((n,) + s, d) for s, d in shapes]
-
-
-def _mega_attn(*a):
-    return _mod("decode_megakernel").decode_layer_megakernel(*a)
-
-
-def _mega_full(*a):
-    return _mod("decode_megakernel").decode_layer_megakernel_full(*a)
-
-
-def _mega_scan(*a):
-    return _mod("decode_megakernel").decode_layers_megakernel(
-        *a, n_layers=2)
-
-
 # (traced function, operand shapes, {pallas name: registry entry})
 CASES = {
     "flash_attention": (
@@ -160,20 +133,6 @@ CASES = {
         lambda *a: _mod("int4_matmul").int4_matmul(*a),
         [((8, 4096), BF), ((4096, 2048), I8), ((4096,), F32)],
         {"int4_matmul": "int4_matmul"}),
-    "decode_megakernel": (
-        _mega_attn, _MEGA_HEAD + _ATTN_W + [_MPOOL, _MPOOL],
-        {"decode_megakernel": "decode_megakernel"}),
-    "decode_megakernel_full": (
-        _mega_full,
-        _MEGA_HEAD + [_ATTN_W[0], ((MH,), F32)] + _ATTN_W[1:] + _MLP_W
-        + [_MPOOL, _MPOOL],
-        {"decode_megakernel_full": "decode_megakernel_full"}),
-    "decode_megakernel_scan": (
-        _mega_scan,
-        _MEGA_HEAD + _stacked([_ATTN_W[0], ((MH,), F32)] + _ATTN_W[1:]
-                              + _MLP_W, 2)
-        + [((2 * MPAGES, MNKV, MBS, MDH), F32)] * 2,
-        {"decode_megakernel_scan": "decode_megakernel_scan"}),
 }
 
 
@@ -225,7 +184,7 @@ def test_no_pallas_call_in_kernels_lacks_a_name():
                       for node in ast.walk(tree)
                       if isinstance(node, ast.Call)
                       and getattr(node.func, "attr", "") == "pallas_call"]
-    assert len(calls) == 17     # the call sites CASES covers
+    assert len(calls) == 15     # the call sites CASES covers
     assert [c[:2] for c in calls if "name" not in c[2]] == []
 
 

@@ -478,7 +478,7 @@ class TestRules(unittest.TestCase):
         hits = r.by_rule().get("TPU903", [])
         self.assertEqual(len(hits), 1)
         self.assertIn("800 kernel launches", hits[0].message)
-        self.assertIn("megakernel", hits[0].hint)
+        self.assertIn("fuse", hits[0].hint)
         big = analyze(lambda x, w: x @ w,
                       jnp.zeros((1024, 1024), jnp.bfloat16),
                       jnp.zeros((1024, 1024), jnp.bfloat16),

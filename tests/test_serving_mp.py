@@ -228,44 +228,6 @@ class TestShardedTokenIdentity(unittest.TestCase):
         self.assertEqual(t1, t2)
 
     @pytest.mark.slow
-    def test_mp2_identity_megakernel(self):
-        """The fused decode megakernel under ServingTP: each shard runs
-        the kernel over its local heads with its local o-proj
-        contraction slice and the f32 partial sums psum across the mp
-        axis — still token-identical to the unfused single-chip path."""
-        cfg, _, params = _tiny_setup()
-        rng = np.random.default_rng(31)
-        prompts = _churn_prompts(cfg, rng)
-        t1 = _serve(_engine(cfg, params, mp=1), prompts)
-        t2 = _serve(_engine(cfg, params, mp=2, decode_megakernel=True),
-                    prompts)
-        self.assertEqual(t1, t2)
-
-    @pytest.mark.slow
-    def test_mp2_scan_request_falls_to_attn_identity(self):
-        """ISSUE 20: requesting the 'scan' rung under tensor
-        parallelism steps the ladder down to 'attn' (the o-proj psum
-        must run outside any fused MLP half), warning ONCE per refused
-        rung at build — and still serves token-identical to mp=1."""
-        import warnings
-
-        cfg, _, params = _tiny_setup()
-        rng = np.random.default_rng(31)
-        prompts = _churn_prompts(cfg, rng)
-        with warnings.catch_warnings(record=True) as caught:
-            warnings.simplefilter("always")
-            eng = _engine(cfg, params, mp=2, decode_megakernel="scan")
-        self.assertEqual(eng.use_megakernel, "scan")
-        self.assertEqual(eng.megakernel_rung, "attn")
-        mega_warns = [str(w.message) for w in caught
-                      if "decode_megakernel" in str(w.message)]
-        self.assertEqual(len(mega_warns), 2)
-        self.assertTrue(any("'scan'" in m for m in mega_warns))
-        self.assertTrue(any("'full'" in m for m in mega_warns))
-        t1 = _serve(_engine(cfg, params, mp=1), prompts)
-        self.assertEqual(t1, _serve(eng, prompts))
-
-    @pytest.mark.slow
     def test_paged_generate_mp2_identity(self):
         """Model-level API: build_paged_generate(serving_mp=2) is
         byte-identical to the single-chip program."""

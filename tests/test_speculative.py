@@ -535,7 +535,7 @@ class TestSmokeSubprocess(unittest.TestCase):
         env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=1"
         proc = subprocess.run(
             [sys.executable, "-m", "paddle_tpu.serving.speculative",
-             "--requests", "4", "--max-new", "8"],
+             "--requests", "4", "--max-new", "16"],
             capture_output=True, text=True, timeout=600, env=env,
             cwd=os.path.dirname(os.path.dirname(os.path.abspath(
                 __file__))))
