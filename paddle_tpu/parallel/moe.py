@@ -23,10 +23,11 @@ that fall on held experts by expert and hands them to the grouped matmul
 (kernels/grouped_matmul.py) with the group sizes. No capacity exists and no
 token is dropped: a held expert computes every row routed to it, and what the
 experts held elsewhere would add is left out (on one chip the layer runs
-without its exchange). It returns its result with the step's counters
-(`moe.rows_held`, `moe.rows_routed`, `moe.rows_multiplied`, `moe.load_max`,
-`moe.load_mean`, `moe.rows_dropped`, `moe.load`), computed on the device, and
-the router's choice (`moe.choice`).
+without its exchange). Rows move between tokens and buffer in proportion to
+the rows in use (kernels/moe_rows.py). It returns its result with the step's
+counters (`moe.rows_held`, `moe.rows_routed`, `moe.rows_multiplied`,
+`moe.rows_moved`, `moe.load_max`, `moe.load_mean`, `moe.rows_dropped`,
+`moe.load`), computed on the device, and the router's choice (`moe.choice`).
 
 A layer that holds a share of the experts does not train its router's weight:
 the gates' gradient needs every chosen expert's output, which the exchange
@@ -35,6 +36,7 @@ either way.
 """
 from __future__ import annotations
 
+import functools
 from typing import Callable, List, Optional
 
 import jax
@@ -359,7 +361,7 @@ def _rows_out_fwd(ys, gates, row_token, dest, row_gate):
 def _rows_out_bwd(res, dy):
     ys, row_token, dest, row_gate = res
     dys = jnp.take(dy, row_token, axis=0, mode="fill", fill_value=0) \
-        * row_gate[:, None].astype(dy.dtype)
+        .astype(jnp.float32) * row_gate[:, None]
     picked = jnp.take(ys, dest.reshape(-1), axis=0, mode="fill",
                       fill_value=0).reshape(dest.shape + ys.shape[1:])
     dgates = jnp.einsum("td,tkd->tk", dy, picked,
@@ -370,12 +372,110 @@ def _rows_out_bwd(res, dy):
 _rows_out.defvjp(_rows_out_fwd, _rows_out_bwd)
 
 
+# ---------------------------------------------------------------------------
+# the same four movements as kernels that walk the rows that exist
+# (kernels/moe_rows.py); the jnp forms above stay as the tests' oracle and for
+# shapes off the kernels' tiles (the CPU tests' tiny models)
+# ---------------------------------------------------------------------------
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _rows_in_vjp(x, dest_t, chunks, rows, groups, on_tpu):
+    from ..kernels import moe_rows
+
+    # the buffer twice, for its two readers: their cotangents then reach
+    # the backward pass apart, and no pass over the buffer adds them
+    xs = moe_rows.scatter_rows(x, dest_t, None, chunks, rows, groups,
+                               name="moe_rows_in")
+    return xs, xs
+
+
+def _rows_in_vjp_fwd(x, dest_t, chunks, rows, groups, on_tpu):
+    return (_rows_in_vjp(x, dest_t, chunks, rows, groups, on_tpu),
+            (dest_t, chunks))
+
+
+def _rows_in_vjp_bwd(rows, groups, on_tpu, res, dxs):
+    from ..kernels import moe_rows
+
+    dest_t, chunks = res
+    return (moe_rows.gather_rows(dxs, dest_t, None, chunks,
+                                 name="moe_rows_in_bwd"), None, None)
+
+
+_rows_in_vjp.defvjp(_rows_in_vjp_fwd, _rows_in_vjp_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _rows_out_vjp(ys, gates_t, dest_t, chunks, groups, train_gates, on_tpu):
+    from ..kernels import moe_rows
+
+    return moe_rows.gather_rows((ys,), dest_t, gates_t, chunks,
+                                name="moe_rows_out")
+
+
+def _rows_out_vjp_fwd(ys, gates_t, dest_t, chunks, groups, train_gates,
+                      on_tpu):
+    return (_rows_out_vjp(ys, gates_t, dest_t, chunks, groups, train_gates,
+                          on_tpu), (ys, gates_t, dest_t, chunks))
+
+
+def _rows_out_vjp_bwd(groups, train_gates, on_tpu, res, dy):
+    from ..kernels import moe_rows
+
+    ys, gates_t, dest_t, chunks = res
+    dys = moe_rows.scatter_rows(dy, dest_t, gates_t, chunks, ys.shape[0],
+                                groups, name="moe_rows_out_bwd")
+    # under a share of the experts the gates are constants (dropless_experts)
+    dgates_t = moe_rows.gather_dots(ys, dy, dest_t, chunks) if train_gates \
+        else jnp.zeros_like(gates_t)
+    return dys, dgates_t, None, None
+
+
+_rows_out_vjp.defvjp(_rows_out_vjp_fwd, _rows_out_vjp_bwd)
+
+# the blocks of a model share one trace and one private function of the
+# lowered module, so a step's text holds each kernel's payload once (PERF.md
+# section 6, PR 29); what the trace depends on beside its operands
+# (`jax.default_backend()`) is an argument, so it is part of the jit's key
+_rows_in_jit = jax.jit(_rows_in_vjp, static_argnums=(3, 4, 5))
+_rows_out_jit = jax.jit(_rows_out_vjp, static_argnums=(4, 5, 6))
+
+
+@functools.partial(jax.jit, static_argnums=(1, 2, 3, 4))
+def _route(idx, held, num_experts: int, rows: int, chunked: bool):
+    """Where each assignment of `idx` [T, k] goes in a buffer of `rows` rows:
+    (the groups' layout, dest [T, k] — `rows` where the expert is held
+    elsewhere —, the token tiles' chunk lists if `chunked`, the assignments
+    on held experts). One jit: a model's blocks share its trace.
+
+    A counting sort, stable: an assignment's row is its group's start plus
+    the assignments of its group before it, so inside a group the rows are
+    in token order (kernels/moe_rows.py rests on that)."""
+    from ..kernels import moe_rows
+    from ..kernels.grouped_matmul import group_layout
+
+    t, k = idx.shape
+    g = len(held)
+    local = jnp.full((num_experts,), g, jnp.int32).at[
+        jnp.asarray(held, jnp.int32)].set(jnp.arange(g, dtype=jnp.int32))
+    lid = local[idx.reshape(-1)]                    # [T*k]; g = held elsewhere
+    mine = lid[:, None] == jnp.arange(g, dtype=jnp.int32)   # [T*k, G]
+    upto = jnp.cumsum(mine.astype(jnp.int32), axis=0)
+    layout = group_layout(upto[-1], rows)
+    dest = jnp.where(lid < g, jnp.sum(jnp.where(
+        mine, layout.starts + upto - 1, 0), axis=1), rows)
+    tile = moe_rows.TOKEN_TILE * k
+    chunks = moe_rows.tile_chunks(upto[tile - 1::tile], layout, k) \
+        if chunked else None
+    return layout, dest.reshape(t, k), chunks, jnp.sum(upto[-1])
+
+
 # what a dropless layer counts in a step, on the device (observability/README);
 # the last is no counter but the router's choice itself [T, k], for a check
 # that holds the layer to a reference token by token
 MOE_COUNTERS = ("moe.rows_held", "moe.rows_routed", "moe.rows_multiplied",
-                "moe.rows_dropped", "moe.load_max", "moe.load_mean",
-                "moe.load", "moe.choice")
+                "moe.rows_dropped", "moe.rows_moved", "moe.load_max",
+                "moe.load_mean", "moe.load", "moe.choice")
 
 
 def dropless_experts(x, idx, gates, w_gate, w_up, w_down, held,
@@ -387,9 +487,11 @@ def dropless_experts(x, idx, gates, w_gate, w_up, w_down, held,
 
     The assignments that fall on held experts are sorted by expert into a
     buffer in which every expert's rows start on a row tile (sized for the
-    worst case, every token on `min(k, G)` held experts: the grouped matmul
-    walks only the tiles in use); the rest are not computed, here or
-    anywhere on this chip.
+    worst case, every token on `min(k, G)` held experts); the rest are not
+    computed, here or anywhere on this chip. Everything that touches the
+    buffer walks the rows in use: the grouped matmul its row tiles, the row
+    movements (kernels/moe_rows.py) the 16-row chunks that hold a token
+    tile's rows. Row tiles no expert uses are neither written nor read.
 
     Where `held` is a share of the experts, the gates are constants of the
     backward pass: a gate's gradient needs the outputs of every expert its
@@ -401,41 +503,54 @@ def dropless_experts(x, idx, gates, w_gate, w_up, w_down, held,
     token. So the router's weight trains where the layer holds every expert,
     and waits for the exchange where it holds a share; the bias rule, which
     needs counts alone, runs in both."""
+    from ..kernels import moe_rows
     from ..kernels.grouped_matmul import (ROW_TILE, buffer_rows,
-                                          group_layout, grouped_matmul)
+                                          grouped_matmul)
 
     t, k = idx.shape
     g = len(held)
     if g < num_experts:
         gates = jax.lax.stop_gradient(gates)
     rows = buffer_rows(t * min(k, g), g)
-    local = jnp.full((num_experts,), g, jnp.int32).at[
-        jnp.asarray(held, jnp.int32)].set(jnp.arange(g, dtype=jnp.int32))
-    lid = local[idx.reshape(-1)]                    # [T*k]; g = held elsewhere
-    order = jnp.argsort(lid, stable=True).astype(jnp.int32)
-    lid_sorted = lid[order]
-    sizes = jnp.bincount(lid, length=g + 1)[:g].astype(jnp.int32)
-    layout = group_layout(sizes, rows)
-    # unpadded and padded start of each group; one more entry for "elsewhere"
-    first = jnp.concatenate([jnp.cumsum(sizes) - sizes,
-                             jnp.zeros((1,), jnp.int32)])
-    starts = jnp.concatenate([layout.starts,
-                              jnp.full((1,), rows, jnp.int32)])
-    rank = jnp.arange(t * k, dtype=jnp.int32) - first[lid_sorted]
-    dest_sorted = jnp.where(lid_sorted < g, starts[lid_sorted] + rank, rows)
-    dest = jnp.full((t * k,), rows, jnp.int32).at[order].set(dest_sorted)
-    row_assign = jnp.full((rows,), t * k, jnp.int32).at[dest_sorted].set(
-        order, mode="drop")
-    row_token = jnp.where(row_assign < t * k, row_assign // k, t)
-    row_gate = jnp.take(gates.reshape(-1), row_assign, mode="fill",
-                        fill_value=0)
-    dest = dest.reshape(t, k)
+    chunked = moe_rows.rows_ok(t, x.shape[1], w_gate.shape[2], rows)
+    layout, dest, chunks, on_held = _route(
+        idx, tuple(int(e) for e in held), num_experts, rows, chunked)
 
-    xs = _rows_in(x, row_token, dest)
-    act = jax.nn.silu(grouped_matmul(xs, w_gate, layout)) \
-        * grouped_matmul(xs, w_up, layout)
+    if chunked:
+        on_tpu = jax.default_backend() == "tpu"
+        dest_t = dest.T
+        gates_t = gates.astype(jnp.float32).T
+
+        def rows_in(x):
+            return _rows_in_jit(x, dest_t, chunks, rows, g, on_tpu)
+
+        def rows_out(ys):
+            return _rows_out_jit(ys, gates_t, dest_t, chunks, g,
+                                 g == num_experts, on_tpu)
+
+        moved = (jnp.sum(chunks.n_read) + layout.n_tiles
+                 * (ROW_TILE // moe_rows.CHUNK)) * moe_rows.CHUNK
+    else:
+        row_assign = jnp.full((rows,), t * k, jnp.int32).at[
+            dest.reshape(-1)].set(jnp.arange(t * k, dtype=jnp.int32),
+                                  mode="drop")
+        row_token = jnp.where(row_assign < t * k, row_assign // k, t)
+        row_gate = jnp.take(gates.reshape(-1), row_assign, mode="fill",
+                            fill_value=0)
+
+        def rows_in(x):
+            return (_rows_in(x, row_token, dest),) * 2
+
+        def rows_out(ys):
+            return _rows_out(ys, gates, row_token, dest, row_gate)
+
+        moved = jnp.asarray(rows + t * k, jnp.int32)
+
+    xs_gate, xs_up = rows_in(x)
+    act = jax.nn.silu(grouped_matmul(xs_gate, w_gate, layout)) \
+        * grouped_matmul(xs_up, w_up, layout)
     ys = grouped_matmul(act, w_down, layout)
-    y = _rows_out(ys, gates, row_token, dest, row_gate)
+    y = rows_out(ys)
 
     load = jnp.bincount(idx.reshape(-1), length=num_experts)
     live = jnp.sum(layout.tile_rows)
@@ -443,9 +558,10 @@ def dropless_experts(x, idx, gates, w_gate, w_up, w_down, held,
         "moe.rows_held": live,
         "moe.rows_routed": jnp.asarray(t * k, jnp.int32),
         "moe.rows_multiplied": layout.n_tiles * ROW_TILE,
-        "moe.rows_dropped": jnp.sum(lid < g).astype(jnp.int32) - live,
-        "moe.load_max": jnp.max(sizes),
-        "moe.load_mean": jnp.mean(sizes.astype(jnp.float32)),
+        "moe.rows_dropped": on_held - live,
+        "moe.rows_moved": moved.astype(jnp.int32),
+        "moe.load_max": jnp.max(layout.sizes),
+        "moe.load_mean": jnp.mean(layout.sizes.astype(jnp.float32)),
         "moe.load": load.astype(jnp.float32),
         "moe.choice": idx,
     }

@@ -13,6 +13,7 @@ the workers different collections (the whole suite then counts 0). Nothing
 here runs at import time; shardings and shapes are built in fixtures/tests.
 """
 import importlib
+import re
 
 import jax
 import jax.numpy as jnp
@@ -291,6 +292,35 @@ def test_grouped_matmul(for_chip, one_chip, rows, k, n):
                  "grouped_matmul_drhs"):
         assert f"{name}" in text, name
     assert text.count("tpu_custom_call") >= 3
+
+
+@pytest.mark.parametrize("held,experts", [(8, 64), (8, 8)],
+                         ids=["a_share_held", "all_held"])
+def test_moe_rows(for_chip, one_chip, held, experts):
+    """The expert layer's row movements at the expert cell's sizes (8192 x
+    2048 tokens, 4 choices, 33,792 buffer rows, bf16) beside the grouped
+    matmul they feed: forward and backward, with a share of the experts held
+    (the cell) and with all of them (the gates train: `moe_rows_dgates`)."""
+    from paddle_tpu.parallel import moe
+
+    t, d, f, k = 8192, 2048, 1536, 4
+
+    def loss(x, gates, wg, wu, wd, idx):
+        y, _ = moe.dropless_experts(x, idx, gates, wg, wu, wd,
+                                    tuple(range(held)), experts)
+        return jnp.square(y.astype(F32)).sum()
+
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2, 3, 4)), one_chip,
+                    ((t, d), BF), ((t, k), F32), ((held, d, f), BF),
+                    ((held, d, f), BF), ((held, f, d), BF), ((t, k), I32))
+    names = ["moe_rows_in", "moe_rows_out", "moe_rows_out_bwd",
+             "moe_rows_in_bwd"] + ["moe_rows_dgates"] * (held == experts)
+    for name in names:
+        assert f'"{name}"' in text or f"{name}" in text, name
+    assert ("moe_rows_dgates" in text) == (held == experts)
+    assert text.count("tpu_custom_call") >= 9 + len(names)
+    # nothing outside the kernels walks every (token, choice) pair's row
+    assert not re.search(r"(bf16|f32)\[32768,2048\]", text)
 
 
 def test_swiglu_fused_refuses_the_1b_mlp_shape():

@@ -51,6 +51,13 @@ def _grouped_grad(lhs, rhs, sizes):
         argnums=(0, 1))(lhs, rhs)
 
 
+def _moe_rows_grad(x, gates, wg, wu, wd, idx):
+    moe = importlib.import_module("paddle_tpu.parallel.moe")
+    return jax.grad(lambda x, gates, wg, wu, wd: moe.dropless_experts(
+        x, idx, gates, wg, wu, wd, (0, 1, 2, 3), 4)[0].astype(F32).sum(),
+        argnums=(0, 1, 2, 3, 4))(x, gates, wg, wu, wd)
+
+
 def _with_scales(fn):
     return lambda *a: fn(*a[:-2], k_scale=a[-2], v_scale=a[-1])
 
@@ -68,6 +75,17 @@ CASES = {
     "grouped_matmul": (
         _grouped_grad, [((1024, 256), BF), ((4, 256, 128), BF), ((4,), I32)],
         {"grouped_matmul": "grouped_matmul",
+         "grouped_matmul_dlhs": "grouped_matmul",
+         "grouped_matmul_drhs": "grouped_matmul"}),
+    # the expert layer with every expert held: the gates train too
+    "moe_rows": (
+        _moe_rows_grad,
+        [((256, 128), BF), ((256, 2), F32), ((4, 128, 256), BF),
+         ((4, 128, 256), BF), ((4, 256, 128), BF), ((256, 2), I32)],
+        {"moe_rows_in": "moe_rows", "moe_rows_out": "moe_rows",
+         "moe_rows_out_bwd": "moe_rows", "moe_rows_in_bwd": "moe_rows",
+         "moe_rows_dgates": "moe_rows",
+         "grouped_matmul": "grouped_matmul",
          "grouped_matmul_dlhs": "grouped_matmul",
          "grouped_matmul_drhs": "grouped_matmul"}),
     "rms_norm": (
@@ -184,7 +202,7 @@ def test_no_pallas_call_in_kernels_lacks_a_name():
                       for node in ast.walk(tree)
                       if isinstance(node, ast.Call)
                       and getattr(node.func, "attr", "") == "pallas_call"]
-    assert len(calls) == 15     # the call sites CASES covers
+    assert len(calls) == 18     # the call sites CASES covers
     assert [c[:2] for c in calls if "name" not in c[2]] == []
 
 
