@@ -570,12 +570,24 @@ def _gqa_softmax_step(q, k, v, live, carry, scale, k_sc=None, v_sc=None):
     return m_new, l_new, acc * corr + pv
 
 
+def _first_live_page(length, page: int, window):
+    """The table column of the oldest position a query at `length` sees:
+    0, or under a window of `window` positions (the query's own included)
+    the page of position `length - window + 1`."""
+    if window is None:
+        return 0
+    return jnp.maximum(length - (window - 1), 0) // page
+
+
 def _paged_gqa_body(tables_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf,
-                    v_buf, sem, cur_ref, *, scale: float, ksc_hbm=None,
-                    vsc_hbm=None, ksc_buf=None, vsc_buf=None):
+                    v_buf, sem, cur_ref, *, scale: float, window=None,
+                    ksc_hbm=None, vsc_hbm=None, ksc_buf=None, vsc_buf=None):
     """Paged grouped-query decode, grid (B,): one grid step is one slot,
     and inside it a loop visits the slot's LIVE pages only —
-    `lens[b] // page + 1` of them, `pps` (= k_buf.shape[1]) a step.
+    `lens[b] // page + 1` of them, `pps` (= k_buf.shape[1]) a step. Under
+    a `window` the loop starts at the page of position `lens[b] - window
+    + 1` (`_first_live_page`): pages wholly behind the window are never
+    fetched, and the mask cuts inside the first one.
 
     The pools stay in HBM; a step's pages, every kv head of each (one
     contiguous [Hkv, page, D] slab), are fetched by async copies into
@@ -607,11 +619,14 @@ def _paged_gqa_body(tables_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf,
     def last_page(slot):                   # the slot's last live column
         return jnp.minimum(len_ref[slot] // page, w - 1)
 
+    def first_page(slot):
+        return _first_live_page(len_ref[slot], page, window)
+
     def copies(slot, step, buf, act):
         """Start or wait for the copies of a step's live pages."""
         last = last_page(slot)
         for i in range(pps):
-            col = step * pps + i
+            col = first_page(slot) + step * pps + i
 
             @pl.when(col <= last)
             def _live(i=i, col=col):
@@ -643,7 +658,8 @@ def _paged_gqa_body(tables_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf,
 
     offset, col_page = _step_columns(hq, hkv, page, pps)
     valid_until = len_ref[b]
-    n_steps = last_page(b) // pps + 1
+    first = first_page(b)
+    n_steps = (last_page(b) - first) // pps + 1
 
     def step_fn(step, carry):
         *state, cur = carry
@@ -660,11 +676,13 @@ def _paged_gqa_body(tables_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf,
             k_sc, v_sc = (
                 _head_scales([buf[cur, i] for i in range(pps)], hq, hkv,
                              col_page) for buf in (ksc_buf, vsc_buf))
+        base = (first + step * pps) * page   # the step's first position
+        live = offset <= valid_until - base
+        if window is not None:
+            live &= offset > valid_until - window - base
         state = _gqa_softmax_step(
             q_ref[0], k_buf[cur].reshape(rows, d),
-            v_buf[cur].reshape(rows, d),
-            offset <= valid_until - step * (pps * page), state, scale,
-            k_sc, v_sc)
+            v_buf[cur].reshape(rows, d), live, state, scale, k_sc, v_sc)
         return *state, 1 - cur
 
     _, l_fin, acc, cur = jax.lax.fori_loop(
@@ -677,17 +695,19 @@ def _paged_gqa_body(tables_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf,
 
 
 def _paged_gqa_kernel(tables_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
-                      k_buf, v_buf, sem, cur_ref, *, scale: float):
+                      k_buf, v_buf, sem, cur_ref, *, scale: float,
+                      window=None):
     _paged_gqa_body(tables_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf,
-                    v_buf, sem, cur_ref, scale=scale)
+                    v_buf, sem, cur_ref, scale=scale, window=window)
 
 
 def _paged_gqa_q8_kernel(tables_ref, len_ref, q_ref, k_hbm, v_hbm, ksc_hbm,
                          vsc_hbm, o_ref, k_buf, v_buf, sem, cur_ref,
-                         ksc_buf, vsc_buf, *, scale: float):
+                         ksc_buf, vsc_buf, *, scale: float, window=None):
     _paged_gqa_body(tables_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf,
-                    v_buf, sem, cur_ref, scale=scale, ksc_hbm=ksc_hbm,
-                    vsc_hbm=vsc_hbm, ksc_buf=ksc_buf, vsc_buf=vsc_buf)
+                    v_buf, sem, cur_ref, scale=scale, window=window,
+                    ksc_hbm=ksc_hbm, vsc_hbm=vsc_hbm, ksc_buf=ksc_buf,
+                    vsc_buf=vsc_buf)
 
 
 # rows of an int8 pool's scales one block of the listed kernel holds
@@ -695,7 +715,8 @@ _SCALE_ROWS = 8
 
 
 def _paged_gqa_list_kernel(tables_ref, len_ref, slot_ref, col_ref, n_ref,
-                           q_ref, k_ref, v_ref, *rest, scale: float):
+                           q_ref, k_ref, v_ref, *rest, scale: float,
+                           window=None):
     """Paged grouped-query decode where the head dim is not a whole
     number of lane tiles (a copy cannot slice such a page out of the
     pool, so `_paged_gqa_body` does not lower): grid (N,) over the
@@ -716,7 +737,7 @@ def _paged_gqa_list_kernel(tables_ref, len_ref, slot_ref, col_ref, n_ref,
     _, hkv, page, d = k_ref.shape
     valid_until = len_ref[slot]
 
-    @pl.when(listed & (col == 0))
+    @pl.when(listed & (col == _first_live_page(valid_until, page, window)))
     def _init():
         m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
@@ -725,10 +746,12 @@ def _paged_gqa_list_kernel(tables_ref, len_ref, slot_ref, col_ref, n_ref,
     @pl.when(listed)
     def _step():
         offset, col_page = _step_columns(hq, hkv, page, 1)
+        live = offset <= valid_until - col * page
+        if window is not None:
+            live &= offset > valid_until - window - col * page
         m_scr[...], l_scr[...], acc_scr[...] = _gqa_softmax_step(
             q_ref[0], k_ref[0].reshape(hkv * page, d),
-            v_ref[0].reshape(hkv * page, d),
-            offset <= valid_until - col * page,
+            v_ref[0].reshape(hkv * page, d), live,
             (m_scr[...], l_scr[...], acc_scr[...]), scale,
             *(_head_scales(
                 [sc[pl.ds(tables_ref[slot, col] % _SCALE_ROWS, 1), :]],
@@ -740,21 +763,25 @@ def _paged_gqa_list_kernel(tables_ref, len_ref, slot_ref, col_ref, n_ref,
         o_ref[0] = (acc_scr[...] / l_scr[...]).astype(o_ref.dtype)
 
 
-def _live_page_list(lens, page: int, w: int, n: int):
+def _live_page_list(lens, page: int, w: int, n: int, window=None):
     """The live (slot, table column) pairs in order, padded to `n` with
     the last pair, and their count: what `_paged_gqa_list_kernel`'s
-    grid walks."""
-    n_live = jnp.minimum(lens // page, w - 1) + 1            # [B]
+    grid walks. Under a `window` a slot's columns start at the window's
+    first page."""
+    first = _first_live_page(lens, page, window)             # [B] (or 0)
+    n_live = jnp.minimum(lens // page, w - 1) + 1 - first    # [B]
     ends = jnp.cumsum(n_live)
     item = jnp.minimum(jnp.arange(n, dtype=jnp.int32), ends[-1] - 1)
     slot = jnp.searchsorted(ends, item, side="right").astype(jnp.int32)
-    return slot, item - (ends - n_live)[slot], ends[-1:]
+    return (slot, item - (ends - n_live)[slot]
+            + jnp.broadcast_to(first, lens.shape)[slot], ends[-1:])
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+@functools.partial(jax.jit,
+                   static_argnames=("scale", "interpret", "window"))
 def _paged_decode_gqa(q, key_cache, value_cache, block_tables, lens,
                       k_scale=None, v_scale=None, *, scale: float,
-                      interpret: bool):
+                      interpret: bool, window=None):
     """Launch the paged GQA decode. Head dims of whole lane tiles take
     `_paged_gqa_body`: q and the output ride BlockSpecs (one slot's
     [Hq, D] a grid step), the rank-4 pools — and an int8 pool's scales —
@@ -780,12 +807,16 @@ def _paged_decode_gqa(q, key_cache, value_cache, block_tables, lens,
         operands += [jnp.pad(k_scale.astype(jnp.float32), pad),
                      jnp.pad(v_scale.astype(jnp.float32), pad)]
     name = (CONSTRAINT_Q8 if quant else CONSTRAINT).name
+    if window is not None:
+        # its own label in the device trace: the windowed layers' reads
+        # are a different quantity from the full layers'
+        name += "_window"
     out_shape = jax.ShapeDtypeStruct((b, hq, d), q.dtype)
     # both carry state from one grid step to the next: in order
     params = pltpu.CompilerParams(dimension_semantics=("arbitrary",))
 
     if d % LANE:
-        slot, col, n_items = _live_page_list(lens, page, w, b * w)
+        slot, col, n_items = _live_page_list(lens, page, w, b * w, window)
 
         def slot_map(i, tbl, lens_, slot_, col_, n_):
             return (slot_[i], 0, 0)
@@ -797,7 +828,8 @@ def _paged_decode_gqa(q, key_cache, value_cache, block_tables, lens,
             return (tbl[slot_[i], col_[i]] // _SCALE_ROWS, 0)
 
         return pl.pallas_call(
-            functools.partial(_paged_gqa_list_kernel, scale=scale),
+            functools.partial(_paged_gqa_list_kernel, scale=scale,
+                              window=window),
             name=name,
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=5,
@@ -815,7 +847,9 @@ def _paged_decode_gqa(q, key_cache, value_cache, block_tables, lens,
             interpret=interpret,
         )(tables, lens, slot, col, n_items, *operands)
 
-    pps = _pages_per_step(w, hkv, page, d, key_cache.dtype.itemsize)
+    # a window's pages are few: no wider a step than they are
+    w_live = w if window is None else min(w, -(-(window - 1) // page) + 1)
+    pps = _pages_per_step(w_live, hkv, page, d, key_cache.dtype.itemsize)
 
     def slot_map(b_, tbl, lens_):
         return (b_, 0, 0)
@@ -824,7 +858,7 @@ def _paged_decode_gqa(q, key_cache, value_cache, block_tables, lens,
     return pl.pallas_call(
         functools.partial(
             _paged_gqa_q8_kernel if quant else _paged_gqa_kernel,
-            scale=scale),
+            scale=scale, window=window),
         name=name,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
@@ -848,7 +882,8 @@ def paged_decode_attention(q: jax.Array, key_cache: jax.Array,
                            lens: jax.Array,
                            scale: float | None = None, *,
                            k_scale: jax.Array | None = None,
-                           v_scale: jax.Array | None = None) -> jax.Array:
+                           v_scale: jax.Array | None = None,
+                           window: int | None = None) -> jax.Array:
     """One decode step over a paged cache (reference: block_attn.h).
 
     q: [B, Hq, D]; key_cache/value_cache: [max_pages, Hkv, block_size, D]
@@ -858,6 +893,12 @@ def paged_decode_attention(q: jax.Array, key_cache: jax.Array,
     ids covering positions [0, n_blocks*block_size); lens: [B]
     previous-token counts (current token already written at position
     lens[b]). Returns [B, Hq, D].
+
+    `window` (a layer of sliding-window attention): the query at position
+    lens[b] sees positions (lens[b] - window, lens[b]] only; the live-page
+    loop starts at the window's first page, so a table column behind the
+    window is never read — it may name a page that has since been given to
+    a later position (a per-sequence ring: column j -> ring page j % R).
 
     int8 pools (``FLAGS_kv_cache_dtype=int8``): pass the per-(page, kv
     head) f32 absmax scale arrays as ``k_scale``/``v_scale``
@@ -884,16 +925,18 @@ def paged_decode_attention(q: jax.Array, key_cache: jax.Array,
             "garbage (TPU103 lints this)")
     if not quant and (k_scale is not None or v_scale is not None):
         raise ValueError("k_scale/v_scale only apply to int8 KV pools")
-    if h != hkv or d % LANE:
+    if window is not None and window < 1:
+        raise ValueError(f"window {window} must be at least 1")
+    if h != hkv or d % LANE or window is not None:
         # grouped queries — or narrow head dims, where the equal-heads
         # kernel's [H, 1, D] broadcast fails to lower (see
         # decode_attention); the grouped kernel's 2-D dots cover
-        # group=1 too
+        # group=1 too, and it alone knows a window
         if h % hkv:
             raise ValueError(f"Hq {h} not a multiple of Hkv {hkv}")
         return _paged_decode_gqa(q, key_cache, value_cache, block_tables,
                                  lens, k_scale, v_scale, scale=scale,
-                                 interpret=not _on_tpu())
+                                 interpret=not _on_tpu(), window=window)
     block_size = key_cache.shape[2]
     n_blocks = block_tables.shape[1]
     in_specs = [

@@ -248,7 +248,7 @@ def _prefix_prefill_q8_kernel(tbl_ref, plen_ref, slen_ref, q_ref, kp_ref,
                               vp_ref, ksc_ref, vsc_ref, ks_ref, vs_ref,
                               o_ref, m_scr, l_scr, acc_scr, *, page: int,
                               block_q: int, block_s: int, group: int,
-                              w_pre: int, scale: float):
+                              w_pre: int, scale: float, window=None):
     """int8-pool prefix prefill: `_prefix_prefill_kernel`'s grid where
     each prefix-phase step streams the int8 (kv head, page) tile PLUS
     its (1, 1) f32 absmax scale, rescaling scores and weighted values
@@ -259,14 +259,16 @@ def _prefix_prefill_q8_kernel(tbl_ref, plen_ref, slen_ref, q_ref, kp_ref,
                            vp_ref, ks_ref, vs_ref, o_ref, m_scr, l_scr,
                            acc_scr, page=page, block_q=block_q,
                            block_s=block_s, group=group, w_pre=w_pre,
-                           scale=scale, ksc_ref=ksc_ref, vsc_ref=vsc_ref)
+                           scale=scale, ksc_ref=ksc_ref, vsc_ref=vsc_ref,
+                           window=window)
 
 
 def _prefix_prefill_kernel(tbl_ref, plen_ref, slen_ref, q_ref, kp_ref,
                            vp_ref, ks_ref, vs_ref, o_ref, m_scr, l_scr,
                            acc_scr, *, page: int, block_q: int,
                            block_s: int, group: int, w_pre: int,
-                           scale: float, ksc_ref=None, vsc_ref=None):
+                           scale: float, ksc_ref=None, vsc_ref=None,
+                           window=None):
     """Grid (b, nkv, nq, j) with j the kv streaming axis: j < w_pre
     streams prefix page tbl[b, j] from the pool, j >= w_pre streams
     in-suffix block j - w_pre. Blocks: q/out [block_q*group, dh]
@@ -274,7 +276,12 @@ def _prefix_prefill_kernel(tbl_ref, plen_ref, slen_ref, q_ref, kp_ref,
     r % group), pool tiles [page, dh], suffix tiles [block_s, dh].
     Online softmax carries across j; scratch re-inits at j == 0.
     `ksc_ref`/`vsc_ref` (int8 pools, via `_prefix_prefill_q8_kernel`)
-    carry the streamed page's f32 absmax scale."""
+    carry the streamed page's f32 absmax scale. Under a `window`
+    (kernels/ragged_attention.py) query position t sees key positions
+    (t - window, t]: prefix step j streams table column `first + j`, the
+    page of the oldest position the row's FIRST query sees, so the prefix
+    axis is only as long as a window's pages and no column behind the
+    window is read."""
     b = pl.program_id(0)
     qi = pl.program_id(2)
     j = pl.program_id(3)
@@ -289,6 +296,8 @@ def _prefix_prefill_kernel(tbl_ref, plen_ref, slen_ref, q_ref, kp_ref,
     plen = plen_ref[b]
     slen = slen_ref[b]
     q_start = qi * block_q
+    first = 0 if window is None \
+        else jnp.maximum(plen - (window - 1), 0) // page
 
     def qpos(t):
         # row r of the tile is query position q_start + r // group
@@ -311,7 +320,7 @@ def _prefix_prefill_kernel(tbl_ref, plen_ref, slen_ref, q_ref, kp_ref,
         l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
 
     # ---- prefix phase: one pool page per step, masked by prefix_lens
-    @pl.when((j < w_pre) & (j * page < plen) & (q_start < slen))
+    @pl.when((j < w_pre) & ((first + j) * page < plen) & (q_start < slen))
     def _prefix():
         q = q_ref[0].astype(jnp.float32)
         k = kp_ref[0].astype(jnp.float32)
@@ -322,9 +331,13 @@ def _prefix_prefill_kernel(tbl_ref, plen_ref, slen_ref, q_ref, kp_ref,
             # int8 page tile: one scalar multiply folds the page's
             # absmax scale into the scores (uniform over the tile)
             s = s * ksc_ref[0, 0]
-        kpos = j * page + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where((kpos < plen) & (qpos(s.shape[1]) < slen),
-                      s, _NEG_INF)
+        kpos = (first + j) * page \
+            + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        qp = qpos(s.shape[1])
+        seen = (kpos < plen) & (qp < slen)
+        if window is not None:
+            seen &= plen + qp - kpos < window
+        s = jnp.where(seen, s, _NEG_INF)
         v = vp_ref[0].astype(jnp.float32)
         if vsc_ref is not None:
             v = v * vsc_ref[0, 0]
@@ -345,8 +358,10 @@ def _prefix_prefill_kernel(tbl_ref, plen_ref, slen_ref, q_ref, kp_ref,
         kpos = (j - w_pre) * block_s + jax.lax.broadcasted_iota(
             jnp.int32, s.shape, 1)
         qp = qpos(s.shape[1])
-        s = jnp.where((kpos <= qp) & (kpos < slen) & (qp < slen),
-                      s, _NEG_INF)
+        seen = (kpos <= qp) & (kpos < slen) & (qp < slen)
+        if window is not None:
+            seen &= qp - kpos < window
+        s = jnp.where(seen, s, _NEG_INF)
         accum(s, vs_ref[0].astype(jnp.float32))
 
     @pl.when(j == nj - 1)

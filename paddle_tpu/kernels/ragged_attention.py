@@ -72,7 +72,7 @@ def _ragged_attention_kernel(tbl_ref, clen_ref, nlen_ref, q_ref, kp_ref,
                              vp_ref, ks_ref, vs_ref, o_ref, m_scr, l_scr,
                              acc_scr, *, page: int, block_q: int,
                              block_s: int, group: int, w_pre: int,
-                             scale: float):
+                             scale: float, window=None):
     """The `_prefix_prefill_kernel` grid verbatim — its masks already
     compare raw token counts (``kpos < cached_len`` handles a partial
     last page; ``new_lens`` is positionally `suffix_lens`), so the
@@ -83,14 +83,15 @@ def _ragged_attention_kernel(tbl_ref, clen_ref, nlen_ref, q_ref, kp_ref,
                            vp_ref, ks_ref, vs_ref, o_ref, m_scr, l_scr,
                            acc_scr, page=page, block_q=block_q,
                            block_s=block_s, group=group, w_pre=w_pre,
-                           scale=scale)
+                           scale=scale, window=window)
 
 
 def _ragged_attention_q8_kernel(tbl_ref, clen_ref, nlen_ref, q_ref,
                                 kp_ref, vp_ref, ksc_ref, vsc_ref, ks_ref,
                                 vs_ref, o_ref, m_scr, l_scr, acc_scr, *,
                                 page: int, block_q: int, block_s: int,
-                                group: int, w_pre: int, scale: float):
+                                group: int, w_pre: int, scale: float,
+                                window=None):
     """int8-pool variant: each cached-phase step streams the int8
     (kv head, page) tile plus its (1, 1) f32 absmax scale (the
     `_prefix_prefill_q8_kernel` recurrence)."""
@@ -98,7 +99,8 @@ def _ragged_attention_q8_kernel(tbl_ref, clen_ref, nlen_ref, q_ref,
                               vp_ref, ksc_ref, vsc_ref, ks_ref, vs_ref,
                               o_ref, m_scr, l_scr, acc_scr, page=page,
                               block_q=block_q, block_s=block_s,
-                              group=group, w_pre=w_pre, scale=scale)
+                              group=group, w_pre=w_pre, scale=scale,
+                              window=window)
 
 
 def _check_ragged_attention_shapes(shapes, dtypes):
@@ -172,7 +174,8 @@ def ragged_paged_attention_reference(q: jax.Array, k_new: jax.Array,
                                      new_lens: jax.Array | None = None, *,
                                      scale: float | None = None,
                                      k_scale: jax.Array | None = None,
-                                     v_scale: jax.Array | None = None
+                                     v_scale: jax.Array | None = None,
+                                     window: int | None = None
                                      ) -> jax.Array:
     """The exact masked-softmax math the ragged kernel replaces — and
     the SINGLE source of it: the unified-step fallback path
@@ -188,7 +191,8 @@ def ragged_paged_attention_reference(q: jax.Array, k_new: jax.Array,
     [0, tn] (None = all rows full). New token i of row b sits at
     absolute position cached_lens[b] + i: it sees every cached token
     and the window causally. Rows at window positions >= new_lens[b]
-    return exact ZEROS (matching the kernel — finite, never NaN).
+    return exact ZEROS (matching the kernel — finite, never NaN). Under
+    a `window` position t sees positions (t - window, t] only.
     Returns [b, tn, nh, dh] in f32."""
     b, tn, nh, dh = q.shape
     nkv, page = key_cache.shape[1], key_cache.shape[2]
@@ -227,6 +231,12 @@ def ragged_paged_attention_reference(q: jax.Array, k_new: jax.Array,
     mask = jnp.concatenate(
         [jnp.broadcast_to(cache_valid[:, None, :], (b, tn, P)),
          jnp.broadcast_to(win_valid, (b, tn, tn))], axis=-1)
+    if window is not None:
+        kpos = jnp.concatenate(
+            [jnp.broadcast_to(jnp.arange(P)[None], (b, P)),
+             cached_lens[:, None] + jnp.arange(tn)[None]], axis=1)
+        qpos = cached_lens[:, None] + jnp.arange(tn)[None]
+        mask &= qpos[:, :, None] - kpos[:, None, :] < window
     q5 = q.reshape(b, tn, nkv, group, dh)
     s = jnp.einsum("bsngd,btnd->bsngt", q5.astype(jnp.float32),
                    keys.astype(jnp.float32)) * scale
@@ -253,7 +263,8 @@ def ragged_paged_attention(q: jax.Array, k_new: jax.Array,
                            block_q: int | None = None,
                            block_n: int | None = None,
                            k_scale: jax.Array | None = None,
-                           v_scale: jax.Array | None = None) -> jax.Array:
+                           v_scale: jax.Array | None = None,
+                           window: int | None = None) -> jax.Array:
     """Mixed decode/prefill attention over the paged pools in ONE grid.
 
     Each row attends its `cached_lens[b]` pooled tokens (streamed page
@@ -269,7 +280,13 @@ def ragged_paged_attention(q: jax.Array, k_new: jax.Array,
 
     Explicit `block_q`/`block_n` override `fit_blocks` (must divide
     tn). The window need not be page-granular — only the cached phase
-    streams pool pages."""
+    streams pool pages.
+
+    `window` (a layer of sliding-window attention): position t sees
+    positions (t - window, t]. The cached phase then starts at the page of
+    the oldest position the row's first query sees and is only as long as
+    a window's pages: a table column behind the window is never read (it
+    may name a page since given to a later position — a ring)."""
     b, tn, nh, dh = q.shape
     nkv, page = key_cache.shape[1], key_cache.shape[2]
     w = block_tables.shape[1]
@@ -298,6 +315,11 @@ def ragged_paged_attention(q: jax.Array, k_new: jax.Array,
                          f"the new-token window {tn}")
     if new_lens is None:
         new_lens = jnp.full((b,), tn, jnp.int32)
+    if window is not None:
+        if window < 1:
+            raise ValueError(f"window {window} must be at least 1")
+        # the pages positions [c - window + 1, c) can lie in
+        w = min(w, -(-max(window - 1, 1) // page) + 1)
     nq = tn // block_q
     n_new = tn // block_n
     bqg = block_q * group
@@ -325,7 +347,9 @@ def ragged_paged_attention(q: jax.Array, k_new: jax.Array,
     def pool_map(b_, h, qi, j, tbl, clens, nlens):
         # pad pages — and the whole window phase — pin to the row's
         # last valid page so skipped blocks are never DMA'd
-        jp = jnp.minimum(j, _last_page(clens, b_))
+        first = 0 if window is None \
+            else jnp.maximum(clens[b_] - (window - 1), 0) // page
+        jp = jnp.minimum(first + j, _last_page(clens, b_))
         return (tbl[b_, jp] * nkv + h, 0, 0)
 
     def win_map(b_, h, qi, j, tbl, clens, nlens):
@@ -347,14 +371,18 @@ def ragged_paged_attention(q: jax.Array, k_new: jax.Array,
                           v_scale.astype(jnp.float32).reshape(-1, 1, 1)]
         kernel = functools.partial(
             _ragged_attention_q8_kernel, page=page, block_q=block_q,
-            block_s=block_n, group=group, w_pre=w, scale=scale)
+            block_s=block_n, group=group, w_pre=w, scale=scale,
+            window=window)
     else:
         kernel = functools.partial(
             _ragged_attention_kernel, page=page, block_q=block_q,
-            block_s=block_n, group=group, w_pre=w, scale=scale)
+            block_s=block_n, group=group, w_pre=w, scale=scale,
+            window=window)
     out = pl.pallas_call(
         kernel,
-        name=(CONSTRAINT_Q8 if quant else CONSTRAINT).name,
+        # a windowed layer's call has its own label in the device trace
+        name=(CONSTRAINT_Q8 if quant else CONSTRAINT).name
+        + ("_window" if window is not None else ""),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(b, nkv, nq, w + n_new),

@@ -13,7 +13,8 @@ x[..., d/2:]) when use_neox_rotary_style else interleaved even/odd lanes.
 """
 from __future__ import annotations
 
-from typing import Optional, Tuple
+import math
+from typing import NamedTuple, Optional, Tuple
 
 import jax.numpy as jnp
 
@@ -38,17 +39,59 @@ CONSTRAINT = register_constraint(KernelConstraint(
 ))
 
 
+class YarnScaling(NamedTuple):
+    """YaRN (arXiv:2309.00071) as a published `rope_parameters` group of
+    `rope_type: yarn` states it. Dimension pairs that turn more than
+    `beta_fast` times over the original context keep their frequency, those
+    that turn fewer than `beta_slow` times are slowed by `factor`, a linear
+    ramp between whole pairs; cos and sin carry `attention_factor`."""
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
+
+
+def rope_inv_freq(head_dim: int, base: float = 10000.0,
+                  scaling: Optional[YarnScaling] = None):
+    """(inverse frequencies [D/2] f32, the factor on cos and sin)."""
+    pos_freq = base ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32)
+                        / head_dim)
+    if scaling is None:
+        return 1.0 / pos_freq, 1.0
+
+    def turns_dim(turns):
+        # the (fractional) pair index that turns `turns` times over the
+        # original context
+        return head_dim * math.log(
+            scaling.original_max_position_embeddings
+            / (turns * 2 * math.pi)) / (2 * math.log(base))
+
+    # the ramp's ends, floored and ceiled to whole pairs
+    low = max(math.floor(turns_dim(scaling.beta_fast)), 0)
+    high = min(math.ceil(turns_dim(scaling.beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    inv = ramp / (scaling.factor * pos_freq) + (1.0 - ramp) / pos_freq
+    return inv, float(scaling.attention_factor)
+
+
 def rope_freqs(seq_len: int, head_dim: int, base: float = 10000.0,
-               position_ids=None, dtype=jnp.float32):
+               position_ids=None, dtype=jnp.float32,
+               scaling: Optional[YarnScaling] = None):
     """cos/sin tables [S, D/2] (fp32 for accuracy, cast at apply)."""
-    inv = 1.0 / (base ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32)
-                          / head_dim))
+    inv, factor = rope_inv_freq(head_dim, base, scaling)
     pos = (jnp.arange(seq_len, dtype=jnp.float32)
            if position_ids is None else position_ids.astype(jnp.float32))
     # broadcast multiply, NOT einsum: the outer product would lower to
     # a dot_general and ride the decode step's kernels_per_step count
     freqs = pos[..., None] * inv
-    return jnp.cos(freqs).astype(dtype), jnp.sin(freqs).astype(dtype)
+    cos, sin = jnp.cos(freqs), jnp.sin(freqs)
+    if factor != 1.0:
+        cos, sin = cos * factor, sin * factor
+    return cos.astype(dtype), sin.astype(dtype)
 
 
 def _rotate_neox(x, cos, sin):
@@ -80,17 +123,20 @@ def _rotate_interleaved(x, cos, sin):
 
 def apply_rotary_emb(q, k=None, v=None, sin=None, cos=None,
                      position_ids=None, use_neox_rotary_style: bool = True,
-                     base: float = 10000.0):
+                     base: float = 10000.0,
+                     scaling: Optional[YarnScaling] = None):
     """Apply RoPE to q (and k) in paddle layout [B, S, H, D].
 
     Mirrors fused_rotary_position_embedding(q, k, v, sin, cos, position_ids,
     use_neox_rotary_style): v passes through untouched (kept for signature
-    parity). Returns the same number of tensors it was given.
+    parity). Returns the same number of tensors it was given. `scaling`
+    (with no table given) picks YaRN frequencies over `base`.
     """
     seq = q.shape[1]
     dh = q.shape[-1]
     if cos is None or sin is None:
-        cos, sin = rope_freqs(seq, dh, base=base, position_ids=position_ids)
+        cos, sin = rope_freqs(seq, dh, base=base, position_ids=position_ids,
+                              scaling=scaling)
     else:
         # paddle passes [1, S, 1, D] tables with values duplicated over the
         # two halves; reduce to [S, D/2]. Reduce by EXPLICIT dims — a blind

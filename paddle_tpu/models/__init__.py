@@ -21,6 +21,7 @@ from .bert import (  # noqa: F401
     ErnieConfig, ErnieForMaskedLM, ErnieForSequenceClassification,
     ErnieModel,
 )
+from .mellum import MellumConfig  # noqa: F401
 from .glm4_moe_lite import (  # noqa: F401
     Glm4MoeLiteConfig, Glm4MoeLiteForCausalLM,
     Glm4MoeLitePretrainingCriterion,

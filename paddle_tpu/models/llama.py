@@ -22,7 +22,7 @@ import dataclasses
 import functools
 import math
 from collections import OrderedDict
-from typing import Optional, Tuple
+from typing import Callable, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -35,7 +35,7 @@ from ..nn import functional as F
 from ..nn.layer.layers import Layer
 from ..nn.layer.common import Linear, Embedding
 from ..kernels.rms_norm import rms_norm as _k_rms
-from ..kernels.rope import rope_freqs, apply_rotary_emb
+from ..kernels.rope import YarnScaling, rope_freqs, apply_rotary_emb
 from ..parallel import mesh as mesh_mod
 
 
@@ -752,13 +752,81 @@ def _sample_next(logits, key, do_sample, temperature, top_k, top_p):
     return jax.random.categorical(key, logits, axis=-1)
 
 
+class ServedLayer(NamedTuple):
+    """What the serving program builders know of one decoder layer. The
+    attention block is the same everywhere — rms_norm, q / k / v without
+    bias, rotary over the whole head, grouped causal attention, o — read
+    under `prefix` + `input_layernorm.weight`, `self_attn.{q,k,v,o}_proj
+    .weight`, `post_attention_layernorm.weight`; what differs is here."""
+    prefix: str
+    # None: every earlier position; W: positions (t - W, t], kept in a
+    # per-sequence ring by the engine
+    window: Optional[int]
+    rope_base: float
+    rope_scaling: Optional[YarnScaling]
+    # (x [..., hidden] after the second norm, p, prefix) -> (y, counts):
+    # counts None, or for a routed layer MOE_COUNTS as int32 [4]
+    mlp: Callable
+
+
+class ServedModel(NamedTuple):
+    """A model as `_make_chunk_prefill`, `_make_decode_step` and
+    `_make_head_logits` read it: parameter names, head size, layers."""
+    embed: str
+    norm: str
+    head: Optional[str]         # None: the embedding, transposed
+    head_dim: int
+    layers: Tuple[ServedLayer, ...]
+
+    @property
+    def window_layers(self) -> Tuple[int, ...]:
+        return tuple(i for i, l in enumerate(self.layers)
+                     if l.window is not None)
+
+    @property
+    def routed(self) -> bool:
+        return any(getattr(l.mlp, "routed", False) for l in self.layers)
+
+
+# what a routed layer counts each time it runs (summed by the programs)
+MOE_COUNTS = ("layer_steps", "rows_routed", "experts_hit", "load_max")
+
+
+def _dense_swiglu(x, p, pre):
+    gate = _mm(x, p[pre + "mlp.gate_proj.weight"])
+    up = _mm(x, p[pre + "mlp.up_proj.weight"])
+    return _mm(jax.nn.silu(gate) * up, p[pre + "mlp.down_proj.weight"]), None
+
+
+def served_model(cfg) -> ServedModel:
+    """The contract of `cfg`'s model: its own `served_model()` where the
+    config has one, else the Llama block (one table, dense SwiGLU)."""
+    own = getattr(cfg, "served_model", None)
+    if own is not None:
+        return own()
+    return ServedModel(
+        embed="llama.embed_tokens.weight", norm="llama.norm.weight",
+        head=None if cfg.tie_word_embeddings else "lm_head.weight",
+        head_dim=cfg.head_dim,
+        layers=tuple(ServedLayer(f"llama.layers.{i}.", None, cfg.rope_theta,
+                                 None, _dense_swiglu)
+                     for i in range(cfg.num_hidden_layers)))
+
+
+def _attn_scope(layer: ServedLayer):
+    return jax.named_scope(
+        "attn.window" if layer.window is not None else "attn.full")
+
+
 def _make_head_logits(cfg):
     """LM-head logits over the decode-params dict (quant-aware via _mm;
     tied embeddings stay a dense transpose-matmul)."""
+    model = served_model(cfg)
+
     def head_logits(h, p):
-        if cfg.tie_word_embeddings:
-            return h @ p["llama.embed_tokens.weight"].T
-        return _mm(h, p["lm_head.weight"])
+        if model.head is None:
+            return h @ p[model.embed].T
+        return _mm(h, p[model.head])
     return head_logits
 
 
@@ -985,15 +1053,20 @@ def _make_chunk_prefill(cfg, tn, tp=None):
     before the replicated o-proj — the same one collective per layer
     as every other serving program.
 
+    The layers are `served_model(cfg)`'s: each says whether it attends a
+    window (then `chunk_table` is a pair and the layer reads its ring's
+    table), which rotary table it turns by, and what its MLP is.
+
     Returns prefill(p, kcs, vcs, ids, chunk_table, cached_len,
     new_len) -> (h_final [1, tn, hidden], [(k_i, v_i)]) with
     rotary-applied window K/V [1, tn, nkv_l, dh] per layer — the
-    caller owns the page scatter."""
+    caller owns the page scatter — and, where a layer is routed, the
+    layers' summed MOE_COUNTS as a third result."""
+    model = served_model(cfg)
     nh, nkv, dh = (cfg.num_attention_heads, cfg.num_key_value_heads,
-                   cfg.head_dim)
+                   model.head_dim)
     nh_l = tp.nh_local if tp is not None else nh
     nkv_l = tp.nkv_local if tp is not None else nkv
-    n_layers = cfg.num_hidden_layers
     eps = cfg.rms_norm_eps
     scale = 1.0 / math.sqrt(dh)
     from ..framework.flags import flag as _flag
@@ -1004,11 +1077,15 @@ def _make_chunk_prefill(cfg, tn, tp=None):
         from ..kernels.ragged_attention import (
             ragged_paged_attention, ragged_paged_attention_reference)
 
-        h = p["llama.embed_tokens.weight"][ids]          # [1, tn, h]
+        h = p[model.embed][ids]                          # [1, tn, h]
         pos_ids = cached_len[:, None] + jnp.arange(tn)[None, :]
-        kvs = []
-        for i in range(n_layers):
-            pre = f"llama.layers.{i}."
+        kvs, counts = [], []
+        for i, layer in enumerate(model.layers):
+            pre = layer.prefix
+            # a model with window layers hands every table in twice:
+            # (the full layers', the window layers' rings)
+            table_i = chunk_table if not model.window_layers \
+                else chunk_table[layer.window is not None]
             x = _k_rms(h, p[pre + "input_layernorm.weight"], eps)
             q = _mm(x, p[pre + "self_attn.q_proj.weight"]).reshape(
                 1, tn, nh_l, dh)
@@ -1017,7 +1094,8 @@ def _make_chunk_prefill(cfg, tn, tp=None):
             v = _mm(x, p[pre + "self_attn.v_proj.weight"]).reshape(
                 1, tn, nkv_l, dh)
             q, k = apply_rotary_emb(q, k, position_ids=pos_ids,
-                                    base=cfg.rope_theta)
+                                    base=layer.rope_base,
+                                    scaling=layer.rope_scaling)
             kvs.append((k, v))
             kc_i, ksc_i = kcs[i] if isinstance(kcs[i], tuple) \
                 else (kcs[i], None)
@@ -1033,7 +1111,7 @@ def _make_chunk_prefill(cfg, tn, tp=None):
                     causal_window_partials, combine_partials,
                     cp_local_view, finalize_partials, paged_partials)
 
-                loc, owned = cp_local_view(chunk_table, kc_i.shape[0],
+                loc, owned = cp_local_view(table_i, kc_i.shape[0],
                                            tp.cp_axis)
                 page = kc_i.shape[2]
                 pos_ok = jnp.arange(loc.shape[1] * page)[None, :] \
@@ -1052,20 +1130,23 @@ def _make_chunk_prefill(cfg, tn, tp=None):
             else:
                 attn_fn = ragged_paged_attention if use_kernel \
                     else ragged_paged_attention_reference
-                attn = attn_fn(q, k, v, kc_i, vc_i, chunk_table,
-                               cached_len, new_len, scale=scale,
-                               k_scale=ksc_i, v_scale=vsc_i
-                               ).astype(h.dtype)
+                with _attn_scope(layer):
+                    attn = attn_fn(q, k, v, kc_i, vc_i, table_i,
+                                   cached_len, new_len, scale=scale,
+                                   k_scale=ksc_i, v_scale=vsc_i,
+                                   window=layer.window).astype(h.dtype)
             if tp is not None:
                 attn = tp.gather_heads(attn)
             h = h + _mm(attn.reshape(1, tn, nh * dh),
                         p[pre + "self_attn.o_proj.weight"])
             x2 = _k_rms(h, p[pre + "post_attention_layernorm.weight"], eps)
-            gate = _mm(x2, p[pre + "mlp.gate_proj.weight"])
-            up = _mm(x2, p[pre + "mlp.up_proj.weight"])
-            h = h + _mm(jax.nn.silu(gate) * up,
-                        p[pre + "mlp.down_proj.weight"])
-        h = _k_rms(h, p["llama.norm.weight"], eps)
+            y, count = layer.mlp(x2, p, pre)
+            h = h + y
+            if count is not None:
+                counts.append(count)
+        h = _k_rms(h, p[model.norm], eps)
+        if counts:
+            return h, kvs, sum(counts)
         return h, kvs
 
     return prefill
@@ -1680,6 +1761,9 @@ class PagedKVManager:
         self._lru = OrderedDict()
         self.prefix_evictions = 0
         self._geometry = None  # set_pool_geometry
+        # the second kind of pool (set_window_rings): none by default
+        self.ring_pages = self.n_rings = self._ring_layers = 0
+        self._free_rings = []
 
     # ---- pool byte accounting -------------------------------------------
 
@@ -1763,9 +1847,47 @@ class PagedKVManager:
                               kv_cache_dtype=kv_cache_dtype,
                               mp=int(mp), cp=cp)
 
+    # ---- the second pool kind: per-sequence rings of window layers ------
+
+    def set_window_rings(self, n_rings: int, ring_pages: int,
+                         n_layers: int) -> None:
+        """Sliding-window layers keep a sequence's last positions only, so
+        their K/V live in pools of their own: `n_rings` rings of
+        `ring_pages` pages (one a live sequence), page 0 the sink of idle
+        rows. A ring is handed out whole at admission and never grows;
+        position t of its sequence lives at ring page (t // block) %
+        ring_pages. `n_layers` window layers share the page ids (as the
+        full layers share the first kind's), which `kv_pool_bytes` counts.
+        The prefix cache knows nothing of rings."""
+        self.ring_pages, self.n_rings = int(ring_pages), int(n_rings)
+        self._ring_layers = int(n_layers)
+        self._free_rings = list(range(self.n_rings - 1, -1, -1))
+
+    @property
+    def window_pool_pages(self) -> int:
+        """Pages of a window layer's pool: the rings and the sink."""
+        return self.n_rings * self.ring_pages + 1 if self.n_rings else 0
+
+    @property
+    def n_rings_free(self) -> int:
+        return len(self._free_rings)
+
+    def alloc_ring(self) -> list:
+        if not self._free_rings:
+            raise RuntimeError(
+                f"all {self.n_rings} window rings are in use (one a live "
+                "sequence: admission holds to that)")
+        r = self._free_rings.pop()
+        return list(range(1 + r * self.ring_pages,
+                          1 + (r + 1) * self.ring_pages))
+
+    def free_ring(self, ring) -> None:
+        if ring:
+            self._free_rings.append((ring[0] - 1) // self.ring_pages)
+
     def kv_pool_bytes(self, aggregate: bool = False) -> int:
-        """Device bytes of the K/V pools (+ int8 scale arrays) this
-        manager allocates pages of — PER CHIP by default (the number an
+        """Device bytes of the K/V pools of both kinds (+ int8 scale
+        arrays) this manager allocates pages of — PER CHIP by default (the number an
         HBM budget constrains; at cp > 1 each chip holds only
         max_pages/cp of the fleet's pages); `aggregate=True` multiplies
         both shard counts back in (the whole-fleet footprint). Requires
@@ -1777,6 +1899,9 @@ class PagedKVManager:
         cp = geo.pop("cp", 1)
         per_chip = (self.max_pages // cp) \
             * self.page_bytes(self.block_size, **geo)
+        if self.n_rings:   # window layers: never sharded (the engine refuses)
+            per_chip += self.window_pool_pages * self.page_bytes(
+                self.block_size, **dict(geo, n_layers=self._ring_layers))
         return per_chip * geo["mp"] * cp if aggregate else per_chip
 
     @property
@@ -2177,7 +2302,7 @@ def _decode_tail(decode_step, p_dec, kcs, vcs, last_logits,
 
 
 def _make_decode_step(cfg, b, max_seq=None, kv_write=None, kv_attend=None,
-                      tp=None):
+                      tp=None, kv_window=None):
     """Single-token decode step — the per-layer transformer math shared
     by EVERY generation program (fp, quant-only, paged); only the KV
     store differs, injected via two callbacks:
@@ -2194,18 +2319,28 @@ def _make_decode_step(cfg, b, max_seq=None, kv_write=None, kv_attend=None,
     compute only the local shard's heads, kv_write/kv_attend operate on
     the local pool shard, and the per-shard context all-gathers along
     the head axis before the replicated o-proj — the ONE cross-chip
-    collective per decode step per layer (the o-proj activations)."""
+    collective per decode step per layer (the o-proj activations).
+
+    The layers are `served_model(cfg)`'s. `kv_window` is the (kv_write,
+    kv_attend) pair of the window layers, over their rings. Where a layer
+    is routed the step returns the layers' summed MOE_COUNTS as a fourth
+    result."""
+    model = served_model(cfg)
     nh, nkv, dh = (cfg.num_attention_heads, cfg.num_key_value_heads,
-                   cfg.head_dim)
+                   model.head_dim)
     nh_l = tp.nh_local if tp is not None else nh
     nkv_l = tp.nkv_local if tp is not None else nkv
     # GQA group from the LOCAL shard's head counts, never the full
     # model config (nh//nkv) — under the replicated-KV MQA fallback the
     # local group is nh_l // nkv, not nh // nkv (ISSUE 7 satellite)
     group = nh_l // nkv_l
-    n_layers = cfg.num_hidden_layers
     eps = cfg.rms_norm_eps
     head_logits = _make_head_logits(cfg)
+    if model.window_layers and kv_window is None:
+        raise ValueError(
+            "a model with sliding-window layers decodes over per-sequence "
+            "rings: pass their (kv_write, kv_attend) as kv_window (the "
+            "serving engine does)")
 
     if kv_write is None:
         def kv_write(kc, vc, k, v, pos):
@@ -2235,12 +2370,14 @@ def _make_decode_step(cfg, b, max_seq=None, kv_write=None, kv_attend=None,
         the [B, 1] position_ids broadcast per-example through the rope
         tables)."""
         # the embedding stays dense (it's a gather, not a matmul)
-        h = p["llama.embed_tokens.weight"][tok[:, 0]][:, None, :]
+        h = p[model.embed][tok[:, 0]][:, None, :]
         pos_ids = pos[:, None] if getattr(pos, "ndim", 0) == 1 \
             else jnp.reshape(pos, (1,))
-        new_kcs, new_vcs = [], []
-        for i in range(n_layers):
-            pre = f"llama.layers.{i}."
+        new_kcs, new_vcs, counts = [], [], []
+        for i, layer in enumerate(model.layers):
+            pre = layer.prefix
+            write, attend = (kv_write, kv_attend) if layer.window is None \
+                else kv_window
             x = _k_rms(h, p[pre + "input_layernorm.weight"], eps)
             q = _mm(x, p[pre + "self_attn.q_proj.weight"]).reshape(
                 b, 1, nh_l, dh)
@@ -2249,22 +2386,27 @@ def _make_decode_step(cfg, b, max_seq=None, kv_write=None, kv_attend=None,
             v = _mm(x, p[pre + "self_attn.v_proj.weight"]).reshape(
                 b, 1, nkv_l, dh)
             q, k = apply_rotary_emb(q, k, position_ids=pos_ids,
-                                    base=cfg.rope_theta)
-            kc, vc = kv_write(kcs[i], vcs[i], k, v, pos)
+                                    base=layer.rope_base,
+                                    scaling=layer.rope_scaling)
+            kc, vc = write(kcs[i], vcs[i], k, v, pos)
             new_kcs.append(kc)
             new_vcs.append(vc)
-            ctx = kv_attend(q[:, 0], kc, vc, pos)       # [b, nh_l, dh]
+            with _attn_scope(layer):
+                ctx = attend(q[:, 0], kc, vc, pos)      # [b, nh_l, dh]
             if tp is not None:
                 ctx = tp.gather_heads(ctx)              # [b, nh, dh]
             h = h + _mm(ctx.reshape(b, 1, nh * dh),
                         p[pre + "self_attn.o_proj.weight"])
             x2 = _k_rms(h, p[pre + "post_attention_layernorm.weight"], eps)
-            gate = _mm(x2, p[pre + "mlp.gate_proj.weight"])
-            up = _mm(x2, p[pre + "mlp.up_proj.weight"])
-            h = h + _mm(jax.nn.silu(gate) * up,
-                        p[pre + "mlp.down_proj.weight"])
-        h = _k_rms(h, p["llama.norm.weight"], eps)
-        return head_logits(h, p)[:, -1], new_kcs, new_vcs
+            y, count = layer.mlp(x2, p, pre)
+            h = h + y
+            if count is not None:
+                counts.append(count)
+        h = _k_rms(h, p[model.norm], eps)
+        logits = head_logits(h, p)[:, -1]
+        if counts:
+            return logits, new_kcs, new_vcs, sum(counts)
+        return logits, new_kcs, new_vcs
 
     return decode_step
 

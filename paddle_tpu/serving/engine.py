@@ -99,7 +99,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..models.llama import (PagedKVManager, _make_chunk_prefill,
+from ..models.llama import (MOE_COUNTS, PagedKVManager, _make_chunk_prefill,
                             _make_decode_step, _make_head_logits,
                             _make_prefill, _make_prefill_with_prefix,
                             _make_verify_window, _sample_next,
@@ -107,11 +107,19 @@ from ..models.llama import (PagedKVManager, _make_chunk_prefill,
                             make_paged_kv_q8_helpers, make_serving_tp,
                             resolve_kv_cache_dtype, resolve_serving_cp,
                             resolve_serving_mp, resolve_unified_step,
-                            serving_param_specs, shard_serving_params)
+                            served_model, serving_param_specs,
+                            shard_serving_params)
 from ..observability import metrics as obs_metrics
 from ..observability import trace as obs_trace
 from ..observability.trace import _NULL_SPAN
 from ..resilience import chaos
+
+
+def _token_logprob(logits, tok):
+    """log softmax(logits)[tok], [B, V] and [B] -> f32 [B]."""
+    logits = logits.astype(jnp.float32)
+    return jnp.take_along_axis(logits, tok[:, None], axis=-1)[:, 0] \
+        - jax.nn.logsumexp(logits, axis=-1)
 
 
 @dataclass
@@ -123,6 +131,9 @@ class ServeRequest:
     arrival_time: float = 0.0
     # filled by the engine
     tokens: list = field(default_factory=list)
+    # log-probability of each of `tokens` under the distribution it was
+    # chosen from (engine option `logprobs`; empty without it)
+    logprobs: list = field(default_factory=list)
     prefill_time: Optional[float] = None   # when the first token was ready
     finish_time: Optional[float] = None
     failed: bool = False                   # retired by the watchdog
@@ -132,6 +143,7 @@ class ServeRequest:
     # host-side scheduling state (None until admitted)
     slot: Optional[int] = None
     pages: Optional[list] = None
+    ring: Optional[list] = None            # window layers' ring, if any
     bucket: Optional[int] = None           # suffix bucket it prefilled at
     n_prefix: int = 0                      # cached prefix blocks mapped in
     cached_tokens: int = 0                 # prompt tokens served from cache
@@ -182,6 +194,16 @@ class ContinuousBatchingEngine:
         eng.run()                      # until all queues drain
         for req in eng.finished: print(req.tokens)
 
+    What the model is, the config says (`models.llama.served_model`): a
+    model with sliding-window layers keeps those layers' K/V in a second
+    kind of pool — a ring a live sequence, `window + token_budget` tokens
+    and a page, reserved whole at admission beside the request's pages and
+    never growing (`PagedKVManager.set_window_rings`) — and serves without
+    the prefix cache (`metrics()["prefix_cache_off"]`); a model with routed
+    layers counts what they did inside the programs (`metrics()["moe_*"]`).
+    Both are served by the unified step and the decode chunk, one chip,
+    bf16 pools; every other option refuses such a model by name.
+
     Scheduling policy: FIFO admission; a request is admitted when a slot
     is free AND the pool can hold its full per-request capacity
     (cached prefix blocks map in for free; ceil((bucketed_suffix +
@@ -222,6 +244,7 @@ class ContinuousBatchingEngine:
                  speculative: Optional[str] = None,
                  spec_k: Optional[int] = None, drafter=None,
                  spec_adaptive: Optional[bool] = None,
+                 logprobs: bool = False,
                  config=None, tracer=None, metrics=None):
         """`kv_cache_dtype` ('bf16' | 'int8'; default from
         FLAGS_kv_cache_dtype / PADDLE_TPU_KV_CACHE_DTYPE) picks the
@@ -308,6 +331,14 @@ class ContinuousBatchingEngine:
         by both workers. Token output is identical to the unified
         scheduler; what changes is that prefill admission no longer
         queues behind decode slot occupancy.
+
+        `logprobs` hands out, beside each generated token, its
+        log-probability under the softmax of the logits it was chosen
+        from (`ServeRequest.logprobs`, one a token): the decode chunk
+        and the unified step return them with the tokens the commit
+        waits for anyway. Off (default) no program changes. Built for
+        the unified step and the decode chunk; refused by name beside
+        `unified_step=False`, speculation and `disaggregated`.
 
         `tracer` / `metrics` (observability, ISSUE 8): an
         `observability.Tracer` records the full request lifecycle
@@ -468,6 +499,41 @@ class ContinuousBatchingEngine:
             else:
                 self._drafter = drafter if drafter is not None \
                     else NGramDrafter()
+        # the model as the program builders read it (models/llama.py's
+        # contract): which layers keep a window, which are routed
+        self._model = served_model(cfg)
+        self._window_layers = frozenset(self._model.window_layers)
+        # the window those layers keep (one width a model), 0 without any
+        self._window = max((self._model.layers[i].window
+                            for i in self._window_layers), default=0)
+        self._routed = self._model.routed
+        # why the prefix cache is off, where the model turns it off
+        self.prefix_cache_off = None
+        if self._window_layers or self._routed:
+            what = " and ".join(
+                w for w, on in (("sliding-window", self._window_layers),
+                                ("routed-expert", self._routed)) if on)
+            for name, on in (
+                    ("unified_step=False (the split prefill programs)",
+                     not self.unified),
+                    (f"speculative={self.speculative!r}",
+                     self.speculative != "off"),
+                    (f"serving_mp={self.mp}", self.mp > 1),
+                    (f"serving_cp={self.cp}", self.cp > 1),
+                    ("disaggregated=True", bool(disaggregated)),
+                    ("kv_cache_dtype='int8'", self.kv_dtype == "int8")):
+                if on:
+                    raise ValueError(
+                        f"{name} is not built for a model with {what} "
+                        "layers: they are served by the unified step and "
+                        "the decode chunk on one chip, bf16 pools "
+                        "(ROADMAP M2 / M3)")
+            if self._window_layers and self.prefix_cache:
+                self.prefix_cache = False
+                self.prefix_cache_off = (
+                    "the model has sliding-window layers: their K/V live "
+                    "in per-sequence rings, which hold no page a later "
+                    "request could map")
         self._tp = make_serving_tp(
             cfg, self.mp,
             quantized_collectives=self.quantized_collectives,
@@ -485,6 +551,18 @@ class ContinuousBatchingEngine:
         # prefill/decode disaggregation: prefilled-but-unslotted
         # requests wait here with their pages already committed
         self.disaggregated = bool(disaggregated)
+        self.logprobs = bool(logprobs)
+        if self.logprobs:
+            for name, on in (
+                    ("unified_step=False (the split prefill programs)",
+                     not self.unified),
+                    (f"speculative={self.speculative!r}",
+                     self.speculative != "off"),
+                    ("disaggregated=True", self.disaggregated)):
+                if on:
+                    raise ValueError(
+                        f"{name} is not built with logprobs=True: the "
+                        "unified step and the decode chunk return them")
         self._handoff: list[ServeRequest] = []
         self.prefill_handoffs = 0   # requests that crossed the handoff
         # pool capacity: every slot simultaneously full-length at the
@@ -501,11 +579,22 @@ class ContinuousBatchingEngine:
         # widest cached prefix any request can map (>= 1 suffix token
         # always prefills, so the last block is never part of a prefix)
         self._prefix_width = max(1, (self.max_prompt_len - 1) // block_size)
-        nkv, dh = cfg.num_key_value_heads, cfg.head_dim
+        nkv, dh = cfg.num_key_value_heads, self._model.head_dim
+        # layers of the first pool kind (whole contexts on pages) and of
+        # the second (a ring a live sequence: `slots` + the one prefilling)
+        n_full = len(self._model.layers) - len(self._window_layers)
+        n_rings = slots + 1 if self._window_layers else 0
         if kv_pool_bytes is not None:
             if max_pages is not None:
                 raise ValueError(
                     "pass max_pages OR kv_pool_bytes, not both")
+            # the budget is both kinds': the rings' share comes off first
+            ring_bytes = 0
+            if n_rings:
+                ring_bytes = (n_rings * self._capacity_ring_pages() + 1) \
+                    * PagedKVManager.page_bytes(
+                        block_size, n_layers=len(self._window_layers),
+                        num_kv_heads=nkv, head_dim=dh)
             # PER-CHIP budget: under kv-head sharding each chip holds
             # only nkv/mp heads of every page, so the same per-chip
             # bytes buy ~mp x the aggregate cacheable pages; under
@@ -513,8 +602,8 @@ class ContinuousBatchingEngine:
             # fleet's pages, so the same bytes buy cp x the FLEET page
             # count — the context-ceiling lift
             max_pages = PagedKVManager.pages_for_bytes(
-                kv_pool_bytes, block_size,
-                n_layers=cfg.num_hidden_layers, num_kv_heads=nkv,
+                kv_pool_bytes - ring_bytes, block_size,
+                n_layers=n_full, num_kv_heads=nkv,
                 head_dim=dh, kv_cache_dtype=self.kv_dtype,
                 mp=self.kv_shards, cp=self.cp)
             if max_pages < cap + 2:
@@ -537,10 +626,13 @@ class ContinuousBatchingEngine:
         self._comms_audit = None    # wire-side twin (ISSUE 11)
         self._roofline_audit = None  # compute-time leg (ISSUE 13)
         self.mgr = PagedKVManager(max_pages, block_size)
-        self.mgr.set_pool_geometry(n_layers=cfg.num_hidden_layers,
+        self.mgr.set_pool_geometry(n_layers=n_full,
                                    num_kv_heads=nkv, head_dim=dh,
                                    kv_cache_dtype=self.kv_dtype,
                                    mp=self.kv_shards, cp=self.cp)
+        if n_rings:
+            self.mgr.set_window_rings(n_rings, self._capacity_ring_pages(),
+                                      len(self._window_layers))
         self.scratch_page = self.mgr.alloc_pages(1)[0]  # retired rows' sink
         if self.kv_dtype == "int8":
             # (int8 pool, per-(page, kv head) f32 absmax scale) pairs —
@@ -551,8 +643,8 @@ class ContinuousBatchingEngine:
                                   jnp.int8),
                         jnp.zeros((max_pages, nkv), jnp.float32))
         else:
-            def _pool():
-                return jnp.zeros((max_pages, nkv, block_size, dh), dtype)
+            def _pool(n=max_pages):
+                return jnp.zeros((n, nkv, block_size, dh), dtype)
         if self._tp is not None:
             # pools are BORN on the serving mesh (kv-head sharded, or
             # replicated under the MQA fallback): max_pages was sized
@@ -572,8 +664,16 @@ class ContinuousBatchingEngine:
                 else NamedSharding(self.mp_mesh, sp)
             _pool.__name__ = "serve_kv_pool_init"
             _pool = jax.jit(_pool, out_shardings=out)
-        self.kcs = [_pool() for _ in range(cfg.num_hidden_layers)]
-        self.vcs = [_pool() for _ in range(cfg.num_hidden_layers)]
+        if self._window_layers:
+            # a window layer's pools are as large as the rings, whatever
+            # the contexts grow to
+            sizes = [self.mgr.window_pool_pages if i in self._window_layers
+                     else max_pages for i in range(len(self._model.layers))]
+            self.kcs = [_pool(n) for n in sizes]
+            self.vcs = [_pool(n) for n in sizes]
+        else:
+            self.kcs = [_pool() for _ in range(cfg.num_hidden_layers)]
+            self.vcs = [_pool() for _ in range(cfg.num_hidden_layers)]
         if self._tp is not None:
             # params per `serving_param_specs` (q/k/v columns sharded,
             # the rest — o-proj included — replicated). Logical shapes
@@ -582,6 +682,15 @@ class ContinuousBatchingEngine:
             self._param_specs = serving_param_specs(self.p, self._tp)
         self._slots = [_Slot() for _ in range(slots)]
         self._tables = np.full((slots, cap), self.scratch_page, np.int32)
+        # the window layers' tables: column j names ring page j % R of the
+        # slot's ring (page 0 of their pools is the sink), set once a bind
+        self._ring_tables = np.zeros((slots, cap), np.int32) \
+            if self._window_layers else None
+        # what the routed layers counted, summed over layers and steps
+        # (MOE_COUNTS), for the decode lane and for the prefill windows
+        self.moe_counts = {"decode": np.zeros(len(MOE_COUNTS), np.int64),
+                           "chunk": np.zeros(len(MOE_COUNTS), np.int64)}
+        self.window_tokens_dropped = 0   # cached tokens that left a window
         self._tokens = np.zeros((slots,), np.int32)
         self._budgets = np.zeros((slots,), np.int32)  # prompt + max_new
         self._key = jax.random.PRNGKey(seed)
@@ -590,13 +699,14 @@ class ContinuousBatchingEngine:
         self._next_id = 0
         self._prefill_cache = {}
         self._decode = self._program(
-            self._build_decode_chunk(), "serve_decode_chunk", 8, 3)
+            self._build_decode_chunk(), "serve_decode_chunk", 8,
+            3 + self._routed + self.logprobs)
         # the ONE mixed prefill+decode program (ISSUE 14) — built only
         # on the unified path; its shape key is (token_budget, slots,
         # steps, kv-dtype, mp) and warm() compiles it once
         self._unified = self._program(
-            self._build_unified_step(), "serve_unified_step", 13, 4) \
-            if self.unified else None
+            self._build_unified_step(), "serve_unified_step", 13,
+            4 + self._routed + 2 * self.logprobs) if self.unified else None
         # speculative verify: one ragged window of spec_k+1 rows per
         # slot scores every draft + the pending token in a single pass
         # (models/llama._make_verify_window); built only when the
@@ -671,6 +781,38 @@ class ContinuousBatchingEngine:
         # same ceil-division as PagedKVManager.pages_needed (which is not
         # constructed yet when __init__ sizes the pool from this)
         return -(-(sb + max_new) // self.block_size)
+
+    def _capacity_ring_pages(self) -> int:
+        """Pages of one sequence's ring in the window layers' pools: the
+        window, one prefill window of `token_budget` written behind it
+        before the oldest page is due again, and a page because neither
+        starts on a page's edge. 0 for a model without window layers."""
+        if not self._window_layers:
+            return 0
+        return -(-(self._window + self.token_budget)
+                 // self.block_size) + 1
+
+    def _ring_table(self, ring, width: int):
+        """The logical table of a ring: column j -> ring page j % R."""
+        return np.asarray(ring, np.int32)[np.arange(width) % len(ring)]
+
+    def _tables_arg(self, full, ring):
+        """One program argument for both pool kinds' tables."""
+        if not self._window_layers:
+            return jnp.asarray(full)
+        return (jnp.asarray(full), jnp.asarray(ring))
+
+    def _scratch_tables_arg(self, rows: int, width: int):
+        return self._tables_arg(
+            np.full((rows, width), self.scratch_page, np.int32),
+            np.zeros((rows, width), np.int32))
+
+    def _release(self, req) -> None:
+        """Give back everything `req` holds in both pool kinds."""
+        self.mgr.free(req.pages)
+        req.pages = None
+        self.mgr.free_ring(req.ring)
+        req.ring = None
 
     # ---- tensor-parallel plumbing (FLAGS_serving_mp) --------------------
 
@@ -790,6 +932,10 @@ class ContinuousBatchingEngine:
         safe to call mid-serve from another thread."""
         mgr = self.mgr
         in_use = mgr.max_pages - mgr.n_available
+        # cached tokens of each sequence that holds pages: the live slots'
+        # and the one prefilling
+        lens = [s.length for s in self._slots if s.req is not None] \
+            + ([self._prefilling["done"]] if self._prefilling else [])
         return {
             "requests_finished": len(self.finished),
             "requests_waiting": len(self.waiting),
@@ -825,6 +971,30 @@ class ContinuousBatchingEngine:
             "prompt_tokens": self.prompt_tokens,
             "prefix_inserts": self.prefix_inserts,
             "prefix_evictions": mgr.prefix_evictions,
+            # None, or why this model serves without the prefix cache
+            "prefix_cache_off": self.prefix_cache_off,
+            # routed layers (MOE_COUNTS summed over layers and steps; a
+            # dense model reads zeros): every time a routed layer ran, the
+            # (token, choice) rows it routed, the experts that got at
+            # least one, the largest group — and the decode lane's part
+            **{f"moe_{name}": int(self.moe_counts["decode"][i]
+                                  + self.moe_counts["chunk"][i])
+               for i, name in enumerate(MOE_COUNTS)},
+            **{f"moe_{name}_decode": int(self.moe_counts["decode"][i])
+               for i, name in enumerate(MOE_COUNTS)},
+            # both pool kinds: pages held on the full layers' pools (the
+            # scratch page left out), pages of the window layers' rings in
+            # use (a ring holds kv_ring_tokens, 0 without window layers),
+            # tokens cached by the live and the prefilling sequences, those
+            # of them a window layer still attends (min(len, W) a
+            # sequence), and those that have left a window, ever
+            "kv_pages_full": in_use - 1,
+            "kv_pages_window": (mgr.n_rings - mgr.n_rings_free)
+            * mgr.ring_pages,
+            "kv_ring_tokens": mgr.ring_pages * self.block_size,
+            "kv_tokens_live": sum(lens),
+            "kv_tokens_window": sum(min(n, self._window) for n in lens),
+            "window_tokens_dropped": self.window_tokens_dropped,
             # sync-wait telemetry (what double buffering hides)
             "sync_wait_s": self.sync_wait_s,
             "blocked_syncs": self.blocked_syncs,
@@ -1080,7 +1250,7 @@ class ContinuousBatchingEngine:
         the helpers are built at the LOCAL kv-head count — the scatter
         runs inside the shard_map body on the local pool shard."""
         cfg = self.cfg
-        nkv, dh = self._nkv_eff, cfg.head_dim
+        nkv, dh = self._nkv_eff, self._model.head_dim
         bs = self.block_size
         tp = self._tp
         cp_drop = tp is not None and tp.cp > 1
@@ -1178,6 +1348,27 @@ class ContinuousBatchingEngine:
         tp = self._tp
         cp_parts = tp is not None and tp.cp > 1
 
+        def window_step(tables, rings):
+            """The step of a model with window layers (one chip, bf16
+            pools: the constructor refused everything else): the two kinds
+            of layer write and read through their own tables."""
+            window = self._window
+            _, kv_write = make_paged_kv_helpers(
+                b, 0, nkv_eff, self._model.head_dim, bs, tables)
+            _, ring_write = make_paged_kv_helpers(
+                b, 0, nkv_eff, self._model.head_dim, bs, rings)
+
+            def kv_attend(q1, kc, vc, lens_):
+                return paged_decode_attention(q1, kc, vc, tables, lens_)
+
+            def ring_attend(q1, kc, vc, lens_):
+                return paged_decode_attention(q1, kc, vc, rings, lens_,
+                                              window=window)
+
+            return _make_decode_step(cfg, b, kv_write=kv_write,
+                                     kv_attend=kv_attend, tp=tp,
+                                     kv_window=(ring_write, ring_attend))
+
         def make_step(tables):
             """Per-layer decode body for one chunk. Under serving_mp
             this runs inside the shard_map body — the kv helpers and
@@ -1188,6 +1379,8 @@ class ContinuousBatchingEngine:
             the owned pages as online-softmax partials, and
             `merge_attn_partials` folds the per-shard stats — never
             the KV — into the global context."""
+            if self._window_layers:
+                return window_step(*tables)    # `_tables_arg`'s pair
             if cp_parts:
                 from ..kernels.partial_attention import (
                     cp_local_view, decode_paged_partials,
@@ -1219,7 +1412,7 @@ class ContinuousBatchingEngine:
 
                     def kv_write(kct, vct, k, v, lens_):
                         _, w = make_paged_kv_q8_helpers(
-                            b, 0, nkv_eff, cfg.head_dim, bs,
+                            b, 0, nkv_eff, self._model.head_dim, bs,
                             _q8_local_tables(kct))
                         return w(kct, vct, k, v, lens_)
 
@@ -1228,7 +1421,7 @@ class ContinuousBatchingEngine:
                         return _cp_attend(q1, kc, vc, lens_, ksc, vsc)
                 else:
                     _, kv_write = make_paged_kv_q8_helpers(
-                        b, 0, nkv_eff, cfg.head_dim, bs, tables)
+                        b, 0, nkv_eff, self._model.head_dim, bs, tables)
 
                     def kv_attend(q1, kct, vct, lens_):
                         (kc, ksc), (vc, vsc) = kct, vct
@@ -1255,7 +1448,7 @@ class ContinuousBatchingEngine:
                     kv_attend = _cp_attend
                 else:
                     _, kv_write = make_paged_kv_helpers(
-                        b, 0, nkv_eff, cfg.head_dim, bs, tables)
+                        b, 0, nkv_eff, self._model.head_dim, bs, tables)
 
                     def kv_attend(q1, kc, vc, lens_):
                         return paged_decode_attention(q1, kc, vc,
@@ -1272,10 +1465,14 @@ class ContinuousBatchingEngine:
         length, so they compute (fixed shape) but touch nothing live.
         `budgets` [slots] freezes each row on-device at prompt+max_new —
         the guarantee that a speculatively-dispatched chunk (double
-        buffering) can never write past a request's reserved pages."""
+        buffering) can never write past a request's reserved pages.
+        Returns (tokens, lengths, done, pools); before the pools a model
+        with routed layers puts their summed MOE_COUNTS [4], and
+        `logprobs` the tokens' log-probabilities [slots, steps]."""
         b, steps = self.slots, self.steps
         do_sample, top_k, eos = self.do_sample, self.top_k, self.eos
         make_step = self._decode_step_maker()
+        routed, logprobs = self._routed, self.logprobs
 
         def run(p, kcs, vcs, toks, lens, budgets, tables, live, key,
                 temperature, top_p):
@@ -1283,11 +1480,13 @@ class ContinuousBatchingEngine:
 
             def step(carry, _):
                 tok, lens_, kcs_, vcs_, done, key_ = carry
-                logits, kcs_, vcs_ = decode_step(p, kcs_, vcs_,
-                                                 tok[:, None], lens_)
+                logits, kcs_, vcs_, *count = decode_step(
+                    p, kcs_, vcs_, tok[:, None], lens_)
                 key_, ks = jax.random.split(key_)
                 nxt = _sample_next(logits.astype(jnp.float32), ks,
                                    do_sample, temperature, top_k, top_p)
+                if logprobs:
+                    count.append(_token_logprob(logits, nxt))
                 frozen = done | ~live | (lens_ >= budgets)
                 if eos is not None:
                     nxt = jnp.where(frozen, eos, nxt)
@@ -1295,15 +1494,20 @@ class ContinuousBatchingEngine:
                 else:
                     nxt = jnp.where(frozen, 0, nxt)
                 lens_ = jnp.where(frozen, lens_, lens_ + 1)
-                return (nxt, lens_, kcs_, vcs_, done, key_), nxt
+                return (nxt, lens_, kcs_, vcs_, done, key_), (nxt, *count)
 
             # every live row enters a chunk un-done (retire clears slots
             # at chunk end); `done` only freezes rows WITHIN the chunk
             done0 = jnp.zeros((b,), bool)
-            (tok, lens, kcs, vcs, done, _), out = jax.lax.scan(
+            (tok, lens, kcs, vcs, done, _), (out, *counts) = jax.lax.scan(
                 step, (toks, lens, kcs, vcs, done0, key), None,
                 length=steps)
-            return jnp.swapaxes(out, 0, 1), lens, done, kcs, vcs
+            # a routed model's steps counted what their layers did: the
+            # sums ride out with the tokens the commit waits for anyway
+            extra = (jnp.sum(counts[0], axis=0),) if routed else ()
+            if logprobs:
+                extra += (jnp.swapaxes(counts[-1], 0, 1),)
+            return (jnp.swapaxes(out, 0, 1), lens, done, *extra, kcs, vcs)
 
         return run
 
@@ -1333,21 +1537,28 @@ class ContinuousBatchingEngine:
         chunk_body = _make_chunk_prefill(cfg, tn, tp=self._tp)
         head_logits = _make_head_logits(cfg)
         scatter = self._page_scatter(1, n_win)
+        window_layers, routed = self._window_layers, self._routed
+        logprobs = self.logprobs
 
         def run(p, kcs, vcs, toks, lens, budgets, tables, live,
                 chunk_ids, chunk_table, chunk_cached, chunk_len,
                 chunk_pages, key, temperature, top_p):
             key, kd, ks = jax.random.split(key, 3)
             # ---- decode lane: the split decode chunk, verbatim ----
-            out, lens_o, done, kcs, vcs = decode_chunk(
+            out, lens_o, done, *extra, kcs, vcs = decode_chunk(
                 p, kcs, vcs, toks, lens, budgets, tables, live, kd,
                 temperature, top_p)
+            count, lps = extra[:routed], extra[routed:]
             # ---- chunk lane: one ragged prefill window ----
-            h, kvs = chunk_body(p, kcs, vcs, chunk_ids, chunk_table,
-                                chunk_cached, chunk_len)
+            h, kvs, *chunk_count = chunk_body(
+                p, kcs, vcs, chunk_ids, chunk_table, chunk_cached,
+                chunk_len)
             for i, (k, v) in enumerate(kvs):
-                kcs[i], vcs[i] = scatter(kcs[i], vcs[i], k, v,
-                                         chunk_pages)
+                # a window layer's rows go to its ring's pages (the pair's
+                # second), a full layer's to the request's own
+                pages_i = chunk_pages if not window_layers \
+                    else chunk_pages[i in window_layers]
+                kcs[i], vcs[i] = scatter(kcs[i], vcs[i], k, v, pages_i)
             # first-token logits at the chunk's true last position —
             # meaningful only when this window completes the prompt
             # (the host ignores it otherwise)
@@ -1357,7 +1568,11 @@ class ContinuousBatchingEngine:
             logits = head_logits(h_last, p)[:, -1]
             first = _sample_next(logits.astype(jnp.float32), ks,
                                  do_sample, temperature, top_k, top_p)
-            return out, lens_o, done, first, kcs, vcs
+            # routed layers' MOE_COUNTS, the decode lane's and the window's
+            extra = (jnp.stack(count + chunk_count),) if routed else ()
+            if logprobs:
+                extra += (*lps, _token_logprob(logits, first))
+            return (out, lens_o, done, first, *extra, kcs, vcs)
 
         return run
 
@@ -1371,7 +1586,7 @@ class ContinuousBatchingEngine:
         one's page state (w <= spec_k+1, so the unrolled loop is
         tiny)."""
         b, bs, W = self.slots, self.block_size, self.table_width
-        nkv, dh = self._nkv_eff, self.cfg.head_dim
+        nkv, dh = self._nkv_eff, self._model.head_dim
         quant = self.kv_dtype == "int8"
         scratch = self.scratch_page
 
@@ -1593,8 +1808,8 @@ class ContinuousBatchingEngine:
                 bsz *= 2
         # scratch-only tables: warming against the live tables would
         # scatter the warm token's K/V into an admitted request's pages
-        scratch_tables = jnp.full((self.slots, self.table_width),
-                                  self.scratch_page, jnp.int32)
+        scratch_tables = self._scratch_tables_arg(self.slots,
+                                                  self.table_width)
         if self.unified:
             # the unified mixed program: an all-scratch window of
             # chunk_len 0 (every window row is pad — the ragged kernel
@@ -1608,13 +1823,12 @@ class ContinuousBatchingEngine:
                 jnp.zeros((self.slots,), jnp.int32), scratch_tables,
                 jnp.zeros((self.slots,), bool),
                 jnp.zeros((1, tn), jnp.int32),
-                jnp.full((1, self.table_width), self.scratch_page,
-                         jnp.int32),
+                self._scratch_tables_arg(1, self.table_width),
                 jnp.zeros((1,), jnp.int32), jnp.zeros((1,), jnp.int32),
-                jnp.full((1, n_win), self.scratch_page, jnp.int32), k,
+                self._scratch_tables_arg(1, n_win), k,
                 jnp.asarray(self.temperature, jnp.float32),
                 jnp.asarray(self.top_p, jnp.float32))
-            _, _, _, _, self.kcs, self.vcs = uout
+            *_, self.kcs, self.vcs = uout
         self._key, k = jax.random.split(self._key)
         out = self._decode(
             self.p, self.kcs, self.vcs, jnp.asarray(self._tokens),
@@ -1623,7 +1837,7 @@ class ContinuousBatchingEngine:
             jnp.zeros((self.slots,), bool), k,
             jnp.asarray(self.temperature, jnp.float32),
             jnp.asarray(self.top_p, jnp.float32))
-        _, _, _, self.kcs, self.vcs = out
+        *_, self.kcs, self.vcs = out
         if self._verify is not None:
             # the speculative verify window: every slot all-scratch
             # with new_len=1 (the pending-token row only — pad columns
@@ -1665,7 +1879,7 @@ class ContinuousBatchingEngine:
         return (self.p, self.kcs, self.vcs,
                 jnp.zeros((b,), jnp.int32), jnp.zeros((b,), jnp.int32),
                 jnp.zeros((b,), jnp.int32),
-                jnp.zeros((b, self.table_width), jnp.int32),
+                self._scratch_tables_arg(b, self.table_width),
                 jnp.zeros((b,), bool), jax.random.PRNGKey(0),
                 jnp.asarray(self.temperature, jnp.float32),
                 jnp.asarray(self.top_p, jnp.float32))
@@ -1694,11 +1908,11 @@ class ContinuousBatchingEngine:
         n_win = tn // self.block_size
         return (self.p, self.kcs, self.vcs,
                 jnp.zeros((b,), jnp.int32), jnp.zeros((b,), jnp.int32),
-                jnp.zeros((b,), jnp.int32), jnp.zeros((b, W), jnp.int32),
+                jnp.zeros((b,), jnp.int32), self._scratch_tables_arg(b, W),
                 jnp.zeros((b,), bool), jnp.zeros((1, tn), jnp.int32),
-                jnp.zeros((1, W), jnp.int32), jnp.zeros((1,), jnp.int32),
+                self._scratch_tables_arg(1, W), jnp.zeros((1,), jnp.int32),
                 jnp.zeros((1,), jnp.int32),
-                jnp.zeros((1, n_win), jnp.int32), jax.random.PRNGKey(0),
+                self._scratch_tables_arg(1, n_win), jax.random.PRNGKey(0),
                 jnp.asarray(self.temperature, jnp.float32),
                 jnp.asarray(self.top_p, jnp.float32))
 
@@ -2336,6 +2550,9 @@ class ContinuousBatchingEngine:
         padded = req.pages + [req.pages[-1]] * \
             (self.table_width - len(req.pages))
         self._tables[slot_id] = padded
+        if req.ring:
+            self._ring_tables[slot_id] = self._ring_table(
+                req.ring, self.table_width)
         self._tokens[slot_id] = first
         self._budgets[slot_id] = len(req.prompt) + req.max_new
         self._override[slot_id] = True
@@ -2356,8 +2573,7 @@ class ContinuousBatchingEngine:
                        tokens=len(req.tokens), failed=False)
         if mt is not None:
             mt.counter("requests_finished").inc()
-        self.mgr.free(req.pages)
-        req.pages = None
+        self._release(req)
 
     def _install_handoffs(self, token: Optional[int] = None):
         """Decode-worker half of the disaggregated split: map handed-
@@ -2434,6 +2650,8 @@ class ContinuousBatchingEngine:
                 return []
         if plan.need + plan.n_lru > self.mgr.n_available:
             return []
+        if self._window_layers and not self.mgr.n_rings_free:
+            return []
         tr, mt = self._tracer, self._metrics
         with self._commit_lock:
             self._check_owner(token)
@@ -2443,6 +2661,9 @@ class ContinuousBatchingEngine:
             priv = self.mgr.alloc_pages(plan.need)
             self.waiting.pop(0)
             req.pages = cached + priv
+            # both kinds are reserved whole here: the ring never grows
+            req.ring = self.mgr.alloc_ring() if self._window_layers \
+                else None
             req.n_prefix = len(cached)
             req.cached_tokens = len(cached) * self.block_size
             req.bucket = self.token_budget
@@ -2488,6 +2709,15 @@ class ContinuousBatchingEngine:
             tbl = np.full((1, self.table_width), self.scratch_page,
                           np.int32)
             tbl[0, :len(req.pages)] = req.pages
+            ring_tbl = ring_win = None
+            if req.ring:
+                # the window's rows in the ring: pages past the chunk's
+                # end take pad rows, which go to the sink as the full
+                # layers' go to the scratch page
+                ring_tbl = self._ring_table(req.ring, self.table_width)[None]
+                ring_win = np.where(
+                    np.arange(n_win) < -(-this_chunk // bs),
+                    ring_tbl[0, wp0:wp0 + n_win], 0)[None]
         if self._watchdog is not None:
             self._watchdog.phase = "decode"
         chaos.maybe_hang("decode")
@@ -2514,15 +2744,18 @@ class ContinuousBatchingEngine:
                         jnp.asarray(np.asarray(
                             [s.length for s in self._slots], np.int32)),
                         jnp.asarray(self._budgets),
-                        jnp.asarray(self._tables),
+                        self._tables_arg(self._tables, self._ring_tables),
                         jnp.asarray(live), jnp.asarray(ids),
-                        jnp.asarray(tbl),
+                        self._tables_arg(tbl, ring_tbl),
                         jnp.asarray([done], np.int32),
                         jnp.asarray([this_chunk], np.int32),
-                        jnp.asarray([win_pages], np.int32), k,
+                        self._tables_arg(
+                            np.asarray([win_pages], np.int32), ring_win), k,
                         jnp.asarray(self.temperature, jnp.float32),
                         jnp.asarray(self.top_p, jnp.float32))
-                    out, new_lens, dn, first_dev, self.kcs, self.vcs = res
+                    out, new_lens, dn, first_dev, *extra, self.kcs, \
+                        self.vcs = res
+                    moe, lps = extra[:self._routed], extra[self._routed:]
                     self.device_steps += 1
                     self.prefill_chunks += 1
                     # a mixed step is authoritative host state — never
@@ -2542,9 +2775,11 @@ class ContinuousBatchingEngine:
                                      self.mgr.n_available)
                     rec = {"out": out, "lens": new_lens, "done": dn,
                            "reqs": [s.req for s in self._slots],
-                           "t_disp0": t_disp0}
+                           "t_disp0": t_disp0, "moe": moe,
+                           "logprobs": lps[:1]}
             produced = self._commit_chunk(rec, token)
             first = int(np.asarray(first_dev)[0])
+            first_lp = [float(np.asarray(lp)[0]) for lp in lps[1:]]
         if mt is not None:
             mt.histogram(
                 "prefill_chunk_s",
@@ -2556,9 +2791,11 @@ class ContinuousBatchingEngine:
                 self._check_owner(token)
                 st["done"] = done + this_chunk
                 self.chunk_tokens += this_chunk
+                self._count_dropped(done, st["done"])
                 final = st["done"] >= L
                 if final:
                     self._prefilling = None
+                    req.logprobs.extend(first_lp)
                     self._finish_unified_prefill(req, first, st["t0"])
             if tr is not None:
                 sp.set(produced=int(final),
@@ -2671,6 +2908,13 @@ class ContinuousBatchingEngine:
             return 0
         return self._commit_chunk(rec, token)
 
+    def _count_dropped(self, before: int, after: int) -> None:
+        """A sequence's cache grew from `before` to `after` tokens: count
+        those that left its window layers' window on the way."""
+        if self._window:
+            self.window_tokens_dropped += max(after - self._window, 0) \
+                - max(before - self._window, 0)
+
     def _retire(self, slot_id: int, failed: bool = False,
                 error: Optional[str] = None):
         slot = self._slots[slot_id]
@@ -2700,11 +2944,12 @@ class ContinuousBatchingEngine:
                         / (len(req.tokens) - 1))
         # refcount-aware: private pages recycle now; shared prefix pages
         # only once NO live slot maps them (then LRU, evict on pressure)
-        self.mgr.free(req.pages)
-        req.pages = None
+        self._release(req)
         slot.req, slot.length, slot.emitted, slot.done = None, 0, 0, False
         # the row MUST stop pointing at freed pages before they recycle
         self._tables[slot_id] = self.scratch_page
+        if self._ring_tables is not None:
+            self._ring_tables[slot_id] = 0
         self._tokens[slot_id] = 0
         self._budgets[slot_id] = 0
         self._override[slot_id] = True
@@ -2748,11 +2993,13 @@ class ContinuousBatchingEngine:
                     toks_in, lens_in = host_toks, host_lens
                 res = self._decode(
                     self.p, self.kcs, self.vcs, toks_in, lens_in,
-                    jnp.asarray(self._budgets), jnp.asarray(self._tables),
+                    jnp.asarray(self._budgets),
+                    self._tables_arg(self._tables, self._ring_tables),
                     jnp.asarray(live), k,
                     jnp.asarray(self.temperature, jnp.float32),
                     jnp.asarray(self.top_p, jnp.float32))
-                out, new_lens, done, self.kcs, self.vcs = res
+                out, new_lens, done, *extra, self.kcs, self.vcs = res
+                moe, lps = extra[:self._routed], extra[self._routed:]
                 self.device_steps += 1
                 if chain:
                     self._chain_tok = out[:, -1]
@@ -2778,7 +3025,7 @@ class ContinuousBatchingEngine:
                 # decode_chunk_s
                 rec = {"out": out, "lens": new_lens, "done": done,
                        "reqs": [s.req for s in self._slots],
-                       "t_disp0": t_disp0}
+                       "t_disp0": t_disp0, "moe": moe, "logprobs": lps}
         return rec
 
     def _commit_chunk(self, rec, token: Optional[int] = None) -> int:
@@ -2799,6 +3046,11 @@ class ContinuousBatchingEngine:
             out = np.asarray(rec["out"])      # the blocking host sync
             new_lens = np.asarray(rec["lens"])
             done = np.asarray(rec["done"])
+            # the routed layers' counts came with them: [4] from the
+            # decode chunk, [2, 4] (decode lane, window) from a mixed step
+            moe = [np.asarray(c).reshape(-1, len(MOE_COUNTS))
+                   for c in rec.get("moe", ())]
+            lps = [np.asarray(lp) for lp in rec.get("logprobs", ())]
             t1 = time.perf_counter()
             wait = t1 - t0
             stalled = wait > self.stall_threshold_s
@@ -2822,6 +3074,9 @@ class ContinuousBatchingEngine:
                 self.sync_wait_s += wait
                 if stalled:
                     self.blocked_syncs += 1
+                for counted in moe:
+                    for lane, row in zip(("decode", "chunk"), counted):
+                        self.moe_counts[lane] += row
                 produced = 0
                 for slot_id, slot in enumerate(self._slots):
                     req = rec["reqs"][slot_id]
@@ -2832,8 +3087,12 @@ class ContinuousBatchingEngine:
                     if self.eos is not None and self.eos in toks:
                         toks = toks[:toks.index(self.eos) + 1]
                     req.tokens.extend(toks)
+                    for lp in lps:
+                        req.logprobs.extend(lp[slot_id, :len(toks)].tolist())
                     produced += len(toks)
                     slot.emitted += len(toks)
+                    self._count_dropped(slot.length,
+                                        int(new_lens[slot_id]))
                     slot.length = int(new_lens[slot_id])
                     slot.done = bool(done[slot_id])
                     self._tokens[slot_id] = toks[-1] if toks else 0
@@ -3273,8 +3532,7 @@ class ContinuousBatchingEngine:
         bump, like `_retire_hung_slot`."""
         st, self._prefilling = self._prefilling, None
         req = st["req"]
-        self.mgr.free(req.pages)
-        req.pages = None
+        self._release(req)
         req.n_prefix = 0
         req.cached_tokens = 0
         req.bucket = None
@@ -3314,11 +3572,10 @@ class ContinuousBatchingEngine:
         req = slot.req
         req.requeued = True
         self.hung_requeued += 1
-        self.mgr.free(req.pages)
-        req.pages = None
+        self._release(req)
         req.slot = None
         req.bucket = None
-        req.tokens = []
+        req.tokens, req.logprobs = [], []
         req.prefill_time = None
         req.n_prefix = 0
         req.cached_tokens = 0
@@ -3326,6 +3583,8 @@ class ContinuousBatchingEngine:
         # the row must stop pointing at released pages before they are
         # handed to another request
         self._tables[slot_id] = self.scratch_page
+        if self._ring_tables is not None:
+            self._ring_tables[slot_id] = 0
         self._tokens[slot_id] = 0
         self._budgets[slot_id] = 0
         self._override[slot_id] = True

@@ -323,6 +323,99 @@ def test_moe_rows(for_chip, one_chip, held, experts):
     assert not re.search(r"(bf16|f32)\[32768,2048\]", text)
 
 
+# ---- the served `mellum` block at its published widths (PR 33) --------------
+# 32 slots, 32 q / 4 kv heads of 128, 64-token pages, contexts up to 7,168
+# tokens (a 112-column table), a window of 1,024 kept in rings of 25 pages
+
+MQ, MKV, MW, MTN, MWIN = 32, 4, 112, 512, 1024
+M_RING_POOL = ((33 * 25 + 1, MKV, PAGE, D), BF)
+M_FULL_POOL = ((1793, MKV, PAGE, D), BF)
+M_TABLES, M_LENS = ((32, MW), I32), ((32,), I32)
+
+
+@pytest.mark.parametrize("window", [MWIN, None], ids=["window", "full"])
+def test_mellum_paged_decode(for_chip, one_chip, window):
+    da = _mod("decode_attention")
+    pool = M_RING_POOL if window else M_FULL_POOL
+    text = _compile(
+        lambda *a: da.paged_decode_attention(*a, window=window), one_chip,
+        ((32, MQ, D), BF), pool, pool, M_TABLES, M_LENS)
+    assert ("decode_attention_window" in text) == bool(window)
+
+
+@pytest.mark.parametrize("window", [MWIN, None], ids=["window", "full"])
+def test_mellum_ragged_window(for_chip, one_chip, window):
+    ra = _mod("ragged_attention")
+    pool = M_RING_POOL if window else M_FULL_POOL
+    one = ((1,), I32)
+    text = _compile(
+        lambda *a: ra.ragged_paged_attention(*a, window=window), one_chip,
+        ((1, MTN, MQ, D), BF), ((1, MTN, MKV, D), BF), ((1, MTN, MKV, D), BF),
+        pool, pool, ((1, MW), I32), one, one)
+    assert ("ragged_attention_window" in text) == bool(window)
+
+
+@pytest.mark.parametrize("tokens", [32, MTN], ids=["decode", "prefill_window"])
+def test_mellum_expert_layer(for_chip, one_chip, tokens):
+    """The routed layer as the served programs call it: 64 experts of 2304 x
+    896 all held, 8 a token, forward alone — 256 rows on 64 groups in a
+    decode step (the row movements' jnp forms: 32 tokens are no token tile),
+    4,096 in a prefill window (their kernels)."""
+    from paddle_tpu.parallel import moe
+
+    d, f, e, k = 2304, 896, 64, 8
+
+    def layer(x, gates, wg, wu, wd, idx):
+        return moe.dropless_experts(x, idx, gates, wg, wu, wd,
+                                    tuple(range(e)), e)[0]
+
+    text = _compile(layer, one_chip, ((tokens, d), BF), ((tokens, k), F32),
+                    ((e, d, f), BF), ((e, d, f), BF), ((e, f, d), BF),
+                    ((tokens, k), I32))
+    assert "grouped_matmul" in text
+    assert ("moe_rows_in" in text and "moe_rows_out" in text) \
+        == (tokens == MTN)
+    assert "_bwd" not in text and "dgates" not in text
+
+
+@pytest.fixture
+def mellum_engine(for_chip):
+    """An engine over one period of the published block (three window layers
+    and a full one, every width as published), its parameters shapes alone."""
+    from paddle_tpu.models import MellumConfig
+    from paddle_tpu.models.mellum import serving_param_shapes
+    from paddle_tpu.serving import ContinuousBatchingEngine
+
+    cfg = MellumConfig(num_hidden_layers=4)
+    p = {k: jax.ShapeDtypeStruct(v, BF)
+         for k, v in serving_param_shapes(cfg).items()}
+    return ContinuousBatchingEngine(
+        cfg, p, slots=32, max_prompt_len=4096, max_new_tokens=3072,
+        token_budget=512, max_pages=225, logprobs=True)
+
+
+@pytest.mark.parametrize("program", ["decode", "unified"])
+def test_mellum_served_programs(mellum_engine, one_chip, program):
+    eng = mellum_engine
+    assert eng.mgr.ring_pages == 25 and eng.table_width == MW
+    fn, args = {name: (fn, args)
+                for name, fn, args in eng._program_inventory()}[program]
+    args = jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+        x.shape, x.dtype, sharding=one_chip), args)
+    text = fn.lower(*args).compile().as_text()
+    for name in ("decode_attention_window", "decode_attention",
+                 "grouped_matmul"):
+        assert re.search(rf"%{name}(\.\d+)? = [^\n]*custom-call", text), name
+    if program == "unified":
+        for name in ("ragged_attention_window", "ragged_attention",
+                     "moe_rows_in", "moe_rows_out"):
+            assert re.search(rf"%{name}(\.\d+)? = [^\n]*custom-call",
+                             text), name
+    assert text.startswith(
+        "HloModule jit_serve_" + {"decode": "decode_chunk",
+                                  "unified": "unified_step"}[program])
+
+
 def test_swiglu_fused_refuses_the_1b_mlp_shape():
     """K 2048, F 5504 is not 512-tileable: a caller who asked for the kernel
     gets an error, never the XLA form under the kernel's name."""

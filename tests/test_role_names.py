@@ -129,6 +129,23 @@ CASES = {
             "decode_attention").paged_decode_attention(*a, **kw)),
         [Q1, POOL8, POOL8, TABLES, LENS, SCALE, SCALE],
         {"decode_attention_q8": "decode_attention_q8"}),
+    # a sliding-window layer's calls carry their own label (PR 33)
+    "decode_attention.paged_gqa_window": (
+        lambda *a: _mod("decode_attention").paged_decode_attention(
+            *a, window=1024),
+        [Q1, POOL, POOL, TABLES, LENS],
+        {"decode_attention_window": "decode_attention"}),
+    "decode_attention.paged_gqa_d64_window": (
+        lambda *a: _mod("decode_attention").paged_decode_attention(
+            *a, window=96),
+        [((B, HQ, 64), BF)] + [((MAX_PAGES, HK, PAGE, 64), BF)] * 2
+        + [TABLES, LENS],
+        {"decode_attention_window": "decode_attention"}),
+    "ragged_attention_window": (
+        lambda *a: _mod("ragged_attention").ragged_paged_attention(
+            *a, window=1024),
+        [QWIN, KWIN, KWIN, POOL, POOL, TABLES, LENS, LENS],
+        {"ragged_attention_window": "ragged_attention"}),
     "ragged_attention": (
         lambda *a: _mod("ragged_attention").ragged_paged_attention(*a),
         [QWIN, KWIN, KWIN, POOL, POOL, TABLES, LENS, LENS],
