@@ -1295,7 +1295,13 @@ def build_quant_generate(cfg, b, sb, max_new, max_seq=None,
 def make_paged_kv_helpers(b, n_pre, nkv, dh, block_size, tables):
     """The two paged-cache plumbing pieces shared by every paged program
     (build_paged_generate and serving.engine): prefill page transpose and
-    the per-token page/slot scatter, closed over the traced block table."""
+    the per-token page/slot commit, closed over the traced block table.
+    The commit updates the pools in place in the layout the decode kernel
+    reads (`kernels/kv_commit.py`) where their shape allows — pages of whole
+    row tiles, heads of whole lane tiles, bf16 or f32 — and is XLA's
+    scatter otherwise."""
+    from ..kernels.kv_commit import commit_ok, kv_commit
+
     def to_pages(kv):
         """[b, n_pre*block_size, nkv, dh] -> [b, n_pre, nkv, block_size, dh]"""
         return jnp.transpose(
@@ -1304,6 +1310,8 @@ def make_paged_kv_helpers(b, n_pre, nkv, dh, block_size, tables):
     def kv_write(kc, vc, k, v, lens):
         page = tables[jnp.arange(b), lens // block_size]
         slot = lens % block_size
+        if commit_ok(kc, vc):
+            return kv_commit(kc, vc, k[:, 0], v[:, 0], page, slot)
         return (kc.at[page, :, slot, :].set(k[:, 0].astype(kc.dtype)),
                 vc.at[page, :, slot, :].set(v[:, 0].astype(vc.dtype)))
 

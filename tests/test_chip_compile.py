@@ -145,6 +145,38 @@ def test_paged_gqa_decode(for_chip, one_chip, geometry, kv):
         _compile(da.paged_decode_attention, one_chip, *shapes)
 
 
+# (slots, kv heads, head dim, page, pool pages, pool dtype)
+KV_COMMIT = {
+    # `mistral7b-reason-sat`: 32 slots, 8 kv heads, the 576-page pool
+    "cell-mistral": (32, HK, D, PAGE, 576, BF),
+    # `mellum2-reason-long`: 4 kv heads, a full layer's pool and a ring pool
+    "cell-mellum-full": (32, 4, D, PAGE, 1793, BF),
+    "cell-mellum-ring": (32, 4, D, PAGE, 826, BF),
+    # 8-row tiles, and more slots than one grid step stages
+    "f32": (8, 4, D, 16, 34, F32),
+    "slots-512": (512, HK, D, PAGE, 576, BF),
+}
+
+
+@pytest.mark.parametrize("geometry", sorted(KV_COMMIT))
+def test_kv_commit(for_chip, one_chip, geometry):
+    """The decode step's in-place K/V commit: one Mosaic call named
+    `kv_commit` whose outputs are its pool operands; with the pools donated,
+    as the served programs donate them, no copy beside it."""
+    kc = _mod("kv_commit")
+    slots, hk, d, page, pages, dtype = KV_COMMIT[geometry]
+    pool, new = ((pages, hk, page, d), dtype), ((slots, hk, d), dtype)
+    args = [jax.ShapeDtypeStruct(s, t, sharding=one_chip) for s, t in (
+        pool, pool, new, new, ((slots,), I32), ((slots,), I32))]
+    text = jax.jit(kc.kv_commit, donate_argnums=(0, 1)).lower(
+        *args).compile().as_text()
+    call = re.search(r"%kv_commit(\.\d+)? = [^\n]*custom-call[^\n]*", text)
+    assert call, "no custom call named kv_commit"
+    assert "output_to_operand_aliasing={{0}: (4, {}), {1}: (5, {})}" \
+        in call.group(0)
+    assert not re.search(r" copy\(", text)
+
+
 def test_role_names_reach_the_compiled_program(for_chip, one_chip):
     """What a profiler's trace will list (ISSUE 25): the Mosaic call is the
     instruction `%decode_attention...` — the kernel's registry name, whatever
@@ -394,23 +426,98 @@ def mellum_engine(for_chip):
         token_budget=512, max_pages=225, logprobs=True)
 
 
-@pytest.mark.parametrize("program", ["decode", "unified"])
-def test_mellum_served_programs(mellum_engine, one_chip, program):
-    eng = mellum_engine
-    assert eng.mgr.ring_pages == 25 and eng.table_width == MW
+def _served_program(eng, program, one_chip):
+    """The compiled text of one program of the engine's inventory."""
     fn, args = {name: (fn, args)
                 for name, fn, args in eng._program_inventory()}[program]
     args = jax.tree.map(lambda x: jax.ShapeDtypeStruct(
         x.shape, x.dtype, sharding=one_chip), args)
-    text = fn.lower(*args).compile().as_text()
+    return fn.lower(*args).compile().as_text()
+
+
+def _pools_in_another_layout(text, eng):
+    """Every mention of an array of a K/V pool's shape in a layout other
+    than the default one, which the decode kernel and the commit read: a
+    pool copied whole between two layouts (PR 33's trace: 26% of the served
+    expert cell's device time) shows as one, whichever side the copy is on."""
+    found = []
+    for shape in {kc.shape for kc in eng.kcs}:
+        dims = ",".join(str(n) for n in shape)
+        found += [m.group(0) for m in re.finditer(
+            rf"\w+\[{dims}\]\{{(?!3,2,1,0[:}}])[^}}]*\}}", text)]
+    return found
+
+
+@pytest.mark.parametrize("program", ["decode", "unified"])
+def test_mellum_served_programs(mellum_engine, one_chip, program):
+    eng = mellum_engine
+    assert eng.mgr.ring_pages == 25 and eng.table_width == MW
+    text = _served_program(eng, program, one_chip)
     for name in ("decode_attention_window", "decode_attention",
-                 "grouped_matmul"):
+                 "grouped_matmul", "kv_commit"):
         assert re.search(rf"%{name}(\.\d+)? = [^\n]*custom-call", text), name
+    assert not _pools_in_another_layout(text, eng)
     if program == "unified":
         for name in ("ragged_attention_window", "ragged_attention",
                      "moe_rows_in", "moe_rows_out"):
             assert re.search(rf"%{name}(\.\d+)? = [^\n]*custom-call",
                              text), name
+    assert text.startswith(
+        "HloModule jit_serve_" + {"decode": "decode_chunk",
+                                  "unified": "unified_step"}[program])
+
+
+@pytest.fixture
+def mistral_engine(for_chip):
+    """The dense served block at the widths and sizes `mistral7b-reason-sat`
+    serves (hidden 4096, MLP 14336, 32 q / 8 kv heads of 128; 32 slots, a
+    pool of 36,864 tokens = 576 pages of 64 and the scratch page's room), cut
+    to two layers, its parameters shapes alone. The numbers are written here
+    and not read from `benchmark/`: the program's tests import none of the
+    benchmark, so that a later change to its files cannot break them."""
+    from paddle_tpu.models import LlamaConfig
+    from paddle_tpu.serving import ContinuousBatchingEngine
+
+    h, f, v, layers, pool_tokens = 4096, 14336, 32768, 2, 36864
+    cfg = LlamaConfig(
+        vocab_size=v, hidden_size=h, intermediate_size=f,
+        num_hidden_layers=layers, num_attention_heads=HQ,
+        num_key_value_heads=HK, max_position_embeddings=32768,
+        rms_norm_eps=1e-5, rope_theta=1e6, tie_word_embeddings=False,
+        dtype="bfloat16")
+    shapes = {"llama.embed_tokens.weight": (v, h), "llama.norm.weight": (h,),
+              "lm_head.weight": (h, v)}
+    for i in range(layers):
+        pre = f"llama.layers.{i}."
+        shapes.update({
+            pre + "self_attn.q_proj.weight": (h, HQ * D),
+            pre + "self_attn.k_proj.weight": (h, HK * D),
+            pre + "self_attn.v_proj.weight": (h, HK * D),
+            pre + "self_attn.o_proj.weight": (HQ * D, h),
+            pre + "mlp.gate_proj.weight": (h, f),
+            pre + "mlp.up_proj.weight": (h, f),
+            pre + "mlp.down_proj.weight": (f, h),
+            pre + "input_layernorm.weight": (h,),
+            pre + "post_attention_layernorm.weight": (h,)})
+    p = {k: jax.ShapeDtypeStruct(s, BF) for k, s in shapes.items()}
+    # K and V, bf16, every layer: the bytes of the cell's pool of tokens
+    return ContinuousBatchingEngine(
+        cfg, p, slots=32, max_prompt_len=1024, max_new_tokens=768,
+        kv_pool_bytes=pool_tokens * 2 * layers * HK * D * 2)
+
+
+@pytest.mark.parametrize("program", ["decode", "unified"])
+def test_llama_served_programs(mistral_engine, one_chip, program):
+    """`mellum`'s twin for the dense block: the decode step commits K/V where
+    the pools lie, so neither served program copies a pool whole."""
+    eng = mistral_engine
+    assert eng.kcs[0].shape == (576, HK, PAGE, D)
+    text = _served_program(eng, program, one_chip)
+    names = ["decode_attention", "kv_commit", "rms_norm"] \
+        + ["ragged_attention"] * (program == "unified")
+    for name in names:
+        assert re.search(rf"%{name}(\.\d+)? = [^\n]*custom-call", text), name
+    assert not _pools_in_another_layout(text, eng)
     assert text.startswith(
         "HloModule jit_serve_" + {"decode": "decode_chunk",
                                   "unified": "unified_step"}[program])

@@ -168,6 +168,10 @@ CASES = {
         lambda *a: _mod("int4_matmul").int4_matmul(*a),
         [((8, 4096), BF), ((4096, 2048), I8), ((4096,), F32)],
         {"int4_matmul": "int4_matmul"}),
+    "kv_commit": (
+        lambda *a: _mod("kv_commit").kv_commit(*a),
+        [POOL, POOL, ((B, HK, D), BF), ((B, HK, D), BF), LENS, LENS],
+        {"kv_commit": "kv_commit"}),
 }
 
 
@@ -219,7 +223,7 @@ def test_no_pallas_call_in_kernels_lacks_a_name():
                       for node in ast.walk(tree)
                       if isinstance(node, ast.Call)
                       and getattr(node.func, "attr", "") == "pallas_call"]
-    assert len(calls) == 18     # the call sites CASES covers
+    assert len(calls) == 19     # the call sites CASES covers
     assert [c[:2] for c in calls if "name" not in c[2]] == []
 
 
