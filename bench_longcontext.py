@@ -12,12 +12,13 @@ Prints one JSON line per config:
 - full train step (fwd+bwd+AdamW, per-layer remat) tok/s + MFU at
   seq 8192 on the 1B-class GQA config;
 - the attention kernel's own TF/s at the 8k shape (fwd and fwd+bwd,
-  splash GQA fast path), so the attention share of the step is explicit.
+  grouped heads through the in-repo flash kernels), so the attention share
+  of the step is explicit.
 
 The multi-chip ring-attention path (parallel/ring_attention.py) cannot
 be wall-clocked on one chip — its numerics at the 8k shape are asserted
 on the virtual CPU mesh in tests/test_ring_attention.py; the single-chip
-8k attention below is the splash kernel the ring degenerates to at
+8k attention below is the flash kernel the ring degenerates to at
 sep=1.
 """
 from __future__ import annotations

@@ -33,13 +33,18 @@ _NEG_INF = -1e30
 # actual seq len; seq lens must then divide the clamped block)
 BLOCK_Q = 512
 BLOCK_K = 512
-# on a TPU, equal heads on long 512-divisible sequences at a lane-aligned head
-# (`_wide_blocks_ok`: the shapes JAX's bundled MHA kernel ran until PR 30)
-# tile the forward at 1024 rows, and splash tiles grouped heads so. The
-# backward tiles at 1024 rows at a head of one lane tile, fewer at wider heads
-# (`_bwd_block_cap`), whatever the path
+# on a TPU, equal or grouped heads on long 512-divisible sequences at a
+# lane-aligned head (`_wide_blocks_ok`) tile the forward at 1024 rows (at
+# [1, 8192, 32/4, 128] the causal forward takes 4.93 ms so, 7.96 at 512: my
+# chip runs, PR 35). The backward tiles at 1024 rows at a head of one lane
+# tile, fewer at wider heads (`_bwd_block_cap`)
 FAST_PATH_BLOCK = 1024
 FAST_PATH_SEQ_MULTIPLE = 512
+# ... and at most 512 under a window: of a band of 2,048 keys 1024-row blocks
+# sweep 1.5 x the mask, 512-row blocks 1.25 x (the backward at that shape:
+# 5.54 against 5.25 ms; the forward is faster at 1024 all the same, 2.89
+# against 3.97 ms: my chip runs, PR 35)
+WINDOW_BWD_BLOCK = 512
 # the widest head whose forward takes the 1024-row blocks (compiled for the
 # chip up to here: the backward works in blocks of 256 rows at 384 and 512)
 WIDE_BLOCK_MAX_HEAD = 512
@@ -53,10 +58,10 @@ BWD_VMEM_BESIDE_DQ_BYTES = 12 << 20
 
 
 def _wide_blocks_ok(sq, sk, hq, hk, dh) -> bool:
-    """Shapes whose forward tiles at FAST_PATH_BLOCK rows on a TPU: equal
-    heads — or, in the core's layout, equal batch * heads — on long
+    """Shapes whose forward tiles at FAST_PATH_BLOCK rows on a TPU: equal or
+    grouped heads — in the core's layout, batch * heads — on long
     block-divisible sequences at a lane-aligned head."""
-    return (hq == hk and dh % LANE == 0 and dh <= WIDE_BLOCK_MAX_HEAD
+    return (hq % hk == 0 and dh % LANE == 0 and dh <= WIDE_BLOCK_MAX_HEAD
             and sq % FAST_PATH_SEQ_MULTIPLE == 0
             and sk % FAST_PATH_SEQ_MULTIPLE == 0 and sq == sk)
 
@@ -70,13 +75,15 @@ def _block_rows(seq: int, cap: int) -> int:
     return rows if seq > cap and rows % LANE == 0 else seq
 
 
-def _bwd_block_cap(d: int) -> int:
+def _bwd_block_cap(d: int, window: Optional[int] = None) -> int:
     """The most rows of a backward block: FAST_PATH_BLOCK at a head of one
     lane tile, fewer as the head widens (the kernel holds q, k, v, do, dk and
     dv blocks and four `[block_k, block_q]` f32 intermediates at once), by
-    powers of two: 128 -> 1024, 256 -> 512, 384 and 512 -> 256."""
-    return min(FAST_PATH_BLOCK,
-               1 << (FAST_PATH_BLOCK * LANE // d).bit_length() - 1)
+    powers of two: 128 -> 1024, 256 -> 512, 384 and 512 -> 256; under a
+    window no more than WINDOW_BWD_BLOCK."""
+    cap = min(FAST_PATH_BLOCK,
+              1 << (FAST_PATH_BLOCK * LANE // d).bit_length() - 1)
+    return cap if window is None else min(cap, WINDOW_BWD_BLOCK)
 
 
 def _fwd_blocks(q_shape, k_shape, on_tpu: bool):
@@ -192,30 +199,89 @@ def _on_tpu() -> bool:
 
 
 # ---------------------------------------------------------------------------
+# a window: row t sees keys s with 0 <= t - s < window (Sq == Sk). The kernels
+# walk, of each q block (forward) or k block (backward), the span of blocks
+# the band crosses, and visit nothing behind it.
+# ---------------------------------------------------------------------------
+
+def _check_window(causal: bool, sq: int, sk: int) -> None:
+    if not causal or sq != sk:
+        raise ValueError("a window is causal self-attention: causal=True and "
+                         f"equal sequence lengths (got causal={causal}, "
+                         f"{sq} and {sk})")
+
+
+def _kv_span(qi, block_q: int, block_k: int, window: int, mx=jnp.maximum):
+    """(first, last) k block that q block `qi` sees inside the window; `mx`
+    is `max` for Python ints, `jnp.maximum` inside a kernel or an index map."""
+    return (mx(qi * block_q - (window - 1), 0) // block_k,
+            (qi * block_q + block_q - 1) // block_k)
+
+
+def _q_span(ki, block: int, nq: int, window: int, mn=jnp.minimum):
+    """(first, last) q block that sees k block `ki` inside the window, at
+    equal blocks: from the diagonal down to the window's far edge."""
+    return ki, mn((ki * block + block - 1 + window - 1) // block, nq - 1)
+
+
+def _span_blocks(span) -> int:
+    return span[1] - span[0] + 1
+
+
+def window_pairs(seq: int, heads: int, kv_heads: int, head_dim: int,
+                 window: int, on_tpu: bool):
+    """(query, key) pairs of ONE head of ONE sequence: (those of the score
+    blocks `flash_attention_window_fwd` sweeps, those `_window_bwd` sweeps,
+    those inside the mask: `window` keys a row, fewer for the first rows).
+    Constants of the shapes, from the same block arithmetic the kernels'
+    grids are built with; a sequence off the forward's blocks takes the jnp
+    form, which masks the whole square."""
+    w = min(window, seq)
+    mask = w * seq - w * (w - 1) // 2
+    bq, bk = _fwd_blocks((heads, seq, head_dim), (kv_heads, seq), on_tpu)
+    if seq % bq or seq % bk:
+        return seq * seq, seq * seq, mask
+    b = _block_rows(seq, _bwd_block_cap(head_dim, window))
+    fwd = sum(_span_blocks(_kv_span(i, bq, bk, window, max))
+              for i in range(seq // bq)) * bq * bk
+    bwd = sum(_span_blocks(_q_span(j, b, seq // b, window, min))
+              for j in range(seq // b)) * b * b
+    return fwd, bwd, mask
+
+
+# ---------------------------------------------------------------------------
 # forward kernel: grid (batch*q_heads, num_q_blocks, num_k_blocks)
 # ---------------------------------------------------------------------------
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
                 m_scr, l_scr, acc_scr, *, causal: bool, scale: float,
-                block_q: int, block_k: int, q_offset: int, lse_rows: bool):
+                block_q: int, block_k: int, q_offset: int, lse_rows: bool,
+                window: Optional[int] = None):
     """q_offset = sk - sq aligns the causal diagonal to the END of the kv
     sequence (paddle/flash-attn convention: the last q row sees all keys).
     `lse_rows`: the row statistic leaves as ONE lane-dense row `[1, block_q]`
     (a block of whole lane tiles can be transposed) instead of replicated
-    across 128 lanes."""
+    across 128 lanes. With a `window` the last grid axis walks the q block's
+    own span of k blocks (`_kv_span`), not the whole kv axis."""
     qi = pl.program_id(1)
-    ki = pl.program_id(2)
+    step = ki = pl.program_id(2)
     nk = pl.num_programs(2)
 
-    @pl.when(ki == 0)
+    @pl.when(step == 0)
     def _init():
         m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    # causal: skip k blocks strictly above the diagonal band
-    run = ((qi * block_q + block_q - 1 + q_offset >= ki * block_k)
-           if causal else True)
+    if window is not None:
+        # the grid holds the widest span: a q block near the start has fewer
+        first, last = _kv_span(qi, block_q, block_k, window)
+        ki = first + step
+        run = ki <= last
+    else:
+        # causal: skip k blocks strictly above the diagonal band
+        run = ((qi * block_q + block_q - 1 + q_offset >= ki * block_k)
+               if causal else True)
 
     @pl.when(run)
     def _compute():
@@ -229,7 +295,10 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
                 jnp.int32, (block_q, block_k), 0)
             kpos = ki * block_k + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(qpos >= kpos, s, _NEG_INF)
+            seen = qpos >= kpos
+            if window is not None:
+                seen = jnp.logical_and(seen, qpos - kpos < window)
+            s = jnp.where(seen, s, _NEG_INF)
         m_prev = m_scr[...]               # [block_q, 128] (row stat replicated)
         l_prev = l_scr[...]
         m_cur = jnp.max(s, axis=1, keepdims=True)
@@ -244,7 +313,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         m_scr[...] = m_new
         l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
 
-    @pl.when(ki == nk - 1)
+    @pl.when(step == nk - 1)
     def _final():
         o_ref[0] = (acc_scr[...] / l_scr[:, :1]).astype(o_ref.dtype)
         # the scratch holds the row statistic replicated across 128 lanes
@@ -254,11 +323,15 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
 
 def _fwd_pallas(q, k, v, causal: bool, scale: float,
                 block_q: int = BLOCK_Q, block_k: int = BLOCK_K,
-                interpret: Optional[bool] = None):
+                interpret: Optional[bool] = None,
+                window: Optional[int] = None):
     """q: [BH, Sq, D]; k/v: [BKVH, Sk, D]. Returns (out [BH, Sq, D],
     lse [BH, Sq] fp32, the row statistic the backward starts from). The
     kernel writes it one f32 a row where its q block is whole lane tiles,
-    and replicated across 128 lanes (lane 0 kept here) where it is not."""
+    and replicated across 128 lanes (lane 0 kept here) where it is not.
+    With a `window` (causal, Sq == Sk) the grid's last axis is as long as the
+    widest span of k blocks a q block sees, and the blocks behind the window
+    are never visited: `flash_attention_window_fwd`."""
     bh, sq, d = q.shape
     bkv, sk, _ = k.shape
     rep = bh // bkv                      # q heads per kv head (GQA)
@@ -267,18 +340,26 @@ def _fwd_pallas(q, k, v, causal: bool, scale: float,
     if sq % block_q or sk % block_k:
         raise ValueError(f"seq lens ({sq},{sk}) not divisible by blocks "
                          f"({block_q},{block_k})")
-    grid = (bh, sq // block_q, sk // block_k)
+    nk = sk // block_k
+    if window is not None:
+        _check_window(causal, sq, sk)
+        nk = max(_span_blocks(_kv_span(i, block_q, block_k, window, max))
+                 for i in range(sq // block_q))
+    grid = (bh, sq // block_q, nk)
     vma = operand_vma(q, k, v)
     q_offset = sk - sq
     lse_rows = block_q % LANE == 0
     kernel = functools.partial(
         _fwd_kernel, causal=causal, scale=scale, block_q=block_q,
-        block_k=block_k, q_offset=q_offset, lse_rows=lse_rows)
+        block_k=block_k, q_offset=q_offset, lse_rows=lse_rows, window=window)
 
     def kv_map(b, i, j):
         # a kv block the causal band skips is not fetched: the index stays
         # on the last one this q block needs
-        if causal:
+        if window is not None:
+            first, last = _kv_span(i, block_q, block_k, window)
+            j = jnp.minimum(first + j, last)
+        elif causal:
             j = jnp.minimum(j, jnp.maximum(
                 (i * block_q + block_q - 1 + q_offset) // block_k, 0))
         return (b // rep, j, 0)
@@ -291,7 +372,7 @@ def _fwd_pallas(q, k, v, causal: bool, scale: float,
         lse_shape = (bh, sq, LANE)
     out, lse = pl.pallas_call(
         kernel,
-        name=CONSTRAINT.name + "_fwd",
+        name=CONSTRAINT.name + ("_fwd" if window is None else "_window_fwd"),
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
@@ -322,7 +403,18 @@ def _fwd_pallas(q, k, v, causal: bool, scale: float,
 # jnp reference core (oracle + odd-shape fallback), layout [BH, S, D]
 # ---------------------------------------------------------------------------
 
-def _fwd_ref(q, k, v, causal: bool, scale: float):
+def _seen(sq: int, sk: int, window: Optional[int]):
+    """[Sq, Sk] bool: the causal mask aligned to the end of the keys, and
+    inside it the window's band."""
+    mask = jnp.tril(jnp.ones((sq, sk), bool), k=sk - sq)
+    if window is not None:
+        mask = jnp.logical_and(mask, jnp.triu(jnp.ones((sq, sk), bool),
+                                              k=sk - sq - (window - 1)))
+    return mask
+
+
+def _fwd_ref(q, k, v, causal: bool, scale: float,
+             window: Optional[int] = None):
     bh, sq, d = q.shape
     bkv, sk, _ = k.shape
     if bkv != bh:
@@ -331,8 +423,7 @@ def _fwd_ref(q, k, v, causal: bool, scale: float):
         v = jnp.repeat(v, rep, axis=0)
     s = jnp.einsum("bqd,bkd->bqk", q, k).astype(jnp.float32) * scale
     if causal:
-        mask = jnp.tril(jnp.ones((sq, sk), bool), k=sk - sq)
-        s = jnp.where(mask, s, _NEG_INF)
+        s = jnp.where(_seen(sq, sk, window), s, _NEG_INF)
     m = jnp.max(s, axis=-1, keepdims=True)
     p = jnp.exp(s - m)
     l = jnp.sum(p, axis=-1, keepdims=True)
@@ -340,12 +431,14 @@ def _fwd_ref(q, k, v, causal: bool, scale: float):
     return out
 
 
-def _pallas_ok(q, k, v, on_tpu: bool):
+def _pallas_ok(q, k, v, on_tpu: bool, window: Optional[int] = None):
     """Whether the in-repo kernels take these operands — decided here,
     before the call, so whatever the kernel then raises propagates."""
     block_q, block_k = _fwd_blocks(q.shape, k.shape, on_tpu)
     if q.shape[1] % block_q or k.shape[1] % block_k \
             or q.shape[0] % k.shape[0]:
+        return False
+    if window is not None and q.shape[1] != k.shape[1]:
         return False
     # jax's Pallas interpreter cannot run under a vma-checked shard_map
     return on_tpu or not operand_vma(q, k, v)
@@ -358,7 +451,7 @@ def _pallas_ok(q, k, v, on_tpu: bool):
 def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr, *,
                 causal: bool, scale: float, block_q: int, block_k: int,
-                q_offset: int):
+                q_offset: int, window: Optional[int] = None):
     """Grid (batch*q_heads, k blocks, q blocks), q innermost. Each block of
     scores is computed once, TRANSPOSED (`[block_k, block_q]`: k rows, q
     columns), so that the per-row terms `lse` and `delta` come in as one
@@ -367,25 +460,38 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     `[block_k, d]` scratch; dq accumulates over the OUTER axis in a
     whole-sequence `[sq, d]` f32 scratch and leaves block by block during the
     last k block's steps (`_bwd_pallas`'s dq index map). That scratch is what
-    bounds the sequence: `_bwd_vmem_bytes` against `VMEM_MAX_BYTES`."""
+    bounds the sequence: `_bwd_vmem_bytes` against `VMEM_MAX_BYTES`.
+
+    With a `window` (equal blocks, Sq == Sk) the inner axis walks the k
+    block's own span of q blocks (`_q_span`), from the diagonal down; q block
+    i has its last k block on its diagonal, so dq's block i leaves at the
+    first step of k block i."""
     ki = pl.program_id(1)
     qi = pl.program_id(2)
     nk = pl.num_programs(1)
     nq = pl.num_programs(2)
+    step = qi
+    if window is not None:
+        first, last = _q_span(ki, block_q, dq_scr.shape[0] // block_q, window)
+        qi = first + step
     rows = pl.ds(pl.multiple_of(qi * block_q, block_q), block_q)
 
-    @pl.when(jnp.logical_and(ki == 0, qi == 0))
+    @pl.when(jnp.logical_and(ki == 0, step == 0))
     def _init_dq():
         dq_scr[...] = jnp.zeros_like(dq_scr)
 
-    @pl.when(qi == 0)
+    @pl.when(step == 0)
     def _init_dkv():
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
-    # causal: skip q blocks strictly above the diagonal band
-    run = ((qi * block_q + block_q - 1 + q_offset >= ki * block_k)
-           if causal else True)
+    if window is not None:
+        # the grid holds the widest span: the last k blocks have fewer
+        run = qi <= last
+    else:
+        # causal: skip q blocks strictly above the diagonal band
+        run = ((qi * block_q + block_q - 1 + q_offset >= ki * block_k)
+               if causal else True)
 
     @pl.when(run)
     def _compute():
@@ -400,7 +506,10 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 jnp.int32, (block_k, block_q), 0)
             qpos = qi * block_q + q_offset + jax.lax.broadcasted_iota(
                 jnp.int32, (block_k, block_q), 1)
-            st = jnp.where(qpos >= kpos, st, _NEG_INF)
+            seen = qpos >= kpos
+            if window is not None:
+                seen = jnp.logical_and(seen, qpos - kpos < window)
+            st = jnp.where(seen, st, _NEG_INF)
         pt = jnp.exp(st - lse_ref[0])                 # [block_k, block_q]
         dv_scr[...] += jax.lax.dot_general(
             pt.astype(do.dtype), do, (((1,), (0,)), ((), ())),
@@ -416,32 +525,44 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             dst, k, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    @pl.when(qi == nq - 1)
+    @pl.when(step == nq - 1)
     def _final_dkv():
         dk_ref[0] = dk_scr[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
 
-    @pl.when(ki == nk - 1)
+    @pl.when(step == 0 if window is not None else ki == nk - 1)
     def _final_dq():
         dq_ref[0] = dq_scr[rows, :].astype(dq_ref.dtype)
 
 
 def _bwd_pallas(q, k, v, out, lse, do, causal: bool, scale: float,
                 interpret: bool, block_q: Optional[int] = None,
-                block_k: Optional[int] = None):
+                block_k: Optional[int] = None,
+                window: Optional[int] = None):
     """Flash backward from `lse` [BH, Sq] f32. Returns (dq [BH,Sq,D], dk/dv
     [BH,Sk,D] per q-head — caller reduces over GQA groups). The scoped VMEM
     limit follows dq's accumulator by arithmetic (`_bwd_vmem_bytes`): Mosaic's
     default up to 4 MiB of it (2048 x 128 and 4096 x 256, the trained cells),
     raised above that, and a sequence past `VMEM_MAX_BYTES` is the caller's
-    to refuse (`_bwd_refusal`)."""
+    to refuse (`_bwd_refusal`). With a `window` (causal, Sq == Sk, equal
+    blocks) the inner axis is as long as the widest span of q blocks that see
+    a k block, and the q blocks past the window's far edge are never
+    visited: `flash_attention_window_bwd`."""
     bh, sq, d = q.shape
     bkv, sk, _ = k.shape
     rep = bh // bkv
-    block_q = block_q or _block_rows(sq, _bwd_block_cap(d))
-    block_k = block_k or _block_rows(sk, _bwd_block_cap(d))
+    block_q = block_q or _block_rows(sq, _bwd_block_cap(d, window))
+    block_k = block_k or _block_rows(sk, _bwd_block_cap(d, window))
     nq, nk = sq // block_q, sk // block_k
     q_offset = sk - sq
+    steps = nq
+    if window is not None:
+        _check_window(causal, sq, sk)
+        if block_q != block_k:
+            raise ValueError(f"the window backward takes equal blocks, not "
+                             f"({block_q},{block_k})")
+        steps = max(_span_blocks(_q_span(j, block_q, nq, window, min))
+                    for j in range(nk))
     vma = operand_vma(q, k, v, do)
     vmem = _bwd_vmem_bytes(sq, d)
     # one f32 a row, never broadcast: [BH, 1, Sq] puts a block's rows in lanes
@@ -452,7 +573,10 @@ def _bwd_pallas(q, k, v, out, lse, do, causal: bool, scale: float,
     def q_map(b, j, i):
         # a q block the causal band skips is not fetched: the index stays on
         # the first one this k block needs
-        if causal:
+        if window is not None:
+            first, last = _q_span(j, block_q, nq, window)
+            i = jnp.minimum(first + i, last)
+        elif causal:
             i = jnp.maximum(i, jnp.clip((j * block_k - q_offset) // block_q,
                                         0, nq - 1))
         return (b, i, 0)
@@ -466,15 +590,17 @@ def _bwd_pallas(q, k, v, out, lse, do, causal: bool, scale: float,
                            lambda b, j, i: (b // rep, j, 0))
     # dq is whole only after the last k block: until then the output window
     # rests on block 0, which nothing writes back before the index moves
+    # (with a window, block j is whole at k block j's first step)
     dq_spec = pl.BlockSpec(
-        (1, block_q, d), lambda b, j, i: (b, jnp.where(j == nk - 1, i, 0), 0))
+        (1, block_q, d), (lambda b, j, i: (b, j, 0)) if window is not None
+        else lambda b, j, i: (b, jnp.where(j == nk - 1, i, 0), 0))
     dkv_spec = pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0))
     return pl.pallas_call(
         functools.partial(_bwd_kernel, causal=causal, scale=scale,
                           block_q=block_q, block_k=block_k,
-                          q_offset=q_offset),
-        name=CONSTRAINT.name + "_bwd",
-        grid=(bh, nk, nq),
+                          q_offset=q_offset, window=window),
+        name=CONSTRAINT.name + ("_bwd" if window is None else "_window_bwd"),
+        grid=(bh, nk, steps),
         in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
         out_specs=[dq_spec, dkv_spec, dkv_spec],
         out_shape=[jax.ShapeDtypeStruct((bh, sq, d), q.dtype, vma=vma),
@@ -494,29 +620,30 @@ def _bwd_pallas(q, k, v, out, lse, do, causal: bool, scale: float,
 # custom_vjp over the [BH, S, D] core, under ONE jit
 # ---------------------------------------------------------------------------
 
-def _fwd_core(q, k, v, causal, scale, on_tpu):
+def _fwd_core(q, k, v, causal, scale, on_tpu, window=None):
     """Returns (out, lse): lse is [BH, Sq] f32 from the kernel, or None behind
     the jnp form (whose backward recomputes the statistics)."""
-    if _pallas_ok(q, k, v, on_tpu):
+    if _pallas_ok(q, k, v, on_tpu, window):
         return _fwd_pallas(q, k, v, causal, scale,
                            *_fwd_blocks(q.shape, k.shape, on_tpu),
-                           interpret=not on_tpu)
-    return _fwd_ref(q, k, v, causal, scale), None
+                           interpret=not on_tpu, window=window)
+    return _fwd_ref(q, k, v, causal, scale, window), None
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _flash_vjp(q, k, v, causal: bool, scale: float, on_tpu: bool):
-    return _fwd_core(q, k, v, causal, scale, on_tpu)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash_vjp(q, k, v, causal: bool, scale: float, on_tpu: bool,
+               window: Optional[int] = None):
+    return _fwd_core(q, k, v, causal, scale, on_tpu, window)[0]
 
 
-def _flash_vjp_fwd(q, k, v, causal, scale, on_tpu):
-    out, lse = _fwd_core(q, k, v, causal, scale, on_tpu)
+def _flash_vjp_fwd(q, k, v, causal, scale, on_tpu, window):
+    out, lse = _fwd_core(q, k, v, causal, scale, on_tpu, window)
     if lse is not None and _bwd_refusal(q.shape[1], q.shape[2]):
         raise ValueError(_bwd_refusal(q.shape[1], q.shape[2]))
     return out, (q, k, v, out, lse)
 
 
-def _flash_vjp_bwd(causal, scale, on_tpu, res, do):
+def _flash_vjp_bwd(causal, scale, on_tpu, window, res, do):
     """FA2 backward: dv = P^T dO ; dS = P * (dO V^T - rowsum(dO*O)) * scale;
     dq = dS K ; dk = dS^T Q (reference math:
     paddle/phi/kernels/gpu/flash_attn_grad_kernel.cu via the flashattn
@@ -526,7 +653,7 @@ def _flash_vjp_bwd(causal, scale, on_tpu, res, do):
     bh, sq, d = q.shape
     if lse is not None:
         dq, dk, dv = _bwd_pallas(q, k, v, out, lse, do, causal, scale,
-                                 interpret=not on_tpu)
+                                 interpret=not on_tpu, window=window)
         rep = bh // k.shape[0]
         if rep > 1:
             dk = dk.reshape(k.shape[0], rep, *dk.shape[1:]).sum(1)
@@ -538,8 +665,7 @@ def _flash_vjp_bwd(causal, scale, on_tpu, res, do):
     vr = jnp.repeat(v, rep, axis=0) if rep > 1 else v
     s = jnp.einsum("bqd,bkd->bqk", q, kr).astype(jnp.float32) * scale
     if causal:
-        mask = jnp.tril(jnp.ones((sq, sk), bool), k=sk - sq)
-        s = jnp.where(mask, s, _NEG_INF)
+        s = jnp.where(_seen(sq, sk, window), s, _NEG_INF)
     lse = jax.scipy.special.logsumexp(s, axis=-1)
     p = jnp.exp(s - lse[..., None])                       # [BH, Sq, Sk] fp32
     do32 = do.astype(jnp.float32)
@@ -563,67 +689,45 @@ _flash_vjp.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 # module, so a step's text holds each kernel's Mosaic payload once; what the
 # trace depends on beside the operands (`jax.default_backend()`) is an
 # argument, so that it is part of the jit's key
-_flash_jit = jax.jit(_flash_vjp, static_argnums=(3, 4, 5))
+_flash_jit = jax.jit(_flash_vjp, static_argnums=(3, 4, 5, 6))
 
 
-def _flash_core(q, k, v, causal: bool, scale: float):
+def _flash_core(q, k, v, causal: bool, scale: float,
+                window: Optional[int] = None):
     """q: [BH, Sq, D]; k/v: [BKVH, Sk, D]; differentiable."""
-    return _flash_jit(q, k, v, causal, scale, _on_tpu())
+    return _flash_jit(q, k, v, causal, scale, _on_tpu(), window)
 
 
 # ---------------------------------------------------------------------------
 # public API, paddle layout [B, S, H, D]
 # ---------------------------------------------------------------------------
 
-def _splash_ok(sq, sk, hq, hk, dh) -> bool:
-    """GQA shapes for the splash kernel (grouped heads natively — the fast
-    path for Llama-2-70B/Llama-3-class configs where hk < hq)."""
-    return (_on_tpu() and hq != hk and hq % hk == 0 and dh % LANE == 0
-            and sq % FAST_PATH_SEQ_MULTIPLE == 0
-            and sk % FAST_PATH_SEQ_MULTIPLE == 0 and sq == sk)
-
-
-@functools.lru_cache(maxsize=16)
-def _splash_kernel(sq, sk, hq, causal: bool):
-    """Build (and cache) a splash GQA kernel.
-
-    Block sizes tuned on v5e at b8/s2048/hq16/hkv4/d128: fwd 20.1 TF/s,
-    fwd+bwd 34.3 TF/s (vs 19.8/30.7 for the in-repo kernel and 16.5/26.7
-    for kv-repeat through the bundled MHA kernel). Callers must construct
-    under jax.ensure_compile_time_eval(): built inside a jit trace, the
-    kernel's mask-info arrays become trace-local constants and poison the
-    cache for later traces (UnexpectedTracerError)."""
-    from jax.experimental.pallas.ops.tpu.splash_attention import (
-        splash_attention_kernel as _sk, splash_attention_mask as _sm)
-
-    mk = (_sm.CausalMask((sq, sk)) if causal else _sm.FullMask((sq, sk)))
-    mask = _sm.MultiHeadMask([mk for _ in range(hq)])
-    bq = min(FAST_PATH_BLOCK, sq)
-    bkv = min(FAST_PATH_BLOCK, sk)
-    bc = min(FAST_PATH_SEQ_MULTIPLE, sk)
-    blocks = _sk.BlockSizes(
-        block_q=bq, block_kv=bkv, block_kv_compute=bc,
-        block_q_dkv=bq, block_kv_dkv=bkv, block_kv_dkv_compute=bc,
-        block_q_dq=bq, block_kv_dq=bkv)
-    return _sk.make_splash_mha(mask, head_shards=1, q_seq_shards=1,
-                               block_sizes=blocks)
-
-
 def flash_attention(q, k, v, causal: bool = False,
-                    scale: Optional[float] = None):
+                    scale: Optional[float] = None,
+                    window: Optional[int] = None):
     """Differentiable flash attention; layout [B, S, H, D] (paddle
     flash_attn layout, ops.yaml:1765). kv heads may divide q heads (GQA).
 
     What runs where, chosen from shapes before the call (nothing a kernel
-    raises is caught): grouped heads on long 512-divisible sequences take
-    splash — the kernel bundled with the installed jax, the TPU analog of the
-    reference vendoring Dao's flash-attn library (third_party/flashattn) —
-    forward and backward (`_splash_ok`). Everything else goes through the
-    jitted `[B*H, S, D]` core: the in-repo `flash_attention_fwd` where
-    `_pallas_ok` holds (on a TPU at 1024-row blocks for equal heads on such
+    raises is caught): everything goes through the jitted `[B*H, S, D]` core
+    — the in-repo `flash_attention_fwd` where `_pallas_ok` holds (on a TPU at
+    1024-row blocks for equal or grouped heads on long 512-divisible
     sequences, `_wide_blocks_ok`, at 512 otherwise), which hands one f32 a
     row to the one-pass `flash_attention_bwd`; the jnp form, which
-    differentiates itself, otherwise.
+    differentiates itself, otherwise. Grouped heads reach their kv head
+    through the index maps, nothing is repeated; the backward returns dk and
+    dv a q head and sums them over the group. One route for every shape:
+    at `[1, 8192, 32/4, 128]` causal this pair takes 4.93 + 9.12 ms where
+    splash, the kernel bundled with jax, takes 4.68 + 13.22 (my chip runs,
+    PR 35).
+
+    `window` (with `causal=True`, equal sequence lengths): row t sees the
+    keys s with 0 <= t - s < window. The same two kernels under the labels
+    `flash_attention_window_fwd|_bwd`, their grids as long as the widest
+    span of blocks the band crosses, so the blocks behind the window are
+    never visited (at the shape above under a window of 2,048: 2.89 + 5.25
+    ms against the causal 4.93 + 9.12). A window no shorter than the
+    sequence is no window.
 
     The backward keeps dq for the whole sequence in VMEM, so differentiating
     raises a ValueError past `sq * head_dim` = 22M (`_bwd_refusal`: 172,032
@@ -635,18 +739,17 @@ def flash_attention(q, k, v, causal: bool = False,
     sk = k.shape[1]
     if scale is None:
         scale = 1.0 / math.sqrt(dh)
-    if _splash_ok(sq, sk, hq, hk, dh):
-        with jax.ensure_compile_time_eval():
-            kernel = _splash_kernel(sq, sk, hq, bool(causal))
-        # splash takes pre-scaled q, per-example [h, s, d] layout
-        qs = jnp.swapaxes(q, 1, 2) * jnp.asarray(scale, q.dtype)
-        out = jax.vmap(kernel)(qs, jnp.swapaxes(k, 1, 2),
-                               jnp.swapaxes(v, 1, 2))
-        return jnp.swapaxes(out, 1, 2)
+    if window is not None:
+        _check_window(causal, sq, sk)
+        if window < 1:
+            raise ValueError(f"a window holds at least the row's own key, "
+                             f"not {window}")
+        if window >= sk:            # a window that masks nothing is none
+            window = None
     qc = jnp.swapaxes(q, 1, 2).reshape(b * hq, sq, dh)
     kc = jnp.swapaxes(k, 1, 2).reshape(b * hk, sk, dh)
     vc = jnp.swapaxes(v, 1, 2).reshape(b * hk, sk, dh)
-    out = _flash_core(qc, kc, vc, causal, scale)
+    out = _flash_core(qc, kc, vc, causal, scale, window)
     return jnp.swapaxes(out.reshape(b, hq, sq, dh), 1, 2)
 
 

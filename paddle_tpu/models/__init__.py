@@ -26,3 +26,6 @@ from .glm4_moe_lite import (  # noqa: F401
     Glm4MoeLiteConfig, Glm4MoeLiteForCausalLM,
     Glm4MoeLitePretrainingCriterion,
 )
+from .afmoe import (  # noqa: F401
+    AfmoeConfig, AfmoeForCausalLM, AfmoePretrainingCriterion,
+)

@@ -17,9 +17,10 @@ from ...framework.flags import flag
 __all__ = ["scaled_dot_product_attention", "flash_attention", "flash_attn_unpadded", "sdp_kernel"]
 
 
-def _sdpa_ref(q, k, v, mask, dropout_p, causal, scale=None):
+def _sdpa_ref(q, k, v, mask, dropout_p, causal, scale=None, window=None):
     """Reference attention in fp32 accumulation. q/k/v: [B, S, H, D] (paddle
-    flash_attn layout)."""
+    flash_attn layout). `window` (causal only): row t sees keys s with
+    0 <= t - s < window."""
     qt = jnp.swapaxes(q, 1, 2)  # [B,H,S,D]
     kt = jnp.swapaxes(k, 1, 2)
     vt = jnp.swapaxes(v, 1, 2)
@@ -34,6 +35,9 @@ def _sdpa_ref(q, k, v, mask, dropout_p, causal, scale=None):
     if causal:
         ql, kl = logits.shape[-2], logits.shape[-1]
         cm = jnp.tril(jnp.ones((ql, kl), bool), k=kl - ql)
+        if window is not None:
+            cm = cm & jnp.triu(jnp.ones((ql, kl), bool),
+                               k=kl - ql - (window - 1))
         logits = jnp.where(cm, logits, -1e30)
     if mask is not None:
         if mask.dtype == jnp.bool_:
@@ -46,26 +50,33 @@ def _sdpa_ref(q, k, v, mask, dropout_p, causal, scale=None):
 
 
 def flash_attention(query, key, value, dropout=0.0, causal=False, return_softmax=False,
-                    fixed_seed_offset=None, rng_name="", training=True, name=None):
+                    fixed_seed_offset=None, rng_name="", training=True, name=None,
+                    window=None):
     """paddle.nn.functional.flash_attention.flash_attention.
 
     Layout [batch, seqlen, num_heads, head_dim] (ref ops.yaml:1765 flash_attn).
     Uses the Pallas kernel pack for the no-dropout path (the flag and the
     dropout rate decide, before the call: nothing the kernel raises is
-    caught).
+    caught). `window` (with `causal=True`, equal sequence lengths): row t
+    sees the keys s with 0 <= t - s < window — a sliding-window layer; the
+    kernels skip the score blocks behind it.
     """
+    if window is not None and not causal:
+        raise ValueError("a window is causal attention: pass causal=True")
     if flag("FLAGS_enable_pallas_kernels") and dropout == 0.0:
         from ...kernels.flash_attention import flash_attention_fwd
 
         out = dispatch(
             "flash_attn",
-            lambda q, k, v: flash_attention_fwd(q, k, v, causal=causal),
+            lambda q, k, v: flash_attention_fwd(q, k, v, causal=causal,
+                                                window=window),
             (query, key, value),
         )
         return out, None
     out = dispatch(
         "flash_attn_ref",
-        lambda q, k, v: _sdpa_ref(q, k, v, None, dropout, causal),
+        lambda q, k, v: _sdpa_ref(q, k, v, None, dropout, causal,
+                                  window=window),
         (query, key, value),
     )
     return out, None

@@ -96,8 +96,7 @@ def test_flash_bwd(for_chip, one_chip):
 
 
 def test_flash_in_repo_kernels(for_chip, one_chip):
-    """The in-repo fwd/bwd kernels behind `_flash_core` at grouped heads
-    (the shapes `_splash_ok` turns away reach it in this layout)."""
+    """The in-repo fwd/bwd kernels behind `_flash_core` at grouped heads."""
     fa = _mod("flash_attention")
     q, kv = ((64, 2048, 128), BF), ((16, 2048, 128), BF)
 
@@ -107,6 +106,23 @@ def test_flash_in_repo_kernels(for_chip, one_chip):
     text = _compile(jax.grad(loss, argnums=(0, 1, 2)), one_chip, q, kv, kv)
     assert text.count("tpu_custom_call") >= 2      # fwd, one-pass bwd
     assert "flash_attention_fwd" in text and "flash_attention_bwd" in text
+
+
+def test_flash_window_kernels_at_grouped_heads(for_chip, one_chip):
+    """The `afmoe` trainer's window layers (`trinitymini-train-8k`): one row
+    of 8,192 tokens, 32 q heads on 4 kv heads of 128, a window of 2,048 —
+    forward at 1,024-row blocks, the one-pass backward at 512 with dq's 4 MiB
+    accumulator, both walking the band alone."""
+    fa = _mod("flash_attention")
+    q, kv = ((1, 8192, 32, 128), BF), ((1, 8192, 4, 128), BF)
+
+    def loss(q, k, v):
+        return fa.flash_attention(q, k, v, causal=True,
+                                  window=2048).astype(F32).sum()
+
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)), one_chip, q, kv, kv)
+    assert "flash_attention_window_fwd" in text
+    assert "flash_attention_window_bwd" in text
 
 
 @pytest.mark.parametrize("rows,hidden", [(4 * 2048, 2048), (8, 4096),

@@ -152,13 +152,14 @@ def test_block_rows_follow_the_head_size(seq, d, rows):
     (2048, (1024, 1024)), (4096, (1024, 1024)), (512, (512, 512)),
     (1536, (512, 512))])       # 512-divisible, and 1024 does not divide it
 def test_forward_blocks_divide_the_sequence(seq, blocks):
-    """Equal heads on long sequences tile the forward at up to 1024 rows on
-    a TPU and at 512 in interpret mode; grouped heads at 512 everywhere."""
+    """Equal and grouped heads on long sequences tile the forward at up to
+    1024 rows on a TPU and at 512 in interpret mode."""
     fa = _fa()
     q, kv = (8, seq, 128), (2, seq, 128)
     assert fa._fwd_blocks(q, q, True) == blocks
     assert fa._fwd_blocks(q, q, False) == (512, 512)
-    assert fa._fwd_blocks(q, kv, True) == (512, 512)
+    assert fa._fwd_blocks(q, kv, True) == blocks
+    assert fa._fwd_blocks(q, kv, False) == (512, 512)
     assert not fa.CONSTRAINT.check([(8, seq, 128)] * 3, ["bfloat16"] * 3)
 
 

@@ -37,6 +37,13 @@ def _flash_grad(q, k, v):
         q, k, v, True, 0.088).astype(F32).sum(), argnums=(0, 1, 2))(q, k, v)
 
 
+def _flash_window_grad(q, k, v):
+    fa = _mod("flash_attention")
+    return jax.grad(lambda q, k, v: fa._flash_core(
+        q, k, v, True, 0.088, 128).astype(F32).sum(),
+        argnums=(0, 1, 2))(q, k, v)
+
+
 def _swiglu_grad(x, wg, wu):
     sw = _mod("swiglu")
     return jax.grad(lambda x, wg, wu: sw.swiglu_matmul(
@@ -68,6 +75,11 @@ CASES = {
         _flash_grad, [((64, 512, 128), BF)] + [((16, 512, 128), BF)] * 2,
         {"flash_attention_fwd": "flash_attention",
          "flash_attention_bwd": "flash_attention"}),
+    "flash_attention_window": (
+        _flash_window_grad,
+        [((64, 512, 128), BF)] + [((16, 512, 128), BF)] * 2,
+        {"flash_attention_window_fwd": "flash_attention",
+         "flash_attention_window_bwd": "flash_attention"}),
     "swiglu": (
         _swiglu_grad, [((512, 512), BF), ((512, 1024), BF),
                        ((512, 1024), BF)],

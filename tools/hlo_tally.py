@@ -44,6 +44,13 @@ elif driver == "train_moe":
     programs = [("", step.jitted, (abstract(params), abstract(opt), sds((), jnp.float32),
                                    sds((dep["batch"], dep["seq"] + ahead), jnp.int32), y) + (y,) * ahead)]
     del params, opt
+elif driver == "train_afmoe":
+    from benchmark.drivers import train_afmoe
+    _, make_step = train_afmoe.build_model(train_afmoe.model_config(m, "bfloat16"), 0)
+    step, params, opt = make_step()
+    x = sds((dep["batch"], dep["seq"]), jnp.int32)
+    programs = [("", step.jitted, (abstract(params), abstract(opt), sds((), jnp.float32), x, x))]
+    del params, opt
 else:   # a serving cell: the engine over parameter shapes, sized as the cell's driver sizes it
     from paddle_tpu.serving import ContinuousBatchingEngine
     sizes = dict(slots=dep["slots"], max_prompt_len=dep["max_prompt_len"], max_new_tokens=dep["max_new_tokens"])
