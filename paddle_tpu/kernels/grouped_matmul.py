@@ -35,11 +35,15 @@ from jax.experimental.pallas import tpu as pltpu
 from .constraints import (KernelConstraint, LANE, dtype_itemsize,
                           register_constraint)
 
-# rows of one tile: every group starts on a multiple of it. A group of n rows
-# costs ceil(n / ROW_TILE) tiles, so the padding a step multiplies is half a
-# tile a group on average: 12% at the ~512 rows an expert one chip's share of
-# an 8-chip group sees, 1.5% at a deployment's ~4,096
+# rows of one tile: every group starts on a multiple of it, and a group of n
+# rows costs ceil(n / tile) tiles. The tile is a function of the call
+# (`row_tile`): ROW_TILE where groups are large (12% padding at the ~512 rows
+# an expert one chip's share of an 8-chip group sees, 1.5% at a deployment's
+# ~4,096), down to SUBLANE_TILE, a bf16 tile's sublanes and the thinnest the
+# kernels take, where a group holds a few rows (a decode step's 256 rows on
+# 64 experts: at 128-row tiles 33 of every 34 buffer rows were padding)
 ROW_TILE = 128
+SUBLANE_TILE = 16
 # widest block of the non-contracted weight dim: [K, COL_BLOCK] of bf16 at
 # K 2048 is 4 MiB, double-buffered
 COL_BLOCK = 1024
@@ -54,6 +58,19 @@ class GroupLayout(NamedTuple):
     tile_group: jax.Array   # [tiles] group of each row tile
     tile_rows: jax.Array    # [tiles] rows of the tile that are the group's
     n_tiles: jax.Array      # [] row tiles in use
+
+
+def row_tile(tokens: int, choices: int, groups: int) -> int:
+    """The row tile of a buffer for `tokens` tokens that each take `choices`
+    of `groups` groups: twice the rows a group holds on average in the worst
+    case (every token on `min(choices, groups)` of them), rounded up to a
+    power of two, no thinner than SUBLANE_TILE and no wider than ROW_TILE.
+    Twice, not once: on the chip a tile costs its grid steps first (~3 us a
+    tile over a SwiGLU layer's three products), the passes over the whole
+    buffer second and its padding rows least (PERF.md section 6, PR 36), and
+    a tile of twice the mean still holds nearly every group whole."""
+    mean = -(-tokens * min(choices, groups) // groups)
+    return min(ROW_TILE, max(SUBLANE_TILE, 1 << (2 * mean - 1).bit_length()))
 
 
 def buffer_rows(max_rows: int, groups: int, tile: int = ROW_TILE) -> int:
@@ -97,7 +114,7 @@ def _col_block(n: int) -> int:
 def _pallas_ok(m: int, k: int, n: int, tile: int) -> bool:
     """Shapes the kernels take: both weight dims whole lane tiles, rows in
     sublane-aligned tiles."""
-    return k % LANE == 0 and n % LANE == 0 and tile % 16 == 0 \
+    return k % LANE == 0 and n % LANE == 0 and tile % SUBLANE_TILE == 0 \
         and m % tile == 0
 
 
@@ -307,7 +324,7 @@ def _check_grouped_shapes(shapes, dtypes):
                         f"dim {d} is not a multiple of the {LANE}-lane "
                         "tile; the wrapper takes the jnp form for it"))
     for s, _ in two:
-        if s[0] % 16:
+        if s[0] % SUBLANE_TILE:
             out.append(("error", f"{s[0]} rows do not divide into "
                                  "sublane-aligned row tiles"))
     return out
@@ -344,7 +361,9 @@ CONSTRAINT = register_constraint(KernelConstraint(
     kernel_fns=("_mm_kernel", "_drhs_kernel"),
     blocks={"row_tile": ROW_TILE, "col_block": COL_BLOCK},
     note="rows sorted by group, every group on a row-tile boundary "
-         "(group_layout); K and N whole lane tiles, else the jnp form",
+         "(group_layout) — the layout's own tile, 16 to row_tile rows, which "
+         "row_tile() gives from the call's tokens, choices and groups; K and "
+         "N whole lane tiles, else the jnp form",
     checker=_check_grouped_shapes,
     source="grouped_matmul.py",
     roofline=_grouped_roofline,

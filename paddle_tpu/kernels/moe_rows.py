@@ -49,11 +49,12 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .constraints import KernelConstraint, LANE, register_constraint
-from .grouped_matmul import (ROW_TILE, GroupLayout, _VMEM_LIMIT, _interpret,
-                             _pallas_ok)
+from .grouped_matmul import (SUBLANE_TILE, GroupLayout, _VMEM_LIMIT,
+                             _interpret, _pallas_ok)
 
 # rows of one copy: a bf16 tile's sublanes, the thinnest slice Mosaic moves
-CHUNK = 16
+# (and the thinnest row tile of a layout, so a row tile is whole chunks)
+CHUNK = SUBLANE_TILE
 # chunks staged for one product: a contraction of SLOTS * CHUNK = 256 rows
 SLOTS = 16
 # tokens of one grid step; the rows of a group that a tile owns are contiguous
@@ -70,20 +71,20 @@ class TileChunks(NamedTuple):
     n_write: jax.Array  # [token tiles] slots scatter_rows forms (zeros too)
 
 
-def rows_ok(tokens: int, d: int, f: int, rows: int) -> bool:
+def rows_ok(tokens: int, d: int, f: int, rows: int, tile: int) -> bool:
     """Shapes the kernels take: whole token tiles, and rows of width `d` in a
-    buffer of `rows` that the grouped matmul's kernels take too, against
-    weights [d, f] — what these kernels leave unwritten would meet its jnp
-    form's 0 x row products."""
-    return tokens % TOKEN_TILE == 0 and _pallas_ok(rows, d, f, ROW_TILE)
+    buffer of `rows` in row tiles of `tile` (whole chunks) that the grouped
+    matmul's kernels take too, against weights [d, f] — what these kernels
+    leave unwritten would meet its jnp form's 0 x row products."""
+    return tokens % TOKEN_TILE == 0 and _pallas_ok(rows, d, f, tile)
 
 
-def _max_slots(k: int, groups: int) -> int:
+def _max_slots(k: int, groups: int, tile: int) -> int:
     """Slots a token tile's list can need: its rows in chunks (a group's
     stretch may start and end inside a chunk), and on the last tile the
-    zero chunks that complete every group's last row tile."""
+    zero chunks that complete every group's last row tile of `tile` rows."""
     n = TOKEN_TILE * min(k, groups) // CHUNK + 2 * groups \
-        + groups * (ROW_TILE // CHUNK)
+        + groups * (tile // CHUNK)
     return -(-n // SLOTS) * SLOTS
 
 
@@ -94,12 +95,12 @@ def _pick(table, index, n: int):
     return jnp.sum(jnp.where(hot, table, 0), axis=-1)
 
 
-def tile_chunks(ends, layout: GroupLayout, k: int) -> TileChunks:
+def tile_chunks(ends, layout: GroupLayout, k: int, tile: int) -> TileChunks:
     """`ends` [token tiles, G]: the assignments of each group up to the end
-    of each token tile (a running count in token order). Plain jnp over
-    [token tiles, S, G] integers."""
+    of each token tile (a running count in token order); `tile`: the
+    layout's row tile. Plain jnp over [token tiles, S, G] integers."""
     nt, g = ends.shape
-    s_max = _max_slots(k, g)
+    s_max = _max_slots(k, g, tile)
     hi = layout.starts + ends                               # [nt, G]
     lo = jnp.concatenate([layout.starts[None], hi[:-1]])
     end = layout.starts + layout.sizes
@@ -119,8 +120,8 @@ def tile_chunks(ends, layout: GroupLayout, k: int) -> TileChunks:
     chunk = of(first) + pos
     # the zero chunks from each group's end to the end of its last row tile
     tail_first = -(-end // CHUNK)
-    tail_n = (layout.starts + jnp.maximum(1, -(-layout.sizes // ROW_TILE))
-              * ROW_TILE) // CHUNK - tail_first
+    tail_n = (layout.starts + jnp.maximum(1, -(-layout.sizes // tile))
+              * tile) // CHUNK - tail_first
     tail_stop = jnp.cumsum(tail_n)
     p = s - n_read[-1]
     tail_group = jnp.minimum(

@@ -765,7 +765,7 @@ class ServedLayer(NamedTuple):
     rope_base: float
     rope_scaling: Optional[YarnScaling]
     # (x [..., hidden] after the second norm, p, prefix) -> (y, counts):
-    # counts None, or for a routed layer MOE_COUNTS as int32 [4]
+    # counts None, or for a routed layer MOE_COUNTS as an int32 vector
     mlp: Callable
 
 
@@ -788,8 +788,11 @@ class ServedModel(NamedTuple):
         return any(getattr(l.mlp, "routed", False) for l in self.layers)
 
 
-# what a routed layer counts each time it runs (summed by the programs)
-MOE_COUNTS = ("layer_steps", "rows_routed", "experts_hit", "load_max")
+# what a routed layer counts each time it runs (summed by the programs);
+# rows_multiplied: the buffer rows its grouped matmuls walked, padding
+# included (row tiles in use x the layout's tile)
+MOE_COUNTS = ("layer_steps", "rows_routed", "experts_hit", "load_max",
+              "rows_multiplied")
 
 
 def _dense_swiglu(x, p, pre):

@@ -176,7 +176,8 @@ def _routed_experts(cfg: MellumConfig):
                 p[pre + "mlp.experts.down_proj"], held, n)
         counted = {"layer_steps": 1, "rows_routed": c["moe.rows_routed"],
                    "experts_hit": jnp.sum(c["moe.load"] > 0),
-                   "load_max": c["moe.load_max"]}
+                   "load_max": c["moe.load_max"],
+                   "rows_multiplied": c["moe.rows_multiplied"]}
         return y.reshape(x.shape), jnp.stack(
             [jnp.asarray(counted[k], jnp.int32) for k in MOE_COUNTS])
 

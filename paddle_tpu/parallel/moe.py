@@ -441,12 +441,13 @@ _rows_in_jit = jax.jit(_rows_in_vjp, static_argnums=(3, 4, 5))
 _rows_out_jit = jax.jit(_rows_out_vjp, static_argnums=(4, 5, 6))
 
 
-@functools.partial(jax.jit, static_argnums=(1, 2, 3, 4))
-def _route(idx, held, num_experts: int, rows: int, chunked: bool):
-    """Where each assignment of `idx` [T, k] goes in a buffer of `rows` rows:
-    (the groups' layout, dest [T, k] — `rows` where the expert is held
-    elsewhere —, the token tiles' chunk lists if `chunked`, the assignments
-    on held experts). One jit: a model's blocks share its trace.
+@functools.partial(jax.jit, static_argnums=(1, 2, 3, 4, 5))
+def _route(idx, held, num_experts: int, rows: int, tile: int, chunked: bool):
+    """Where each assignment of `idx` [T, k] goes in a buffer of `rows` rows
+    in row tiles of `tile`: (the groups' layout, dest [T, k] — `rows` where
+    the expert is held elsewhere —, the token tiles' chunk lists if
+    `chunked`, the assignments on held experts). One jit: a model's blocks
+    share its trace.
 
     A counting sort, stable: an assignment's row is its group's start plus
     the assignments of its group before it, so inside a group the rows are
@@ -461,11 +462,11 @@ def _route(idx, held, num_experts: int, rows: int, chunked: bool):
     lid = local[idx.reshape(-1)]                    # [T*k]; g = held elsewhere
     mine = lid[:, None] == jnp.arange(g, dtype=jnp.int32)   # [T*k, G]
     upto = jnp.cumsum(mine.astype(jnp.int32), axis=0)
-    layout = group_layout(upto[-1], rows)
+    layout = group_layout(upto[-1], rows, tile)
     dest = jnp.where(lid < g, jnp.sum(jnp.where(
         mine, layout.starts + upto - 1, 0), axis=1), rows)
-    tile = moe_rows.TOKEN_TILE * k
-    chunks = moe_rows.tile_chunks(upto[tile - 1::tile], layout, k) \
+    step = moe_rows.TOKEN_TILE * k
+    chunks = moe_rows.tile_chunks(upto[step - 1::step], layout, k, tile) \
         if chunked else None
     return layout, dest.reshape(t, k), chunks, jnp.sum(upto[-1])
 
@@ -486,12 +487,15 @@ def dropless_experts(x, idx, gates, w_gate, w_up, w_down, held,
     indices, G of them). Returns (y [T, d], counters).
 
     The assignments that fall on held experts are sorted by expert into a
-    buffer in which every expert's rows start on a row tile (sized for the
-    worst case, every token on `min(k, G)` held experts); the rest are not
-    computed, here or anywhere on this chip. Everything that touches the
-    buffer walks the rows in use: the grouped matmul its row tiles, the row
-    movements (kernels/moe_rows.py) the 16-row chunks that hold a token
-    tile's rows. Row tiles no expert uses are neither written nor read.
+    buffer in which every expert's rows start on a row tile, sized for the
+    worst case (every token on `min(k, G)` held experts); the rest are not
+    computed, here or anywhere on this chip. The tile follows from T, k and
+    G alone (`grouped_matmul.row_tile`): a trainer's thousands of rows an
+    expert take 128-row tiles, a decode step's handful the 16-row sublane
+    tile. Everything that touches the buffer walks the rows in use: the
+    grouped matmul its row tiles, the row movements (kernels/moe_rows.py)
+    the 16-row chunks that hold a token tile's rows. Row tiles no expert
+    uses are neither written nor read.
 
     Where `held` is a share of the experts, the gates are constants of the
     backward pass: a gate's gradient needs the outputs of every expert its
@@ -504,17 +508,18 @@ def dropless_experts(x, idx, gates, w_gate, w_up, w_down, held,
     and waits for the exchange where it holds a share; the bias rule, which
     needs counts alone, runs in both."""
     from ..kernels import moe_rows
-    from ..kernels.grouped_matmul import (ROW_TILE, buffer_rows,
-                                          grouped_matmul)
+    from ..kernels.grouped_matmul import (buffer_rows, grouped_matmul,
+                                          row_tile)
 
     t, k = idx.shape
     g = len(held)
     if g < num_experts:
         gates = jax.lax.stop_gradient(gates)
-    rows = buffer_rows(t * min(k, g), g)
-    chunked = moe_rows.rows_ok(t, x.shape[1], w_gate.shape[2], rows)
+    tile = row_tile(t, k, g)
+    rows = buffer_rows(t * min(k, g), g, tile)
+    chunked = moe_rows.rows_ok(t, x.shape[1], w_gate.shape[2], rows, tile)
     layout, dest, chunks, on_held = _route(
-        idx, tuple(int(e) for e in held), num_experts, rows, chunked)
+        idx, tuple(int(e) for e in held), num_experts, rows, tile, chunked)
 
     if chunked:
         on_tpu = jax.default_backend() == "tpu"
@@ -529,7 +534,7 @@ def dropless_experts(x, idx, gates, w_gate, w_up, w_down, held,
                                  g == num_experts, on_tpu)
 
         moved = (jnp.sum(chunks.n_read) + layout.n_tiles
-                 * (ROW_TILE // moe_rows.CHUNK)) * moe_rows.CHUNK
+                 * (tile // moe_rows.CHUNK)) * moe_rows.CHUNK
     else:
         row_assign = jnp.full((rows,), t * k, jnp.int32).at[
             dest.reshape(-1)].set(jnp.arange(t * k, dtype=jnp.int32),
@@ -557,7 +562,7 @@ def dropless_experts(x, idx, gates, w_gate, w_up, w_down, held,
     counters = {
         "moe.rows_held": live,
         "moe.rows_routed": jnp.asarray(t * k, jnp.int32),
-        "moe.rows_multiplied": layout.n_tiles * ROW_TILE,
+        "moe.rows_multiplied": layout.n_tiles * tile,
         "moe.rows_dropped": on_held - live,
         "moe.rows_moved": moved.astype(jnp.int32),
         "moe.load_max": jnp.max(layout.sizes),

@@ -976,7 +976,8 @@ class ContinuousBatchingEngine:
             # routed layers (MOE_COUNTS summed over layers and steps; a
             # dense model reads zeros): every time a routed layer ran, the
             # (token, choice) rows it routed, the experts that got at
-            # least one, the largest group — and the decode lane's part
+            # least one, the largest group, the buffer rows its matmuls
+            # walked (padding included) — and the decode lane's part
             **{f"moe_{name}": int(self.moe_counts["decode"][i]
                                   + self.moe_counts["chunk"][i])
                for i, name in enumerate(MOE_COUNTS)},
@@ -1467,7 +1468,7 @@ class ContinuousBatchingEngine:
         the guarantee that a speculatively-dispatched chunk (double
         buffering) can never write past a request's reserved pages.
         Returns (tokens, lengths, done, pools); before the pools a model
-        with routed layers puts their summed MOE_COUNTS [4], and
+        with routed layers puts their summed MOE_COUNTS vector, and
         `logprobs` the tokens' log-probabilities [slots, steps]."""
         b, steps = self.slots, self.steps
         do_sample, top_k, eos = self.do_sample, self.top_k, self.eos
@@ -3046,8 +3047,9 @@ class ContinuousBatchingEngine:
             out = np.asarray(rec["out"])      # the blocking host sync
             new_lens = np.asarray(rec["lens"])
             done = np.asarray(rec["done"])
-            # the routed layers' counts came with them: [4] from the
-            # decode chunk, [2, 4] (decode lane, window) from a mixed step
+            # the routed layers' counts came with them: one MOE_COUNTS
+            # vector from the decode chunk, two (decode lane, window) from
+            # a mixed step
             moe = [np.asarray(c).reshape(-1, len(MOE_COUNTS))
                    for c in rec.get("moe", ())]
             lps = [np.asarray(lp) for lp in rec.get("logprobs", ())]

@@ -322,20 +322,26 @@ def test_flash_bwd_at_the_longest_sequences(for_chip, one_chip, seq, d):
                            past, past, past)
 
 
-@pytest.mark.parametrize("rows", [4096, 32768])
-@pytest.mark.parametrize("k,n", [(2048, 1536), (1536, 2048)])
-def test_grouped_matmul(for_chip, one_chip, rows, k, n):
-    """Forward and both backward products over 8 groups, from the rows one
-    chip's share sees in a step to the buffer's worst case."""
+@pytest.mark.parametrize("groups,rows,tile,k,n", [
+    (8, 4096, 128, 2048, 1536), (8, 4096, 128, 1536, 2048),
+    (8, 32768, 128, 2048, 1536), (8, 32768, 128, 1536, 2048),
+    (64, 256, 16, 2304, 896), (64, 256, 16, 896, 2304)])
+def test_grouped_matmul(for_chip, one_chip, groups, rows, tile, k, n):
+    """Forward and both backward products: over 8 groups on 128-row tiles,
+    from the rows one chip's share sees in a step to the buffer's worst case;
+    and on the sublane tile at the served expert layer's widths (a decode
+    step's 256 rows on 64 experts: [1280, 2304] x [64, 2304, 896] and
+    [1280, 896] x [64, 896, 2304])."""
     gm = _mod("grouped_matmul")
-    m = gm.buffer_rows(rows, 8)
+    m = gm.buffer_rows(rows, groups, tile)
+    assert tile == 128 or m == 1280
 
     def loss(lhs, rhs, sizes):
-        out = gm.grouped_matmul(lhs, rhs, gm.group_layout(sizes, m))
+        out = gm.grouped_matmul(lhs, rhs, gm.group_layout(sizes, m, tile))
         return jnp.square(out.astype(F32)).sum()    # keeps the forward
 
     text = _compile(jax.grad(loss, argnums=(0, 1)), one_chip,
-                    ((m, k), BF), ((8, k, n), BF), ((8,), I32))
+                    ((m, k), BF), ((groups, k, n), BF), ((groups,), I32))
     for name in ("grouped_matmul", "grouped_matmul_dlhs",
                  "grouped_matmul_drhs"):
         assert f"{name}" in text, name
@@ -403,12 +409,16 @@ def test_mellum_ragged_window(for_chip, one_chip, window):
     assert ("ragged_attention_window" in text) == bool(window)
 
 
-@pytest.mark.parametrize("tokens", [32, MTN], ids=["decode", "prefill_window"])
+@pytest.mark.parametrize("tokens", [32, 128, MTN],
+                         ids=["decode", "decode_128_slots", "prefill_window"])
 def test_mellum_expert_layer(for_chip, one_chip, tokens):
     """The routed layer as the served programs call it: 64 experts of 2304 x
     896 all held, 8 a token, forward alone — 256 rows on 64 groups in a
-    decode step (the row movements' jnp forms: 32 tokens are no token tile),
-    4,096 in a prefill window (their kernels)."""
+    decode step, on 16-row tiles in a buffer of 1,280 rows (at 128-row tiles
+    it held 8,448; the row movements' jnp forms: 32 tokens are no token
+    tile), 4,096 in a prefill window on 128-row tiles in 12,288 (their
+    kernels); and a decode lane of 128 slots, which no cell runs: a token
+    tile on 32-row tiles, the row kernels on a thin layout."""
     from paddle_tpu.parallel import moe
 
     d, f, e, k = 2304, 896, 64, 8
@@ -422,8 +432,11 @@ def test_mellum_expert_layer(for_chip, one_chip, tokens):
                     ((tokens, k), I32))
     assert "grouped_matmul" in text
     assert ("moe_rows_in" in text and "moe_rows_out" in text) \
-        == (tokens == MTN)
+        == (tokens % 128 == 0)
     assert "_bwd" not in text and "dgates" not in text
+    rows = {32: 1280, 128: 3072, MTN: 12288}[tokens]
+    assert f"bf16[{rows},{d}]" in text and f"bf16[{rows},{f}]" in text
+    assert not re.search(r"\[8448,", text)
 
 
 @pytest.fixture
@@ -473,6 +486,8 @@ def test_mellum_served_programs(mellum_engine, one_chip, program):
                  "grouped_matmul", "kv_commit"):
         assert re.search(rf"%{name}(\.\d+)? = [^\n]*custom-call", text), name
     assert not _pools_in_another_layout(text, eng)
+    # the decode lane's expert buffer on 16-row tiles, not 128-row ones
+    assert "bf16[1280,2304]" in text and not re.search(r"\[8448,", text)
     if program == "unified":
         for name in ("ragged_attention_window", "ragged_attention",
                      "moe_rows_in", "moe_rows_out"):

@@ -22,6 +22,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
 from benchmark import reference_mellum as reference  # noqa: E402
+from paddle_tpu.kernels.grouped_matmul import row_tile  # noqa: E402
 from paddle_tpu.kernels.rope import (YarnScaling, rope_freqs,  # noqa: E402
                                      rope_inv_freq)
 from paddle_tpu.models import LlamaConfig, LlamaForCausalLM  # noqa: E402
@@ -172,6 +173,13 @@ def test_moe_counters_add_up(tiny):
     assert 0 < m["moe_experts_hit"] <= n_exp * m["moe_layer_steps"]
     # the largest group holds at least the mean
     assert m["moe_load_max"] * n_exp >= m["moe_rows_routed"]
+    # no expert can get more rows than a lane has tokens, and neither lane
+    # has more than its row tile: every expert one tile a layer-step
+    assert m["moe_rows_multiplied_decode"] \
+        == layers * dec_steps * n_exp * row_tile(eng.slots, k, n_exp)
+    assert m["moe_rows_multiplied"] - m["moe_rows_multiplied_decode"] \
+        == layers * m["prefill_chunks"] * n_exp \
+        * row_tile(eng.token_budget, k, n_exp)
     assert m["prefix_cache_off"] and "rings" in m["prefix_cache_off"]
 
 
