@@ -19,12 +19,15 @@ host spans are recorded by ONE `Tracer`:
   arguments (`step_span` a `StepTraceAnnotation`), so whenever a
   profiler session is live the span is an event on the host plane of
   the same `.xplane.pb` as the device's operations, its arguments the
-  event's stats. The two records keep their own clocks: this JSON
-  counts `time.perf_counter_ns`, the profiler counts from the start
-  of its session — lay host spans over device operations in the
-  profiler's trace, never by matching the JSON's timestamps. Outside
-  a session an annotation is one check of an atomic. `complete()`
-  records after the fact and so cannot enter one;
+  event's stats. The two records count on different clocks — this
+  JSON `time.perf_counter_ns`, the profiler nanoseconds from its
+  session's `profile_start_time` (CLOCK_REALTIME) — and the Tracer
+  reads both of its own clocks back to back (`clock_anchor`, taken at
+  construction and at `clear()`, written into the exported JSON's
+  metadata), so `session_ns` lays any JSON event on the session's
+  clock to within microseconds. Outside a session an annotation is one
+  check of an atomic. `complete()` records after the fact and so
+  cannot enter one;
 - **trace-safety guard** (lint rule TPU602): a span/instant emitted
   while jax is TRACING a program would bake a host callback — and a
   per-execution host round-trip — into the compiled artifact. Like
@@ -53,7 +56,8 @@ from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 __all__ = ["Tracer", "TraceUnderJitError", "write_chrome_trace",
            "merge_chrome_traces", "get_tracer", "enable", "disable",
-           "span", "instant", "export_global"]
+           "span", "instant", "export_global", "session_ns",
+           "profile_start_time"]
 
 
 class TraceUnderJitError(RuntimeError):
@@ -136,6 +140,38 @@ def merge_chrome_traces(paths, out: Optional[str] = None, *,
     return doc
 
 
+def _clock_anchor() -> dict:
+    """One reading of the Tracer's clock (`perf_counter_ns`, the midpoint
+    of two reads) and the wall clock (`time_ns`) taken between them."""
+    a = time.perf_counter_ns()
+    wall = time.time_ns()
+    b = time.perf_counter_ns()
+    return {"perf_counter_ns": (a + b) // 2, "time_ns": wall}
+
+
+def session_ns(ts_us: float, clock_anchor: dict,
+               profile_start_time: int) -> float:
+    """An exported event's `ts` (microseconds on `perf_counter`) as
+    nanoseconds from the start of a profiler session, the clock of every
+    event in that session's `.xplane.pb`. `clock_anchor` is the exported
+    JSON's `metadata["clock_anchor"]`; `profile_start_time` the session's
+    (`profile_start_time(path)`). Exact up to the anchor's read and the
+    wall clock's steps between anchor and event."""
+    return (ts_us * 1e3 - clock_anchor["perf_counter_ns"]
+            + clock_anchor["time_ns"] - profile_start_time)
+
+
+def profile_start_time(xplane_path: str) -> int:
+    """The session's start (CLOCK_REALTIME ns): the `Task Environment`
+    plane's `profile_start_time` stat, from which its events count."""
+    from jax.profiler import ProfileData
+
+    for plane in ProfileData.from_file(xplane_path).planes:
+        if plane.name == "Task Environment":
+            return int(dict(plane.stats)["profile_start_time"])
+    raise ValueError(f"{xplane_path}: no Task Environment plane")
+
+
 class _SpanHandle:
     """Context manager for one live span (created only when tracing is
     ON — the disabled path never reaches here). `ann` is the profiler
@@ -197,6 +233,9 @@ class Tracer:
         self._thread_names = {}  # tid -> name (metadata, never evicted)
         self.dropped = 0         # spans the ring buffer evicted
         self.n_recorded = 0
+        # both clocks read back to back: `session_ns` maps the events
+        # onto a profiler session's clock through it
+        self.clock_anchor = _clock_anchor()
 
     # -- recording -----------------------------------------------------
     def span(self, name: str, **args) -> _SpanHandle:
@@ -271,9 +310,11 @@ class Tracer:
         with self._lock:
             self._events.clear()
             self.dropped = 0
+            self.clock_anchor = _clock_anchor()
 
     def export(self, path: str, metadata: Optional[dict] = None) -> str:
-        md = {"n_recorded": self.n_recorded, "dropped": self.dropped}
+        md = {"n_recorded": self.n_recorded, "dropped": self.dropped,
+              "clock_anchor": dict(self.clock_anchor)}
         if metadata:
             md.update(metadata)
         return write_chrome_trace(self.events(), path, metadata=md,

@@ -115,6 +115,14 @@ from ..observability.trace import _NULL_SPAN
 from ..resilience import chaos
 
 
+def _moved(direction: str, arrays) -> dict:
+    """A transfer's span arguments: how many arrays (`h2d` / `d2h`) and
+    how many bytes (`h2d_bytes` / `d2h_bytes`) it moved."""
+    leaves = jax.tree.leaves(arrays)
+    return {direction: len(leaves),
+            direction + "_bytes": sum(int(a.nbytes) for a in leaves)}
+
+
 def _token_logprob(logits, tok):
     """log softmax(logits)[tok], [B, V] and [B] -> f32 [B]."""
     logits = logits.astype(jnp.float32)
@@ -2736,24 +2744,34 @@ class ContinuousBatchingEngine:
                 with self._commit_lock:
                     self._check_owner(token)
                     st["dispatched"] = True
-                    self._key, k = jax.random.split(self._key)
-                    live = np.asarray(
-                        [s.req is not None for s in self._slots])
-                    res = self._unified(
-                        self.p, self.kcs, self.vcs,
-                        jnp.asarray(self._tokens),
-                        jnp.asarray(np.asarray(
-                            [s.length for s in self._slots], np.int32)),
-                        jnp.asarray(self._budgets),
-                        self._tables_arg(self._tables, self._ring_tables),
-                        jnp.asarray(live), jnp.asarray(ids),
-                        self._tables_arg(tbl, ring_tbl),
-                        jnp.asarray([done], np.int32),
-                        jnp.asarray([this_chunk], np.int32),
-                        self._tables_arg(
-                            np.asarray([win_pages], np.int32), ring_win), k,
-                        jnp.asarray(self.temperature, jnp.float32),
-                        jnp.asarray(self.top_p, jnp.float32))
+                    chunk = self.device_steps + 1
+                    with (_NULL_SPAN if tr is None else tr.span(
+                            "decode.stage", chunk=chunk)) as stage:
+                        self._key, k = jax.random.split(self._key)
+                        live = np.asarray(
+                            [s.req is not None for s in self._slots])
+                        staged = (
+                            jnp.asarray(self._tokens),
+                            jnp.asarray(np.asarray(
+                                [s.length for s in self._slots], np.int32)),
+                            jnp.asarray(self._budgets),
+                            self._tables_arg(self._tables,
+                                             self._ring_tables),
+                            jnp.asarray(live), jnp.asarray(ids),
+                            self._tables_arg(tbl, ring_tbl),
+                            jnp.asarray([done], np.int32),
+                            jnp.asarray([this_chunk], np.int32),
+                            self._tables_arg(
+                                np.asarray([win_pages], np.int32),
+                                ring_win))
+                        temp = jnp.asarray(self.temperature, jnp.float32)
+                        top_p = jnp.asarray(self.top_p, jnp.float32)
+                        if tr is not None:
+                            stage.set(**_moved("h2d", (staged, temp, top_p)))
+                    with (_NULL_SPAN if tr is None else tr.span(
+                            "decode.enqueue", chunk=chunk)):
+                        res = self._unified(self.p, self.kcs, self.vcs,
+                                            *staged, k, temp, top_p)
                     out, new_lens, dn, first_dev, *extra, self.kcs, \
                         self.vcs = res
                     moe, lps = extra[:self._routed], extra[self._routed:]
@@ -2777,10 +2795,13 @@ class ContinuousBatchingEngine:
                     rec = {"out": out, "lens": new_lens, "done": dn,
                            "reqs": [s.req for s in self._slots],
                            "t_disp0": t_disp0, "moe": moe,
-                           "logprobs": lps[:1]}
+                           "logprobs": lps[:1], "chunk": chunk,
+                           "first": [first_dev, *lps[1:]]}
+            # the first token and its log-probabilities are read back
+            # with the decode lane's outputs, in the same readback
             produced = self._commit_chunk(rec, token)
-            first = int(np.asarray(first_dev)[0])
-            first_lp = [float(np.asarray(lp)[0]) for lp in lps[1:]]
+            first = int(rec["first"][0][0])
+            first_lp = [float(lp[0]) for lp in rec["first"][1:]]
         if mt is not None:
             mt.histogram(
                 "prefill_chunk_s",
@@ -2981,24 +3002,36 @@ class ContinuousBatchingEngine:
             t_disp0 = time.perf_counter()
             with self._commit_lock:
                 self._check_owner(token)
-                self._key, k = jax.random.split(self._key)
-                host_toks = jnp.asarray(self._tokens)
-                host_lens = jnp.asarray(np.asarray(
-                    [s.length for s in self._slots], np.int32))
-                if chain and self._chain_tok is not None \
-                        and not self._override.all():
-                    ov = jnp.asarray(self._override)
-                    toks_in = jnp.where(ov, host_toks, self._chain_tok)
-                    lens_in = jnp.where(ov, host_lens, self._chain_lens)
-                else:
-                    toks_in, lens_in = host_toks, host_lens
-                res = self._decode(
-                    self.p, self.kcs, self.vcs, toks_in, lens_in,
-                    jnp.asarray(self._budgets),
-                    self._tables_arg(self._tables, self._ring_tables),
-                    jnp.asarray(live), k,
-                    jnp.asarray(self.temperature, jnp.float32),
-                    jnp.asarray(self.top_p, jnp.float32))
+                chunk = self.device_steps + 1
+                with (_NULL_SPAN if tr is None else tr.span(
+                        "decode.stage", chunk=chunk)) as stage:
+                    self._key, k = jax.random.split(self._key)
+                    host_toks = jnp.asarray(self._tokens)
+                    host_lens = jnp.asarray(np.asarray(
+                        [s.length for s in self._slots], np.int32))
+                    staged = [host_toks, host_lens]
+                    if chain and self._chain_tok is not None \
+                            and not self._override.all():
+                        ov = jnp.asarray(self._override)
+                        staged.append(ov)
+                        toks_in = jnp.where(ov, host_toks, self._chain_tok)
+                        lens_in = jnp.where(ov, host_lens, self._chain_lens)
+                    else:
+                        toks_in, lens_in = host_toks, host_lens
+                    budgets = jnp.asarray(self._budgets)
+                    tables = self._tables_arg(self._tables,
+                                              self._ring_tables)
+                    live_in = jnp.asarray(live)
+                    temp = jnp.asarray(self.temperature, jnp.float32)
+                    top_p = jnp.asarray(self.top_p, jnp.float32)
+                    if tr is not None:
+                        stage.set(**_moved("h2d", staged + [
+                            budgets, tables, live_in, temp, top_p]))
+                with (_NULL_SPAN if tr is None else tr.span(
+                        "decode.enqueue", chunk=chunk)):
+                    res = self._decode(
+                        self.p, self.kcs, self.vcs, toks_in, lens_in,
+                        budgets, tables, live_in, k, temp, top_p)
                 out, new_lens, done, *extra, self.kcs, self.vcs = res
                 moe, lps = extra[:self._routed], extra[self._routed:]
                 self.device_steps += 1
@@ -3026,8 +3059,26 @@ class ContinuousBatchingEngine:
                 # decode_chunk_s
                 rec = {"out": out, "lens": new_lens, "done": done,
                        "reqs": [s.req for s in self._slots],
-                       "t_disp0": t_disp0, "moe": moe, "logprobs": lps}
+                       "t_disp0": t_disp0, "moe": moe, "logprobs": lps,
+                       "chunk": chunk}
         return rec
+
+    def _read_back(self, chunk: int, outs: list) -> list:
+        """The host's side of a program's end, under the caller's
+        `decode.sync_wait`: wait until the program's host-visible
+        outputs are ready (`decode.device_wait`: the host waiting on the
+        device), then copy them (`decode.readback`: copies of finished
+        arrays, the transfers alone). Returns the host copies in order."""
+        tr = self._tracer
+        with (_NULL_SPAN if tr is None else tr.span(
+                "decode.device_wait", chunk=chunk)):
+            jax.block_until_ready(outs)
+        with (_NULL_SPAN if tr is None else tr.span(
+                "decode.readback", chunk=chunk)) as sp:
+            host = [np.asarray(x) for x in outs]
+            if tr is not None:
+                sp.set(**_moved("d2h", host))
+        return host
 
     def _commit_chunk(self, rec, token: Optional[int] = None) -> int:
         """Block on a dispatched chunk's host-visible outputs and commit
@@ -3041,18 +3092,22 @@ class ContinuousBatchingEngine:
         # a `stalled` span is the double-buffer stall the pipeline
         # exists to hide (Perfetto query: name='decode.sync_wait' AND
         # args.stalled)
-        with (_NULL_SPAN if tr is None
-              else tr.span("decode.sync_wait")) as sp:
+        with (_NULL_SPAN if tr is None else tr.span(
+                "decode.sync_wait", chunk=rec["chunk"])) as sp:
             t0 = time.perf_counter()
-            out = np.asarray(rec["out"])      # the blocking host sync
-            new_lens = np.asarray(rec["lens"])
-            done = np.asarray(rec["done"])
-            # the routed layers' counts came with them: one MOE_COUNTS
-            # vector from the decode chunk, two (decode lane, window) from
-            # a mixed step
-            moe = [np.asarray(c).reshape(-1, len(MOE_COUNTS))
-                   for c in rec.get("moe", ())]
-            lps = [np.asarray(lp) for lp in rec.get("logprobs", ())]
+            # the routed layers' counts came with the tokens: one
+            # MOE_COUNTS vector from the decode chunk, two (decode lane,
+            # window) from a mixed step; a mixed step's first token (and
+            # its log-probabilities) too, handed back in `rec["first"]`
+            n_moe, n_lp = len(rec["moe"]), len(rec["logprobs"])
+            host = self._read_back(rec["chunk"], [
+                rec["out"], rec["lens"], rec["done"], *rec["moe"],
+                *rec["logprobs"], *rec.get("first", ())])
+            out, new_lens, done = host[:3]
+            moe = [c.reshape(-1, len(MOE_COUNTS))
+                   for c in host[3:3 + n_moe]]
+            lps = host[3 + n_moe:3 + n_moe + n_lp]
+            rec["first"] = host[3 + n_moe + n_lp:]
             t1 = time.perf_counter()
             wait = t1 - t0
             stalled = wait > self.stall_threshold_s
@@ -3198,10 +3253,11 @@ class ContinuousBatchingEngine:
                         int(live.sum()))
         # the blocking readback stays OUTSIDE the lock — sync-wait
         # telemetry identical to _commit_chunk's
-        with (_NULL_SPAN if tr is None
-              else tr.span("decode.sync_wait")) as sp:
+        chunk = self.device_steps
+        with (_NULL_SPAN if tr is None else tr.span(
+                "decode.sync_wait", chunk=chunk)) as sp:
             t0 = time.perf_counter()
-            preds = np.asarray(preds_dev)
+            (preds,) = self._read_back(chunk, [preds_dev])
             t1 = time.perf_counter()
             wait = t1 - t0
             stalled = wait > self.stall_threshold_s

@@ -689,8 +689,11 @@ def test_step_phase_spans_account_for_every_iteration(mode):
             (st,) = [st for st in steps if _inside(e, st)]
             if e["name"].startswith("sched."):
                 assert e["args"]["iter"] == st["args"]["iter"], e
+    # the median step is covered; a step the host's scheduler preempted
+    # between two spans may fall short, but one in ten at most
     cover = sorted(_coverage(st, spans) for st in steps)
-    assert cover[len(cover) // 2] >= 0.95 and cover[0] >= 0.8, cover
+    assert cover[len(cover) // 2] >= 0.95, cover
+    assert sum(c < 0.8 for c in cover) <= max(1, len(cover) // 10), cover
     # the per-chunk emission stamp: every token surfaced is in one commit
     commits = [e["args"] for e in spans if e["name"] == "sched.commit"]
     emitted = {}
@@ -824,6 +827,188 @@ def test_dispatch_span_starts_at_the_dispatch_stamp(mode):
         assert e["ts"] <= t <= e["ts"] + e["dur"], (t, e)
         # the stamp is at the start, before the lock and the enqueue
         assert t - e["ts"] <= 0.5 * e["dur"], (t, e)
+
+
+# ---- ISSUE 37: the host path between two programs ------------------------
+
+HOST_PATH = ("decode.stage", "decode.enqueue", "decode.device_wait",
+             "decode.readback")
+
+
+def _host_path_children(parent, spans):
+    """The host-path spans inside `parent` on its thread, by start."""
+    return sorted((e for e in spans if e["name"] in HOST_PATH
+                   and e["tid"] == parent["tid"] and _inside(e, parent)),
+                  key=lambda e: e["ts"])
+
+
+@pytest.mark.parametrize("mode", sorted(ENGINE_MODES))
+def test_dispatch_and_wait_split_into_the_host_path_by_chunk(mode):
+    """Every `decode.dispatch` holds one `decode.stage`, then one
+    `decode.enqueue`; every `decode.sync_wait` one `decode.device_wait`,
+    then its `decode.readback`s; all of them carry the program's
+    `chunk`, and every chunk dispatched is waited on once (but a
+    pipelined engine's last, left in flight when the work ran out)."""
+    from collections import Counter
+
+    tr = Tracer()
+    cfg, eng = _tiny_engine(tracer=tr, **ENGINE_MODES[mode])
+    _serve_some(eng, cfg)
+    spans = [e for e in tr.events() if e["ph"] == "X"]
+    disp = [e for e in spans if e["name"] == "decode.dispatch"]
+    waits = [e for e in spans if e["name"] == "decode.sync_wait"]
+    assert disp and waits
+    for d in disp:
+        kids = _host_path_children(d, spans)
+        assert [e["name"] for e in kids] \
+            == ["decode.stage", "decode.enqueue"], kids
+        assert {e["args"]["chunk"] for e in kids} == {d["args"]["chunk"]}
+    for w in waits:
+        names = [e["name"] for e in _host_path_children(w, spans)]
+        assert names[0] == "decode.device_wait" and len(names) >= 2 \
+            and set(names[1:]) == {"decode.readback"}, names
+        assert {e["args"]["chunk"] for e in _host_path_children(
+            w, spans)} == {w["args"]["chunk"]}
+    waited = Counter(w["args"]["chunk"] for w in waits)
+    assert max(waited.values()) == 1
+    dispatched = {d["args"]["chunk"] for d in disp}
+    assert dispatched - set(waited) <= (
+        {max(dispatched)} if mode == "pipelined" else set())
+    # no host-path span outside a dispatch or a wait
+    parents = disp + waits
+    assert all(any(_inside(e, p) for p in parents)
+               for e in spans if e["name"] in HOST_PATH)
+
+
+def _mellum_engine(tracer):
+    from paddle_tpu.models import MellumConfig, mellum
+    from paddle_tpu.serving import ContinuousBatchingEngine
+
+    cfg = MellumConfig.tiny()
+    p = mellum.init_serving_params(cfg, seed=7, dtype="float32")
+    return cfg, ContinuousBatchingEngine(
+        cfg, p, slots=2, prompt_bucket=16, block_size=8, max_prompt_len=64,
+        max_new_tokens=8, token_budget=16, steps_per_sync=4,
+        dtype="float32", logprobs=True, tracer=tracer)
+
+
+def _moves(args, skip_key: bool):
+    """(arrays, bytes) of a program's host arguments or outputs."""
+    import jax
+
+    leaves = [x for x in jax.tree.leaves(args)
+              if not (skip_key and x.dtype == np.uint32)]
+    return len(leaves), sum(int(np.prod(x.shape)) * x.dtype.itemsize
+                            for x in leaves)
+
+
+@pytest.mark.parametrize("engine", ["unified", "unified_logprobs", "split",
+                                    "routed_logprobs"])
+def test_transfers_are_the_programs_arguments_and_outputs(engine):
+    """`decode.stage`'s `h2d` / `h2d_bytes` are the program's arguments
+    less the weights, the pools and the key; the summed `decode.readback`
+    `d2h` / `d2h_bytes` of a program are its outputs less the pools —
+    with log-probabilities and routed layers' counts, and a mixed step's
+    first token, which lies in a `decode.readback` of its chunk."""
+    import jax
+
+    tr = Tracer()
+    if engine == "routed_logprobs":
+        cfg, eng = _mellum_engine(tr)
+        assert eng._routed and eng.logprobs
+    else:
+        cfg, eng = _tiny_engine(
+            tracer=tr, logprobs=engine.endswith("logprobs"),
+            **ENGINE_MODES[engine.split("_")[0]])
+    _serve_some(eng, cfg)
+    want = {}
+    for kind, fn, args in [("decode", eng._decode,
+                            eng._decode_example_args()),
+                           ("mixed", eng._unified,
+                            eng._unified_example_args()
+                            if eng.unified else None)]:
+        if fn is None:
+            continue
+        outs = jax.eval_shape(fn, *args)[:-2]       # the pools stay
+        want[kind] = (_moves(args[3:], True), _moves(outs, False))
+    spans = [e for e in tr.events() if e["ph"] == "X"]
+    kind = {e["args"]["chunk"]: "mixed" if e["args"].get("prefill_window")
+            else "decode" for e in spans if e["name"] == "decode.dispatch"}
+    assert set(kind.values()) == set(want)
+    back = {}
+    for e in spans:
+        if e["name"] == "decode.readback":
+            n, b = back.get(e["args"]["chunk"], (0, 0))
+            back[e["args"]["chunk"]] = (n + e["args"]["d2h"],
+                                        b + e["args"]["d2h_bytes"])
+    for e in spans:
+        if e["name"] == "decode.stage":
+            k = kind[e["args"]["chunk"]]
+            h2d, d2h = want[k]
+            assert (e["args"]["h2d"], e["args"]["h2d_bytes"]) == h2d, k
+            assert back[e["args"]["chunk"]] == d2h, k
+    if eng.unified:
+        # the mixed step reads back one array more than the decode chunk
+        # (its first token), and one more with log-probabilities
+        assert want["mixed"][1][0] - want["decode"][1][0] \
+            == 1 + eng.logprobs
+
+
+@pytest.mark.parametrize("mode", ["unified", "split", "pipelined"])
+def test_the_tracer_changes_no_token_and_no_program(mode):
+    """One code path: with the Tracer on the engine serves the same tokens
+    from the same compiled programs as with it off."""
+    got = []
+    for tracer in (False, Tracer()):
+        cfg, eng = _tiny_engine(tracer=tracer, **ENGINE_MODES[mode])
+        reqs = _serve_some(eng, cfg)
+        got.append(([r.tokens for r in reqs], eng.compile_stats()))
+    assert got[0] == got[1]
+
+
+def test_clock_anchor_lays_a_span_on_the_profiler_session(tmp_path):
+    """The exported JSON's `clock_anchor` and the session's
+    `profile_start_time` put a Tracer span on the device trace's clock,
+    within 1 ms of its own annotation in the `.xplane.pb`."""
+    import glob
+    import os
+
+    import jax
+
+    tr = Tracer()
+    anchor0 = tr.clock_anchor
+    time.sleep(0.002)
+    tr.clear()                      # a fresh anchor with a fresh buffer
+    assert tr.clock_anchor["time_ns"] > anchor0["time_ns"]
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    jax.profiler.start_trace(str(tmp_path / "tb"), profiler_options=opts)
+    try:
+        for i in range(5):
+            with tr.span("clock.probe", i=i):
+                time.sleep(0.002)
+    finally:
+        jax.profiler.stop_trace()
+    with open(tr.export(str(tmp_path / "t.json"))) as f:
+        doc = json.load(f)
+    anchor = doc["metadata"]["clock_anchor"]
+    assert anchor == tr.clock_anchor
+    (path,) = glob.glob(os.path.join(str(tmp_path / "tb"), "plugins",
+                                     "profile", "*", "*.xplane.pb"))
+    start = obs_trace.profile_start_time(path)
+    got = sorted(_profiler_host_events(str(tmp_path / "tb"))["clock.probe"],
+                 key=lambda x: x[1])
+    mine = sorted((e for e in doc["traceEvents"]
+                   if e["name"] == "clock.probe"), key=lambda e: e["ts"])
+    assert len(got) == len(mine) == 5
+    # a span's stamps sit just inside its annotation's; the median keeps a
+    # probe the host preempted between the two from failing the clock
+    errs = sorted(max(abs(obs_trace.session_ns(e["ts"], anchor, start) - a),
+                      abs(obs_trace.session_ns(e["ts"] + e["dur"], anchor,
+                                               start) - b))
+                  for (_, a, b, _), e in zip(got, mine))
+    assert errs[2] < 1e6, errs
 
 
 if __name__ == "__main__":
