@@ -394,9 +394,10 @@ def _op_bench(only=None):
             max_new_tokens=64, block_size=64, steps_per_sync=16,
             prefill_batch=1, prefix_cache=False, serving_mp=serving_mp,
             quantized_collectives=quantized_collectives)
-        stables = jnp.full((eng.slots, eng.table_width), eng.scratch_page,
-                           jnp.int32)
-        slive = jnp.ones((eng.slots,), bool)
+        # every row live at length 96 on the scratch page; the tokens and
+        # lengths chain through the programs' device carries
+        sflat, _ = eng._put(eng._io["decode"][0], dict(
+            eng._scratch_inputs(), budgets=96, live=True, override=False))
         slens = jnp.full((eng.slots,), 96, jnp.int32)
         sone = jnp.asarray(1.0, jnp.float32)
         skey = jax.random.PRNGKey(0)
@@ -407,10 +408,9 @@ def _op_bench(only=None):
                 for i in range(int(n)):
                     if tracer is not None:
                         t0 = time.perf_counter_ns()
-                    out, lens, _, eng.kcs, eng.vcs = eng._decode(
-                        eng.p, eng.kcs, eng.vcs, toks, lens, slens,
-                        stables, slive, skey, sone, sone)
-                    toks = out[:, -1]
+                    _, toks, lens, _, eng.kcs, eng.vcs = eng._decode(
+                        eng.p, eng.kcs, eng.vcs, sflat, toks, lens, skey,
+                        sone, sone)
                     if tracer is not None:
                         tracer.complete("decode.dispatch", t0,
                                         time.perf_counter_ns(), chunk=i,
@@ -666,16 +666,12 @@ def _op_bench(only=None):
             max_new_tokens=64, block_size=64, steps_per_sync=16,
             prefill_batch=1, prefix_cache=False, unified_step=True,
             token_budget=128)
+        # every row live at length 96 and a full window of a cached
+        # prefix, all on the scratch page
         tn = ueng.token_budget
-        n_win = tn // ueng.block_size
-        utables = jnp.full((ueng.slots, ueng.table_width),
-                           ueng.scratch_page, jnp.int32)
-        ulive = jnp.ones((ueng.slots,), bool)
-        ulens = jnp.full((ueng.slots,), 96, jnp.int32)
-        uids = jnp.ones((1, tn), jnp.int32)
-        uctbl = jnp.full((1, ueng.table_width), ueng.scratch_page,
-                         jnp.int32)
-        uwin = jnp.full((1, n_win), ueng.scratch_page, jnp.int32)
+        uflat, _ = ueng._put(ueng._io["mixed"][0], dict(
+            ueng._scratch_inputs(), lens=96, budgets=96, live=True,
+            chunk_ids=1, chunk_len=tn))
         uone = jnp.asarray(1.0, jnp.float32)
         ukey = jax.random.PRNGKey(0)
 
@@ -683,17 +679,10 @@ def _op_bench(only=None):
             # chained donated invocations, synced once — the slope
             # cancels the fixed per-call cost like the decode-chunk rig; a full
             # 64-token cached window keeps per-call cost constant
-            toks = jnp.zeros((ueng.slots,), jnp.int32)
-            lens = ulens
             for _ in range(int(n)):
-                out, lens, _, _, ueng.kcs, ueng.vcs = ueng._unified(
-                    ueng.p, ueng.kcs, ueng.vcs, toks, lens, ulens,
-                    utables, ulive, uids, uctbl,
-                    jnp.zeros((1,), jnp.int32),
-                    jnp.full((1,), tn, jnp.int32), uwin, ukey, uone,
-                    uone)
-                toks = out[:, -1]
-            return float(jnp.sum(lens))
+                packed, _, ueng.kcs, ueng.vcs = ueng._unified(
+                    ueng.p, ueng.kcs, ueng.vcs, uflat, ukey, uone, uone)
+            return float(jnp.sum(packed))
 
         urun(1)  # compile once
         ops["ragged_step"] = round(paired_slope_ms(urun, 1, 13,

@@ -130,6 +130,47 @@ def _token_logprob(logits, tok):
         - jax.nn.logsumexp(logits, axis=-1)
 
 
+class _Flat:
+    """Named fields at fixed offsets in one flat int32 vector: how a served
+    program's host inputs go in (one transfer) and its host-visible outputs
+    come back (one copy). A field is int32, bool (as 0 / 1) or float32 (its
+    bits). `pack` and `unpack` take numpy arrays on the host and traced
+    arrays inside a program alike; `pack` broadcasts a scalar over its
+    field, and ignores values no field names."""
+
+    def __init__(self, fields):
+        self.fields, self.size = [], 0
+        for name, shape, dtype in fields:
+            n = int(np.prod(shape))
+            self.fields.append((name, shape, np.dtype(dtype), self.size, n))
+            self.size += n
+        self.names = [f[0] for f in self.fields]
+
+    def pack(self, values: dict):
+        xp = jnp if any(isinstance(values[name], jax.Array)
+                        for name in self.names) else np
+        parts = []
+        for name, shape, dtype, _, _ in self.fields:
+            x = xp.broadcast_to(xp.asarray(values[name], dtype), shape)
+            if dtype == np.float32:
+                x = jax.lax.bitcast_convert_type(x, jnp.int32) \
+                    if xp is jnp else x.view(np.int32)
+            parts.append(x.astype(np.int32).reshape(-1))
+        return xp.concatenate(parts)
+
+    def unpack(self, flat) -> dict:
+        out = {}
+        for name, shape, dtype, off, n in self.fields:
+            x = flat[off:off + n].reshape(shape)
+            if dtype == np.float32:
+                x = x.view(np.float32) if isinstance(flat, np.ndarray) \
+                    else jax.lax.bitcast_convert_type(x, jnp.float32)
+            elif dtype == bool:
+                x = x != 0
+            out[name] = x
+        return out
+
+
 @dataclass
 class ServeRequest:
     """One generation request tracked through the engine."""
@@ -701,20 +742,23 @@ class ContinuousBatchingEngine:
         self.window_tokens_dropped = 0   # cached tokens that left a window
         self._tokens = np.zeros((slots,), np.int32)
         self._budgets = np.zeros((slots,), np.int32)  # prompt + max_new
-        self._key = jax.random.PRNGKey(seed)
+        # the key lives on the device: the decode chunk and the mixed
+        # step split it themselves and hand the next one back
+        self._key = self._replicated(jax.random.PRNGKey(seed))
+        self._sampling_at = None    # (temperature, top_p) of _sampling_dev
         self.waiting: list[ServeRequest] = []
         self.finished: list[ServeRequest] = []
         self._next_id = 0
         self._prefill_cache = {}
+        self._io = self._io_layouts()
         self._decode = self._program(
-            self._build_decode_chunk(), "serve_decode_chunk", 8,
-            3 + self._routed + self.logprobs)
+            self._build_decode_chunk(), "serve_decode_chunk", 6, 4)
         # the ONE mixed prefill+decode program (ISSUE 14) — built only
         # on the unified path; its shape key is (token_budget, slots,
         # steps, kv-dtype, mp) and warm() compiles it once
         self._unified = self._program(
-            self._build_unified_step(), "serve_unified_step", 13,
-            4 + self._routed + 2 * self.logprobs) if self.unified else None
+            self._build_unified_step(), "serve_unified_step", 4, 2) \
+            if self.unified else None
         # speculative verify: one ragged window of spec_k+1 rows per
         # slot scores every draft + the pending token in a single pass
         # (models/llama._make_verify_window); built only when the
@@ -753,6 +797,9 @@ class ContinuousBatchingEngine:
         self._chain_tok = None
         self._chain_lens = None
         self._override = np.ones((slots,), bool)
+        # what an unchained chunk passes for the carries: every row
+        # overrides them
+        self._no_chain = self._replicated(jnp.zeros((slots,), jnp.int32))
         # observability (ISSUE 8): None defers to the flag-armed
         # globals, False forces OFF regardless of flags (how an
         # untraced bench baseline stays untraced next to an armed
@@ -804,16 +851,107 @@ class ContinuousBatchingEngine:
         """The logical table of a ring: column j -> ring page j % R."""
         return np.asarray(ring, np.int32)[np.arange(width) % len(ring)]
 
-    def _tables_arg(self, full, ring):
-        """One program argument for both pool kinds' tables."""
-        if not self._window_layers:
-            return jnp.asarray(full)
-        return (jnp.asarray(full), jnp.asarray(ring))
+    # ---- the served programs' host inputs and outputs (PR 38) -----------
 
-    def _scratch_tables_arg(self, rows: int, width: int):
-        return self._tables_arg(
-            np.full((rows, width), self.scratch_page, np.int32),
-            np.zeros((rows, width), np.int32))
+    def _io_layouts(self) -> dict:
+        """{"decode" | "mixed": (inputs, outputs)}: the `_Flat` layouts of
+        the decode chunk's and the mixed step's host inputs and host-visible
+        outputs. Their offsets follow from the engine's shapes — slots,
+        table width, steps, token budget, whether window or routed layers
+        exist, `logprobs` — and from nothing else. A `*_ring` field is a
+        table of the window layers' ring pools, beside the full layers'
+        table of the same name."""
+        b, W, steps = self.slots, self.table_width, self.steps
+        n_win = self.token_budget // self.block_size
+        i32, f32 = np.int32, np.float32
+        rings = bool(self._window_layers)
+
+        def table(name, shape):
+            return [(name, shape, i32)] \
+                + ([(name + "_ring", shape, i32)] if rings else [])
+
+        slots = [("toks", (b,), i32), ("lens", (b,), i32),
+                 ("budgets", (b,), i32), ("live", (b,), bool)] \
+            + table("tables", (b, W))
+        outs = [("out", (b, steps), i32), ("lens", (b,), i32),
+                ("done", (b,), bool)]
+
+        def counted(lanes):
+            # the routed layers' MOE_COUNTS: one row a lane
+            return [("moe", (lanes, len(MOE_COUNTS)), i32)] \
+                if self._routed else []
+
+        lps = [("logprobs", (b, steps), f32)] if self.logprobs else []
+        return {
+            # `override`: rows whose host state wins over the chained
+            # device carries of a double-buffered chunk
+            "decode": (_Flat(slots + [("override", (b,), bool)]),
+                       _Flat(outs + counted(1) + lps)),
+            "mixed": (_Flat(slots + [("chunk_ids", (1, self.token_budget),
+                                      i32)]
+                            + table("chunk_table", (1, W))
+                            + [("chunk_cached", (1,), i32),
+                               ("chunk_len", (1,), i32)]
+                            + table("chunk_pages", (1, n_win))),
+                      _Flat(outs + [("first", (1,), i32)] + counted(2)
+                            + lps + ([("first_logprob", (1,), f32)]
+                                     if self.logprobs else []))),
+        }
+
+    def _table_arg(self, a: dict, name: str):
+        """The program argument of table `name` in unpacked inputs `a`: the
+        full layers' table, or the pair with the window layers' ring
+        table."""
+        return (a[name], a[name + "_ring"]) if self._window_layers \
+            else a[name]
+
+    def _put(self, layout: _Flat, values: dict):
+        """A served program's host inputs packed into `layout` and put on
+        the device in one transfer: (device buffer, host buffer)."""
+        flat = layout.pack(values)
+        return jax.device_put(flat), flat
+
+    def _sampling(self) -> tuple:
+        """Temperature and top-p as device scalars: made once, and again
+        only when either attribute changes."""
+        at = (self.temperature, self.top_p)
+        if self._sampling_at != at:
+            self._sampling_at = at
+            self._sampling_dev = tuple(
+                self._replicated(jnp.asarray(x, jnp.float32)) for x in at)
+        return self._sampling_dev
+
+    def _replicated(self, x):
+        """`x` placed as the served programs hand their replicated outputs
+        back (the key, the chained carries): over the serving mesh when
+        there is one. An argument placed otherwise would be a second entry
+        in a program's jit cache."""
+        if self._tp is None:
+            return x
+        from jax.sharding import NamedSharding, PartitionSpec
+
+        return jax.device_put(x, NamedSharding(self.mp_mesh,
+                                               PartitionSpec()))
+
+    def _slot_inputs(self, live) -> dict:
+        """Every slot's host state as the served programs read it."""
+        return {"toks": self._tokens,
+                "lens": np.asarray([s.length for s in self._slots],
+                                   np.int32),
+                "budgets": self._budgets, "live": live,
+                "tables": self._tables, "tables_ring": self._ring_tables}
+
+    def _scratch_inputs(self) -> dict:
+        """Inputs that aim every row of both served programs at the
+        scratch page (the ring pools' sink is their page 0): warm-up and
+        the audits' example arguments. A window of chunk_len 0 is all
+        pad."""
+        return {"toks": 0, "lens": 0, "budgets": 0, "live": False,
+                "override": True, "chunk_ids": 0, "chunk_cached": 0,
+                "chunk_len": 0,
+                **{name + ring: 0 if ring else self.scratch_page
+                   for name in ("tables", "chunk_table", "chunk_pages")
+                   for ring in ("", "_ring")}}
 
     def _release(self, req) -> None:
         """Give back everything `req` holds in both pool kinds."""
@@ -1389,7 +1527,7 @@ class ContinuousBatchingEngine:
             `merge_attn_partials` folds the per-shard stats — never
             the KV — into the global context."""
             if self._window_layers:
-                return window_step(*tables)    # `_tables_arg`'s pair
+                return window_step(*tables)    # `_table_arg`'s pair
             if cp_parts:
                 from ..kernels.partial_attention import (
                     cp_local_view, decode_paged_partials,
@@ -1468,16 +1606,17 @@ class ContinuousBatchingEngine:
 
         return make_step
 
-    def _build_decode_chunk(self):
-        """`steps` decode tokens for every slot in one program. Retired /
-        free rows point their table at the scratch page and freeze their
-        length, so they compute (fixed shape) but touch nothing live.
-        `budgets` [slots] freezes each row on-device at prompt+max_new —
-        the guarantee that a speculatively-dispatched chunk (double
-        buffering) can never write past a request's reserved pages.
-        Returns (tokens, lengths, done, pools); before the pools a model
-        with routed layers puts their summed MOE_COUNTS vector, and
-        `logprobs` the tokens' log-probabilities [slots, steps]."""
+    def _decode_chunk_body(self):
+        """`steps` decode tokens for every slot. Retired / free rows point
+        their table at the scratch page and freeze their length, so they
+        compute (fixed shape) but touch nothing live. `budgets` [slots]
+        freezes each row on-device at prompt+max_new — the guarantee that
+        a speculatively-dispatched chunk (double buffering) can never
+        write past a request's reserved pages. Returns (outputs, last
+        tokens, pools): `outputs` holds the tokens [slots, steps], the
+        lengths and the done flags; a model with routed layers adds their
+        summed MOE_COUNTS vector (`moe`), and `logprobs` the tokens'
+        log-probabilities [slots, steps]."""
         b, steps = self.slots, self.steps
         do_sample, top_k, eos = self.do_sample, self.top_k, self.eos
         make_step = self._decode_step_maker()
@@ -1511,12 +1650,43 @@ class ContinuousBatchingEngine:
             (tok, lens, kcs, vcs, done, _), (out, *counts) = jax.lax.scan(
                 step, (toks, lens, kcs, vcs, done0, key), None,
                 length=steps)
+            res = {"out": jnp.swapaxes(out, 0, 1), "lens": lens,
+                   "done": done}
             # a routed model's steps counted what their layers did: the
             # sums ride out with the tokens the commit waits for anyway
-            extra = (jnp.sum(counts[0], axis=0),) if routed else ()
+            if routed:
+                res["moe"] = jnp.sum(counts[0], axis=0)
             if logprobs:
-                extra += (jnp.swapaxes(counts[-1], 0, 1),)
-            return (jnp.swapaxes(out, 0, 1), lens, done, *extra, kcs, vcs)
+                res["logprobs"] = jnp.swapaxes(counts[-1], 0, 1)
+            return res, tok, kcs, vcs
+
+        return run
+
+    def _build_decode_chunk(self):
+        """The decode chunk as the engine serves it, one transfer each
+        way: it splits the key it is handed (the host's split, moved in)
+        and returns the next one, reads every host input from ONE packed
+        buffer (`_io_layouts()["decode"]`) and returns every host-visible
+        output in ONE packed vector. A double-buffered chunk's tokens and
+        lengths come from the previous chunk's device carries — also
+        returned — except in rows the host overrides. Returns (packed,
+        last tokens, lengths, next key, pools)."""
+        body = self._decode_chunk_body()
+        io_in, io_out = self._io["decode"]
+
+        def run(p, kcs, vcs, flat, chain_tok, chain_lens, key, temperature,
+                top_p):
+            key, k = jax.random.split(key)
+            a = io_in.unpack(flat)
+            toks = jnp.where(a["override"], a["toks"], chain_tok)
+            lens = jnp.where(a["override"], a["lens"], chain_lens)
+            res, tok, kcs, vcs = body(
+                p, kcs, vcs, toks, lens, a["budgets"],
+                self._table_arg(a, "tables"), a["live"], k, temperature,
+                top_p)
+            if "moe" in res:
+                res["moe"] = res["moe"][None]
+            return io_out.pack(res), tok, res["lens"], key, kcs, vcs
 
         return run
 
@@ -1537,30 +1707,39 @@ class ContinuousBatchingEngine:
         0, cache-hit prompts start at their prefix depth, long prompts
         stream across steps (chunked prefill — decode latency becomes
         immune to a 100k-token prompt). The program's shape key is just
-        (token_budget, slots, steps, kv-dtype, mp)."""
-        cfg, b, bs = self.cfg, self.slots, self.block_size
+        (token_budget, slots, steps, kv-dtype, mp).
+
+        Served like the decode chunk, one transfer each way: the key in
+        and the next key out, the host inputs in one packed buffer and
+        the host-visible outputs — the decode lane's, the first token,
+        its log-probability — in one packed vector
+        (`_io_layouts()["mixed"]`). Returns (packed, next key, pools)."""
+        cfg, bs = self.cfg, self.block_size
         tn = self.token_budget
         n_win = tn // bs
         do_sample, top_k = self.do_sample, self.top_k
-        decode_chunk = self._build_decode_chunk()
+        decode_chunk = self._decode_chunk_body()
         chunk_body = _make_chunk_prefill(cfg, tn, tp=self._tp)
         head_logits = _make_head_logits(cfg)
         scatter = self._page_scatter(1, n_win)
-        window_layers, routed = self._window_layers, self._routed
-        logprobs = self.logprobs
+        window_layers = self._window_layers
+        io_in, io_out = self._io["mixed"]
 
-        def run(p, kcs, vcs, toks, lens, budgets, tables, live,
-                chunk_ids, chunk_table, chunk_cached, chunk_len,
-                chunk_pages, key, temperature, top_p):
-            key, kd, ks = jax.random.split(key, 3)
+        def run(p, kcs, vcs, flat, key, temperature, top_p):
+            key, k = jax.random.split(key)
+            _, kd, ks = jax.random.split(k, 3)
+            a = io_in.unpack(flat)
+            chunk_len = a["chunk_len"]
+            chunk_pages = self._table_arg(a, "chunk_pages")
             # ---- decode lane: the split decode chunk, verbatim ----
-            out, lens_o, done, *extra, kcs, vcs = decode_chunk(
-                p, kcs, vcs, toks, lens, budgets, tables, live, kd,
-                temperature, top_p)
-            count, lps = extra[:routed], extra[routed:]
+            res, _, kcs, vcs = decode_chunk(
+                p, kcs, vcs, a["toks"], a["lens"], a["budgets"],
+                self._table_arg(a, "tables"), a["live"], kd, temperature,
+                top_p)
             # ---- chunk lane: one ragged prefill window ----
             h, kvs, *chunk_count = chunk_body(
-                p, kcs, vcs, chunk_ids, chunk_table, chunk_cached,
+                p, kcs, vcs, a["chunk_ids"],
+                self._table_arg(a, "chunk_table"), a["chunk_cached"],
                 chunk_len)
             for i, (k, v) in enumerate(kvs):
                 # a window layer's rows go to its ring's pages (the pair's
@@ -1575,13 +1754,15 @@ class ContinuousBatchingEngine:
                 h, jnp.maximum(chunk_len[0] - 1, 0), axis=1,
                 keepdims=True)
             logits = head_logits(h_last, p)[:, -1]
-            first = _sample_next(logits.astype(jnp.float32), ks,
-                                 do_sample, temperature, top_k, top_p)
-            # routed layers' MOE_COUNTS, the decode lane's and the window's
-            extra = (jnp.stack(count + chunk_count),) if routed else ()
-            if logprobs:
-                extra += (*lps, _token_logprob(logits, first))
-            return (out, lens_o, done, first, *extra, kcs, vcs)
+            res["first"] = _sample_next(logits.astype(jnp.float32), ks,
+                                        do_sample, temperature, top_k, top_p)
+            if "moe" in res:
+                # routed layers' MOE_COUNTS, the decode lane's and the
+                # window's
+                res["moe"] = jnp.stack([res["moe"], *chunk_count])
+            if "first_logprob" in io_out.names:
+                res["first_logprob"] = _token_logprob(logits, res["first"])
+            return io_out.pack(res), key, kcs, vcs
 
         return run
 
@@ -1792,8 +1973,7 @@ class ContinuousBatchingEngine:
                     jnp.zeros((bsz, sb), jnp.int32),
                     jnp.ones((bsz,), jnp.int32),
                     jnp.full((bsz, n_pre), self.scratch_page, jnp.int32),
-                    k, jnp.asarray(self.temperature, jnp.float32),
-                    jnp.asarray(self.top_p, jnp.float32))
+                    k, *self._sampling())
                 if self.prefix_cache:
                     # prefix length 0 masks the whole (scratch) prefix:
                     # the warm run computes garbage, touches only the
@@ -1810,43 +1990,25 @@ class ContinuousBatchingEngine:
                             jnp.full((bsz, w), self.scratch_page,
                                      jnp.int32),
                             jnp.zeros((bsz,), jnp.int32),
-                            k, jnp.asarray(self.temperature, jnp.float32),
-                            jnp.asarray(self.top_p, jnp.float32))
+                            k, *self._sampling())
                 if bsz >= cap:
                     break
                 bsz *= 2
-        # scratch-only tables: warming against the live tables would
+        # scratch-only inputs: warming against the live tables would
         # scatter the warm token's K/V into an admitted request's pages
-        scratch_tables = self._scratch_tables_arg(self.slots,
-                                                  self.table_width)
+        scratch = self._scratch_inputs()
         if self.unified:
             # the unified mixed program: an all-scratch window of
             # chunk_len 0 (every window row is pad — the ragged kernel
             # emits zeros, the scatter hits only the scratch page)
-            self._key, k = jax.random.split(self._key)
-            tn = self.token_budget
-            n_win = tn // self.block_size
-            uout = self._unified(
-                self.p, self.kcs, self.vcs, jnp.asarray(self._tokens),
-                jnp.zeros((self.slots,), jnp.int32),
-                jnp.zeros((self.slots,), jnp.int32), scratch_tables,
-                jnp.zeros((self.slots,), bool),
-                jnp.zeros((1, tn), jnp.int32),
-                self._scratch_tables_arg(1, self.table_width),
-                jnp.zeros((1,), jnp.int32), jnp.zeros((1,), jnp.int32),
-                self._scratch_tables_arg(1, n_win), k,
-                jnp.asarray(self.temperature, jnp.float32),
-                jnp.asarray(self.top_p, jnp.float32))
-            *_, self.kcs, self.vcs = uout
-        self._key, k = jax.random.split(self._key)
-        out = self._decode(
-            self.p, self.kcs, self.vcs, jnp.asarray(self._tokens),
-            jnp.zeros((self.slots,), jnp.int32),
-            jnp.zeros((self.slots,), jnp.int32), scratch_tables,
-            jnp.zeros((self.slots,), bool), k,
-            jnp.asarray(self.temperature, jnp.float32),
-            jnp.asarray(self.top_p, jnp.float32))
-        *_, self.kcs, self.vcs = out
+            flat, _ = self._put(self._io["mixed"][0], scratch)
+            _, self._key, self.kcs, self.vcs = self._unified(
+                self.p, self.kcs, self.vcs, flat, self._key,
+                *self._sampling())
+        flat, _ = self._put(self._io["decode"][0], scratch)
+        *_, self._key, self.kcs, self.vcs = self._decode(
+            self.p, self.kcs, self.vcs, flat, self._no_chain,
+            self._no_chain, self._key, *self._sampling())
         if self._verify is not None:
             # the speculative verify window: every slot all-scratch
             # with new_len=1 (the pending-token row only — pad columns
@@ -1854,7 +2016,9 @@ class ContinuousBatchingEngine:
             vout = self._verify(
                 self.p, self.kcs, self.vcs,
                 jnp.zeros((self.slots, self.spec_k + 1), jnp.int32),
-                scratch_tables, jnp.zeros((self.slots,), jnp.int32),
+                jnp.full((self.slots, self.table_width), self.scratch_page,
+                         jnp.int32),
+                jnp.zeros((self.slots,), jnp.int32),
                 jnp.ones((self.slots,), jnp.int32))
             _, self.kcs, self.vcs = vout
         if self._drafter is not None:
@@ -1884,14 +2048,9 @@ class ContinuousBatchingEngine:
     # ---- static memory audit (ISSUE 10) ---------------------------------
 
     def _decode_example_args(self):
-        b = self.slots
-        return (self.p, self.kcs, self.vcs,
-                jnp.zeros((b,), jnp.int32), jnp.zeros((b,), jnp.int32),
-                jnp.zeros((b,), jnp.int32),
-                self._scratch_tables_arg(b, self.table_width),
-                jnp.zeros((b,), bool), jax.random.PRNGKey(0),
-                jnp.asarray(self.temperature, jnp.float32),
-                jnp.asarray(self.top_p, jnp.float32))
+        flat, _ = self._put(self._io["decode"][0], self._scratch_inputs())
+        return (self.p, self.kcs, self.vcs, flat, self._no_chain,
+                self._no_chain, jax.random.PRNGKey(0), *self._sampling())
 
     def _prefill_example_args(self, key):
         """Warm()-shaped example args for a `_prefill_cache` entry —
@@ -1903,9 +2062,7 @@ class ContinuousBatchingEngine:
                 jnp.zeros((bsz, sb), jnp.int32),
                 jnp.ones((bsz,), jnp.int32),
                 jnp.zeros((bsz, n_pre), jnp.int32))
-        tail = (jax.random.PRNGKey(0),
-                jnp.asarray(self.temperature, jnp.float32),
-                jnp.asarray(self.top_p, jnp.float32))
+        tail = (jax.random.PRNGKey(0), *self._sampling())
         if kind == "prefix":
             w = key[3]
             return head + (jnp.zeros((bsz, w), jnp.int32),
@@ -1913,17 +2070,9 @@ class ContinuousBatchingEngine:
         return head + tail
 
     def _unified_example_args(self):
-        b, tn, W = self.slots, self.token_budget, self.table_width
-        n_win = tn // self.block_size
-        return (self.p, self.kcs, self.vcs,
-                jnp.zeros((b,), jnp.int32), jnp.zeros((b,), jnp.int32),
-                jnp.zeros((b,), jnp.int32), self._scratch_tables_arg(b, W),
-                jnp.zeros((b,), bool), jnp.zeros((1, tn), jnp.int32),
-                self._scratch_tables_arg(1, W), jnp.zeros((1,), jnp.int32),
-                jnp.zeros((1,), jnp.int32),
-                self._scratch_tables_arg(1, n_win), jax.random.PRNGKey(0),
-                jnp.asarray(self.temperature, jnp.float32),
-                jnp.asarray(self.top_p, jnp.float32))
+        flat, _ = self._put(self._io["mixed"][0], self._scratch_inputs())
+        return (self.p, self.kcs, self.vcs, flat, jax.random.PRNGKey(0),
+                *self._sampling())
 
     def _verify_example_args(self):
         b, W = self.slots, self.table_width
@@ -2442,14 +2591,12 @@ class ContinuousBatchingEngine:
                         out = fn(self.p, self.kcs, self.vcs, jnp.asarray(ids),
                                  jnp.asarray(s0s), jnp.asarray(pages),
                                  jnp.asarray(ptbl), jnp.asarray(plens), k,
-                                 jnp.asarray(self.temperature, jnp.float32),
-                                 jnp.asarray(self.top_p, jnp.float32))
+                                 *self._sampling())
                     else:
                         fn = self._get_prefill(sb_suf, bsz)
                         out = fn(self.p, self.kcs, self.vcs, jnp.asarray(ids),
                                  jnp.asarray(s0s), jnp.asarray(pages), k,
-                                 jnp.asarray(self.temperature, jnp.float32),
-                                 jnp.asarray(self.top_p, jnp.float32))
+                                 *self._sampling())
                     firsts_dev, self.kcs, self.vcs = out
                 # blocking readback OUTSIDE the lock: a hung device wait
                 # must never hold the lock the timeout path needs
@@ -2718,15 +2865,18 @@ class ContinuousBatchingEngine:
             tbl = np.full((1, self.table_width), self.scratch_page,
                           np.int32)
             tbl[0, :len(req.pages)] = req.pages
-            ring_tbl = ring_win = None
+            window = {"chunk_ids": ids, "chunk_table": tbl,
+                      "chunk_cached": done, "chunk_len": this_chunk,
+                      "chunk_pages": win_pages}
             if req.ring:
                 # the window's rows in the ring: pages past the chunk's
                 # end take pad rows, which go to the sink as the full
                 # layers' go to the scratch page
-                ring_tbl = self._ring_table(req.ring, self.table_width)[None]
-                ring_win = np.where(
+                ring_tbl = self._ring_table(req.ring, self.table_width)
+                window["chunk_table_ring"] = ring_tbl
+                window["chunk_pages_ring"] = np.where(
                     np.arange(n_win) < -(-this_chunk // bs),
-                    ring_tbl[0, wp0:wp0 + n_win], 0)[None]
+                    ring_tbl[wp0:wp0 + n_win], 0)
         if self._watchdog is not None:
             self._watchdog.phase = "decode"
         chaos.maybe_hang("decode")
@@ -2747,34 +2897,19 @@ class ContinuousBatchingEngine:
                     chunk = self.device_steps + 1
                     with (_NULL_SPAN if tr is None else tr.span(
                             "decode.stage", chunk=chunk)) as stage:
-                        self._key, k = jax.random.split(self._key)
                         live = np.asarray(
                             [s.req is not None for s in self._slots])
-                        staged = (
-                            jnp.asarray(self._tokens),
-                            jnp.asarray(np.asarray(
-                                [s.length for s in self._slots], np.int32)),
-                            jnp.asarray(self._budgets),
-                            self._tables_arg(self._tables,
-                                             self._ring_tables),
-                            jnp.asarray(live), jnp.asarray(ids),
-                            self._tables_arg(tbl, ring_tbl),
-                            jnp.asarray([done], np.int32),
-                            jnp.asarray([this_chunk], np.int32),
-                            self._tables_arg(
-                                np.asarray([win_pages], np.int32),
-                                ring_win))
-                        temp = jnp.asarray(self.temperature, jnp.float32)
-                        top_p = jnp.asarray(self.top_p, jnp.float32)
+                        io_in, io_out = self._io["mixed"]
+                        flat, host = self._put(
+                            io_in, {**self._slot_inputs(live), **window})
                         if tr is not None:
-                            stage.set(**_moved("h2d", (staged, temp, top_p)))
+                            stage.set(**_moved("h2d", host))
                     with (_NULL_SPAN if tr is None else tr.span(
                             "decode.enqueue", chunk=chunk)):
                         res = self._unified(self.p, self.kcs, self.vcs,
-                                            *staged, k, temp, top_p)
-                    out, new_lens, dn, first_dev, *extra, self.kcs, \
-                        self.vcs = res
-                    moe, lps = extra[:self._routed], extra[self._routed:]
+                                            flat, self._key,
+                                            *self._sampling())
+                    packed, self._key, self.kcs, self.vcs = res
                     self.device_steps += 1
                     self.prefill_chunks += 1
                     # a mixed step is authoritative host state — never
@@ -2792,16 +2927,15 @@ class ContinuousBatchingEngine:
                         mt.gauge("kv_pages_available",
                                  "free + evictable pool pages").set(
                                      self.mgr.n_available)
-                    rec = {"out": out, "lens": new_lens, "done": dn,
+                    rec = {"packed": packed, "layout": io_out,
                            "reqs": [s.req for s in self._slots],
-                           "t_disp0": t_disp0, "moe": moe,
-                           "logprobs": lps[:1], "chunk": chunk,
-                           "first": [first_dev, *lps[1:]]}
-            # the first token and its log-probabilities are read back
-            # with the decode lane's outputs, in the same readback
+                           "t_disp0": t_disp0, "chunk": chunk}
+            # the first token and its log-probability come back with the
+            # decode lane's outputs, in the same copy
             produced = self._commit_chunk(rec, token)
-            first = int(rec["first"][0][0])
-            first_lp = [float(lp[0]) for lp in rec["first"][1:]]
+            first = int(rec["host"]["first"][0])
+            first_lp = [float(x) for x in
+                        rec["host"].get("first_logprob", ())]
         if mt is not None:
             mt.histogram(
                 "prefill_chunk_s",
@@ -3005,38 +3139,24 @@ class ContinuousBatchingEngine:
                 chunk = self.device_steps + 1
                 with (_NULL_SPAN if tr is None else tr.span(
                         "decode.stage", chunk=chunk)) as stage:
-                    self._key, k = jax.random.split(self._key)
-                    host_toks = jnp.asarray(self._tokens)
-                    host_lens = jnp.asarray(np.asarray(
-                        [s.length for s in self._slots], np.int32))
-                    staged = [host_toks, host_lens]
-                    if chain and self._chain_tok is not None \
-                            and not self._override.all():
-                        ov = jnp.asarray(self._override)
-                        staged.append(ov)
-                        toks_in = jnp.where(ov, host_toks, self._chain_tok)
-                        lens_in = jnp.where(ov, host_lens, self._chain_lens)
-                    else:
-                        toks_in, lens_in = host_toks, host_lens
-                    budgets = jnp.asarray(self._budgets)
-                    tables = self._tables_arg(self._tables,
-                                              self._ring_tables)
-                    live_in = jnp.asarray(live)
-                    temp = jnp.asarray(self.temperature, jnp.float32)
-                    top_p = jnp.asarray(self.top_p, jnp.float32)
+                    chained = chain and self._chain_tok is not None
+                    io_in, io_out = self._io["decode"]
+                    flat, host = self._put(io_in, {
+                        **self._slot_inputs(live),
+                        "override": self._override if chained else True})
                     if tr is not None:
-                        stage.set(**_moved("h2d", staged + [
-                            budgets, tables, live_in, temp, top_p]))
+                        stage.set(**_moved("h2d", host))
+                carries = (self._chain_tok, self._chain_lens) if chained \
+                    else (self._no_chain, self._no_chain)
                 with (_NULL_SPAN if tr is None else tr.span(
                         "decode.enqueue", chunk=chunk)):
-                    res = self._decode(
-                        self.p, self.kcs, self.vcs, toks_in, lens_in,
-                        budgets, tables, live_in, k, temp, top_p)
-                out, new_lens, done, *extra, self.kcs, self.vcs = res
-                moe, lps = extra[:self._routed], extra[self._routed:]
+                    res = self._decode(self.p, self.kcs, self.vcs, flat,
+                                       *carries, self._key,
+                                       *self._sampling())
+                packed, tok, new_lens, self._key, self.kcs, self.vcs = res
                 self.device_steps += 1
                 if chain:
-                    self._chain_tok = out[:, -1]
+                    self._chain_tok = tok
                     self._chain_lens = new_lens
                     self._override[:] = False
                 else:
@@ -3057,25 +3177,24 @@ class ContinuousBatchingEngine:
                 # dispatch wall time rides the record: _commit_chunk
                 # turns (dispatch start -> readback done) into
                 # decode_chunk_s
-                rec = {"out": out, "lens": new_lens, "done": done,
+                rec = {"packed": packed, "layout": io_out,
                        "reqs": [s.req for s in self._slots],
-                       "t_disp0": t_disp0, "moe": moe, "logprobs": lps,
-                       "chunk": chunk}
+                       "t_disp0": t_disp0, "chunk": chunk}
         return rec
 
-    def _read_back(self, chunk: int, outs: list) -> list:
+    def _read_back(self, chunk: int, out):
         """The host's side of a program's end, under the caller's
-        `decode.sync_wait`: wait until the program's host-visible
-        outputs are ready (`decode.device_wait`: the host waiting on the
-        device), then copy them (`decode.readback`: copies of finished
-        arrays, the transfers alone). Returns the host copies in order."""
+        `decode.sync_wait`: wait until the program's one host-visible
+        output is ready (`decode.device_wait`: the host waiting on the
+        device), then copy it (`decode.readback`: one copy of a finished
+        array, the transfer alone). Returns the host copy."""
         tr = self._tracer
         with (_NULL_SPAN if tr is None else tr.span(
                 "decode.device_wait", chunk=chunk)):
-            jax.block_until_ready(outs)
+            out.block_until_ready()
         with (_NULL_SPAN if tr is None else tr.span(
                 "decode.readback", chunk=chunk)) as sp:
-            host = [np.asarray(x) for x in outs]
+            host = np.asarray(out)
             if tr is not None:
                 sp.set(**_moved("d2h", host))
         return host
@@ -3087,7 +3206,9 @@ class ContinuousBatchingEngine:
         dispatched (double buffering: retired then re-admitted) are
         skipped — their device work was speculative waste, their writes
         are confined to pages that are overwritten before any new owner
-        reads them. Returns live tokens produced."""
+        reads them. The outputs, split by `rec["layout"]`, stay on
+        `rec["host"]` (a mixed step's first token is among them).
+        Returns live tokens produced."""
         tr, mt = self._tracer, self._metrics
         # a `stalled` span is the double-buffer stall the pipeline
         # exists to hide (Perfetto query: name='decode.sync_wait' AND
@@ -3095,19 +3216,10 @@ class ContinuousBatchingEngine:
         with (_NULL_SPAN if tr is None else tr.span(
                 "decode.sync_wait", chunk=rec["chunk"])) as sp:
             t0 = time.perf_counter()
-            # the routed layers' counts came with the tokens: one
-            # MOE_COUNTS vector from the decode chunk, two (decode lane,
-            # window) from a mixed step; a mixed step's first token (and
-            # its log-probabilities) too, handed back in `rec["first"]`
-            n_moe, n_lp = len(rec["moe"]), len(rec["logprobs"])
-            host = self._read_back(rec["chunk"], [
-                rec["out"], rec["lens"], rec["done"], *rec["moe"],
-                *rec["logprobs"], *rec.get("first", ())])
-            out, new_lens, done = host[:3]
-            moe = [c.reshape(-1, len(MOE_COUNTS))
-                   for c in host[3:3 + n_moe]]
-            lps = host[3 + n_moe:3 + n_moe + n_lp]
-            rec["first"] = host[3 + n_moe + n_lp:]
+            got = rec["host"] = rec["layout"].unpack(
+                self._read_back(rec["chunk"], rec["packed"]))
+            out, new_lens, done = got["out"], got["lens"], got["done"]
+            lps = got.get("logprobs")
             t1 = time.perf_counter()
             wait = t1 - t0
             stalled = wait > self.stall_threshold_s
@@ -3131,9 +3243,10 @@ class ContinuousBatchingEngine:
                 self.sync_wait_s += wait
                 if stalled:
                     self.blocked_syncs += 1
-                for counted in moe:
-                    for lane, row in zip(("decode", "chunk"), counted):
-                        self.moe_counts[lane] += row
+                # the routed layers' counts came with the tokens: the
+                # decode lane's row, and a mixed step's window's
+                for lane, row in zip(("decode", "chunk"), got.get("moe", ())):
+                    self.moe_counts[lane] += row
                 produced = 0
                 for slot_id, slot in enumerate(self._slots):
                     req = rec["reqs"][slot_id]
@@ -3144,8 +3257,9 @@ class ContinuousBatchingEngine:
                     if self.eos is not None and self.eos in toks:
                         toks = toks[:toks.index(self.eos) + 1]
                     req.tokens.extend(toks)
-                    for lp in lps:
-                        req.logprobs.extend(lp[slot_id, :len(toks)].tolist())
+                    if lps is not None:
+                        req.logprobs.extend(
+                            lps[slot_id, :len(toks)].tolist())
                     produced += len(toks)
                     slot.emitted += len(toks)
                     self._count_dropped(slot.length,
@@ -3257,7 +3371,7 @@ class ContinuousBatchingEngine:
         with (_NULL_SPAN if tr is None else tr.span(
                 "decode.sync_wait", chunk=chunk)) as sp:
             t0 = time.perf_counter()
-            (preds,) = self._read_back(chunk, [preds_dev])
+            preds = self._read_back(chunk, preds_dev)
             t1 = time.perf_counter()
             wait = t1 - t0
             stalled = wait > self.stall_threshold_s

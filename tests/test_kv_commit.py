@@ -269,32 +269,35 @@ def test_the_engine_serves_the_same_tokens_through_the_kernel(
 def test_every_table_a_program_gets_names_pages_of_its_pools(family):
     """The kernel's copies reach whatever page a table names — a page outside
     the pool is a fault on the chip, where the scatter dropped the update. So
-    every table the engine hands a served program (`_tables_arg`: the slots'
-    tables, a chunk's, the warm-up's), through admission, retirement and a
-    recycled slot, names pages of the pool kind it is for: full layers' pools
-    and the window layers' ring pools have sizes of their own."""
+    every table the engine hands a served program (the tables among the
+    fields `_put` packs: the slots' tables, a chunk's, the warm-up's),
+    through admission, retirement and a recycled slot, names pages of the
+    pool kind it is for: full layers' pools and the window layers' ring
+    pools (`*_ring`) have sizes of their own."""
     seen = []
 
     def watch(eng):
-        real = eng._tables_arg
+        real = eng._put
         window = eng._window_layers
         full_pages = min(kc.shape[0] for i, kc in enumerate(eng.kcs)
                          if i not in window)
         ring_pages = min((kc.shape[0] for i, kc in enumerate(eng.kcs)
                           if i in window), default=None)
 
-        def checked(full, ring):
-            for table, pages in ((full, full_pages), (ring, ring_pages)):
-                # a model with no window layers has no ring pools, and
-                # `_tables_arg` drops whatever ring table it is passed
-                if table is not None and pages is not None:
-                    table = np.asarray(table)
-                    assert table.min() >= 0 and table.max() < pages, (
-                        table.min(), table.max(), pages)
-                    seen.append(pages)
-            return real(full, ring)
+        def checked(layout, values):
+            for name in layout.names:
+                if name.split("_ring")[0] not in (
+                        "tables", "chunk_table", "chunk_pages"):
+                    continue
+                pages = ring_pages if name.endswith("_ring") \
+                    else full_pages
+                table = np.asarray(values[name])
+                assert table.min() >= 0 and table.max() < pages, (
+                    name, table.min(), table.max(), pages)
+                seen.append(pages)
+            return real(layout, values)
 
-        eng._tables_arg = checked
+        eng._put = checked
 
     cfg, p, kw = family()
     eng, _ = _serve(cfg, p, kw, watch=watch)

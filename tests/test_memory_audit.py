@@ -334,7 +334,7 @@ class TestEngineAudit(unittest.TestCase):
         tiny 13-page pools are deliberately below it)."""
         eng = _tiny_engine(max_pages=260)
         undonated = jax.jit(
-            eng._shard_program(eng._build_decode_chunk(), 8, 3))
+            eng._shard_program(eng._build_decode_chunk(), 6, 4))
         g = trace_for_memory(undonated, *eng._decode_example_args(),
                              name="undonated-decode")
         report = analyze(None, graph=g, rules=["TPU701"])

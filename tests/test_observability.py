@@ -892,24 +892,16 @@ def _mellum_engine(tracer):
         dtype="float32", logprobs=True, tracer=tracer)
 
 
-def _moves(args, skip_key: bool):
-    """(arrays, bytes) of a program's host arguments or outputs."""
-    import jax
-
-    leaves = [x for x in jax.tree.leaves(args)
-              if not (skip_key and x.dtype == np.uint32)]
-    return len(leaves), sum(int(np.prod(x.shape)) * x.dtype.itemsize
-                            for x in leaves)
-
-
 @pytest.mark.parametrize("engine", ["unified", "unified_logprobs", "split",
                                     "routed_logprobs"])
 def test_transfers_are_the_programs_arguments_and_outputs(engine):
-    """`decode.stage`'s `h2d` / `h2d_bytes` are the program's arguments
-    less the weights, the pools and the key; the summed `decode.readback`
-    `d2h` / `d2h_bytes` of a program are its outputs less the pools —
-    with log-probabilities and routed layers' counts, and a mixed step's
-    first token, which lies in a `decode.readback` of its chunk."""
+    """Each served program crosses the host boundary once each way (PR 38):
+    `decode.stage` moves one array (`h2d` 1), the packed buffer of the
+    program's host inputs, and the program's `decode.readback` one array
+    (`d2h` 1), the packed vector of its host-visible outputs —
+    log-probabilities, routed layers' counts and a mixed step's first token
+    among them. The bytes are the layouts' sizes, which are the sizes of the
+    program's packed argument and first output."""
     import jax
 
     tr = Tracer()
@@ -929,8 +921,12 @@ def test_transfers_are_the_programs_arguments_and_outputs(engine):
                             if eng.unified else None)]:
         if fn is None:
             continue
-        outs = jax.eval_shape(fn, *args)[:-2]       # the pools stay
-        want[kind] = (_moves(args[3:], True), _moves(outs, False))
+        io_in, io_out = eng._io[kind]
+        packed_out = jax.eval_shape(fn, *args)[0]
+        assert args[3].shape == (io_in.size,) and args[3].dtype == np.int32
+        assert packed_out.shape == (io_out.size,) \
+            and packed_out.dtype == np.int32
+        want[kind] = ((1, 4 * io_in.size), (1, 4 * io_out.size))
     spans = [e for e in tr.events() if e["ph"] == "X"]
     kind = {e["args"]["chunk"]: "mixed" if e["args"].get("prefill_window")
             else "decode" for e in spans if e["name"] == "decode.dispatch"}
@@ -948,10 +944,49 @@ def test_transfers_are_the_programs_arguments_and_outputs(engine):
             assert (e["args"]["h2d"], e["args"]["h2d_bytes"]) == h2d, k
             assert back[e["args"]["chunk"]] == d2h, k
     if eng.unified:
-        # the mixed step reads back one array more than the decode chunk
-        # (its first token), and one more with log-probabilities
-        assert want["mixed"][1][0] - want["decode"][1][0] \
-            == 1 + eng.logprobs
+        # the mixed step hands back more than the decode chunk: its
+        # window's routed counts, its first token, and the first token's
+        # log-probability
+        from paddle_tpu.models.llama import MOE_COUNTS
+
+        _, dec = eng._io["decode"]
+        _, mix = eng._io["mixed"]
+        assert mix.size - dec.size == 1 + eng.logprobs \
+            + eng._routed * len(MOE_COUNTS)
+
+
+@pytest.mark.parametrize("mode", ["unified", "pipelined"])
+def test_a_warmed_step_splits_no_key_and_makes_no_scalar_on_the_host(
+        mode, monkeypatch):
+    """Once the programs are compiled, serving runs no device program on
+    the host's side but the served ones: no `jax.random.split` (the
+    programs split the key they are handed) and no scalar made into an
+    array (temperature and top-p are device scalars made once)."""
+    import jax
+    import jax.numpy as jnp
+
+    cfg, eng = _tiny_engine(do_sample=True, temperature=0.7, top_p=0.9,
+                            **ENGINE_MODES[mode])
+    _serve_some(eng, cfg, seed=5)               # compiles every program
+    calls = {"split": 0, "scalar": 0}
+    split, asarray = jax.random.split, jnp.asarray
+
+    def counted_split(*a, **k):
+        calls["split"] += 1
+        return split(*a, **k)
+
+    def counted_asarray(x, *a, **k):
+        calls["scalar"] += np.ndim(x) == 0
+        return asarray(x, *a, **k)
+
+    monkeypatch.setattr(jax.random, "split", counted_split)
+    monkeypatch.setattr(jnp, "asarray", counted_asarray)
+    before = eng.compile_stats()
+    steps0 = eng.device_steps
+    _serve_some(eng, cfg)
+    assert eng.device_steps > steps0 and eng.prefill_chunks
+    assert calls == {"split": 0, "scalar": 0}
+    assert eng.compile_stats() == before
 
 
 @pytest.mark.parametrize("mode", ["unified", "split", "pipelined"])
