@@ -314,7 +314,7 @@ def run(cell, seed: int, seconds: float, trace: bool, say, engine_kw=None):
     say(f"serve: step={'unified' if em['unified_step'] else 'split'} "
         f"token_budget={em['token_budget']} steps_per_sync={eng.steps} "
         f"double_buffer={eng.double_buffer} block_size={eng.block_size} "
-        f"kv={em['kv_cache_dtype']} megakernel={em['megakernel_rung']} "
+        f"kv={em['kv_cache_dtype']} "
         f"speculative={em['speculative']} pages={em['n_cacheable_pages']}; "
         f"warm(): {wc['compile_requests']} programs, {wc['cache_hits']} "
         f"from the cache in {wc['persistent_cache_dir']}")

@@ -3,11 +3,11 @@ engine's host path between two programs — `decode.stage` and
 `decode.enqueue` inside `decode.dispatch`, `decode.device_wait` and
 `decode.readback` inside `decode.sync_wait`, each carrying the program's
 `chunk`. A program without them (the parent) gives every reader `None`."""
-import json
 import os
 
 import numpy as np
 import pytest
+from test_benchmark_contract import load_bench
 from test_benchmark_phase_readers import _x
 from test_benchmark_tracing import _reader
 
@@ -141,14 +141,21 @@ def test_a_parent_gives_nothing(name):
     assert _reader(name)(_ctx([])) is None
 
 
+def check_entries(bench, root, names=NEW):
+    """The four entries of `bench`, appended together in their order (a
+    later PR appends behind them), each listing both serving cells."""
+    listed = [x["name"] for x in bench["per_layer"]]
+    at = listed.index(NEW[0])
+    assert listed[at:at + len(NEW)] == list(NEW)
+    for name in names:
+        (entry,) = [x for x in bench["per_layer"] if x["name"] == name]
+        assert entry == {"name": name, "unit": "ms", "better": "lower",
+                         "source": "program_span",
+                         "layer": "engine scheduler",
+                         "moves": "output_tok_s", "workloads": CELLS}
+        assert not name.endswith("mellum2")
+
+
 @pytest.mark.parametrize("name", NEW)
 def test_the_entries_are_appended_for_both_serving_cells(name):
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        bench = json.load(f)
-    names = [x["name"] for x in bench["per_layer"]]
-    assert names[-len(NEW):] == list(NEW)
-    (entry,) = [x for x in bench["per_layer"] if x["name"] == name]
-    assert entry == {"name": name, "unit": "ms", "better": "lower",
-                     "source": "program_span", "layer": "engine scheduler",
-                     "moves": "output_tok_s", "workloads": CELLS}
-    assert not name.endswith("mellum2")
+    check_entries(load_bench(ROOT), ROOT, names=(name,))

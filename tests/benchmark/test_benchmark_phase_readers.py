@@ -7,6 +7,7 @@ import json
 import os
 
 import pytest
+from test_benchmark_contract import load_bench
 from test_benchmark_tracing import _reader
 
 from benchmark import arith
@@ -125,15 +126,13 @@ def test_nothing_to_read_gives_nothing(name):
     assert _reader(name)(empty) is None
 
 
-def test_benchmark_lists_the_span_metrics_for_the_sat_cell_only():
+def check_entries(bench, root):
     """The three that read the engine's spans and the two that read a
-    program's device time are entries of BENCHMARK.json, appended behind
-    what was there in the order they came (PR 25, PR 27).
+    program's device time are entries of `bench`, appended behind what was
+    there in the order they came (PR 25, PR 27).
     `kernel.decode_attn_read_share` stays a reader file read by hand: its
     bytes are the whole window's mean live tokens and its seconds the
     trace's, so it can read over 100% (PERF.md 7c)."""
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        bench = json.load(f)
     listed = [n for n in NEW if n.startswith("sched.")] \
         + [n for n in NEW if n.startswith("step.")]
     tail = [m for m in bench["per_layer"] if m["name"] in listed]
@@ -152,3 +151,7 @@ def test_benchmark_lists_the_span_metrics_for_the_sat_cell_only():
                                else "program_span")
         assert set(m) == {"name", "unit", "better", "source", "layer",
                           "moves", "workloads"}
+
+
+def test_benchmark_lists_the_span_metrics_for_the_sat_cell_only():
+    check_entries(load_bench(ROOT), ROOT)

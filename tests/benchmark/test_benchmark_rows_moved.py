@@ -1,9 +1,7 @@
 """`moe.rows_moved_over_held` (PR 32): the step's report with and without the
 counter — a number, and nothing (the parent's program counts no moved rows)."""
-import json
-import os
-
 import pytest
+from test_benchmark_contract import load_bench
 
 from benchmark import run
 
@@ -37,13 +35,18 @@ def test_nothing_to_read_gives_none(reports):
     assert _read(_ctx(reports)) is None
 
 
-def test_the_metric_is_an_entry_of_the_expert_cell():
-    with open(os.path.join(run.ROOT, "BENCHMARK.json")) as f:
-        entry = [m for m in json.load(f)["per_layer"]
-                 if m["name"] == "moe.rows_moved_over_held"]
+def check_entries(bench, root):
+    """The entry of `bench`, and the cell as `run`, pointed at `root`,
+    loads it."""
+    entry = [m for m in bench["per_layer"]
+             if m["name"] == "moe.rows_moved_over_held"]
     assert entry == [{"name": "moe.rows_moved_over_held", "unit": "x",
                       "better": "lower", "source": "program_counter",
                       "layer": "expert layer", "moves": "train_tok_s",
                       "workloads": ["glm47flash-train-4k"]}]
     cell = run.load_cell("glm47flash-train-4k")
     assert entry[0] in cell["per_layer"]
+
+
+def test_the_metric_is_an_entry_of_the_expert_cell():
+    check_entries(load_bench(run.ROOT), run.ROOT)

@@ -12,6 +12,7 @@ import sys
 import numpy as np
 import pytest
 from test_benchmark_cells import _tiny
+from test_benchmark_contract import load_bench
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
@@ -227,9 +228,14 @@ def test_a_reader_finds_nothing_on_a_program_that_records_nothing(
     assert reader(name)(ctx) is None
 
 
-def test_the_new_readers_are_the_cells_entries():
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        bench = json.load(f)
+# the serving cells `output_tok_s` lists, in the order they were accepted
+SERVED = ["mistral7b-reason-sat", CELL]
+
+
+def check_entries(bench, root):
+    """The cell's entries of `bench` and their readers under `root`. The
+    end-to-end list is held by membership and order: a later serving cell
+    is appended to it."""
     mine = [x for x in bench["per_layer"] if x.get("workloads") == [CELL]]
     assert [x["name"] for x in mine] == NEW_READERS
     assert all(x["moves"] == "output_tok_s" for x in mine)
@@ -243,14 +249,17 @@ def test_the_new_readers_are_the_cells_entries():
         sat, own = by[stem + ".sat"], by[stem + ".mellum2"]
         assert {**own, "name": 0, "workloads": 0} \
             == {**sat, "name": 0, "workloads": 0}
+    readers = os.path.join(root, "benchmark", "readers")
     for name in NEW_READERS + SHARED_READERS:
-        assert os.path.isfile(os.path.join(
-            ROOT, "benchmark", "readers", name + ".py"))
-    assert not [n for n in os.listdir(os.path.join(
-        ROOT, "benchmark", "readers")) if n.endswith("mellum2.py")
-        and n[:-3] not in NEW_READERS]
+        assert os.path.isfile(os.path.join(readers, name + ".py"))
+    assert not [n for n in os.listdir(readers) if n.endswith("mellum2.py")
+                and n[:-3] not in NEW_READERS]
     out = [x for x in bench["end_to_end"] if x["name"] == "output_tok_s"][0]
-    assert out["workloads"][-1] == CELL
+    assert [c for c in out["workloads"] if c in SERVED] == SERVED
+
+
+def test_the_new_readers_are_the_cells_entries():
+    check_entries(load_bench(ROOT), ROOT)
 
 
 # ---- the comparison that decides `correct` --------------------------------------

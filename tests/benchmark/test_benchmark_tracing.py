@@ -251,9 +251,10 @@ def test_train_readers_by_hand():
     assert _reader("train.peak_hbm_gib")(ctx) == 12.0
 
 
-def test_load_cell_finds_every_file_by_name():
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        bench = json.load(f)
+def check_entries(bench, root):
+    """Every cell of `bench` as `run`, pointed at `root`, loads it: its
+    sizes, its end-to-end metrics, a reader a per-layer metric and its
+    driver, each found by name."""
     for w in bench["workloads"]:
         cell = run.load_cell(w["name"])
         assert cell["config"]["hidden_size"] >= 2048
@@ -262,6 +263,11 @@ def test_load_cell_finds_every_file_by_name():
         for m in cell["per_layer"]:
             assert callable(run.load_module("readers", m["name"]).read)
         assert run.load_module("drivers", cell["mix"]["driver"]).run
+
+
+def test_load_cell_finds_every_file_by_name():
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        check_entries(json.load(f), ROOT)
     with pytest.raises(SystemExit, match="no workload"):
         run.load_cell("nope")
     with pytest.raises(SystemExit, match="no readers"):
