@@ -752,12 +752,25 @@ def _sample_next(logits, key, do_sample, temperature, top_k, top_p):
     return jax.random.categorical(key, logits, axis=-1)
 
 
+# what a routed layer counts each time it runs (summed by the programs);
+# rows_multiplied: the buffer rows its grouped matmuls walked, padding
+# included (row tiles in use x the layout's tile)
+MOE_COUNTS = ("layer_steps", "rows_routed", "experts_hit", "load_max",
+              "rows_multiplied")
+# what a layer of learned sparse attention counts beside them: `full`
+# layer-steps (layers that scored their keys), keys scored (a full layer's
+# rows' context lengths) and keys attended (a row's selection, every layer)
+DSA_COUNTS = ("dsa_full_layer_steps", "dsa_keys_scored",
+              "dsa_keys_attended")
+
+
 class ServedLayer(NamedTuple):
     """What the serving program builders know of one decoder layer. The
-    attention block is the same everywhere — rms_norm, q / k / v without
-    bias, rotary over the whole head, grouped causal attention, o — read
-    under `prefix` + `input_layernorm.weight`, `self_attn.{q,k,v,o}_proj
-    .weight`, `post_attention_layernorm.weight`; what differs is here."""
+    attention block is the same everywhere unless `attn` says otherwise —
+    rms_norm, q / k / v without bias, rotary over the whole head, grouped
+    causal attention, o — read under `prefix` + `input_layernorm.weight`,
+    `self_attn.{q,k,v,o}_proj.weight`, `post_attention_layernorm.weight`;
+    what differs is here."""
     prefix: str
     # None: every earlier position; W: positions (t - W, t], kept in a
     # per-sequence ring by the engine
@@ -765,8 +778,21 @@ class ServedLayer(NamedTuple):
     rope_base: float
     rope_scaling: Optional[YarnScaling]
     # (x [..., hidden] after the second norm, p, prefix) -> (y, counts):
-    # counts None, or for a routed layer MOE_COUNTS as an int32 vector
+    # counts None, or for a routed layer the model's `counts` as an int32
+    # vector
     mlp: Callable
+    # None: the block above. Else the layer's own attention over pools of
+    # its own (models/hy_v4.py): `pools(max_pages, block_size, dtype)` ->
+    # (kc, vc), either may be None; `row_bytes(dtype)` -> (bytes a token of
+    # kc, of vc); `decode` / `window` (x after the first norm, p, prefix, kc,
+    # vc, lane, carry) -> (y, kc, vc, carry, counts), writing the layer's
+    # rows themselves. `carry` is a dict the layers of one program hand
+    # down; `lane` holds the lane's tables and positions
+    attn: Optional[object] = None
+    # None: h + F(h). Else (h, F, p, prefix, "attn" | "mlp") -> (h', aux),
+    # F: u -> (y, aux), the sub-block around which the rule joins the
+    # residual (a model of `streams` residual streams has one)
+    residual: Optional[Callable] = None
 
 
 class ServedModel(NamedTuple):
@@ -777,6 +803,13 @@ class ServedModel(NamedTuple):
     head: Optional[str]         # None: the embedding, transposed
     head_dim: int
     layers: Tuple[ServedLayer, ...]
+    # residual streams: the embedding enters as this many copies, summed
+    # before the final norm
+    streams: int = 1
+    # the head in f32 (true f32 products) whatever the served type
+    head_f32: bool = False
+    # what a routed model's layers count, in order (summed by the programs)
+    counts: Tuple[str, ...] = MOE_COUNTS
 
     @property
     def window_layers(self) -> Tuple[int, ...]:
@@ -784,15 +817,13 @@ class ServedModel(NamedTuple):
                      if l.window is not None)
 
     @property
+    def latent(self) -> bool:
+        """Whether the layers keep their own pools (`ServedLayer.attn`)."""
+        return any(l.attn is not None for l in self.layers)
+
+    @property
     def routed(self) -> bool:
         return any(getattr(l.mlp, "routed", False) for l in self.layers)
-
-
-# what a routed layer counts each time it runs (summed by the programs);
-# rows_multiplied: the buffer rows its grouped matmuls walked, padding
-# included (row tiles in use x the layout's tile)
-MOE_COUNTS = ("layer_steps", "rows_routed", "experts_hit", "load_max",
-              "rows_multiplied")
 
 
 def _dense_swiglu(x, p, pre):
@@ -829,8 +860,51 @@ def _make_head_logits(cfg):
     def head_logits(h, p):
         if model.head is None:
             return h @ p[model.embed].T
+        if model.head_f32:
+            return jnp.dot(h.astype(jnp.float32),
+                           p[model.head].astype(jnp.float32),
+                           precision=jax.lax.Precision.HIGHEST)
         return _mm(h, p[model.head])
     return head_logits
+
+
+def _embed(model: ServedModel, h):
+    """The residual entering the first layer: the embedding, as `streams`
+    copies where the model has several."""
+    if model.streams == 1:
+        return h
+    return jnp.broadcast_to(h[..., None, :],
+                            h.shape[:-1] + (model.streams, h.shape[-1]))
+
+
+def _streams_out(model: ServedModel, h):
+    """The last layer's residual as the final norm takes it."""
+    if model.streams == 1:
+        return h
+    return jnp.sum(h.astype(jnp.float32), axis=-2).astype(h.dtype)
+
+
+def _own_layer(layer: ServedLayer, h, p, eps, attend, kc, vc, carry):
+    """A layer with its own attention (`ServedLayer.attn`): `attend(x, kc,
+    vc, carry)` is its lane's call. Returns (h, kc, vc, carry, counts)."""
+    pre = layer.prefix
+
+    def join(h, block, which):
+        if layer.residual is None:
+            y, aux = block(h)
+            return h + y, aux
+        return layer.residual(h, block, p, pre, which)
+
+    def attn_block(u):
+        y, *rest = attend(_k_rms(u, p[pre + "input_layernorm.weight"], eps),
+                          kc, vc, carry)
+        return y, rest
+
+    h, (kc, vc, carry, c_attn) = join(h, attn_block, "attn")
+    h, c_mlp = join(h, lambda u: layer.mlp(
+        _k_rms(u, p[pre + "post_attention_layernorm.weight"], eps), p, pre),
+        "mlp")
+    return h, kc, vc, carry, [c for c in (c_attn, c_mlp) if c is not None]
 
 
 def _make_prefill(cfg, b, sb, tp=None):
@@ -1025,7 +1099,7 @@ def _make_prefill_with_prefix(cfg, b, sb, w_pre, block_size, tp=None):
     return prefill
 
 
-def _make_chunk_prefill(cfg, tn, tp=None):
+def _make_chunk_prefill(cfg, tn, tp=None, block_size=None):
     """Chunk-lane transformer body of the UNIFIED serving step (ISSUE
     14): one ragged prefill WINDOW of `tn` tokens for ONE request,
     attending its already-committed tokens (earlier chunks, or a cached
@@ -1061,10 +1135,15 @@ def _make_chunk_prefill(cfg, tn, tp=None):
     table), which rotary table it turns by, and what its MLP is.
 
     Returns prefill(p, kcs, vcs, ids, chunk_table, cached_len,
-    new_len) -> (h_final [1, tn, hidden], [(k_i, v_i)]) with
-    rotary-applied window K/V [1, tn, nkv_l, dh] per layer — the
+    new_len, chunk_pages=None) -> (h_final [1, tn, hidden], [(k_i, v_i)])
+    with rotary-applied window K/V [1, tn, nkv_l, dh] per layer — the
     caller owns the page scatter — and, where a layer is routed, the
-    layers' summed MOE_COUNTS as a third result."""
+    layers' summed counters as a third result. A layer with its own
+    attention (`ServedLayer.attn`) writes its rows itself, at
+    `chunk_pages` [1, tn / block_size] (the window's pages, pad pages the
+    scratch page): its entries of the lists `kcs` / `vcs` are replaced in
+    place and its entry of the K/V list is None. Its window starts on a
+    page's edge (no prefix cache; windows of whole pages)."""
     model = served_model(cfg)
     nh, nkv, dh = (cfg.num_attention_heads, cfg.num_key_value_heads,
                    model.head_dim)
@@ -1076,15 +1155,27 @@ def _make_chunk_prefill(cfg, tn, tp=None):
 
     use_kernel = bool(_flag("prefix_prefill_kernel"))
 
-    def prefill(p, kcs, vcs, ids, chunk_table, cached_len, new_len):
+    def prefill(p, kcs, vcs, ids, chunk_table, cached_len, new_len,
+                chunk_pages=None):
         from ..kernels.ragged_attention import (
             ragged_paged_attention, ragged_paged_attention_reference)
 
-        h = p[model.embed][ids]                          # [1, tn, h]
+        h = _embed(model, p[model.embed][ids])           # [1, tn, h]
         pos_ids = cached_len[:, None] + jnp.arange(tn)[None, :]
-        kvs, counts = [], []
+        kvs, counts, carry = [], [], {}
+        lane = {"table": chunk_table[0], "pos": pos_ids[0],
+                "new_len": new_len[0], "block_size": block_size,
+                "pages": None if chunk_pages is None else chunk_pages[0]}
         for i, layer in enumerate(model.layers):
             pre = layer.prefix
+            if layer.attn is not None:
+                h, kcs[i], vcs[i], carry, cs = _own_layer(
+                    layer, h, p, eps, lambda x, kc, vc, carry: layer.attn
+                    .window(x, p, pre, kc, vc, lane, carry), kcs[i], vcs[i],
+                    carry)
+                kvs.append(None)
+                counts += cs
+                continue
             # a model with window layers hands every table in twice:
             # (the full layers', the window layers' rings)
             table_i = chunk_table if not model.window_layers \
@@ -1147,7 +1238,7 @@ def _make_chunk_prefill(cfg, tn, tp=None):
             h = h + y
             if count is not None:
                 counts.append(count)
-        h = _k_rms(h, p[model.norm], eps)
+        h = _k_rms(_streams_out(model, h), p[model.norm], eps)
         if counts:
             return h, kvs, sum(counts)
         return h, kvs
@@ -1781,14 +1872,18 @@ class PagedKVManager:
     @staticmethod
     def page_bytes(block_size: int, *, n_layers: int, num_kv_heads: int,
                    head_dim: int, kv_cache_dtype: str = "bf16",
-                   mp: int = 1) -> int:
+                   mp: int = 1, latent_bytes: int = 0,
+                   index_bytes: int = 0) -> int:
         """PER-CHIP device bytes ONE page costs across all layers: K + V
         pools (2 x nkv x block x dh x itemsize per layer) plus, for
         int8, the per-(page, kv-head) f32 absmax scale rows
         (2 x nkv x 4). Under kv-head sharding (`mp` — ServingTP with
         nkv % mp == 0) each chip holds only nkv/mp heads of every page,
         so a page costs 1/mp of the replicated bytes per chip; page ids
-        and page COUNTS stay global (every chip maps the same ids)."""
+        and page COUNTS stay global (every chip maps the same ids).
+        The third pool kind — layers that keep their own rows (latent
+        attention) — adds `latent_bytes` + `index_bytes` a token: a
+        token's latent rows and index keys summed over those layers."""
         mp = int(mp)
         if mp > 1:
             if num_kv_heads % mp:
@@ -1801,13 +1896,15 @@ class PagedKVManager:
         per_layer = 2 * num_kv_heads * block_size * head_dim * itemsize
         if kv_cache_dtype == "int8":
             per_layer += 2 * num_kv_heads * 4
-        return per_layer * n_layers
+        return per_layer * n_layers \
+            + block_size * (int(latent_bytes) + int(index_bytes))
 
     @classmethod
     def pages_for_bytes(cls, budget_bytes: int, block_size: int, *,
                         n_layers: int, num_kv_heads: int, head_dim: int,
                         kv_cache_dtype: str = "bf16", mp: int = 1,
-                        cp: int = 1) -> int:
+                        cp: int = 1, latent_bytes: int = 0,
+                        index_bytes: int = 0) -> int:
         """FLEET pages a PER-CHIP device byte budget holds — the
         capacity side of the int8 win (at the same budget an int8 pool
         holds ~2x the pages) AND of both sharding axes: at mp shards a
@@ -1821,12 +1918,15 @@ class PagedKVManager:
         per_page = cls.page_bytes(block_size, n_layers=n_layers,
                                   num_kv_heads=num_kv_heads,
                                   head_dim=head_dim,
-                                  kv_cache_dtype=kv_cache_dtype, mp=mp)
+                                  kv_cache_dtype=kv_cache_dtype, mp=mp,
+                                  latent_bytes=latent_bytes,
+                                  index_bytes=index_bytes)
         return max(0, int(budget_bytes) // per_page) * max(1, int(cp))
 
     def set_pool_geometry(self, *, n_layers: int, num_kv_heads: int,
                           head_dim: int, kv_cache_dtype: str = "bf16",
-                          mp: int = 1, cp: int = 1):
+                          mp: int = 1, cp: int = 1, latent_bytes: int = 0,
+                          index_bytes: int = 0):
         """Record the pool geometry this manager's page ids index into,
         enabling `kv_pool_bytes()` (benches attribute capacity-driven
         hit-rate changes with it). `mp` is the kv-head shard count (1
@@ -1856,7 +1956,9 @@ class PagedKVManager:
                               num_kv_heads=int(num_kv_heads),
                               head_dim=int(head_dim),
                               kv_cache_dtype=kv_cache_dtype,
-                              mp=int(mp), cp=cp)
+                              mp=int(mp), cp=cp,
+                              latent_bytes=int(latent_bytes),
+                              index_bytes=int(index_bytes))
 
     # ---- the second pool kind: per-sequence rings of window layers ------
 
@@ -1912,7 +2014,8 @@ class PagedKVManager:
             * self.page_bytes(self.block_size, **geo)
         if self.n_rings:   # window layers: never sharded (the engine refuses)
             per_chip += self.window_pool_pages * self.page_bytes(
-                self.block_size, **dict(geo, n_layers=self._ring_layers))
+                self.block_size, **dict(geo, n_layers=self._ring_layers,
+                                        latent_bytes=0, index_bytes=0))
         return per_chip * geo["mp"] * cp if aggregate else per_chip
 
     @property
@@ -2313,7 +2416,7 @@ def _decode_tail(decode_step, p_dec, kcs, vcs, last_logits,
 
 
 def _make_decode_step(cfg, b, max_seq=None, kv_write=None, kv_attend=None,
-                      tp=None, kv_window=None):
+                      tp=None, kv_window=None, tables=None, block_size=None):
     """Single-token decode step — the per-layer transformer math shared
     by EVERY generation program (fp, quant-only, paged); only the KV
     store differs, injected via two callbacks:
@@ -2333,9 +2436,11 @@ def _make_decode_step(cfg, b, max_seq=None, kv_write=None, kv_attend=None,
     collective per decode step per layer (the o-proj activations).
 
     The layers are `served_model(cfg)`'s. `kv_window` is the (kv_write,
-    kv_attend) pair of the window layers, over their rings. Where a layer
-    is routed the step returns the layers' summed MOE_COUNTS as a fourth
-    result."""
+    kv_attend) pair of the window layers, over their rings. A layer with
+    its own attention (`ServedLayer.attn`) reads and writes its pools
+    itself through the paged `tables` [B, W] of `block_size`-token pages.
+    Where a layer is routed the step returns the layers' summed counters
+    as a fourth result."""
     model = served_model(cfg)
     nh, nkv, dh = (cfg.num_attention_heads, cfg.num_key_value_heads,
                    model.head_dim)
@@ -2381,12 +2486,22 @@ def _make_decode_step(cfg, b, max_seq=None, kv_write=None, kv_attend=None,
         the [B, 1] position_ids broadcast per-example through the rope
         tables)."""
         # the embedding stays dense (it's a gather, not a matmul)
-        h = p[model.embed][tok[:, 0]][:, None, :]
+        h = _embed(model, p[model.embed][tok[:, 0]][:, None, :])
         pos_ids = pos[:, None] if getattr(pos, "ndim", 0) == 1 \
             else jnp.reshape(pos, (1,))
-        new_kcs, new_vcs, counts = [], [], []
+        new_kcs, new_vcs, counts, carry = [], [], [], {}
+        lane = {"tables": tables, "pos": pos, "block_size": block_size}
         for i, layer in enumerate(model.layers):
             pre = layer.prefix
+            if layer.attn is not None:
+                h, kc, vc, carry, cs = _own_layer(
+                    layer, h, p, eps, lambda x, kc, vc, carry: layer.attn
+                    .decode(x, p, pre, kc, vc, lane, carry), kcs[i], vcs[i],
+                    carry)
+                new_kcs.append(kc)
+                new_vcs.append(vc)
+                counts += cs
+                continue
             write, attend = (kv_write, kv_attend) if layer.window is None \
                 else kv_window
             x = _k_rms(h, p[pre + "input_layernorm.weight"], eps)
@@ -2413,7 +2528,7 @@ def _make_decode_step(cfg, b, max_seq=None, kv_write=None, kv_attend=None,
             h = h + y
             if count is not None:
                 counts.append(count)
-        h = _k_rms(h, p[model.norm], eps)
+        h = _k_rms(_streams_out(model, h), p[model.norm], eps)
         logits = head_logits(h, p)[:, -1]
         if counts:
             return logits, new_kcs, new_vcs, sum(counts)

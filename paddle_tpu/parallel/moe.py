@@ -480,7 +480,7 @@ MOE_COUNTERS = ("moe.rows_held", "moe.rows_routed", "moe.rows_multiplied",
 
 
 def dropless_experts(x, idx, gates, w_gate, w_up, w_down, held,
-                     num_experts: int):
+                     num_experts: int, limit: Optional[float] = None):
     """The held experts' part of the layer: x [T, d], the router's choice
     idx [T, k] over all `num_experts` and its gates [T, k]; stacked SwiGLU
     weights [G, d, f], [G, d, f], [G, f, d] of the experts `held` (global
@@ -496,6 +496,9 @@ def dropless_experts(x, idx, gates, w_gate, w_up, w_down, held,
     grouped matmul its row tiles, the row movements (kernels/moe_rows.py)
     the 16-row chunks that hold a token tile's rows. Row tiles no expert
     uses are neither written nor read.
+
+    `limit` clamps the SwiGLU (`swiglu_limit`): silu(min(g, limit)) *
+    clip(u, -limit, limit); None leaves it unclamped.
 
     Where `held` is a share of the experts, the gates are constants of the
     backward pass: a gate's gradient needs the outputs of every expert its
@@ -552,8 +555,12 @@ def dropless_experts(x, idx, gates, w_gate, w_up, w_down, held,
         moved = jnp.asarray(rows + t * k, jnp.int32)
 
     xs_gate, xs_up = rows_in(x)
-    act = jax.nn.silu(grouped_matmul(xs_gate, w_gate, layout)) \
-        * grouped_matmul(xs_up, w_up, layout)
+    g_out = grouped_matmul(xs_gate, w_gate, layout)
+    u_out = grouped_matmul(xs_up, w_up, layout)
+    if limit is not None:
+        g_out = jnp.minimum(g_out, limit)
+        u_out = jnp.clip(u_out, -limit, limit)
+    act = jax.nn.silu(g_out) * u_out
     ys = grouped_matmul(act, w_down, layout)
     y = rows_out(ys)
 

@@ -99,7 +99,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..models.llama import (MOE_COUNTS, PagedKVManager, _make_chunk_prefill,
+from ..models.llama import (PagedKVManager, _make_chunk_prefill,
                             _make_decode_step, _make_head_logits,
                             _make_prefill, _make_prefill_with_prefix,
                             _make_verify_window, _sample_next,
@@ -121,6 +121,11 @@ def _moved(direction: str, arrays) -> dict:
     leaves = jax.tree.leaves(arrays)
     return {direction: len(leaves),
             direction + "_bytes": sum(int(a.nbytes) for a in leaves)}
+
+
+def _counter(name: str) -> str:
+    """`metrics()`'s key of a counter the programs count."""
+    return name if name.startswith("dsa_") else "moe_" + name
 
 
 def _token_logprob(logits, tok):
@@ -556,12 +561,15 @@ class ContinuousBatchingEngine:
         self._window = max((self._model.layers[i].window
                             for i in self._window_layers), default=0)
         self._routed = self._model.routed
+        # layers that keep their own rows (latent attention)
+        self._latent = self._model.latent
         # why the prefix cache is off, where the model turns it off
         self.prefix_cache_off = None
-        if self._window_layers or self._routed:
+        if self._window_layers or self._routed or self._latent:
             what = " and ".join(
                 w for w, on in (("sliding-window", self._window_layers),
-                                ("routed-expert", self._routed)) if on)
+                                ("routed-expert", self._routed),
+                                ("latent-attention", self._latent)) if on)
             for name, on in (
                     ("unified_step=False (the split prefill programs)",
                      not self.unified),
@@ -583,6 +591,12 @@ class ContinuousBatchingEngine:
                     "the model has sliding-window layers: their K/V live "
                     "in per-sequence rings, which hold no page a later "
                     "request could map")
+            if self._latent and self.prefix_cache:
+                self.prefix_cache = False
+                self.prefix_cache_off = (
+                    "the model's layers keep latent rows and index keys in "
+                    "pools of their own, which the prefix cache does not "
+                    "share yet (ROADMAP M4)")
         self._tp = make_serving_tp(
             cfg, self.mp,
             quantized_collectives=self.quantized_collectives,
@@ -629,10 +643,18 @@ class ContinuousBatchingEngine:
         # always prefills, so the last block is never part of a prefix)
         self._prefix_width = max(1, (self.max_prompt_len - 1) // block_size)
         nkv, dh = cfg.num_key_value_heads, self._model.head_dim
-        # layers of the first pool kind (whole contexts on pages) and of
-        # the second (a ring a live sequence: `slots` + the one prefilling)
+        # layers of the first pool kind (whole contexts on pages), of the
+        # second (a ring a live sequence: `slots` + the one prefilling) and
+        # of the third (a layer's own latent rows and index keys on pages,
+        # bytes a token over those layers)
         n_full = len(self._model.layers) - len(self._window_layers)
         n_rings = slots + 1 if self._window_layers else 0
+        own = [l.attn.row_bytes(dtype) for l in self._model.layers
+               if l.attn is not None]
+        latent_bytes = sum(r[0] for r in own)
+        index_bytes = sum(r[1] for r in own)
+        if own:
+            n_full = 0
         if kv_pool_bytes is not None:
             if max_pages is not None:
                 raise ValueError(
@@ -654,7 +676,8 @@ class ContinuousBatchingEngine:
                 kv_pool_bytes - ring_bytes, block_size,
                 n_layers=n_full, num_kv_heads=nkv,
                 head_dim=dh, kv_cache_dtype=self.kv_dtype,
-                mp=self.kv_shards, cp=self.cp)
+                mp=self.kv_shards, cp=self.cp, latent_bytes=latent_bytes,
+                index_bytes=index_bytes)
             if max_pages < cap + 2:
                 raise ValueError(
                     f"kv_pool_bytes {kv_pool_bytes} holds only "
@@ -678,7 +701,10 @@ class ContinuousBatchingEngine:
         self.mgr.set_pool_geometry(n_layers=n_full,
                                    num_kv_heads=nkv, head_dim=dh,
                                    kv_cache_dtype=self.kv_dtype,
-                                   mp=self.kv_shards, cp=self.cp)
+                                   mp=self.kv_shards, cp=self.cp,
+                                   latent_bytes=latent_bytes,
+                                   index_bytes=index_bytes)
+        self.kv_token_bytes = {"latent": latent_bytes, "index": index_bytes}
         if n_rings:
             self.mgr.set_window_rings(n_rings, self._capacity_ring_pages(),
                                       len(self._window_layers))
@@ -713,7 +739,14 @@ class ContinuousBatchingEngine:
                 else NamedSharding(self.mp_mesh, sp)
             _pool.__name__ = "serve_kv_pool_init"
             _pool = jax.jit(_pool, out_shardings=out)
-        if self._window_layers:
+        if self._latent:
+            # each layer makes its own pools: latent rows on every layer,
+            # index keys where the layer selects its keys (else None)
+            made = [l.attn.pools(max_pages, block_size, dtype)
+                    for l in self._model.layers]
+            self.kcs = [kc for kc, _ in made]
+            self.vcs = [vc for _, vc in made]
+        elif self._window_layers:
             # a window layer's pools are as large as the rings, whatever
             # the contexts grow to
             sizes = [self.mgr.window_pool_pages if i in self._window_layers
@@ -736,9 +769,10 @@ class ContinuousBatchingEngine:
         self._ring_tables = np.zeros((slots, cap), np.int32) \
             if self._window_layers else None
         # what the routed layers counted, summed over layers and steps
-        # (MOE_COUNTS), for the decode lane and for the prefill windows
-        self.moe_counts = {"decode": np.zeros(len(MOE_COUNTS), np.int64),
-                           "chunk": np.zeros(len(MOE_COUNTS), np.int64)}
+        # (the model's `counts`), for the decode lane and the prefill windows
+        n = len(self._model.counts)
+        self.moe_counts = {"decode": np.zeros(n, np.int64),
+                           "chunk": np.zeros(n, np.int64)}
         self.window_tokens_dropped = 0   # cached tokens that left a window
         self._tokens = np.zeros((slots,), np.int32)
         self._budgets = np.zeros((slots,), np.int32)  # prompt + max_new
@@ -877,8 +911,8 @@ class ContinuousBatchingEngine:
                 ("done", (b,), bool)]
 
         def counted(lanes):
-            # the routed layers' MOE_COUNTS: one row a lane
-            return [("moe", (lanes, len(MOE_COUNTS)), i32)] \
+            # the routed layers' counters: one row a lane
+            return [("moe", (lanes, len(self._model.counts)), i32)] \
                 if self._routed else []
 
         lps = [("logprobs", (b, steps), f32)] if self.logprobs else []
@@ -1124,11 +1158,13 @@ class ContinuousBatchingEngine:
             # (token, choice) rows it routed, the experts that got at
             # least one, the largest group, the buffer rows its matmuls
             # walked (padding included) — and the decode lane's part
-            **{f"moe_{name}": int(self.moe_counts["decode"][i]
-                                  + self.moe_counts["chunk"][i])
-               for i, name in enumerate(MOE_COUNTS)},
-            **{f"moe_{name}_decode": int(self.moe_counts["decode"][i])
-               for i, name in enumerate(MOE_COUNTS)},
+            # (learned sparse attention adds its DSA_COUNTS, under their
+            # own `dsa_` names)
+            **{_counter(name): int(self.moe_counts["decode"][i]
+                                   + self.moe_counts["chunk"][i])
+               for i, name in enumerate(self._model.counts)},
+            **{_counter(name) + "_decode": int(self.moe_counts["decode"][i])
+               for i, name in enumerate(self._model.counts)},
             # both pool kinds: pages held on the full layers' pools (the
             # scratch page left out), pages of the window layers' rings in
             # use (a ring holds kv_ring_tokens, 0 without window layers),
@@ -1142,11 +1178,13 @@ class ContinuousBatchingEngine:
             "kv_tokens_live": sum(lens),
             "kv_tokens_window": sum(min(n, self._window) for n in lens),
             "window_tokens_dropped": self.window_tokens_dropped,
+            # the third kind: bytes a token of the layers' own pools (latent
+            # rows, index keys), 0 where no layer keeps its own
+            "kv_latent_token_bytes": self.kv_token_bytes["latent"],
+            "kv_index_token_bytes": self.kv_token_bytes["index"],
             # sync-wait telemetry (what double buffering hides)
             "sync_wait_s": self.sync_wait_s,
             "blocked_syncs": self.blocked_syncs,
-            # constant: benchmark/drivers/serve.py prints it
-            "megakernel_rung": "off",
             # quantized collectives (ISSUE 15): int8 wire on the mp
             # o-proj gather when True
             "quantized_collectives": self.quantized_collectives,
@@ -1517,7 +1555,10 @@ class ContinuousBatchingEngine:
                                      kv_window=(ring_write, ring_attend))
 
         def make_step(tables):
-            """Per-layer decode body for one chunk. Under serving_mp
+            """Per-layer decode body for one chunk. A model whose layers
+            keep their own pools reads and writes them through `tables`
+            itself (one chip, bf16: the constructor refused the rest).
+            Under serving_mp
             this runs inside the shard_map body — the kv helpers and
             the attention see the LOCAL kv heads. Under serving_cp
             (ISSUE 18) the pools arrive PAGE-sharded: the kv commit
@@ -1526,6 +1567,9 @@ class ContinuousBatchingEngine:
             the owned pages as online-softmax partials, and
             `merge_attn_partials` folds the per-shard stats — never
             the KV — into the global context."""
+            if self._latent:
+                return _make_decode_step(cfg, b, tp=tp, tables=tables,
+                                         block_size=bs)
             if self._window_layers:
                 return window_step(*tables)    # `_table_arg`'s pair
             if cp_parts:
@@ -1719,7 +1763,9 @@ class ContinuousBatchingEngine:
         n_win = tn // bs
         do_sample, top_k = self.do_sample, self.top_k
         decode_chunk = self._decode_chunk_body()
-        chunk_body = _make_chunk_prefill(cfg, tn, tp=self._tp)
+        chunk_body = _make_chunk_prefill(cfg, tn, tp=self._tp,
+                                         block_size=bs)
+        latent = self._latent
         head_logits = _make_head_logits(cfg)
         scatter = self._page_scatter(1, n_win)
         window_layers = self._window_layers
@@ -1737,16 +1783,20 @@ class ContinuousBatchingEngine:
                 self._table_arg(a, "tables"), a["live"], kd, temperature,
                 top_p)
             # ---- chunk lane: one ragged prefill window ----
+            # layers with pools of their own write the window's rows
+            # themselves, at its pages (kcs / vcs replaced in place)
             h, kvs, *chunk_count = chunk_body(
                 p, kcs, vcs, a["chunk_ids"],
                 self._table_arg(a, "chunk_table"), a["chunk_cached"],
-                chunk_len)
-            for i, (k, v) in enumerate(kvs):
+                chunk_len, **({"chunk_pages": chunk_pages} if latent else {}))
+            for i, kv in enumerate(kvs):
+                if kv is None:
+                    continue
                 # a window layer's rows go to its ring's pages (the pair's
                 # second), a full layer's to the request's own
                 pages_i = chunk_pages if not window_layers \
                     else chunk_pages[i in window_layers]
-                kcs[i], vcs[i] = scatter(kcs[i], vcs[i], k, v, pages_i)
+                kcs[i], vcs[i] = scatter(kcs[i], vcs[i], *kv, pages_i)
             # first-token logits at the chunk's true last position —
             # meaningful only when this window completes the prompt
             # (the host ignores it otherwise)

@@ -554,6 +554,98 @@ def test_llama_served_programs(mistral_engine, one_chip, program):
                                   "unified": "unified_step"}[program])
 
 
+# the learned sparse attention of `hy4-longctx-reason` at its widths: 24 slots,
+# 64 heads over a 576-value latent row (384 packed words), 32 index heads of
+# 128, 2,048 selected, 256-token pages, a table of 108 pages
+HB, HH, HW, HP, HPAGES, HK_SEL, HTN = 24, 64, 108, 256, 300, 2048, 2048
+HLATENT = ((HPAGES * HP, 1, 384), I32)
+HINDEX = ((HPAGES, 1, HP, 128), BF)
+
+
+def _labels(text, *names):
+    for name in names:
+        assert re.search(rf"%{name}(\.\d+)? = [^\n]*custom-call", text), name
+
+
+def test_hy4_sparse_attention_kernels(for_chip, one_chip):
+    sa = _mod("sparse_attention")
+
+    def decode(q, w, index, latent, tables, pos, sink):
+        scores = sa.dsa_index_scores(q[:, None], w[:, None], index, tables,
+                                     pos[:, None])
+        _, idx = sa.select_topk(scores[:, 0], HK_SEL)
+        rows = tables[jnp.arange(HB)[:, None], idx // HP] * HP + idx % HP
+        qa = jnp.zeros((HB, HH, 576), BF)
+        return sa.sparse_mla_decode(qa, latent, rows, pos + 1, sink,
+                                    scale=1 / 16, value_width=512)
+
+    text = _compile(decode, one_chip, ((HB, 32, 128), BF), ((HB, 32), F32),
+                    HINDEX, HLATENT, ((HB, HW), I32), ((HB,), I32),
+                    ((HH,), F32))
+    _labels(text, "dsa_index_scores", "sparse_mla_decode")
+
+    def window(q, w, index, latent, table, pos, sink, qa):
+        g = HTN // sa.INDEX_ROWS
+        scores = sa.dsa_index_scores(
+            q.reshape(g, -1, 32, 128), w.reshape(g, -1, 32), index,
+            jnp.broadcast_to(table[None], (g, HW)),
+            pos.reshape(g, -1)).reshape(HTN, -1)
+        thr, tie = sa.window_selection(scores, HK_SEL)
+        return sa.sparse_mla_window(qa, latent, table, pos, scores, thr, tie,
+                                    sink, scale=1 / 16, value_width=512,
+                                    block_size=HP)
+
+    text = _compile(window, one_chip, ((HTN, 32, 128), BF), ((HTN, 32), F32),
+                    HINDEX, HLATENT, ((HW,), I32), ((HTN,), I32),
+                    ((HH,), F32), ((HTN, HH, 576), BF))
+    _labels(text, "dsa_index_scores", "sparse_mla_window")
+    text = _compile(lambda pool, rows, flat: sa.latent_commit(pool, rows,
+                                                              flat),
+                    one_chip, HLATENT, ((HB, 384), I32), ((HB,), I32))
+    _labels(text, "latent_commit")
+
+
+@pytest.fixture
+def hy4_engine(for_chip):
+    """An engine over the published block's first three layers (dense and
+    full, sparse and full, sparse and shared), every width as published, 16
+    held experts, its parameters shapes alone."""
+    from paddle_tpu.models.hy_v4 import (HyV4Config, param_dtype,
+                                         serving_param_shapes)
+    from paddle_tpu.serving import ContinuousBatchingEngine
+
+    cfg = HyV4Config(num_hidden_layers=3, vocab_size=15104,
+                     held=tuple(range(16)))
+    p = {k: jax.ShapeDtypeStruct(v, param_dtype(k, BF))
+         for k, v in serving_param_shapes(cfg).items()}
+    return ContinuousBatchingEngine(
+        cfg, p, slots=HB, max_prompt_len=24576, max_new_tokens=3072,
+        token_budget=HTN, block_size=HP, prompt_bucket=HP, max_pages=HPAGES,
+        logprobs=True)
+
+
+@pytest.mark.parametrize("program", ["decode", "unified"])
+def test_hy4_served_programs(hy4_engine, one_chip, program):
+    """Both served programs of `hy4-longctx-reason`: the sparse attention
+    kernels, the in-place latent commit and the expert layer in the
+    program, no pool copied whole into another layout."""
+    eng = hy4_engine
+    assert eng.table_width == HW
+    text = _served_program(eng, program, one_chip)
+    _labels(text, "dsa_index_scores", "sparse_mla_decode", "latent_commit",
+            "grouped_matmul")
+    if program == "unified":
+        _labels(text, "sparse_mla_window", "moe_rows_in", "moe_rows_out")
+    for pool in [x for x in eng.kcs + eng.vcs if x is not None]:
+        dims = ",".join(str(n) for n in pool.shape)
+        default = ",".join(str(i) for i in reversed(range(len(pool.shape))))
+        assert not re.findall(rf"\w+\[{dims}\]\{{(?!{default}[:}}])[^}}]*\}}",
+                              text), pool.shape
+    assert text.startswith(
+        "HloModule jit_serve_" + {"decode": "decode_chunk",
+                                  "unified": "unified_step"}[program])
+
+
 def test_swiglu_fused_refuses_the_1b_mlp_shape():
     """K 2048, F 5504 is not 512-tileable: a caller who asked for the kernel
     gets an error, never the XLA form under the kernel's name."""

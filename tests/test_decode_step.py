@@ -364,4 +364,4 @@ def test_no_rung_in_any_program_key():
     assert not any(r in name.split(":") for name in eng.compile_stats()
                    for r in rungs)
     assert "decode_megakernel" not in eng.metrics()
-    assert eng.metrics()["megakernel_rung"] == "off"
+    assert "megakernel_rung" not in eng.metrics()

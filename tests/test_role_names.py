@@ -184,6 +184,28 @@ CASES = {
         lambda *a: _mod("kv_commit").kv_commit(*a),
         [POOL, POOL, ((B, HK, D), BF), ((B, HK, D), BF), LENS, LENS],
         {"kv_commit": "kv_commit"}),
+    # the learned sparse attention over latent pages
+    "dsa_index_scores": (
+        lambda *a: _mod("sparse_attention").dsa_index_scores(*a),
+        [((2, 1, 4, 128), BF), ((2, 1, 4), F32), ((5, 1, 16, 128), BF),
+         ((2, 3), I32), ((2, 1), I32)],
+        {"dsa_index_scores": "dsa_index_scores"}),
+    "sparse_mla_decode": (
+        lambda *a: _mod("sparse_attention").sparse_mla_decode(
+            *a, scale=0.0625, value_width=512),
+        [((2, 4, 576), BF), ((80, 1, 384), I32), ((2, 8), I32), ((2,), I32),
+         ((4,), F32)],
+        {"sparse_mla_decode": "sparse_mla_decode"}),
+    "sparse_mla_window": (
+        lambda *a: _mod("sparse_attention").sparse_mla_window(
+            *a, scale=0.0625, value_width=512, block_size=16),
+        [((16, 4, 576), BF), ((80, 1, 384), I32), ((5,), I32), ((16,), I32),
+         ((16, 80), F32), ((16,), F32), ((16,), I32), ((4,), F32)],
+        {"sparse_mla_window": "sparse_mla_window"}),
+    "latent_commit": (
+        lambda *a: _mod("sparse_attention").latent_commit(*a),
+        [((80, 1, 384), I32), ((2, 384), I32), ((2,), I32)],
+        {"latent_commit": "latent_commit"}),
 }
 
 
@@ -235,7 +257,7 @@ def test_no_pallas_call_in_kernels_lacks_a_name():
                       for node in ast.walk(tree)
                       if isinstance(node, ast.Call)
                       and getattr(node.func, "attr", "") == "pallas_call"]
-    assert len(calls) == 19     # the call sites CASES covers
+    assert len(calls) == 23     # the call sites CASES covers
     assert [c[:2] for c in calls if "name" not in c[2]] == []
 
 
