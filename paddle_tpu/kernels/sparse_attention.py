@@ -10,18 +10,23 @@ latent rows of those positions. Four pieces, each under its own trace label:
                      inside the kernel (the f32 [rows, heads, context] tensor
                      is never written); -inf past each row's position
   (selection)        exact top-k of I, the lower position winning a tie: in
-                     decode `jax.lax.top_k` (the positions, for the row
-                     fetches); in a prefill window each row's k-th largest
-                     score and its last kept tie (`window_selection`, no
-                     sort), which the window kernel reads as its mask. Each
-                     lane keeps the faster on a v5e at 2,048 of 27,648 keys:
+                     decode `jax.lax.top_k` (the positions, which
+                     `decode_plan` sorts by page); in a prefill window
+                     each row's k-th largest score and its last kept tie
+                     (`window_selection`, no sort), which the window kernel
+                     reads as its mask. Each lane keeps the faster on a
+                     v5e at 2,048 of 27,648 keys:
                      top_k 0.55 ms for 24 rows (the bisection and a
                      compaction 3.3), the bisection 17 ms for 2,048 rows
                      (top_k 62, and 648 MiB of temporaries)
-  sparse_mla_decode  one query row a sequence: the selected latent rows
-                     fetched row by row from the pool in HBM (one DMA a row,
-                     through the page table), online softmax with a learned
-                     sink per head in the denominator
+  sparse_mla_decode  one query row a sequence over the pages that hold its
+                     selected rows (`decode_plan`, made once a `full` layer
+                     and reused by the `shared` ones), each page one DMA of
+                     the whole page, `DECODE_DEPTH` in flight; the page's
+                     selected rows are copied out in VMEM and scored, in
+                     position order, a chunk at a time as each chunk fills:
+                     online softmax with a learned sink per head in the
+                     denominator
   sparse_mla_window  a prefill window's query rows, blockwise: a masked sweep
                      over the window's context pages, the mask being each
                      row's selection (scores above its k-th largest, ties to
@@ -42,6 +47,7 @@ a grid step through BlockSpecs. The latent pool of a layer is [pages * block,
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -52,8 +58,16 @@ from .constraints import LANE, KernelConstraint, register_constraint
 from .decode_attention import _on_tpu
 
 _NEG = -1e30
-# selected rows one grid step of the decode kernel fetches and scores
-DECODE_CHUNK = 256
+# selected rows one step of the decode kernel scores, and the pages it keeps
+# in flight. Every page that holds a selected row is copied whole: on a v5e
+# at 24 sequences, 64 heads, 384-word rows and 256-row pages a page copied
+# whole costs 0.63 us up to 16 selected rows (384 KiB at ~620 GB/s, the
+# rows' copies and scoring hidden under it), a page fetched a row at a time
+# 0.24 us + ~37 ns a row, so a row-by-row path would save at most ~0.4 us,
+# and only on a page with 10 or fewer selected rows. A call at the cell's
+# selections (`tools/sparse_decode_sweep.py`) takes 1,177 / 1,035 / 963 /
+# 945 us at 2 / 3 / 4 / 6 pages in flight
+DECODE_CHUNK, DECODE_DEPTH = 256, 4
 # query rows one grid step of the window kernels takes
 WINDOW_ROWS = 8
 # query rows one grid step of the index kernel takes in a prefill window
@@ -65,9 +79,10 @@ for _name, _fn, _blocks, _note in (
          "q [groups, rows x heads, d], a page of index keys [1, 1, block, d] "
          "a grid step through the table; scores [groups, rows, W x block]"),
         ("sparse_mla_decode", "_decode_kernel",
-         {"decode_chunk": DECODE_CHUNK},
-         "selected rows fetched one DMA a row from a [N, 1, W] int32 pool "
-         "(W whole lane tiles); the selection in SMEM a sequence"),
+         {"decode_chunk": DECODE_CHUNK, "decode_depth": DECODE_DEPTH},
+         "a sequence's pages that hold a selected row, DECODE_DEPTH in "
+         "flight, each one DMA of [block, 1, W] int32; the selected rows "
+         "[K + 1, 1, W] in VMEM, the plan in SMEM a sequence"),
         ("sparse_mla_window", "_window_kernel", {"window_rows": WINDOW_ROWS},
          "a masked sweep of a window's query blocks over its context pages "
          "[block, 1, W]; the mask each row's selection"),
@@ -229,36 +244,94 @@ def _acc_scratch(rows: int, words: int, lanes: int):
 
 # ---- sparse latent attention, decode -------------------------------------
 
-def _decode_kernel(nsel_ref, rows_ref, q_ref, sink_ref, pool_hbm, o_ref,
-                   buf, sem, m_ref, l_ref, acc_lo, *acc_hi,
-                   chunk: int, scale: float, words: int):
-    """Grid (sequences, chunks of the selection). q [H, 2W] bf16; the
-    chunk's selected rows are copied one DMA a row into `buf` [chunk, 1, W]."""
-    b, c = pl.program_id(0), pl.program_id(1)
-    n = nsel_ref[b]
+# meta rows of a `DecodePlan`
+_PAGE, _COUNT, _FIRST = range(3)
 
-    @pl.when(c == 0)
-    def _():
-        m_ref[...] = jnp.full(m_ref.shape, _NEG, jnp.float32)
-        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
-        for acc in (acc_lo, *acc_hi):
-            acc[...] = jnp.zeros(acc.shape, jnp.float32)
 
-    @pl.when(c * chunk < n)
-    def _():
-        def start(i, carry):
-            pltpu.make_async_copy(pool_hbm.at[rows_ref[0, 0, c * chunk + i]],
-                                  buf.at[i], sem.at[0]).start()
+class DecodePlan(NamedTuple):
+    """The decode lane's selection arranged by page (`decode_plan`): made
+    once a `full` layer, reused by the layers that share its selection.
+    `cols` is the table's width."""
+    # [B] int32: selected rows, and pages that hold at least one of them
+    n_sel: jax.Array
+    pages: jax.Array
+    # [B, 3, cols] int32: of the j-th such page in position order, its pool
+    # page, its selected rows and the first of them in `offsets`
+    meta: jax.Array
+    # [B, 1, K] int32: the selected positions' offsets in their pages,
+    # ascending by position
+    offsets: jax.Array
+
+
+def decode_plan(idx, n_sel, tables, block_size: int):
+    """The selection `idx` [B, K] (positions in score order, as
+    `select_topk` gives them; past `n_sel` [B] unread) by the pages of
+    `tables` [B, cols]: one sort of the positions, no scatter."""
+    b, k = idx.shape
+    cols, bs = tables.shape[1], block_size
+    live = jnp.arange(k)[None] < n_sel[:, None]
+    pos = jnp.sort(jnp.where(live, idx, cols * bs), axis=1)
+    # selected rows before each page's first position
+    before = jnp.sum(pos[:, :, None] < jnp.arange(cols + 1) * bs, axis=1,
+                     dtype=jnp.int32)
+    count = before[:, 1:] - before[:, :-1]
+    # the columns that hold a selected row first, ascending
+    order = jnp.argsort(count == 0, axis=1, stable=True)
+    meta = jnp.stack([jnp.take_along_axis(a.astype(jnp.int32), order, axis=1)
+                      for a in (tables, count, before[:, :-1])], axis=1)
+    return DecodePlan(
+        n_sel=n_sel.astype(jnp.int32),
+        pages=jnp.sum(count > 0, axis=1, dtype=jnp.int32), meta=meta,
+        offsets=(pos % bs).astype(jnp.int32)[:, None])
+
+
+def _decode_kernel(nsel_ref, pages_ref, meta_ref, offs_ref, q_ref, sink_ref,
+                   pool_hbm, o_ref, page_buf, rows_buf, sem, m_ref, l_ref,
+                   acc_lo, *acc_hi, bs: int, chunk: int, scale: float,
+                   words: int):
+    """Grid (sequences,). The sequence's pages that hold a selected row, in
+    position order, `depth` (`page_buf`'s first dimension) in flight, each
+    one DMA of the whole page into `page_buf`; its selected rows are then
+    copied into `rows_buf` by vector loads. So `rows_buf` fills with the
+    selection in position order (its last row takes what the unrolled
+    copies carry past a page's count), and each chunk of it is scored as
+    soon as it is whole, while the next pages' copies run. Rows at or past
+    n_sel score -inf and weigh as zeros: whatever they hold (an earlier
+    sequence's rows, or VMEM never written) reaches no sum, so each
+    sequence stands alone."""
+    b = pl.program_id(0)
+    n, pages, k = nsel_ref[b], pages_ref[b], rows_buf.shape[0] - 1
+    depth = page_buf.shape[0]
+
+    m_ref[...] = jnp.full(m_ref.shape, _NEG, jnp.float32)
+    l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+    for acc in (acc_lo, *acc_hi):
+        acc[...] = jnp.zeros(acc.shape, jnp.float32)
+
+    def copy(j):
+        slot = j % depth
+        return pltpu.make_async_copy(
+            pool_hbm.at[pl.ds(meta_ref[0, _PAGE, j] * bs, bs)],
+            page_buf.at[slot], sem.at[slot])
+
+    def gather(j):
+        count, first = meta_ref[0, _COUNT, j], meta_ref[0, _FIRST, j]
+
+        def eight(t, carry):
+            for u in range(8):
+                i = t * 8 + u
+                at = jnp.minimum(first + i, k - 1)
+                rows_buf[jnp.where(i < count, first + i, k)] = \
+                    page_buf[j % depth, offs_ref[0, 0, at]]
             return carry
 
-        def wait(i, carry):
-            pltpu.make_async_copy(pool_hbm.at[0], buf.at[0],
-                                  sem.at[0]).wait()
-            return carry
+        jax.lax.fori_loop(0, (count + 7) // 8, eight, 0)
 
-        jax.lax.fori_loop(0, chunk, start, 0)
-        jax.lax.fori_loop(0, chunk, wait, 0)
-        lo, hi = _halves(buf[:, 0, :])
+    def score(c, carry):
+        start = pl.multiple_of(c * chunk, chunk)
+        rows = rows_buf[pl.ds(start, chunk), 0, :]
+        row = start + jax.lax.broadcasted_iota(jnp.int32, rows.shape, 0)
+        lo, hi = _halves(jnp.where(row < n, rows, 0))
         lo, hi = lo.astype(jnp.bfloat16), hi.astype(jnp.bfloat16)
         q = q_ref[0]
         dims = (((1,), (1,)), ((), ()))
@@ -267,7 +340,7 @@ def _decode_kernel(nsel_ref, rows_ref, q_ref, sink_ref, pool_hbm, o_ref,
              + jax.lax.dot_general(q[:, words:], hi, dims,
                                    preferred_element_type=jnp.float32)) \
             * scale
-        col = c * chunk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        col = start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         s = jnp.where(col < n, s, -jnp.inf)
         m_prev = m_ref[...]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
@@ -276,59 +349,81 @@ def _decode_kernel(nsel_ref, rows_ref, q_ref, sink_ref, pool_hbm, o_ref,
         l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
         _accumulate(p, alpha, lo, hi, acc_lo, acc_hi)
         m_ref[...] = m_new
+        return carry
 
-    @pl.when(c == pl.num_programs(1) - 1)
-    def _():
-        den = l_ref[...] + jnp.exp(sink_ref[...] - m_ref[...])
-        o_ref[0] = _finish(acc_lo, acc_hi, den, o_ref.shape[-1])
+    for j in range(depth - 1):
+        @pl.when(j < pages)
+        def _():
+            copy(j).start()
+
+    def page(j, carry):
+        @pl.when(j + depth - 1 < pages)
+        def _():
+            copy(j + depth - 1).start()
+
+        copy(j).wait()
+        gather(j)
+        # the chunks this page completes
+        first = meta_ref[0, _FIRST, j]
+        jax.lax.fori_loop(first // chunk,
+                          (first + meta_ref[0, _COUNT, j]) // chunk, score, 0)
+        return carry
+
+    jax.lax.fori_loop(0, pages, page, 0)
+    jax.lax.fori_loop(n // chunk, (n + chunk - 1) // chunk, score, 0)
+    den = l_ref[...] + jnp.exp(sink_ref[...] - m_ref[...])
+    o_ref[0] = _finish(acc_lo, acc_hi, den, o_ref.shape[-1])
 
 
-def sparse_mla_decode(q, pool, rows, n_sel, sink, *, scale: float,
-                      value_width: int):
+def sparse_mla_decode(q, pool, plan: DecodePlan, sink, *, scale: float,
+                      value_width: int, block_size: int):
     """Latent attention of one query row a sequence over its selected rows.
 
     q [B, H, width] (the absorbed query: q_nope W_uk, then q_rope), pool
-    [N, 1, W] int32 packed latent rows, rows [B, K] int32 (row ids into the
-    pool; past `n_sel` any valid id), n_sel [B] int32, sink [H] f32.
-    Returns [B, H, value_lanes(value_width)] bf16: the softmax-weighted sum
-    of the rows' first `value_width` values (c_kv), a sink in the
-    denominator."""
+    [N, 1, W] int32 packed latent rows in pages of `block_size`, `plan` the
+    selection by page (`decode_plan` over the pool's page tables), sink [H]
+    f32. Returns [B, H, value_lanes(value_width)] bf16: the
+    softmax-weighted sum of the rows' first `value_width` values (c_kv), a
+    sink in the denominator."""
     b, h, _ = q.shape
     words = pool.shape[-1]
-    k = rows.shape[1]
+    cols, k = plan.meta.shape[-1], plan.offsets.shape[-1]
     chunk = min(DECODE_CHUNK, k)
     if k % chunk:
         raise ValueError(f"sparse_mla_decode: {k} rows in chunks of {chunk}")
     lanes = value_lanes(value_width)
     qp = jnp.pad(q.astype(jnp.bfloat16), ((0, 0), (0, 0),
                                           (0, 2 * words - q.shape[-1])))
-    kernel = functools.partial(_decode_kernel, chunk=chunk, scale=scale,
-                               words=words)
+    kernel = functools.partial(_decode_kernel, bs=block_size, chunk=chunk,
+                               scale=scale, words=words)
+    seq = lambda i, n, pg: (i, 0, 0)  # noqa: E731
     return pl.pallas_call(
         kernel,
         name="sparse_mla_decode",
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(b, k // chunk),
+            num_scalar_prefetch=2,
+            grid=(b,),
             in_specs=[
-                pl.BlockSpec((1, 1, k), lambda i, c, n: (i, 0, 0),
-                             memory_space=pltpu.SMEM),
-                pl.BlockSpec((1, h, 2 * words), lambda i, c, n: (i, 0, 0)),
-                pl.BlockSpec((h, 1), lambda i, c, n: (0, 0)),
+                pl.BlockSpec((1, 3, cols), seq, memory_space=pltpu.SMEM),
+                pl.BlockSpec((1, 1, k), seq, memory_space=pltpu.SMEM),
+                pl.BlockSpec((1, h, 2 * words), seq),
+                pl.BlockSpec((h, 1), lambda i, n, pg: (0, 0)),
                 pl.BlockSpec(memory_space=pltpu.HBM),
             ],
-            out_specs=pl.BlockSpec((1, h, lanes), lambda i, c, n: (i, 0, 0)),
-            scratch_shapes=[pltpu.VMEM((chunk, 1, words), jnp.int32),
-                            pltpu.SemaphoreType.DMA((1,)),
+            out_specs=pl.BlockSpec((1, h, lanes), seq),
+            scratch_shapes=[pltpu.VMEM((DECODE_DEPTH, block_size, 1, words),
+                                       jnp.int32),
+                            pltpu.VMEM((k + 1, 1, words), jnp.int32),
+                            pltpu.SemaphoreType.DMA((DECODE_DEPTH,)),
                             pltpu.VMEM((h, 1), jnp.float32),
                             pltpu.VMEM((h, 1), jnp.float32),
                             *_acc_scratch(h, words, lanes)],
         ),
         out_shape=jax.ShapeDtypeStruct((b, h, lanes), jnp.bfloat16),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("parallel",)),
         interpret=_interpret(),
-    )(n_sel.astype(jnp.int32), rows.astype(jnp.int32).reshape(b, 1, k), qp,
+    )(plan.n_sel, plan.pages, plan.meta, plan.offsets, qp,
       sink.astype(jnp.float32).reshape(h, 1), pool)
 
 

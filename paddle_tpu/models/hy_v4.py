@@ -361,7 +361,8 @@ class SparseLatentAttention:
         """One token a row: x [B, 1, C] (normed), lane {"tables" [B, W],
         "pos" [B] (the token's position), "block_size"}. Returns (y [B, 1,
         C], latent, index, carry, counts)."""
-        from ..kernels.sparse_attention import (dsa_index_scores,
+        from ..kernels.sparse_attention import (decode_plan,
+                                                dsa_index_scores,
                                                 latent_commit, pack_rows,
                                                 select_topk,
                                                 sparse_mla_decode)
@@ -385,15 +386,16 @@ class SparseLatentAttention:
                     index.dtype))
                 scores = dsa_index_scores(qi, w, index, tables, pos[:, None])
                 _, idx = select_topk(scores[:, 0], k)
-                sel = tables[rows_b[:, None], idx // bs] * bs + idx % bs
-            carry = dict(carry, decode=sel)
+                plan = decode_plan(idx, n_sel, tables, bs)
+            carry = dict(carry, decode=plan)
             scored = jnp.sum(pos + 1)
+        plan = carry["decode"]
         with jax.named_scope("attn.mla"):
             o_lat = sparse_mla_decode(
-                q_abs[:, 0], latent, carry["decode"], n_sel,
-                p[pre + "self_attn.sinks"],
+                q_abs[:, 0], latent, plan, p[pre + "self_attn.sinks"],
                 scale=1.0 / math.sqrt(c.qk_head_dim),
-                value_width=c.kv_lora_rank)[..., :c.kv_lora_rank]
+                value_width=c.kv_lora_rank,
+                block_size=bs)[..., :c.kv_lora_rank]
             y = self._out(o_lat[:, None], gate, p, pre)
         return y, latent, index, carry, self._count(scored, jnp.sum(n_sel))
 

@@ -574,15 +574,17 @@ def test_hy4_sparse_attention_kernels(for_chip, one_chip):
         scores = sa.dsa_index_scores(q[:, None], w[:, None], index, tables,
                                      pos[:, None])
         _, idx = sa.select_topk(scores[:, 0], HK_SEL)
-        rows = tables[jnp.arange(HB)[:, None], idx // HP] * HP + idx % HP
+        plan = sa.decode_plan(idx, pos + 1, tables, HP)
         qa = jnp.zeros((HB, HH, 576), BF)
-        return sa.sparse_mla_decode(qa, latent, rows, pos + 1, sink,
-                                    scale=1 / 16, value_width=512)
+        return sa.sparse_mla_decode(qa, latent, plan, sink, scale=1 / 16,
+                                    value_width=512, block_size=HP)
 
     text = _compile(decode, one_chip, ((HB, 32, 128), BF), ((HB, 32), F32),
                     HINDEX, HLATENT, ((HB, HW), I32), ((HB,), I32),
                     ((HH,), F32))
     _labels(text, "dsa_index_scores", "sparse_mla_decode")
+    # the plan that arranges the selection by page is no scatter
+    assert " scatter(" not in text
 
     def window(q, w, index, latent, table, pos, sink, qa):
         g = HTN // sa.INDEX_ROWS

@@ -98,15 +98,24 @@ def test_the_engine_matches_the_reference_at_every_position(dtype):
     assert em["prefix_cache_off"] and "latent" in em["prefix_cache_off"]
 
 
+def _selected(plan, bs=8):
+    """A decode plan's selection: each row's selected pool rows, in
+    position order ([B, n] where every row selects n)."""
+    page, count, first = plan.meta[:, 0], plan.meta[:, 1], plan.meta[:, 2]
+    return np.stack([np.concatenate([
+        page[r, j] * bs + plan.offsets[r, 0, first[r, j]:][:count[r, j]]
+        for j in range(plan.pages[r])]) for r in range(len(plan.pages))])
+
+
 def _recording(monkeypatch):
     """Every selection the sparse attention kernels are handed, a call at a
-    time: decode (row ids) and window (scores, thr, tie)."""
+    time: decode (its plan) and window (scores, thr, tie)."""
     got = {"decode": [], "window": []}
     real_d, real_w = sa.sparse_mla_decode, sa.sparse_mla_window
 
-    def dec(q, pool, rows, n_sel, sink, **kw):
-        got["decode"].append(np.asarray(rows))
-        return real_d(q, pool, rows, n_sel, sink, **kw)
+    def dec(q, pool, plan, sink, **kw):
+        got["decode"].append(jax.tree.map(np.asarray, plan))
+        return real_d(q, pool, plan, sink, **kw)
 
     def win(q, pool, table, pos, scores, thr, tie, sink, **kw):
         got["window"].append(tuple(np.asarray(x) for x in (scores, thr,
@@ -146,10 +155,52 @@ def test_a_shared_layer_uses_the_full_layers_selection(monkeypatch):
     step(p, kcs, vcs, jnp.asarray([[7]], jnp.int32),
          jnp.asarray([45], jnp.int32))
     d0, d1, d2 = got["decode"]
-    np.testing.assert_array_equal(d1, d2)
-    assert not np.array_equal(np.sort(d0), np.sort(d1))
+    for a, b in zip(d1, d2):
+        np.testing.assert_array_equal(a, b)
+    d0, d1 = _selected(d0), _selected(d1)
+    assert not np.array_equal(d0, d1)
     # a selection of 8 of the 46 positions, none past the token's own
     assert d1.shape == (1, 8) and (d1 <= 45).all()
+
+
+def _plan_rows(plan, bs):
+    """The pool rows a decode plan names, in position order ([B, K]; past a
+    row's n_sel, rows of its last page)."""
+    k = plan.offsets.shape[-1]
+    first = plan.meta[:, 2]
+    live = jnp.arange(first.shape[1])[None] < plan.pages[:, None]
+    j = jnp.sum(live[:, None] & (first[:, None] <= jnp.arange(k)[:, None]),
+                axis=2) - 1
+    page = jnp.take_along_axis(plan.meta[:, 0], jnp.maximum(j, 0), axis=1)
+    return page * bs + plan.offsets[:, 0]
+
+
+def test_the_decode_lane_attends_the_rows_its_plans_name(monkeypatch):
+    """Served, every call of the decode kernel, which copies whole each page
+    that holds a selected row, gives the attention over the pool rows its
+    plan names as `sparse_decode_reference` gathers them; the plans'
+    selected rows are the keys the decode lane counts as attended."""
+    cfg = HyV4Config.tiny()
+    real = sa.sparse_mla_decode
+    seen = []
+
+    def dec(q, pool, plan, sink, **kw):
+        out = real(q, pool, plan, sink, **kw)
+        want = sa.sparse_decode_reference(
+            q, pool, _plan_rows(plan, kw["block_size"]), plan.n_sel, sink,
+            scale=kw["scale"], value_width=kw["value_width"])
+        jax.debug.callback(
+            lambda a, b, c: seen.append((np.abs(a - b).max(), c.sum())),
+            out[..., :kw["value_width"]].astype(jnp.float32), want,
+            plan.meta[:, 1])
+        return out
+
+    monkeypatch.setattr(sa, "sparse_mla_decode", dec)
+    eng, _ = _served(cfg, "float32")
+    monkeypatch.undo()
+    err, attended = np.asarray(seen).T
+    assert len(seen) and err.max() < 1e-2
+    assert attended.sum() == eng.metrics()["dsa_keys_attended_decode"]
 
 
 def test_below_index_topk_the_selection_is_every_earlier_position(

@@ -191,10 +191,13 @@ CASES = {
          ((2, 3), I32), ((2, 1), I32)],
         {"dsa_index_scores": "dsa_index_scores"}),
     "sparse_mla_decode": (
-        lambda *a: _mod("sparse_attention").sparse_mla_decode(
-            *a, scale=0.0625, value_width=512),
+        lambda q, pool, idx, n_sel, tables, sink: _mod(
+            "sparse_attention").sparse_mla_decode(
+            q, pool, _mod("sparse_attention").decode_plan(idx, n_sel, tables,
+                                                          16),
+            sink, scale=0.0625, value_width=512, block_size=16),
         [((2, 4, 576), BF), ((80, 1, 384), I32), ((2, 8), I32), ((2,), I32),
-         ((4,), F32)],
+         ((2, 5), I32), ((4,), F32)],
         {"sparse_mla_decode": "sparse_mla_decode"}),
     "sparse_mla_window": (
         lambda *a: _mod("sparse_attention").sparse_mla_window(
