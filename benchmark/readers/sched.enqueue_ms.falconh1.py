@@ -1,0 +1,5 @@
+"""`sched.enqueue_ms`'s reading, in `falconh1-chat-sat` (an entry of its own,
+and why: `_mellum.same_as`)."""
+from benchmark.readers import _mellum
+
+read = _mellum.same_as("sched.enqueue_ms")

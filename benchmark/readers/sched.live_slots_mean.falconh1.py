@@ -1,0 +1,5 @@
+"""`sched.live_slots_mean`'s reading, in `falconh1-chat-sat` (an entry of its own,
+and why: `_mellum.same_as`)."""
+from benchmark.readers import _mellum
+
+read = _mellum.same_as("sched.live_slots_mean")

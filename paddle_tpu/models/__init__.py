@@ -23,6 +23,7 @@ from .bert import (  # noqa: F401
 )
 from .mellum import MellumConfig  # noqa: F401
 from .hy_v4 import HyV4Config  # noqa: F401
+from .falcon_h1 import FalconH1Config  # noqa: F401
 from .glm4_moe_lite import (  # noqa: F401
     Glm4MoeLiteConfig, Glm4MoeLiteForCausalLM,
     Glm4MoeLitePretrainingCriterion,

@@ -793,6 +793,19 @@ class ServedLayer(NamedTuple):
     # F: u -> (y, aux), the sub-block around which the rule joins the
     # residual (a model of `streams` residual streams has one)
     residual: Optional[Callable] = None
+    # None: the attention block alone. Else a mixer beside the block above,
+    # on the same normed input, its output added to the block's, that keeps
+    # a state of fixed size a sequence (models/falcon_h1.py's Mamba-2):
+    # `state(rows)` -> the layer's state arrays, a row a sequence, row 0
+    # the sink of idle rows; `row_bytes()` -> the bytes of one row;
+    # `decode` / `window` (x after the first norm, p, prefix, state, lane)
+    # -> (y, state, counts), updating the rows `lane` names in place. A row
+    # starts from zero at its sequence's first position and is carried from
+    # one window to the next
+    mixer: Optional[object] = None
+    # None, or the block above's (input, key, output) multipliers: q, k and
+    # v read input * x, k is multiplied by key, o's output by output
+    attn_scales: Optional[Tuple[float, float, float]] = None
 
 
 class ServedModel(NamedTuple):
@@ -810,6 +823,9 @@ class ServedModel(NamedTuple):
     head_f32: bool = False
     # what a routed model's layers count, in order (summed by the programs)
     counts: Tuple[str, ...] = MOE_COUNTS
+    # the multipliers of the embedding's rows and of the logits
+    embed_scale: float = 1.0
+    logit_scale: float = 1.0
 
     @property
     def window_layers(self) -> Tuple[int, ...]:
@@ -824,6 +840,16 @@ class ServedModel(NamedTuple):
     @property
     def routed(self) -> bool:
         return any(getattr(l.mlp, "routed", False) for l in self.layers)
+
+    @property
+    def stateful(self) -> bool:
+        """Whether a layer keeps state a sequence (`ServedLayer.mixer`)."""
+        return any(l.mixer is not None for l in self.layers)
+
+    @property
+    def counted(self) -> bool:
+        """Whether the layers count what they did (`counts`)."""
+        return self.routed or self.stateful
 
 
 def _dense_swiglu(x, p, pre):
@@ -852,6 +878,18 @@ def _attn_scope(layer: ServedLayer):
         "attn.window" if layer.window is not None else "attn.full")
 
 
+def _attn_in(layer: ServedLayer, x):
+    """What the attention block's projections read: the normed input, times
+    the layer's input multiplier where it has one."""
+    return x if layer.attn_scales is None else x * layer.attn_scales[0]
+
+
+def _attn_out(layer: ServedLayer, y):
+    """The attention block's output `y` (o's) as the residual takes it:
+    times the layer's output multiplier where it has one."""
+    return y if layer.attn_scales is None else y * layer.attn_scales[2]
+
+
 def _make_head_logits(cfg):
     """LM-head logits over the decode-params dict (quant-aware via _mm;
     tied embeddings stay a dense transpose-matmul)."""
@@ -859,18 +897,24 @@ def _make_head_logits(cfg):
 
     def head_logits(h, p):
         if model.head is None:
-            return h @ p[model.embed].T
-        if model.head_f32:
-            return jnp.dot(h.astype(jnp.float32),
-                           p[model.head].astype(jnp.float32),
-                           precision=jax.lax.Precision.HIGHEST)
-        return _mm(h, p[model.head])
+            logits = h @ p[model.embed].T
+        elif model.head_f32:
+            logits = jnp.dot(h.astype(jnp.float32),
+                             p[model.head].astype(jnp.float32),
+                             precision=jax.lax.Precision.HIGHEST)
+        else:
+            logits = _mm(h, p[model.head])
+        if model.logit_scale != 1.0:
+            logits = logits * model.logit_scale
+        return logits
     return head_logits
 
 
 def _embed(model: ServedModel, h):
-    """The residual entering the first layer: the embedding, as `streams`
-    copies where the model has several."""
+    """The residual entering the first layer: the embedding (times the
+    model's multiplier), as `streams` copies where the model has several."""
+    if model.embed_scale != 1.0:
+        h = h * model.embed_scale
     if model.streams == 1:
         return h
     return jnp.broadcast_to(h[..., None, :],
@@ -1143,7 +1187,11 @@ def _make_chunk_prefill(cfg, tn, tp=None, block_size=None):
     `chunk_pages` [1, tn / block_size] (the window's pages, pad pages the
     scratch page): its entries of the lists `kcs` / `vcs` are replaced in
     place and its entry of the K/V list is None. Its window starts on a
-    page's edge (no prefix cache; windows of whole pages)."""
+    page's edge (no prefix cache; windows of whole pages). A model whose
+    layers keep state a sequence (`ServedLayer.mixer`) passes the layers'
+    `states` and the window's sequence's row `state_row` [1]; the window
+    starts from that row's carried state, or from zero where `cached_len`
+    is 0, and the states come back after the K/V list."""
     model = served_model(cfg)
     nh, nkv, dh = (cfg.num_attention_heads, cfg.num_key_value_heads,
                    model.head_dim)
@@ -1156,7 +1204,7 @@ def _make_chunk_prefill(cfg, tn, tp=None, block_size=None):
     use_kernel = bool(_flag("prefix_prefill_kernel"))
 
     def prefill(p, kcs, vcs, ids, chunk_table, cached_len, new_len,
-                chunk_pages=None):
+                chunk_pages=None, states=None, state_row=None):
         from ..kernels.ragged_attention import (
             ragged_paged_attention, ragged_paged_attention_reference)
 
@@ -1166,6 +1214,9 @@ def _make_chunk_prefill(cfg, tn, tp=None, block_size=None):
         lane = {"table": chunk_table[0], "pos": pos_ids[0],
                 "new_len": new_len[0], "block_size": block_size,
                 "pages": None if chunk_pages is None else chunk_pages[0]}
+        if states is not None:
+            states = list(states)
+            lane.update(state_row=state_row[0], fresh=cached_len[0] == 0)
         for i, layer in enumerate(model.layers):
             pre = layer.prefix
             if layer.attn is not None:
@@ -1181,12 +1232,15 @@ def _make_chunk_prefill(cfg, tn, tp=None, block_size=None):
             table_i = chunk_table if not model.window_layers \
                 else chunk_table[layer.window is not None]
             x = _k_rms(h, p[pre + "input_layernorm.weight"], eps)
-            q = _mm(x, p[pre + "self_attn.q_proj.weight"]).reshape(
+            xa = _attn_in(layer, x)
+            q = _mm(xa, p[pre + "self_attn.q_proj.weight"]).reshape(
                 1, tn, nh_l, dh)
-            k = _mm(x, p[pre + "self_attn.k_proj.weight"]).reshape(
+            k = _mm(xa, p[pre + "self_attn.k_proj.weight"]).reshape(
                 1, tn, nkv_l, dh)
-            v = _mm(x, p[pre + "self_attn.v_proj.weight"]).reshape(
+            v = _mm(xa, p[pre + "self_attn.v_proj.weight"]).reshape(
                 1, tn, nkv_l, dh)
+            if layer.attn_scales is not None:
+                k = k * layer.attn_scales[1]
             q, k = apply_rotary_emb(q, k, position_ids=pos_ids,
                                     base=layer.rope_base,
                                     scaling=layer.rope_scaling)
@@ -1231,17 +1285,25 @@ def _make_chunk_prefill(cfg, tn, tp=None, block_size=None):
                                    window=layer.window).astype(h.dtype)
             if tp is not None:
                 attn = tp.gather_heads(attn)
-            h = h + _mm(attn.reshape(1, tn, nh * dh),
-                        p[pre + "self_attn.o_proj.weight"])
+            y = _attn_out(layer, _mm(attn.reshape(1, tn, nh * dh),
+                                     p[pre + "self_attn.o_proj.weight"]))
+            if layer.mixer is not None:
+                # the mixer beside attention, on the same normed input
+                m, states[i], count = layer.mixer.window(
+                    x, p, pre, states[i], lane)
+                y = y + m
+                counts.append(count)
+            h = h + y
             x2 = _k_rms(h, p[pre + "post_attention_layernorm.weight"], eps)
             y, count = layer.mlp(x2, p, pre)
             h = h + y
             if count is not None:
                 counts.append(count)
         h = _k_rms(_streams_out(model, h), p[model.norm], eps)
+        out = (h, kvs) if states is None else (h, kvs, states)
         if counts:
-            return h, kvs, sum(counts)
-        return h, kvs
+            return out + (sum(counts),)
+        return out
 
     return prefill
 
@@ -1866,6 +1928,9 @@ class PagedKVManager:
         # the second kind of pool (set_window_rings): none by default
         self.ring_pages = self.n_rings = self._ring_layers = 0
         self._free_rings = []
+        # the fourth kind (set_state_rows): none by default
+        self.n_state_rows = self.state_row_bytes = 0
+        self._free_states = []
 
     # ---- pool byte accounting -------------------------------------------
 
@@ -1998,9 +2063,37 @@ class PagedKVManager:
         if ring:
             self._free_rings.append((ring[0] - 1) // self.ring_pages)
 
+    # ---- the fourth kind: a row of state a sequence ---------------------
+
+    def set_state_rows(self, n_rows: int, row_bytes: int) -> None:
+        """Layers that keep a state of fixed size a sequence (a recurrent
+        mixer beside attention) hold it in arrays of `n_rows` rows, one a
+        live sequence, row 0 the sink of idle rows; `row_bytes` is a row's
+        bytes over those layers. A row is handed out whole at admission and
+        never grows; the programs zero it at its sequence's first position.
+        The prefix cache knows nothing of rows."""
+        self.n_state_rows, self.state_row_bytes = int(n_rows), int(row_bytes)
+        self._free_states = list(range(self.n_state_rows - 1, 0, -1))
+
+    @property
+    def n_states_free(self) -> int:
+        return len(self._free_states)
+
+    def alloc_state(self) -> int:
+        if not self._free_states:
+            raise RuntimeError(
+                f"all {self.n_state_rows - 1} state rows are in use (one a "
+                "live sequence: admission holds to that)")
+        return self._free_states.pop()
+
+    def free_state(self, row) -> None:
+        if row:
+            self._free_states.append(row)
+
     def kv_pool_bytes(self, aggregate: bool = False) -> int:
         """Device bytes of the K/V pools of both kinds (+ int8 scale
-        arrays) this manager allocates pages of — PER CHIP by default (the number an
+        arrays) this manager allocates pages of, and of the state rows —
+        PER CHIP by default (the number an
         HBM budget constrains; at cp > 1 each chip holds only
         max_pages/cp of the fleet's pages); `aggregate=True` multiplies
         both shard counts back in (the whole-fleet footprint). Requires
@@ -2016,6 +2109,8 @@ class PagedKVManager:
             per_chip += self.window_pool_pages * self.page_bytes(
                 self.block_size, **dict(geo, n_layers=self._ring_layers,
                                         latent_bytes=0, index_bytes=0))
+        # state rows: never sharded (the engine refuses)
+        per_chip += self.n_state_rows * self.state_row_bytes
         return per_chip * geo["mp"] * cp if aggregate else per_chip
 
     @property
@@ -2416,7 +2511,8 @@ def _decode_tail(decode_step, p_dec, kcs, vcs, last_logits,
 
 
 def _make_decode_step(cfg, b, max_seq=None, kv_write=None, kv_attend=None,
-                      tp=None, kv_window=None, tables=None, block_size=None):
+                      tp=None, kv_window=None, tables=None, block_size=None,
+                      state_rows=None):
     """Single-token decode step — the per-layer transformer math shared
     by EVERY generation program (fp, quant-only, paged); only the KV
     store differs, injected via two callbacks:
@@ -2439,8 +2535,12 @@ def _make_decode_step(cfg, b, max_seq=None, kv_write=None, kv_attend=None,
     kv_attend) pair of the window layers, over their rings. A layer with
     its own attention (`ServedLayer.attn`) reads and writes its pools
     itself through the paged `tables` [B, W] of `block_size`-token pages.
-    Where a layer is routed the step returns the layers' summed counters
-    as a fourth result."""
+    A model whose layers keep state a sequence (`ServedLayer.mixer`)
+    decodes only through `decode_step(..., states)`: `states` the layers'
+    state arrays (None where a layer has no mixer), each batch row's at its
+    row of `state_rows` [B] (idle rows: the sink row 0); the step then
+    returns them as a fourth result. Where a layer is routed or has a mixer
+    the step returns the layers' summed counters last."""
     model = served_model(cfg)
     nh, nkv, dh = (cfg.num_attention_heads, cfg.num_key_value_heads,
                    model.head_dim)
@@ -2480,17 +2580,24 @@ def _make_decode_step(cfg, b, max_seq=None, kv_write=None, kv_attend=None,
                 return decode_attention(q1, kc, vc, lens)
             return gqa_decode_attention(q1, kc, vc, lens)
 
-    def decode_step(p, kcs, vcs, tok, pos):
+    def decode_step(p, kcs, vcs, tok, pos, states=None):
         """tok [B, 1] int32; pos: tokens already cached — a traced scalar
         (contiguous) or a per-sequence [B] vector (paged ragged batch;
         the [B, 1] position_ids broadcast per-example through the rope
         tables)."""
+        if model.stateful and states is None:
+            raise ValueError(
+                "the model's layers keep state a sequence: decode through "
+                "decode_step(..., states) (the serving engine does)")
         # the embedding stays dense (it's a gather, not a matmul)
         h = _embed(model, p[model.embed][tok[:, 0]][:, None, :])
         pos_ids = pos[:, None] if getattr(pos, "ndim", 0) == 1 \
             else jnp.reshape(pos, (1,))
         new_kcs, new_vcs, counts, carry = [], [], [], {}
-        lane = {"tables": tables, "pos": pos, "block_size": block_size}
+        lane = {"tables": tables, "pos": pos, "block_size": block_size,
+                "state_rows": state_rows}
+        if states is not None:
+            states = list(states)
         for i, layer in enumerate(model.layers):
             pre = layer.prefix
             if layer.attn is not None:
@@ -2505,12 +2612,15 @@ def _make_decode_step(cfg, b, max_seq=None, kv_write=None, kv_attend=None,
             write, attend = (kv_write, kv_attend) if layer.window is None \
                 else kv_window
             x = _k_rms(h, p[pre + "input_layernorm.weight"], eps)
-            q = _mm(x, p[pre + "self_attn.q_proj.weight"]).reshape(
+            xa = _attn_in(layer, x)
+            q = _mm(xa, p[pre + "self_attn.q_proj.weight"]).reshape(
                 b, 1, nh_l, dh)
-            k = _mm(x, p[pre + "self_attn.k_proj.weight"]).reshape(
+            k = _mm(xa, p[pre + "self_attn.k_proj.weight"]).reshape(
                 b, 1, nkv_l, dh)
-            v = _mm(x, p[pre + "self_attn.v_proj.weight"]).reshape(
+            v = _mm(xa, p[pre + "self_attn.v_proj.weight"]).reshape(
                 b, 1, nkv_l, dh)
+            if layer.attn_scales is not None:
+                k = k * layer.attn_scales[1]
             q, k = apply_rotary_emb(q, k, position_ids=pos_ids,
                                     base=layer.rope_base,
                                     scaling=layer.rope_scaling)
@@ -2521,8 +2631,15 @@ def _make_decode_step(cfg, b, max_seq=None, kv_write=None, kv_attend=None,
                 ctx = attend(q[:, 0], kc, vc, pos)      # [b, nh_l, dh]
             if tp is not None:
                 ctx = tp.gather_heads(ctx)              # [b, nh, dh]
-            h = h + _mm(ctx.reshape(b, 1, nh * dh),
-                        p[pre + "self_attn.o_proj.weight"])
+            y = _attn_out(layer, _mm(ctx.reshape(b, 1, nh * dh),
+                                     p[pre + "self_attn.o_proj.weight"]))
+            if layer.mixer is not None:
+                # the mixer beside attention, on the same normed input
+                m, states[i], count = layer.mixer.decode(
+                    x, p, pre, states[i], lane)
+                y = y + m
+                counts.append(count)
+            h = h + y
             x2 = _k_rms(h, p[pre + "post_attention_layernorm.weight"], eps)
             y, count = layer.mlp(x2, p, pre)
             h = h + y
@@ -2530,9 +2647,11 @@ def _make_decode_step(cfg, b, max_seq=None, kv_write=None, kv_attend=None,
                 counts.append(count)
         h = _k_rms(_streams_out(model, h), p[model.norm], eps)
         logits = head_logits(h, p)[:, -1]
+        out = (logits, new_kcs, new_vcs) if states is None \
+            else (logits, new_kcs, new_vcs, states)
         if counts:
-            return logits, new_kcs, new_vcs, sum(counts)
-        return logits, new_kcs, new_vcs
+            return out + (sum(counts),)
+        return out
 
     return decode_step
 

@@ -125,7 +125,7 @@ def _moved(direction: str, arrays) -> dict:
 
 def _counter(name: str) -> str:
     """`metrics()`'s key of a counter the programs count."""
-    return name if name.startswith("dsa_") else "moe_" + name
+    return name if name.startswith(("dsa_", "ssm_")) else "moe_" + name
 
 
 def _token_logprob(logits, tok):
@@ -198,6 +198,7 @@ class ServeRequest:
     slot: Optional[int] = None
     pages: Optional[list] = None
     ring: Optional[list] = None            # window layers' ring, if any
+    state_row: Optional[int] = None        # its row of the layers' state
     bucket: Optional[int] = None           # suffix bucket it prefilled at
     n_prefix: int = 0                      # cached prefix blocks mapped in
     cached_tokens: int = 0                 # prompt tokens served from cache
@@ -255,7 +256,13 @@ class ContinuousBatchingEngine:
     never growing (`PagedKVManager.set_window_rings`) — and serves without
     the prefix cache (`metrics()["prefix_cache_off"]`); a model with routed
     layers counts what they did inside the programs (`metrics()["moe_*"]`).
-    Both are served by the unified step and the decode chunk, one chip,
+    A model whose layers keep a state of fixed size a sequence (a mixer
+    beside attention, `ServedLayer.mixer`) holds it in a fourth kind: a row
+    a sequence, reserved at admission (`PagedKVManager.set_state_rows`),
+    read and written in place by the programs, zero at a sequence's first
+    position and carried from one prefill window to the next; it serves
+    without the prefix cache and counts its steps (`metrics()["ssm_*"]`).
+    All are served by the unified step and the decode chunk, one chip,
     bf16 pools; every other option refuses such a model by name.
 
     Scheduling policy: FIFO admission; a request is admitted when a slot
@@ -563,13 +570,17 @@ class ContinuousBatchingEngine:
         self._routed = self._model.routed
         # layers that keep their own rows (latent attention)
         self._latent = self._model.latent
+        # layers that keep a state a sequence (a mixer beside attention)
+        self._stateful = self._model.stateful
         # why the prefix cache is off, where the model turns it off
         self.prefix_cache_off = None
-        if self._window_layers or self._routed or self._latent:
+        if self._window_layers or self._routed or self._latent \
+                or self._stateful:
             what = " and ".join(
                 w for w, on in (("sliding-window", self._window_layers),
                                 ("routed-expert", self._routed),
-                                ("latent-attention", self._latent)) if on)
+                                ("latent-attention", self._latent),
+                                ("recurrent-state", self._stateful)) if on)
             for name, on in (
                     ("unified_step=False (the split prefill programs)",
                      not self.unified),
@@ -597,6 +608,13 @@ class ContinuousBatchingEngine:
                     "the model's layers keep latent rows and index keys in "
                     "pools of their own, which the prefix cache does not "
                     "share yet (ROADMAP M4)")
+            if self._stateful and self.prefix_cache:
+                self.prefix_cache = False
+                self.prefix_cache_off = (
+                    "the model's layers keep a recurrent state a sequence, "
+                    "which no cached page holds: a request that maps a "
+                    "prefix's pages could not start from its state "
+                    "(ROADMAP M5)")
         self._tp = make_serving_tp(
             cfg, self.mp,
             quantized_collectives=self.quantized_collectives,
@@ -655,14 +673,20 @@ class ContinuousBatchingEngine:
         index_bytes = sum(r[1] for r in own)
         if own:
             n_full = 0
+        # the fourth kind: a row of state a sequence, over the layers that
+        # keep one — the live slots', the one prefilling, and the sink row 0
+        n_state_rows = slots + 2 if self._stateful else 0
+        state_row_bytes = sum(l.mixer.row_bytes() for l in self._model.layers
+                              if l.mixer is not None)
         if kv_pool_bytes is not None:
             if max_pages is not None:
                 raise ValueError(
                     "pass max_pages OR kv_pool_bytes, not both")
-            # the budget is both kinds': the rings' share comes off first
-            ring_bytes = 0
+            # the budget is every kind's: the rings' and the state rows'
+            # shares come off first
+            apart_bytes = n_state_rows * state_row_bytes
             if n_rings:
-                ring_bytes = (n_rings * self._capacity_ring_pages() + 1) \
+                apart_bytes += (n_rings * self._capacity_ring_pages() + 1) \
                     * PagedKVManager.page_bytes(
                         block_size, n_layers=len(self._window_layers),
                         num_kv_heads=nkv, head_dim=dh)
@@ -673,7 +697,7 @@ class ContinuousBatchingEngine:
             # fleet's pages, so the same bytes buy cp x the FLEET page
             # count — the context-ceiling lift
             max_pages = PagedKVManager.pages_for_bytes(
-                kv_pool_bytes - ring_bytes, block_size,
+                kv_pool_bytes - apart_bytes, block_size,
                 n_layers=n_full, num_kv_heads=nkv,
                 head_dim=dh, kv_cache_dtype=self.kv_dtype,
                 mp=self.kv_shards, cp=self.cp, latent_bytes=latent_bytes,
@@ -708,6 +732,8 @@ class ContinuousBatchingEngine:
         if n_rings:
             self.mgr.set_window_rings(n_rings, self._capacity_ring_pages(),
                                       len(self._window_layers))
+        if n_state_rows:
+            self.mgr.set_state_rows(n_state_rows, state_row_bytes)
         self.scratch_page = self.mgr.alloc_pages(1)[0]  # retired rows' sink
         if self.kv_dtype == "int8":
             # (int8 pool, per-(page, kv head) f32 absmax scale) pairs —
@@ -756,6 +782,11 @@ class ContinuousBatchingEngine:
         else:
             self.kcs = [_pool() for _ in range(cfg.num_hidden_layers)]
             self.vcs = [_pool() for _ in range(cfg.num_hidden_layers)]
+        # each layer's state arrays (None where it keeps none), threaded
+        # through the served programs beside the pools and donated with them
+        self.states = [l.mixer.state(n_state_rows) if l.mixer is not None
+                       else None for l in self._model.layers] \
+            if self._stateful else []
         if self._tp is not None:
             # params per `serving_param_specs` (q/k/v columns sharded,
             # the rest — o-proj included — replicated). Logical shapes
@@ -768,6 +799,8 @@ class ContinuousBatchingEngine:
         # slot's ring (page 0 of their pools is the sink), set once a bind
         self._ring_tables = np.zeros((slots, cap), np.int32) \
             if self._window_layers else None
+        # each slot's row of the layers' state (0, the sink, when free)
+        self._state_rows = np.zeros((slots,), np.int32)
         # what the routed layers counted, summed over layers and steps
         # (the model's `counts`), for the decode lane and the prefill windows
         n = len(self._model.counts)
@@ -786,13 +819,14 @@ class ContinuousBatchingEngine:
         self._prefill_cache = {}
         self._io = self._io_layouts()
         self._decode = self._program(
-            self._build_decode_chunk(), "serve_decode_chunk", 6, 4)
+            self._build_decode_chunk(), "serve_decode_chunk", 6, 4,
+            states=True)
         # the ONE mixed prefill+decode program (ISSUE 14) — built only
         # on the unified path; its shape key is (token_budget, slots,
         # steps, kv-dtype, mp) and warm() compiles it once
         self._unified = self._program(
-            self._build_unified_step(), "serve_unified_step", 4, 2) \
-            if self.unified else None
+            self._build_unified_step(), "serve_unified_step", 4, 2,
+            states=True) if self.unified else None
         # speculative verify: one ragged window of spec_k+1 rows per
         # slot scores every draft + the pending token in a single pass
         # (models/llama._make_verify_window); built only when the
@@ -906,14 +940,15 @@ class ContinuousBatchingEngine:
 
         slots = [("toks", (b,), i32), ("lens", (b,), i32),
                  ("budgets", (b,), i32), ("live", (b,), bool)] \
-            + table("tables", (b, W))
+            + table("tables", (b, W)) \
+            + ([("state_rows", (b,), i32)] if self._stateful else [])
         outs = [("out", (b, steps), i32), ("lens", (b,), i32),
                 ("done", (b,), bool)]
 
         def counted(lanes):
             # the routed layers' counters: one row a lane
             return [("moe", (lanes, len(self._model.counts)), i32)] \
-                if self._routed else []
+                if self._model.counted else []
 
         lps = [("logprobs", (b, steps), f32)] if self.logprobs else []
         return {
@@ -926,7 +961,9 @@ class ContinuousBatchingEngine:
                             + table("chunk_table", (1, W))
                             + [("chunk_cached", (1,), i32),
                                ("chunk_len", (1,), i32)]
-                            + table("chunk_pages", (1, n_win))),
+                            + table("chunk_pages", (1, n_win))
+                            + ([("chunk_state", (1,), i32)]
+                               if self._stateful else [])),
                       _Flat(outs + [("first", (1,), i32)] + counted(2)
                             + lps + ([("first_logprob", (1,), f32)]
                                      if self.logprobs else []))),
@@ -973,7 +1010,8 @@ class ContinuousBatchingEngine:
                 "lens": np.asarray([s.length for s in self._slots],
                                    np.int32),
                 "budgets": self._budgets, "live": live,
-                "tables": self._tables, "tables_ring": self._ring_tables}
+                "tables": self._tables, "tables_ring": self._ring_tables,
+                "state_rows": self._state_rows}
 
     def _scratch_inputs(self) -> dict:
         """Inputs that aim every row of both served programs at the
@@ -982,17 +1020,19 @@ class ContinuousBatchingEngine:
         pad."""
         return {"toks": 0, "lens": 0, "budgets": 0, "live": False,
                 "override": True, "chunk_ids": 0, "chunk_cached": 0,
-                "chunk_len": 0,
+                "chunk_len": 0, "state_rows": 0, "chunk_state": 0,
                 **{name + ring: 0 if ring else self.scratch_page
                    for name in ("tables", "chunk_table", "chunk_pages")
                    for ring in ("", "_ring")}}
 
     def _release(self, req) -> None:
-        """Give back everything `req` holds in both pool kinds."""
+        """Give back everything `req` holds in every pool kind."""
         self.mgr.free(req.pages)
         req.pages = None
         self.mgr.free_ring(req.ring)
         req.ring = None
+        self.mgr.free_state(req.state_row)
+        req.state_row = None
 
     # ---- tensor-parallel plumbing (FLAGS_serving_mp) --------------------
 
@@ -1029,14 +1069,15 @@ class ContinuousBatchingEngine:
             pool = sc = P(None, self._tp.axis) if mp_shard else P()
         return (pool, sc) if self.kv_dtype == "int8" else pool
 
-    def _shard_program(self, fn, n_repl: int, n_out_repl: int):
+    def _shard_program(self, fn, n_repl: int, n_out_repl: int,
+                       states: bool = False):
         """Wrap an engine device program (signature: p, kcs, vcs,
-        *replicated) in shard_map over the serving mesh. Params follow
-        `serving_param_specs`, pools follow `_pool_entry_spec`, every
-        other input — and the leading `n_out_repl` outputs (tokens,
+        [states,] *replicated) in shard_map over the serving mesh. Params
+        follow `serving_param_specs`, pools follow `_pool_entry_spec`,
+        every other input — and the leading `n_out_repl` outputs (tokens,
         lengths, done flags) — replicates; the trailing outputs are the
-        threaded (donated) pools. Identity at mp=1: the single-chip
-        engine never touches shard_map."""
+        threaded (donated) pools [and states]. Identity at mp=1: the
+        single-chip engine never touches shard_map."""
         if self._tp is None:
             return fn
         from jax.sharding import PartitionSpec as P
@@ -1044,21 +1085,27 @@ class ContinuousBatchingEngine:
         from jax import shard_map
 
         pools = [self._pool_entry_spec()] * len(self.kcs)
-        in_specs = (self._param_specs, pools, pools) + (P(),) * n_repl
-        out_specs = (P(),) * n_out_repl + (pools, pools)
+        # the layers' states: none where the programs are sharded (the
+        # constructor refuses a model that keeps any)
+        held = (P(),) if states else ()
+        in_specs = (self._param_specs, pools, pools) + held \
+            + (P(),) * n_repl
+        out_specs = (P(),) * n_out_repl + (pools, pools) + held
         return shard_map(fn, mesh=self.mp_mesh, in_specs=in_specs,
                          out_specs=out_specs, check_vma=False)
 
-    def _program(self, fn, name: str, n_repl: int, n_out_repl: int):
+    def _program(self, fn, name: str, n_repl: int, n_out_repl: int,
+                 states: bool = False):
         """An engine device program, sharded over the serving mesh and
         jitted under the name of its ROLE: the compiler's module and
         the profiler's "XLA Modules" row read `jit_<name>`, so the pure
         decode chunk and the mixed step are two programs in a trace
         (every builder's closure is called `run`). The pools (arguments
-        1 and 2) are donated."""
-        fn = self._shard_program(fn, n_repl, n_out_repl)
+        1 and 2) are donated, and with `states` the layers' states
+        (argument 3, returned last)."""
+        fn = self._shard_program(fn, n_repl, n_out_repl, states)
         fn.__name__ = name
-        return jax.jit(fn, donate_argnums=(1, 2))
+        return jax.jit(fn, donate_argnums=(1, 2, 3) if states else (1, 2))
 
     @property
     def n_active(self) -> int:
@@ -1182,6 +1229,11 @@ class ContinuousBatchingEngine:
             # rows, index keys), 0 where no layer keeps its own
             "kv_latent_token_bytes": self.kv_token_bytes["latent"],
             "kv_index_token_bytes": self.kv_token_bytes["index"],
+            # the fourth: bytes of a sequence's row of the layers' state,
+            # and the rows (the sink's among them), 0 where no layer keeps
+            # one
+            "state_row_bytes": mgr.state_row_bytes,
+            "state_rows": mgr.n_state_rows,
             # sync-wait telemetry (what double buffering hides)
             "sync_wait_s": self.sync_wait_s,
             "blocked_syncs": self.blocked_syncs,
@@ -1554,10 +1606,12 @@ class ContinuousBatchingEngine:
                                      kv_attend=kv_attend, tp=tp,
                                      kv_window=(ring_write, ring_attend))
 
-        def make_step(tables):
+        def make_step(tables, state_rows=None):
             """Per-layer decode body for one chunk. A model whose layers
             keep their own pools reads and writes them through `tables`
-            itself (one chip, bf16: the constructor refused the rest).
+            itself (one chip, bf16: the constructor refused the rest); a
+            model whose layers keep state reads each slot's at its row of
+            `state_rows`.
             Under serving_mp
             this runs inside the shard_map body — the kv helpers and
             the attention see the LOCAL kv heads. Under serving_cp
@@ -1646,34 +1700,41 @@ class ContinuousBatchingEngine:
                                                       tables, lens_)
 
             return _make_decode_step(cfg, b, kv_write=kv_write,
-                                     kv_attend=kv_attend, tp=tp)
+                                     kv_attend=kv_attend, tp=tp,
+                                     state_rows=state_rows)
 
         return make_step
 
     def _decode_chunk_body(self):
         """`steps` decode tokens for every slot. Retired / free rows point
-        their table at the scratch page and freeze their length, so they
-        compute (fixed shape) but touch nothing live. `budgets` [slots]
-        freezes each row on-device at prompt+max_new — the guarantee that
-        a speculatively-dispatched chunk (double buffering) can never
-        write past a request's reserved pages. Returns (outputs, last
-        tokens, pools): `outputs` holds the tokens [slots, steps], the
-        lengths and the done flags; a model with routed layers adds their
-        summed MOE_COUNTS vector (`moe`), and `logprobs` the tokens'
-        log-probabilities [slots, steps]."""
+        their table at the scratch page (their state row at the sink row)
+        and freeze their length, so they compute (fixed shape) but touch
+        nothing live. `budgets` [slots] freezes each row on-device at
+        prompt+max_new — the guarantee that a speculatively-dispatched
+        chunk (double buffering) can never write past a request's
+        reserved pages. Returns (outputs, last tokens, pools, states):
+        `outputs` holds the tokens [slots, steps], the lengths and the done
+        flags; a model whose layers count adds their summed counts vector
+        (`moe`), and `logprobs` the tokens' log-probabilities [slots,
+        steps]."""
         b, steps = self.slots, self.steps
         do_sample, top_k, eos = self.do_sample, self.top_k, self.eos
         make_step = self._decode_step_maker()
-        routed, logprobs = self._routed, self.logprobs
+        counted, logprobs = self._model.counted, self.logprobs
+        stateful = self._stateful
 
-        def run(p, kcs, vcs, toks, lens, budgets, tables, live, key,
-                temperature, top_p):
-            decode_step = make_step(tables)
+        def run(p, kcs, vcs, states, toks, lens, budgets, tables, live, key,
+                temperature, top_p, state_rows=None):
+            decode_step = make_step(tables, state_rows)
 
             def step(carry, _):
-                tok, lens_, kcs_, vcs_, done, key_ = carry
-                logits, kcs_, vcs_, *count = decode_step(
-                    p, kcs_, vcs_, tok[:, None], lens_)
+                tok, lens_, kcs_, vcs_, sts_, done, key_ = carry
+                if stateful:
+                    logits, kcs_, vcs_, sts_, *count = decode_step(
+                        p, kcs_, vcs_, tok[:, None], lens_, sts_)
+                else:
+                    logits, kcs_, vcs_, *count = decode_step(
+                        p, kcs_, vcs_, tok[:, None], lens_)
                 key_, ks = jax.random.split(key_)
                 nxt = _sample_next(logits.astype(jnp.float32), ks,
                                    do_sample, temperature, top_k, top_p)
@@ -1686,23 +1747,24 @@ class ContinuousBatchingEngine:
                 else:
                     nxt = jnp.where(frozen, 0, nxt)
                 lens_ = jnp.where(frozen, lens_, lens_ + 1)
-                return (nxt, lens_, kcs_, vcs_, done, key_), (nxt, *count)
+                return (nxt, lens_, kcs_, vcs_, sts_, done, key_), \
+                    (nxt, *count)
 
             # every live row enters a chunk un-done (retire clears slots
             # at chunk end); `done` only freezes rows WITHIN the chunk
             done0 = jnp.zeros((b,), bool)
-            (tok, lens, kcs, vcs, done, _), (out, *counts) = jax.lax.scan(
-                step, (toks, lens, kcs, vcs, done0, key), None,
-                length=steps)
+            (tok, lens, kcs, vcs, states, done, _), (out, *counts) = \
+                jax.lax.scan(step, (toks, lens, kcs, vcs, states, done0, key),
+                             None, length=steps)
             res = {"out": jnp.swapaxes(out, 0, 1), "lens": lens,
                    "done": done}
-            # a routed model's steps counted what their layers did: the
+            # a counting model's steps counted what their layers did: the
             # sums ride out with the tokens the commit waits for anyway
-            if routed:
+            if counted:
                 res["moe"] = jnp.sum(counts[0], axis=0)
             if logprobs:
                 res["logprobs"] = jnp.swapaxes(counts[-1], 0, 1)
-            return res, tok, kcs, vcs
+            return res, tok, kcs, vcs, states
 
         return run
 
@@ -1714,23 +1776,24 @@ class ContinuousBatchingEngine:
         output in ONE packed vector. A double-buffered chunk's tokens and
         lengths come from the previous chunk's device carries — also
         returned — except in rows the host overrides. Returns (packed,
-        last tokens, lengths, next key, pools)."""
+        last tokens, lengths, next key, pools, states)."""
         body = self._decode_chunk_body()
         io_in, io_out = self._io["decode"]
 
-        def run(p, kcs, vcs, flat, chain_tok, chain_lens, key, temperature,
-                top_p):
+        def run(p, kcs, vcs, states, flat, chain_tok, chain_lens, key,
+                temperature, top_p):
             key, k = jax.random.split(key)
             a = io_in.unpack(flat)
             toks = jnp.where(a["override"], a["toks"], chain_tok)
             lens = jnp.where(a["override"], a["lens"], chain_lens)
-            res, tok, kcs, vcs = body(
-                p, kcs, vcs, toks, lens, a["budgets"],
+            res, tok, kcs, vcs, states = body(
+                p, kcs, vcs, states, toks, lens, a["budgets"],
                 self._table_arg(a, "tables"), a["live"], k, temperature,
-                top_p)
+                top_p, a.get("state_rows"))
             if "moe" in res:
                 res["moe"] = res["moe"][None]
-            return io_out.pack(res), tok, res["lens"], key, kcs, vcs
+            return io_out.pack(res), tok, res["lens"], key, kcs, vcs, \
+                states
 
         return run
 
@@ -1757,7 +1820,9 @@ class ContinuousBatchingEngine:
         and the next key out, the host inputs in one packed buffer and
         the host-visible outputs — the decode lane's, the first token,
         its log-probability — in one packed vector
-        (`_io_layouts()["mixed"]`). Returns (packed, next key, pools)."""
+        (`_io_layouts()["mixed"]`). A model whose layers keep state
+        carries the window's sequence's from its row (`chunk_state`).
+        Returns (packed, next key, pools, states)."""
         cfg, bs = self.cfg, self.block_size
         tn = self.token_budget
         n_win = tn // bs
@@ -1765,30 +1830,35 @@ class ContinuousBatchingEngine:
         decode_chunk = self._decode_chunk_body()
         chunk_body = _make_chunk_prefill(cfg, tn, tp=self._tp,
                                          block_size=bs)
-        latent = self._latent
+        latent, stateful = self._latent, self._stateful
         head_logits = _make_head_logits(cfg)
         scatter = self._page_scatter(1, n_win)
         window_layers = self._window_layers
         io_in, io_out = self._io["mixed"]
 
-        def run(p, kcs, vcs, flat, key, temperature, top_p):
+        def run(p, kcs, vcs, states, flat, key, temperature, top_p):
             key, k = jax.random.split(key)
             _, kd, ks = jax.random.split(k, 3)
             a = io_in.unpack(flat)
             chunk_len = a["chunk_len"]
             chunk_pages = self._table_arg(a, "chunk_pages")
             # ---- decode lane: the split decode chunk, verbatim ----
-            res, _, kcs, vcs = decode_chunk(
-                p, kcs, vcs, a["toks"], a["lens"], a["budgets"],
+            res, _, kcs, vcs, states = decode_chunk(
+                p, kcs, vcs, states, a["toks"], a["lens"], a["budgets"],
                 self._table_arg(a, "tables"), a["live"], kd, temperature,
-                top_p)
+                top_p, a.get("state_rows"))
             # ---- chunk lane: one ragged prefill window ----
             # layers with pools of their own write the window's rows
             # themselves, at its pages (kcs / vcs replaced in place)
+            extra = {"chunk_pages": chunk_pages} if latent else {}
+            if stateful:
+                extra.update(states=states, state_row=a["chunk_state"])
             h, kvs, *chunk_count = chunk_body(
                 p, kcs, vcs, a["chunk_ids"],
                 self._table_arg(a, "chunk_table"), a["chunk_cached"],
-                chunk_len, **({"chunk_pages": chunk_pages} if latent else {}))
+                chunk_len, **extra)
+            if stateful:
+                states, *chunk_count = chunk_count
             for i, kv in enumerate(kvs):
                 if kv is None:
                     continue
@@ -1812,7 +1882,7 @@ class ContinuousBatchingEngine:
                 res["moe"] = jnp.stack([res["moe"], *chunk_count])
             if "first_logprob" in io_out.names:
                 res["first_logprob"] = _token_logprob(logits, res["first"])
-            return io_out.pack(res), key, kcs, vcs
+            return io_out.pack(res), key, kcs, vcs, states
 
         return run
 
@@ -2052,12 +2122,12 @@ class ContinuousBatchingEngine:
             # chunk_len 0 (every window row is pad — the ragged kernel
             # emits zeros, the scatter hits only the scratch page)
             flat, _ = self._put(self._io["mixed"][0], scratch)
-            _, self._key, self.kcs, self.vcs = self._unified(
-                self.p, self.kcs, self.vcs, flat, self._key,
+            _, self._key, self.kcs, self.vcs, self.states = self._unified(
+                self.p, self.kcs, self.vcs, self.states, flat, self._key,
                 *self._sampling())
         flat, _ = self._put(self._io["decode"][0], scratch)
-        *_, self._key, self.kcs, self.vcs = self._decode(
-            self.p, self.kcs, self.vcs, flat, self._no_chain,
+        *_, self._key, self.kcs, self.vcs, self.states = self._decode(
+            self.p, self.kcs, self.vcs, self.states, flat, self._no_chain,
             self._no_chain, self._key, *self._sampling())
         if self._verify is not None:
             # the speculative verify window: every slot all-scratch
@@ -2099,8 +2169,9 @@ class ContinuousBatchingEngine:
 
     def _decode_example_args(self):
         flat, _ = self._put(self._io["decode"][0], self._scratch_inputs())
-        return (self.p, self.kcs, self.vcs, flat, self._no_chain,
-                self._no_chain, jax.random.PRNGKey(0), *self._sampling())
+        return (self.p, self.kcs, self.vcs, self.states, flat,
+                self._no_chain, self._no_chain, jax.random.PRNGKey(0),
+                *self._sampling())
 
     def _prefill_example_args(self, key):
         """Warm()-shaped example args for a `_prefill_cache` entry —
@@ -2121,8 +2192,8 @@ class ContinuousBatchingEngine:
 
     def _unified_example_args(self):
         flat, _ = self._put(self._io["mixed"][0], self._scratch_inputs())
-        return (self.p, self.kcs, self.vcs, flat, jax.random.PRNGKey(0),
-                *self._sampling())
+        return (self.p, self.kcs, self.vcs, self.states, flat,
+                jax.random.PRNGKey(0), *self._sampling())
 
     def _verify_example_args(self):
         b, W = self.slots, self.table_width
@@ -2759,6 +2830,7 @@ class ContinuousBatchingEngine:
         if req.ring:
             self._ring_tables[slot_id] = self._ring_table(
                 req.ring, self.table_width)
+        self._state_rows[slot_id] = req.state_row or 0
         self._tokens[slot_id] = first
         self._budgets[slot_id] = len(req.prompt) + req.max_new
         self._override[slot_id] = True
@@ -2858,6 +2930,8 @@ class ContinuousBatchingEngine:
             return []
         if self._window_layers and not self.mgr.n_rings_free:
             return []
+        if self._stateful and not self.mgr.n_states_free:
+            return []
         tr, mt = self._tracer, self._metrics
         with self._commit_lock:
             self._check_owner(token)
@@ -2869,6 +2943,8 @@ class ContinuousBatchingEngine:
             req.pages = cached + priv
             # both kinds are reserved whole here: the ring never grows
             req.ring = self.mgr.alloc_ring() if self._window_layers \
+                else None
+            req.state_row = self.mgr.alloc_state() if self._stateful \
                 else None
             req.n_prefix = len(cached)
             req.cached_tokens = len(cached) * self.block_size
@@ -2917,7 +2993,8 @@ class ContinuousBatchingEngine:
             tbl[0, :len(req.pages)] = req.pages
             window = {"chunk_ids": ids, "chunk_table": tbl,
                       "chunk_cached": done, "chunk_len": this_chunk,
-                      "chunk_pages": win_pages}
+                      "chunk_pages": win_pages,
+                      "chunk_state": req.state_row or 0}
             if req.ring:
                 # the window's rows in the ring: pages past the chunk's
                 # end take pad rows, which go to the sink as the full
@@ -2957,9 +3034,9 @@ class ContinuousBatchingEngine:
                     with (_NULL_SPAN if tr is None else tr.span(
                             "decode.enqueue", chunk=chunk)):
                         res = self._unified(self.p, self.kcs, self.vcs,
-                                            flat, self._key,
+                                            self.states, flat, self._key,
                                             *self._sampling())
-                    packed, self._key, self.kcs, self.vcs = res
+                    packed, self._key, self.kcs, self.vcs, self.states = res
                     self.device_steps += 1
                     self.prefill_chunks += 1
                     # a mixed step is authoritative host state — never
@@ -3156,6 +3233,7 @@ class ContinuousBatchingEngine:
         self._tables[slot_id] = self.scratch_page
         if self._ring_tables is not None:
             self._ring_tables[slot_id] = 0
+        self._state_rows[slot_id] = 0
         self._tokens[slot_id] = 0
         self._budgets[slot_id] = 0
         self._override[slot_id] = True
@@ -3200,10 +3278,11 @@ class ContinuousBatchingEngine:
                     else (self._no_chain, self._no_chain)
                 with (_NULL_SPAN if tr is None else tr.span(
                         "decode.enqueue", chunk=chunk)):
-                    res = self._decode(self.p, self.kcs, self.vcs, flat,
-                                       *carries, self._key,
-                                       *self._sampling())
-                packed, tok, new_lens, self._key, self.kcs, self.vcs = res
+                    res = self._decode(self.p, self.kcs, self.vcs,
+                                       self.states, flat, *carries,
+                                       self._key, *self._sampling())
+                (packed, tok, new_lens, self._key, self.kcs, self.vcs,
+                 self.states) = res
                 self.device_steps += 1
                 if chain:
                     self._chain_tok = tok
@@ -3807,6 +3886,7 @@ class ContinuousBatchingEngine:
         self._tables[slot_id] = self.scratch_page
         if self._ring_tables is not None:
             self._ring_tables[slot_id] = 0
+        self._state_rows[slot_id] = 0
         self._tokens[slot_id] = 0
         self._budgets[slot_id] = 0
         self._override[slot_id] = True

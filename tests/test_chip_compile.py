@@ -648,6 +648,81 @@ def test_hy4_served_programs(hy4_engine, one_chip, program):
                                   "unified": "unified_step"}[program])
 
 
+# the Mamba-2 mixer of `falconh1-chat-sat` at its widths: 32 heads of 128 over
+# 2 groups of d_state 256, float32 state rows (64 slots, the one prefilling
+# and the sink), windows of 512 tokens in chunks of 128
+FS, FROWS, FH, FN, FP, FG, FTN = 64, 66, 32, 256, 128, 2, 512
+FSTATE = ((FROWS, FH, FN, FP), F32)
+
+
+def _state_copied(text, shape):
+    """Mentions of a copy whose result has the state's shape: a state
+    copied whole, in whatever layout."""
+    dims = ",".join(str(n) for n in shape)
+    return re.findall(rf"= f32\[{dims}\]\{{[^}}]*\}} copy\(", text)
+
+
+def _compile_donating_state(fn, one_chip, *shapes):
+    """`_compile`, the state (the first argument) donated as the engine's
+    programs donate it: the kernels then update it where it lies."""
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    text = jax.jit(fn, donate_argnums=0).lower(*args).compile().as_text()
+    assert text.count("tpu_custom_call") >= 1, "no Mosaic kernel in program"
+    return text
+
+
+def test_ssd_kernels(for_chip, one_chip):
+    ssd = _mod("ssd")
+    text = _compile_donating_state(
+        ssd.ssd_decode, one_chip, FSTATE, ((FS,), I32), ((FS, FH, FP), F32),
+        ((FS, FH), F32), ((FS, FG, FN), F32), ((FS, FG, FN), F32))
+    _labels(text, "ssd_decode")
+    assert not _state_copied(text, FSTATE[0])
+    text = _compile_donating_state(
+        lambda *a: ssd.ssd_scan(*a, chunk=128), one_chip, FSTATE,
+        ((1,), I32), ((1,), I32), ((1, FH, FTN, FP), F32),
+        ((1, FH, FTN), F32), ((1, FG, FTN, FN), F32), ((1, FG, FTN, FN), F32))
+    _labels(text, "ssd_scan")
+    assert not _state_copied(text, FSTATE[0])
+
+
+@pytest.fixture
+def falcon_engine(for_chip):
+    """An engine over one layer of the published block (attention beside
+    the Mamba-2 mixer, every width and multiplier as published), 16 slots
+    and a pool of 128 pages (the programs take any), its parameters shapes
+    alone."""
+    from paddle_tpu.models.falcon_h1 import (FalconH1Config, param_dtype,
+                                             serving_param_shapes)
+    from paddle_tpu.serving import ContinuousBatchingEngine
+
+    cfg = FalconH1Config(num_hidden_layers=1)
+    p = {k: jax.ShapeDtypeStruct(v, param_dtype(k, BF))
+         for k, v in serving_param_shapes(cfg).items()}
+    return ContinuousBatchingEngine(
+        cfg, p, slots=16, max_prompt_len=1536, max_new_tokens=1024,
+        token_budget=FTN, block_size=PAGE, prompt_bucket=PAGE, max_pages=129,
+        logprobs=True)
+
+
+@pytest.mark.parametrize("program", ["decode", "unified"])
+def test_falcon_served_programs(falcon_engine, one_chip, program):
+    """Both served programs of `falconh1-chat-sat`: the recurrence's kernels
+    beside the attention kernels and the in-place commit, and no pool or
+    state row copied whole."""
+    eng = falcon_engine
+    text = _served_program(eng, program, one_chip)
+    _labels(text, "ssd_decode", "decode_attention", "kv_commit")
+    if program == "unified":
+        _labels(text, "ssd_scan", "ragged_attention")
+    assert not _pools_in_another_layout(text, eng)
+    for state in jax.tree.leaves(eng.states):
+        assert not _state_copied(text, state.shape), state.shape
+    assert text.startswith(
+        "HloModule jit_serve_" + {"decode": "decode_chunk",
+                                  "unified": "unified_step"}[program])
+
+
 def test_swiglu_fused_refuses_the_1b_mlp_shape():
     """K 2048, F 5504 is not 512-tileable: a caller who asked for the kernel
     gets an error, never the XLA form under the kernel's name."""

@@ -923,7 +923,7 @@ def test_transfers_are_the_programs_arguments_and_outputs(engine):
             continue
         io_in, io_out = eng._io[kind]
         packed_out = jax.eval_shape(fn, *args)[0]
-        assert args[3].shape == (io_in.size,) and args[3].dtype == np.int32
+        assert args[4].shape == (io_in.size,) and args[4].dtype == np.int32
         assert packed_out.shape == (io_out.size,) \
             and packed_out.dtype == np.int32
         want[kind] = ((1, 4 * io_in.size), (1, 4 * io_out.size))

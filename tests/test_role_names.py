@@ -209,6 +209,18 @@ CASES = {
         lambda *a: _mod("sparse_attention").latent_commit(*a),
         [((80, 1, 384), I32), ((2, 384), I32), ((2,), I32)],
         {"latent_commit": "latent_commit"}),
+    # the Mamba-2 mixer's recurrence over float32 state rows
+    "ssd_decode": (
+        lambda *a: _mod("ssd").ssd_decode(*a),
+        [((10, 32, 256, 128), F32), LENS, ((B, 32, 128), F32),
+         ((B, 32), F32), ((B, 2, 256), F32), ((B, 2, 256), F32)],
+        {"ssd_decode": "ssd_decode"}),
+    "ssd_scan": (
+        lambda *a: _mod("ssd").ssd_scan(*a, chunk=128),
+        [((10, 32, 256, 128), F32), ((1,), I32), ((1,), I32),
+         ((1, 32, 512, 128), F32), ((1, 32, 512), F32),
+         ((1, 2, 512, 256), F32), ((1, 2, 512, 256), F32)],
+        {"ssd_scan": "ssd_scan"}),
 }
 
 
@@ -260,7 +272,7 @@ def test_no_pallas_call_in_kernels_lacks_a_name():
                       for node in ast.walk(tree)
                       if isinstance(node, ast.Call)
                       and getattr(node.func, "attr", "") == "pallas_call"]
-    assert len(calls) == 23     # the call sites CASES covers
+    assert len(calls) == 25     # the call sites CASES covers
     assert [c[:2] for c in calls if "name" not in c[2]] == []
 
 

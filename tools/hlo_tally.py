@@ -6,9 +6,10 @@ run — by opcode, result shape and whether the fusion they call holds a convolu
 as `fusion bf16[2048]` in a device trace's labels). A `copy` keeps its result's layout in the tally: a pool copied
 whole between two layouts is `copy bf16[576,8,64,128]{3,1,2,0...}`.
 
-A train cell gives its `jit_train_step`; a serving cell (drivers `serve`, `serve_moe`) gives every program of the
-engine's `_program_inventory()` (`jit_serve_decode_chunk`, `jit_serve_unified_step`), the engine built over
-parameter SHAPES with the deployment's sizes as the driver passes them.
+A train cell gives its `jit_train_step`; a serving cell (drivers `serve`, `serve_moe`, `serve_hy4`, `serve_falconh1`)
+gives every program of the engine's `_program_inventory()` (`jit_serve_decode_chunk`, `jit_serve_unified_step`), the
+engine built over parameter SHAPES, in the types they are served in, with the deployment's sizes as the driver passes
+them (its pools and state rows are made on the host at the cell's size).
 python <repo>/tools/hlo_tally.py <cell> [min count to print] [layers, to compile a shallower model faster]"""
 import collections, os, re, sys
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
@@ -54,18 +55,33 @@ elif driver == "train_afmoe":
 else:   # a serving cell: the engine over parameter shapes, sized as the cell's driver sizes it
     from paddle_tpu.serving import ContinuousBatchingEngine
     sizes = dict(slots=dep["slots"], max_prompt_len=dep["max_prompt_len"], max_new_tokens=dep["max_new_tokens"])
+    dtype_of = lambda name: jnp.bfloat16
     if driver == "serve_moe":
         from benchmark.drivers import serve_moe
         from paddle_tpu.models.mellum import serving_param_shapes
         cfg = serve_moe.model_config(m, "bfloat16")
         shapes = serving_param_shapes(cfg)
         sizes.update(token_budget=dep["token_budget"], max_pages=dep["kv_pool_tokens"] // 64 + 1, logprobs=True)
+    elif driver == "serve_hy4":
+        from benchmark.drivers import serve_hy4
+        from paddle_tpu.models.hy_v4 import param_dtype, serving_param_shapes
+        cfg = serve_hy4.model_config(m, "bfloat16")
+        shapes, dtype_of = serving_param_shapes(cfg), lambda name: param_dtype(name, jnp.bfloat16)
+        block = dep["block_size"]
+        sizes.update(token_budget=dep["token_budget"], block_size=block, prompt_bucket=block,
+                     max_pages=dep["kv_pool_tokens"] // block + 1, logprobs=True)
+    elif driver == "serve_falconh1":
+        from benchmark.drivers import serve_falconh1
+        from paddle_tpu.models.falcon_h1 import param_dtype, serving_param_shapes
+        cfg = serve_falconh1.model_config(m, "bfloat16")
+        shapes, dtype_of = serving_param_shapes(cfg), lambda name: param_dtype(name, jnp.bfloat16)
+        sizes = serve_falconh1.engine_args(m)
     else:
         from benchmark import arith
         from benchmark.drivers import serve
         cfg, shapes = serve.llama_config(m, "bfloat16"), serve.weight_shapes(m)
         sizes.update(kv_pool_bytes=dep["kv_pool_tokens"] * arith.kv_bytes_per_token(m, 2))
-    eng = ContinuousBatchingEngine(cfg, {k: jax.ShapeDtypeStruct(v, jnp.bfloat16) for k, v in shapes.items()}, **sizes)
+    eng = ContinuousBatchingEngine(cfg, {k: jax.ShapeDtypeStruct(v, dtype_of(k)) for k, v in shapes.items()}, **sizes)
     programs = [("." + name, fn, abstract(args)) for name, fn, args in eng._program_inventory()]
 jax.default_backend = lambda: "tpu"
 
